@@ -164,14 +164,17 @@ async def run_node(
             validator.network_syncer.await_completion()
         )
         term_wait = asyncio.ensure_future(term.wait())
+        warmup_failure = asyncio.ensure_future(validator.warmup_failure())
         timeout = exit_after if exit_after > 0 else None
         done, pending = await asyncio.wait(
-            (completion, term_wait),
+            (completion, term_wait, warmup_failure),
             timeout=timeout,
             return_when=asyncio.FIRST_COMPLETED,
         )
         for task in pending:
             task.cancel()
+        if warmup_failure in done:
+            warmup_failure.result()  # raises the warm-up's error
         if completion in done:
             completion.result()  # a node that died with an error must raise
         else:
@@ -404,6 +407,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     vs.add_argument("--metrics-port", type=int, default=None,
                     help="expose /metrics + /healthz (queue depth, "
                     "in-flight per connection, dispatch sizes, padding)")
+    vs.add_argument("--devices", type=int, default=None,
+                    help="shard batches over this many of the host's chips "
+                    "(default: all of them; 1 = one chip, no mesh)")
 
     f = sub.add_parser(
         "fleet",
@@ -477,7 +483,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         keys = None
         if args.committee_path:
             keys = Committee.load(args.committee_path).public_key_bytes()
-        run_service(args.socket, keys, metrics_port=args.metrics_port)
+        run_service(args.socket, keys, metrics_port=args.metrics_port,
+                    devices=args.devices)
         return 0
     if args.command == "orchestrator":
         return run_orchestrator(args)
@@ -804,7 +811,13 @@ def run_orchestrator(args) -> int:
         )
         for path in written:
             print(f"wrote {path}")
-    return 0
+    for died in orchestrator.unexpected_exits:
+        print(
+            f"run {died['run']}: {died['process']} exited on its own "
+            f"(code {died['exit_code']})",
+            file=sys.stderr,
+        )
+    return 1 if orchestrator.unexpected_exits else 0
 
 
 if __name__ == "__main__":

@@ -60,7 +60,10 @@ class PublicKey:
     def __init__(self, raw: bytes) -> None:
         if len(raw) != PUBLIC_KEY_SIZE:
             raise ValueError(f"public key must be {PUBLIC_KEY_SIZE} bytes")
-        self.bytes = raw
+        # Any bytes-like buffer is accepted (the verifier service hands over
+        # memoryviews of its request frames); ``cryptography`` loads keys
+        # from ``bytes`` only.  No copy when ``raw`` already is one.
+        self.bytes = bytes(raw)
         self._key: Optional[Ed25519PublicKey] = None
 
     def _loaded(self) -> Ed25519PublicKey:

@@ -36,12 +36,17 @@ class Orchestrator:
         self.scrape_interval_s = scrape_interval_s
         self.workload = workload
         self.collections: List[MeasurementsCollection] = []
+        # Processes that ended on their own (not by the fault schedule), as
+        # {"run": i, "process": name, "exit_code": rc}: a fleet that lost a
+        # node or its verifier service is a failed run, whatever the
+        # survivors measured.
+        self.unexpected_exits: List[dict] = []
 
     async def run_benchmarks(self) -> List[MeasurementsCollection]:
         os.makedirs(self.results_dir, exist_ok=True)
         run_index = 0
         while (parameters := self.generator.next_parameters()) is not None:
-            collection = await self._run_one(parameters)
+            collection = await self._run_one(parameters, run_index)
             self.collections.append(collection)
             collection.save(
                 os.path.join(self.results_dir, f"measurements-{run_index}.json")
@@ -50,7 +55,9 @@ class Orchestrator:
             run_index += 1
         return self.collections
 
-    async def _run_one(self, parameters: BenchmarkParameters) -> MeasurementsCollection:
+    async def _run_one(
+        self, parameters: BenchmarkParameters, run_index: int = 0
+    ) -> MeasurementsCollection:
         await self.runner.cleanup()
         await self.runner.configure(parameters.nodes, parameters.load)
         for authority in range(parameters.nodes):
@@ -102,5 +109,9 @@ class Orchestrator:
                     await self.runner.kill_node(node)
                 for node in to_boot:
                     await self.runner.boot_node(node)
+        self.unexpected_exits.extend(
+            {"run": run_index, "process": name, "exit_code": code}
+            for name, code in self.runner.unexpected_exits().items()
+        )
         await self.runner.cleanup()
         return collection

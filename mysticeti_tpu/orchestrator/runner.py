@@ -14,9 +14,11 @@ into cloud instances over libssh2 and runs binaries under tmux; here the
 from __future__ import annotations
 
 import asyncio
+import json
 import os
 import signal
 import sys
+import time
 from typing import Dict, List, Optional
 
 from ..cli import benchmark_genesis
@@ -43,6 +45,12 @@ class Runner:
         hosts."""
         return None
 
+    def unexpected_exits(self) -> Dict[str, int]:
+        """Exit code of every fleet process that ended without the runner
+        having stopped it (``kill_node``/``cleanup``); empty when the runner
+        cannot observe its processes."""
+        return {}
+
     async def cleanup(self) -> None:
         raise NotImplementedError
 
@@ -63,6 +71,21 @@ async def _http_get_metrics(host: str, port: int, timeout: float = 5.0,
         return None
 
 
+STOP_GRACE_S = 15.0
+
+
+async def _stop_process(proc, grace_s: float = STOP_GRACE_S) -> int:
+    """SIGTERM, wait, SIGKILL after ``grace_s``; returns the exit code."""
+    if proc.returncode is None:
+        proc.send_signal(signal.SIGTERM)
+        try:
+            await asyncio.wait_for(proc.wait(), grace_s)
+        except asyncio.TimeoutError:
+            proc.send_signal(signal.SIGKILL)
+            await proc.wait()
+    return proc.returncode
+
+
 # One orchestration coroutine drives start/run/stop sequentially; the
 # lifecycle fields never see a concurrent writer, so read-await-write
 # spans in these methods cannot interleave.
@@ -74,8 +97,12 @@ class LocalProcessRunner(Runner):
         tps_per_node: int = 100,
         transaction_size: int = 512,
         verifier: str = "cpu",
+        service_devices: Optional[int] = None,
     ) -> None:
         self.working_dir = working_dir
+        # Chips the fleet's verifier service shards over (None = all the
+        # host shows): the deployment's mapping onto one chip or one host.
+        self.service_devices = service_devices
         self.tps_per_node = tps_per_node
         self.transaction_size = transaction_size
         self.verifier = verifier
@@ -85,6 +112,16 @@ class LocalProcessRunner(Runner):
         self._host_sampler = None
         self._verifier_service: Optional[asyncio.subprocess.Process] = None
         self._service_socket: Optional[str] = None
+        # What the warmed service said about itself: the platform HELLO_OK
+        # advertised, its device/kernel report (verifier_service.py writes
+        # it next to the socket) and spawn->warm seconds.  The service is
+        # the only process of a fleet that may touch the chip, so this is
+        # the one place a launcher learns what device the run is on.
+        self.service_backend: Optional[str] = None
+        self.service_report: Optional[dict] = None
+        self.service_warm_seconds: Optional[float] = None
+        # Exit codes seen by cleanup(), by process name (chip_smoke.py).
+        self.exit_codes: Dict[str, int] = {}
 
     async def configure(self, committee_size: int, load_tx_s: int = 0) -> None:
         self.committee_size = committee_size
@@ -106,10 +143,9 @@ class LocalProcessRunner(Runner):
             os.path.join(self.working_dir, "parameters.yaml")
         )
         self._assert_ports_free()
-        if (
-            self.verifier.startswith("tpu")
-            and not os.environ.get("MYSTICETI_NO_VERIFIER_SERVICE")
-        ):
+        if self.verifier.startswith("tpu"):
+            # Always: a chip belongs to one process, so N validators each
+            # with a JAX runtime of their own cannot share it.
             await self._start_verifier_service()
 
     async def _start_verifier_service(self) -> None:
@@ -122,11 +158,15 @@ class LocalProcessRunner(Runner):
         self._service_socket = os.path.join(
             os.path.abspath(self.working_dir), "verifier.sock"
         )
-        # A previous run's cleanup SIGKILLs the service, skipping its own
-        # unlink — a stale socket file would satisfy the exists() wait below
-        # before the fresh process has bound it.
-        if os.path.exists(self._service_socket):
-            os.unlink(self._service_socket)
+        # A service that had to be SIGKILLed skipped its own unlink — a
+        # stale socket file would satisfy the exists() wait below before
+        # the fresh process has bound it.
+        from ..verifier_service import report_path
+
+        for stale in (self._service_socket, report_path(self._service_socket)):
+            if os.path.exists(stale):
+                os.unlink(stale)
+        started = time.monotonic()
         log = open(os.path.join(self.working_dir, "verifier-service.log"), "ab")
         env = dict(os.environ)
         env.pop("MYSTICETI_VERIFIER_SOCKET", None)  # the service IS the backend
@@ -139,6 +179,11 @@ class LocalProcessRunner(Runner):
             self._service_socket,
             "--committee-path",
             os.path.join(self.working_dir, "committee.yaml"),
+            *(
+                ["--devices", str(self.service_devices)]
+                if self.service_devices is not None
+                else []
+            ),
             env=env,
             stdout=log,
             stderr=log,
@@ -148,14 +193,14 @@ class LocalProcessRunner(Runner):
             await self._await_service_warm()
         except BaseException:
             # A failed boot must not leak the child: an orphaned service
-            # would hold the accelerator and contend with the next run's
-            # service for the chip.
+            # would hold the chip against the next run's service.
             service, self._verifier_service = self._verifier_service, None
             self._service_socket = None
-            if service is not None and service.returncode is None:
-                service.send_signal(signal.SIGKILL)
-                await service.wait()
+            if service is not None:
+                await _stop_process(service)
             raise
+        self.service_warm_seconds = round(time.monotonic() - started, 3)
+        self._read_service_report()
 
     async def _await_service_warm(self) -> None:
         # The socket appears as soon as the listener is up.
@@ -190,6 +235,7 @@ class LocalProcessRunner(Runner):
         for _ in range(50):
             try:
                 await loop.run_in_executor(None, probe.warmup)
+                self.service_backend = probe.advertised_backend
                 return
             except (ConnectionError, OSError):
                 # Bound but briefly unready, or unlink/bind race: retry
@@ -201,6 +247,15 @@ class LocalProcessRunner(Runner):
                     )
                 await asyncio.sleep(0.2)
         raise RuntimeError("verifier service never became warm")
+
+    def _read_service_report(self) -> None:
+        from ..verifier_service import report_path
+
+        if self._service_socket and os.path.exists(
+            report_path(self._service_socket)
+        ):
+            with open(report_path(self._service_socket)) as f:
+                self.service_report = json.load(f)
 
     def _assert_ports_free(self) -> None:
         """Fail fast when another fleet holds our ports: a node that cannot
@@ -278,19 +333,49 @@ class LocalProcessRunner(Runner):
             except ImportError:  # no psutil on this host: no host series
                 return None
         pids = {
-            f"node-{a}": proc.pid
-            for a, proc in self.processes.items()
-            if proc.returncode is None
+            name: pid for name, pid in self.live_pids().items()
+            if name.startswith("node-")
         }
         return self._host_sampler.sample(pids)
 
+    def live_pids(self) -> Dict[str, int]:
+        """pid of every fleet process still running, the service included."""
+        procs = {f"node-{a}": p for a, p in self.processes.items()}
+        if self._verifier_service is not None:
+            procs["verifier-service"] = self._verifier_service
+        return {
+            name: p.pid for name, p in procs.items() if p.returncode is None
+        }
+
+    def unexpected_exits(self) -> Dict[str, int]:
+        # kill_node and cleanup pop what they stop, so whatever is still
+        # registered and has an exit code ended on its own.
+        exits = {
+            f"node-{a}": proc.returncode
+            for a, proc in self.processes.items()
+            if proc.returncode is not None
+        }
+        service = self._verifier_service
+        if service is not None and service.returncode is not None:
+            exits["verifier-service"] = service.returncode
+        return exits
+
     async def cleanup(self) -> None:
-        for authority in list(self.processes):
-            await self.kill_node(authority)
+        """Stop the fleet in order: SIGTERM (a node closes its WAL and
+        flushes its telemetry, the service lets go of the chip), SIGKILL only
+        for what is still there after the grace period."""
+        procs = {f"node-{a}": p for a, p in self.processes.items()}
+        self.processes.clear()
         service, self._verifier_service = self._verifier_service, None
-        if service is not None and service.returncode is None:
-            service.send_signal(signal.SIGKILL)
-            await service.wait()
+        if service is not None:
+            procs["verifier-service"] = service
+        codes = await asyncio.gather(
+            *(_stop_process(p) for p in procs.values())
+        )
+        self.exit_codes.update(zip(procs, codes))
+        # The service rewrites its report as it stops: dispatch counts now
+        # cover the whole run.
+        self._read_service_report()
 
 
 class SshRunner(Runner):

@@ -17,23 +17,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as PSpec
-
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.8
-
-    _REP_KW = "check_vma"
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _REP_KW = "check_rep"
-
-
-def shard_map(f, **kwargs):
-    # The replication-check kwarg was renamed check_rep -> check_vma; we
-    # disable it either way (the psum'd total is intentionally replicated).
-    kwargs[_REP_KW] = kwargs.pop("check_rep")
-    return _shard_map(f, **kwargs)
 
 from ..ops import ed25519 as E
 
@@ -66,12 +51,14 @@ def sharded_verify_kernel(mesh: Mesh):
         total = jax.lax.psum(jnp.sum(ok.astype(jnp.int32)), "batch")
         return ok, total
 
+    # check_vma stays off in every kernel here: the psum'd total is
+    # intentionally replicated.
     sharded = shard_map(
         _shard_body,
         mesh=mesh,
         in_specs=(spec,) * 7,
         out_specs=(spec, PSpec()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded)
 
@@ -101,6 +88,19 @@ def sharded_verify_batch(
 # One compiled kernel per (mesh, flavor) — rebuilding the shard_map wrapper on
 # every dispatch would recompile each time.
 _KERNEL_CACHE: dict = {}
+
+
+def mesh_lanes(mesh: Mesh, bucket: int) -> int:
+    """Lanes a ``bucket``-sized chunk occupies on the mesh.  With the Pallas
+    ladder every shard holds at least one full tile: the 256 bucket over four
+    chips would otherwise leave 64-lane shards, under the 128-lane register
+    width the kernel's limb-major layout is built on.  The extra lanes are
+    host_ok=0 padding and run in parallel, one tile per chip."""
+    if E._backend() != "pallas":
+        return bucket
+    from ..ops import ed25519_pallas as PK
+
+    return max(bucket, PK.default_tile() * mesh.devices.size)
 
 
 def _cached_fused_kernel(mesh: Mesh):
@@ -133,7 +133,7 @@ def _cached_fused_kernel(mesh: Mesh):
                 mesh=mesh,
                 in_specs=(spec,) * 3,
                 out_specs=(spec, PSpec()),
-                check_rep=False,
+                check_vma=False,
             )
         )
     return _KERNEL_CACHE[key]
@@ -170,7 +170,7 @@ def _cached_indexed_kernel(mesh: Mesh):
                 mesh=mesh,
                 in_specs=(spec, PSpec()),
                 out_specs=(spec, PSpec()),
-                check_rep=False,
+                check_vma=False,
             )
         )
     return _KERNEL_CACHE[key]
@@ -197,16 +197,17 @@ def dispatch_sharded_indexed(
     # is part of the sharded program) but not fetched: padded lanes are
     # host_ok=False, so the global count equals the host-side sum of the
     # combined single fetch — one round-trip instead of 2 per chunk.
-    handles = [
-        (
+    handles = []
+    for start, count, b in E.iter_buckets(n):
+        lanes = mesh_lanes(mesh, b)
+        E._note_kernel("mesh-indexed", lanes, E._backend())
+        handles.append((
             count,
             kernel(
-                jnp.asarray(E._pad_to(blob[start : start + count], b)),
+                jnp.asarray(E._pad_to(blob[start : start + count], lanes)),
                 table.words,
             )[0],
-        )
-        for start, count, b in E.iter_buckets(n)
-    ]
+        ))
     patches = []
     if not known.all():
         stragglers = np.flatnonzero(~known)
@@ -257,17 +258,18 @@ def dispatch_sharded_fused(
     # overlap policy as ops.ed25519.dispatch_blob_chunks.  The psum total is
     # compiled (the ICI collective stays in the program) but recomputed from
     # the combined fetch: padded lanes are host_ok=False, so the sums agree.
-    handles = [
-        (
+    handles = []
+    for start, count, b in E.iter_buckets(n):
+        lanes = mesh_lanes(mesh, b)
+        E._note_kernel("mesh-fused", lanes, E._backend())
+        handles.append((
             count,
             kernel(
-                jnp.asarray(E._pad_to(msg_words[start : start + count], b)),
-                jnp.asarray(E._pad_to(s_words[start : start + count], b)),
-                jnp.asarray(E._pad_to(host_ok[start : start + count], b)),
+                jnp.asarray(E._pad_to(msg_words[start : start + count], lanes)),
+                jnp.asarray(E._pad_to(s_words[start : start + count], lanes)),
+                jnp.asarray(E._pad_to(host_ok[start : start + count], lanes)),
             )[0],
-        )
-        for start, count, b in E.iter_buckets(n)
-    ]
+        ))
     return E.VerifyDispatch(handles)
 
 

@@ -65,10 +65,15 @@ def _make_verifier(kind: str, committee: Committee, metrics=None):
 
     The returned verifier carries a ``ready`` threading.Event: set once its
     one-time warmup is done (immediately for cpu/accept; after the JAX
-    trace/compile for tpu).  Load generators gate on it."""
+    trace/compile, or the service's HELLO_OK, for tpu).  Load generators
+    gate on it.  A warmup that raised never sets it and leaves the cause in
+    ``warmup_error``: ``Validator.warmup_failure`` turns that into the
+    node's exit, so a node whose kernels the chip refused does not carry on
+    verifying nothing."""
     import threading
 
     ready = threading.Event()
+    warm = None  # background warmup, started once the verifier is assembled
     aggregate = kind.endswith("-agg")
     if aggregate:
         kind = kind[: -len("-agg")]
@@ -124,16 +129,18 @@ def _make_verifier(kind: str, committee: Committee, metrics=None):
             else HybridSignatureVerifier(tpu=tpu_backend, metrics=metrics)
         )
 
-        def _warm() -> None:
+        verifier = BatchedSignatureVerifier(committee, backend, **collector_opts)
+
+        def warm() -> None:
             # Pay the JAX trace/compile (or cache load) off the hot path:
             # blocks arriving during warmup queue in the batching collector.
             try:
                 backend.warmup()
-            finally:
-                ready.set()
+            except BaseException as exc:
+                verifier.warmup_error = exc
+                raise
+            ready.set()
 
-        threading.Thread(target=_warm, daemon=True, name="verifier-warmup").start()
-        verifier = BatchedSignatureVerifier(committee, backend, **collector_opts)
     elif kind == "cpu":
         ready.set()
         verifier = BatchedSignatureVerifier(
@@ -145,6 +152,9 @@ def _make_verifier(kind: str, committee: Committee, metrics=None):
     else:
         raise ValueError(f"unknown verifier kind {kind!r}")
     verifier.ready = ready
+    verifier.warmup_error = None
+    if warm is not None:
+        threading.Thread(target=warm, daemon=True, name="verifier-warmup").start()
     return verifier
 
 
@@ -164,6 +174,19 @@ class Validator:
         self.ingress: Optional[IngressPlane] = None
         self.gateway: Optional[IngressGateway] = None
         self.host_monitor = None
+
+    async def warmup_failure(self) -> None:
+        """Completes only by raising: the verifier's background warmup
+        failed (``_make_verifier``).  Polls at the load generator's own
+        ``ready`` cadence and parks once the warmup has succeeded."""
+        verifier = self.network_syncer.block_verifier
+        while not verifier.ready.is_set():
+            if verifier.warmup_error is not None:
+                raise RuntimeError(
+                    "verifier warm-up failed"
+                ) from verifier.warmup_error
+            await asyncio.sleep(0.5)
+        await asyncio.Event().wait()
 
     def _make_recorder(self, authority: int, lifecycle, observer):
         """The always-on flight recorder: ring in memory unconditionally,

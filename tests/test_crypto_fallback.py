@@ -83,3 +83,28 @@ def test_crypto_surface_works_with_active_backend():
     assert signer.public_key.verify(sig, digest) is True
     assert signer.public_key.verify(sig, crypto.blake2b_256(b"other")) is False
     assert isinstance(crypto.HAVE_CRYPTOGRAPHY, bool)
+
+
+def test_oracles_accept_memoryviews():
+    """The verifier service slices its request frames into memoryviews and
+    hands them to the CPU oracle untouched; both oracles — the pure-Python
+    one directly, and whichever backend crypto.py selected (``cryptography``
+    loads keys from ``bytes`` only) — must take any bytes-like buffer."""
+    from mysticeti_tpu.block_validator import CpuSignatureVerifier
+
+    seed = hashlib.blake2b(b"memoryview-seed", digest_size=32).digest()
+    key, pub = _keypair(seed)
+    digest = crypto.blake2b_256(b"payload")
+    sig = key.sign(digest)
+    frame = memoryview(pub.public_bytes_raw() + digest + sig)
+    pk_v, digest_v, sig_v = frame[:32], frame[32:64], frame[64:]
+
+    F.Ed25519PublicKey.from_public_bytes(pk_v).verify(sig_v, digest_v)
+
+    assert crypto.PublicKey(pk_v).verify(sig_v, digest_v) is True
+    assert crypto.PublicKey(pk_v) == crypto.PublicKey(bytes(pk_v))
+    flipped = bytearray(sig)
+    flipped[1] ^= 1
+    assert CpuSignatureVerifier().verify_signatures(
+        [pk_v, pk_v], [digest_v, digest_v], [sig_v, memoryview(flipped)]
+    ) == [True, False]

@@ -354,7 +354,7 @@ def test_mid_pipeline_backend_failure_degrades_with_zero_lost_futures():
                 call = self.calls
             time.sleep(self.delay_s)
             if call > self.die_after:
-                raise ConnectionError("accelerator tunnel dropped")
+                raise ConnectionError("accelerator link dropped")
             return [True] * len(signatures)
 
     committee, blocks = _signed_blocks(12)
@@ -385,7 +385,7 @@ def test_verify_pipeline_depth_adapts_to_fixed_cost():
     assert p.depth() == VerifyPipeline.MIN_DEPTH  # co-located: nothing to hide
     cost["s"] = 0.01
     assert p.depth() == 3
-    cost["s"] = 0.120  # tunneled chip
+    cost["s"] = 0.120  # remote chip
     assert p.depth() == VerifyPipeline.MAX_DEPTH
     assert VerifyPipeline(depth=7).depth() == 7  # pinned overrides
 

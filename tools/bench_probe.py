@@ -2,7 +2,7 @@
 """Decompose the e2e bench into its serial components on the live device:
 null RTT, host->device bandwidth, kernel-only time, and the current e2e
 number — the measurement discipline that separates chip weather from real
-regressions (see BENCH_SAMPLES_r02.json).
+regressions.
 """
 from __future__ import annotations
 

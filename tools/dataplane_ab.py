@@ -284,7 +284,10 @@ def main(argv=None) -> int:
                      indent=1))
 
     if not args.no_trend:
-        append_dataplane_trend(microbench, args.round)
+        path = os.environ.get(
+            "BENCH_TREND_PATH", os.path.join(_REPO, "BENCH_TREND.json")
+        )
+        append_dataplane_trend(microbench, args.round, path)
         import bench_trend
 
         source = f"DATAPLANE_r{args.round:02d}.json"
@@ -304,9 +307,6 @@ def main(argv=None) -> int:
             args.round, source, "NODE_DATAPLANE.ab_hotpath_cpu_reduction",
             reduction_pct, "%",
         ))
-        path = os.environ.get(
-            "BENCH_TREND_PATH", os.path.join(_REPO, "BENCH_TREND.json")
-        )
         index = bench_trend.load_index(path)
         if bench_trend.merge_index(index, fresh):
             bench_trend.write_index(index, path)

@@ -64,13 +64,12 @@ def oracle_verify(pk: bytes, msg: bytes, sig: bytes) -> bool:
         return False
 
 
-def build_cases(n: int, seed: int):
+def build_cases(n: int, seed: int, n_keys: int = 16):
     from cryptography.hazmat.primitives.asymmetric.ed25519 import (
         Ed25519PrivateKey,
     )
 
     rng = random.Random(seed)
-    n_keys = 16
     keys = [
         Ed25519PrivateKey.from_private_bytes(
             bytes(rng.randrange(256) for _ in range(32))
@@ -117,13 +116,10 @@ def build_cases(n: int, seed: int):
     return raw_pks, pks, msgs, sigs, labels
 
 
-def main() -> None:
-    parser = argparse.ArgumentParser()
-    parser.add_argument("--n", type=int, default=12288)
-    parser.add_argument("--seed", type=int, default=2026)
-    parser.add_argument("--out", default="KERNEL_PARITY.json")
-    args = parser.parse_args()
-
+def run_parity(n: int, seed: int, n_keys: int = 16) -> dict:
+    """RFC 8032 vectors + ``n`` seeded cases through both deployed paths on
+    the process's default JAX device; the returned document's ``pass`` is
+    true iff every accept/reject bit matched the OpenSSL oracle."""
     import numpy as np
 
     import jax
@@ -135,8 +131,8 @@ def main() -> None:
         "metric": "kernel_parity_on_device",
         "device": f"{device.platform}:{device.device_kind}",
         "backend": E._backend(),
-        "seed": args.seed,
-        "n_randomized": args.n,
+        "seed": seed,
+        "n_randomized": n,
     }
 
     # RFC 8032 vectors (variable-length messages -> host-hash packing, the
@@ -155,7 +151,7 @@ def main() -> None:
     ).any()
     out["rfc8032"] = {"accept_all_valid": rfc_ok, "reject_all_corrupt": rfc_rej}
 
-    committee_keys, pks, msgs, sigs, labels = build_cases(args.n, args.seed)
+    committee_keys, pks, msgs, sigs, labels = build_cases(n, seed, n_keys)
     expected = np.array(
         [oracle_verify(pk, m, s) for pk, m, s in zip(pks, msgs, sigs)]
     )
@@ -178,7 +174,8 @@ def main() -> None:
                 "mismatches": int(sum(got[i] != expected[i] for i in sel)),
             }
         results[name] = {
-            "cases": args.n,
+            "cases": n,
+            "accepted": int(got.sum()),
             "mismatches": int(mism.size),
             "first_mismatches": mism[:5].tolist(),
             "per_class": per_class,
@@ -189,6 +186,17 @@ def main() -> None:
         and rfc_rej
         and all(r["mismatches"] == 0 for r in results.values())
     )
+    return out
+
+
+def main() -> None:
+    parser = argparse.ArgumentParser()
+    parser.add_argument("--n", type=int, default=12288)
+    parser.add_argument("--seed", type=int, default=2026)
+    parser.add_argument("--out", default="KERNEL_PARITY.json")
+    args = parser.parse_args()
+
+    out = run_parity(args.n, args.seed)
     with open(args.out, "w") as f:
         json.dump(out, f, indent=2)
         f.write("\n")

@@ -6,7 +6,7 @@ binary search (benchmark.rs:202-271 semantics — double until out-of-capacity,
 then bisect; out-of-capacity = avg latency > 5x previous or tps < 2/3
 offered) with the chosen --verifier and records every probe.
 
-Weather pinning (VERDICT r5 #8): the same box moves 20k->32k tx/s across
+Weather pinning: the same box moves 20k->32k tx/s across
 hours, so a lone peak is not evidence.  Every probe embeds the hostmon
 weather summary AND wall-clock window, and every non-cpu run is followed
 immediately by a fixed-load cpu reference probe at that run's peak — so each
@@ -15,7 +15,7 @@ never need to reach across windows.
 
 Usage:
   python tools/maxload_bench.py --verifier cpu --out MAXLOAD_r03.json
-  python tools/maxload_bench.py --verifiers cpu tpu --out MAXLOAD_TPU_r03.json
+  python tools/maxload_bench.py --verifiers cpu tpu --out MAXLOAD_TPU.json
 """
 from __future__ import annotations
 
@@ -90,14 +90,6 @@ async def search_one(verifier: str, nodes: int, start_load: int,
     # warm BEFORE booting nodes; validators are jax-free and seed their
     # routers from HELLO_OK).  Identical delays keep probes comparable.
     os.environ["INITIAL_DELAY"] = "1"
-    if verifier.startswith("tpu") and os.environ.get(
-        "MYSTICETI_NO_VERIFIER_SERVICE"
-    ):
-        # Service opted out: every node builds a cold JAX runtime again —
-        # the probe window must outlast the old ~2-3 min contended warmup
-        # or each probe measures zero tx and the search bisects down.
-        os.environ["INITIAL_DELAY"] = "10"
-        duration = max(duration, 240.0)
     runner = LocalProcessRunner(
         os.path.join(workdir, f"fleet-{verifier}"), verifier=verifier
     )
@@ -118,6 +110,9 @@ async def search_one(verifier: str, nodes: int, start_load: int,
     peak = max((p["tps"] for p in probes), default=0.0)
     return {
         "verifier": verifier,
+        # The platform the fleet's verifier service resolved (HELLO_OK);
+        # None for flavors that run no service.
+        "service_backend": runner.service_backend,
         "nodes": nodes,
         "max_sustainable_load_tx_s": generator.max_sustainable_load(),
         "peak_committed_tx_s": round(peak, 1),
@@ -140,24 +135,8 @@ def main() -> None:
     )
     args = parser.parse_args()
 
-    if any(v.startswith("tpu") for v in args.verifiers):
-        # Compile every kernel flavor a node will touch into the persistent
-        # cache once, in THIS process, so the fleet's per-node warmups are
-        # cache loads instead of four contending ~40 s compiles.  Keys via
-        # mysticeti_tpu.crypto (pure-Python RFC 8032 fallback): hosts
-        # without the `cryptography` package still prewarm.
-        print("prewarming kernel cache...", flush=True)
-        from mysticeti_tpu import crypto
-        from mysticeti_tpu.block_validator import TpuSignatureVerifier
-
-        signers = [
-            crypto.Signer.from_seed(bytes([i] * 32))
-            for i in range(args.nodes)
-        ]
-        TpuSignatureVerifier(
-            committee_keys=[s.public_key.bytes for s in signers]
-        ).warmup()
-
+    # This process stays off JAX: each tpu fleet's verifier service is the
+    # one process that holds the chip, and the runner waits for it to warm.
     runs = []
     for verifier in args.verifiers:
         print(f"max-load search verifier={verifier}...", flush=True)

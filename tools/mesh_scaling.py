@@ -1,8 +1,8 @@
 #!/usr/bin/env python3
 """Multi-chip sharding scaling curve over virtual device meshes.
 
-Extends the driver's one-shot ``dryrun_multichip`` into a measured curve
-(VERDICT r4 #8): for each mesh size, the SAME fixed global batch is sharded
+Extends the driver's one-shot ``dryrun_multichip`` into a measured curve:
+for each mesh size, the SAME fixed global batch is sharded
 over an n-device ``jax.sharding.Mesh`` through the deployed committee-
 indexed path (``parallel/mesh.py:sharded_verify_batch_indexed``), asserting
 per-shard shapes and the psum'd global valid count, and timing the jitted

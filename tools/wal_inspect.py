@@ -32,6 +32,7 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from mysticeti_tpu.block_store import (  # noqa: E402
+    CommitData,
     WAL_ENTRY_BLOCK,
     WAL_ENTRY_COMMIT,
     WAL_ENTRY_OWN_BLOCK,
@@ -44,6 +45,7 @@ from mysticeti_tpu.storage import (  # noqa: E402
     MANIFEST_NAME,
     checkpoint_files,
 )
+from mysticeti_tpu.serde import Reader  # noqa: E402
 from mysticeti_tpu.wal import HEADER_SIZE, WalReader  # noqa: E402
 
 TAG_NAMES = {
@@ -71,6 +73,34 @@ def _scan_file(path: str, base: int, census: dict) -> int:
     finally:
         reader.close()
     return consumed
+
+
+def committed_leaders(path: str) -> dict:
+    """{commit height: repr(leader reference)} from every commit entry the
+    WAL still holds (core.write_commits) — a node's own durable record of
+    its committed leader sequence, comparable across a fleet by height."""
+    if os.path.isdir(path):
+        with open(os.path.join(path, MANIFEST_NAME), encoding="utf-8") as f:
+            files = [
+                os.path.join(path, entry["name"])
+                for entry in json.load(f).get("segments", [])
+            ]
+    else:
+        files = [path]
+    leaders: dict = {}
+    for file in files:
+        reader = WalReader(file)
+        try:
+            for _pos, tag, payload in reader.iter_until():
+                if tag != WAL_ENTRY_COMMIT:
+                    continue
+                r = Reader(payload)
+                for _ in range(r.u32()):
+                    commit = CommitData.decode(r)
+                    leaders[commit.height] = repr(commit.leader)
+        finally:
+            reader.close()
+    return leaders
 
 
 def inspect(path: str) -> dict:
