@@ -191,7 +191,11 @@ JIT_IMPURE_PREFIXES = ("numpy.", "time.")
 # Rule 7: span-tracer call surface.  A stage-name typo at an instrumentation
 # site silently splits (begin under one name, end under another: the span
 # never closes) — every literal stage must come from spans.STAGES.
-SPAN_CALL_NAMES = {"span", "begin_span", "end_span", "record_span"}
+SPAN_CALL_NAMES = {
+    "span", "begin_span", "end_span", "record_span",
+    # The always-on stage clock (spans.StageClock) takes the same names.
+    "stage", "request_stage", "book", "book_since",
+}
 
 _IGNORE_RE = re.compile(r"#\s*lint:\s*ignore(?!-module)(?:\[([A-Za-z0-9_,\- ]+)\])?")
 # Whole-module opt-out for rules whose premise a module structurally

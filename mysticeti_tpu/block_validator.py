@@ -1538,6 +1538,7 @@ class BatchedSignatureVerifier(BlockVerifier):
             # -- bounded in-flight window: held from device submission
             # through result fetch.  Other flush windows keep packing (and
             # submitting, up to the depth) while this dispatch is in flight.
+            req_id = None
             async with self.pipeline.slot():
                 t_dispatch = tracer.now() if tracer is not None else 0.0
                 t_fetch = t_dispatch
@@ -1582,6 +1583,10 @@ class BatchedSignatureVerifier(BlockVerifier):
                     )
                     if tracer is not None:
                         t_fetch = tracer.now()
+                        # A request to the verifier service carries its
+                        # req_id: the service's own stages of it
+                        # (spans.SERVICE_STAGES) join this span on it.
+                        req_id = getattr(handle, "req_id", None)
                         for block in sub_blocks:
                             tracer.record_span(
                                 "verify_device", block.reference, t_dispatch,
@@ -1629,7 +1634,8 @@ class BatchedSignatureVerifier(BlockVerifier):
                         "verify_fetch", block.reference, t_fetch, t1=t1
                     )
                     tracer.record_span(
-                        "verify_dispatch", block.reference, t_dispatch, t1=t1
+                        "verify_dispatch", block.reference, t_dispatch, t1=t1,
+                        extra=None if req_id is None else {"req_id": req_id},
                     )
             # Backend counters measure ACTUAL dispatches: counted here, per
             # dispatch, so aggregate-skipped blocks never inflate them.

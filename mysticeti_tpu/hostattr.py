@@ -51,7 +51,9 @@ class LoopLagProbe:
 
     One coroutine, one short sleep per interval: the overshoot beyond the
     requested interval is exactly the time the loop spent running other
-    callbacks (or a blocking call) instead of this one.
+    callbacks (or a blocking call) instead of this one.  ``on_lag(lag)``
+    hears every sample (the verifier service books them as its
+    ``service_loop_lag`` stage).
     """
 
     def __init__(
@@ -59,9 +61,11 @@ class LoopLagProbe:
         interval_s: float = 0.25,
         metrics=None,
         window: int = 256,
+        on_lag=None,
     ) -> None:
         self.interval_s = interval_s
         self.metrics = metrics
+        self.on_lag = on_lag
         self._lags: deque = deque(maxlen=window)
         self._task: Optional[asyncio.Task] = None
 
@@ -87,6 +91,8 @@ class LoopLagProbe:
             await asyncio.sleep(self.interval_s)
             lag = max(0.0, loop.time() - scheduled)
             self._lags.append(lag)
+            if self.on_lag is not None:
+                self.on_lag(lag)
             if self.metrics is not None:
                 self.metrics.mysticeti_loop_lag_seconds.observe(lag)
                 self.metrics.mysticeti_loop_lag_p99_seconds.set(

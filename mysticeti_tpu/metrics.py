@@ -135,6 +135,69 @@ class PreciseHistogram:
         self._window_count = 0
 
 
+class StageSeries:
+    """A ``<name>{stage}`` histogram (and a ``<cpu_name>{stage}`` counter)
+    rendered at scrape time from the stage clocks attached to it
+    (``spans.StageClock``), summed where there are several: the clock is on
+    the verifier service's per-request path and keeps plain arrays there,
+    not one locked prometheus child a sample."""
+
+    def __init__(self, name: str, doc: str, cpu_name: Optional[str] = None,
+                 cpu_doc: str = "") -> None:
+        self.name, self.doc = name, doc
+        self.cpu_name, self.cpu_doc = cpu_name, cpu_doc
+        self._clocks: list = []
+
+    def attach(self, clock) -> None:
+        self._clocks.append(clock)
+
+    def collect(self):
+        from prometheus_client.core import (
+            CounterMetricFamily,
+            HistogramMetricFamily,
+        )
+
+        from .spans import SAMPLED_STAGES, STAGE_BUCKETS, WAITING_STAGES
+
+        merged: Dict[str, dict] = {}
+        for clock in self._clocks:
+            totals = clock.totals()
+            del totals["answered"]
+            for stage, row in totals.items():
+                into = merged.setdefault(
+                    stage, {"wall_s": 0.0, "cpu_s": 0.0,
+                            "buckets": [0] * len(row["buckets"])},
+                )
+                into["wall_s"] += row["wall_s"]
+                # A request's stages are clocked for one request in
+                # ``sample_one_in``: their CPU stands for that many.  A sum
+                # of tick-sized readings less the collections inside them
+                # can dip under zero; a counter cannot.
+                scale = clock.sample_one_in if stage in SAMPLED_STAGES else 1
+                into["cpu_s"] += max(0.0, row["cpu_s"]) * scale
+                into["buckets"] = [
+                    a + b for a, b in zip(into["buckets"], row["buckets"])
+                ]
+        seconds = HistogramMetricFamily(self.name, self.doc, labels=["stage"])
+        cpu = None
+        if self.cpu_name:
+            cpu = CounterMetricFamily(
+                self.cpu_name, self.cpu_doc, labels=["stage"]
+            )
+        bounds = [repr(float(b)) for b in STAGE_BUCKETS] + ["+Inf"]
+        for stage, row in merged.items():
+            running, cumulative = 0, []
+            for bound, count in zip(bounds, row["buckets"]):
+                running += count
+                cumulative.append((bound, running))
+            seconds.add_metric([stage], cumulative, sum_value=row["wall_s"])
+            if cpu is not None and stage not in WAITING_STAGES:
+                cpu.add_metric([stage], row["cpu_s"])
+        yield seconds
+        if cpu is not None:
+            yield cpu
+
+
 class Metrics:
     """Registers every series on a fresh registry (metrics.rs:121-424)."""
 
@@ -349,6 +412,34 @@ class Metrics:
             "in-flight verify requests per service client connection",
             labels=("connection",),
         )
+        # The stage clock (spans.StageClock) inside the verifier service:
+        # every millisecond of a request and every core-second of the
+        # process by the program's own stages (spans.SERVICE_STAGES).  The
+        # clock keeps its own sums; these series render them at scrape time.
+        self.verifier_service_stages = StageSeries(
+            "verifier_service_stage_seconds",
+            "wall seconds a verify request spent in each stage of the "
+            "verifier service, one sample a clocked request (one request in "
+            "32; service_gc: one a collection, service_loop_lag: one a probe "
+            "tick)",
+            "verifier_service_stage_cpu_seconds_total",
+            "CPU seconds (time.thread_time of the thread that did it) in "
+            "each working stage of the verifier service: the CPU of the "
+            "one request in 32 that is clocked, times 32; a stage's wall "
+            "minus its CPU is time blocked or waiting for the GIL (where "
+            "the kernel moves that clock in ticks, only long sums mean "
+            "anything)",
+        )
+        r.register(self.verifier_service_stages)
+        # The same clock on a validator's verification path (net_sync.py):
+        # one sample a received batch of blocks, always on.
+        self.block_stages = StageSeries(
+            "block_stage_seconds",
+            "wall seconds of a received batch of blocks in receive (decode "
+            "+ dedup + structure), verify (collector window + the round "
+            "trip to the verifier) and dag_add (core-task queue + insertion)",
+        )
+        r.register(self.block_stages)
         # Staged dispatch pipeline (verify_pipeline.py): the collector may
         # hold several dispatches in flight; these series say how full the
         # window runs and where each dispatch's time goes.
@@ -566,15 +657,9 @@ class Metrics:
             "mysticeti_device_transfer_bytes_total",
             "bytes moved between host and device on the verifier hot path "
             "(to_device = packed signature blobs, from_device = verdict "
-            "fetches)",
+            "fetches); summed in the dispatch path and moved here twice a "
+            "second",
             labels=("direction",),
-        )
-        self.mysticeti_verify_occupancy_fraction = gauge(
-            "mysticeti_verify_occupancy_fraction",
-            "fraction of cumulative verify-dispatch time in each phase "
-            "(device = device-busy, pack = host packing, fetch = "
-            "result-wait), from the verify_pipeline stage timers",
-            labels=("phase",),
         )
 
         # Overload-resilient ingress plane (ingress.py): the admission-

@@ -141,6 +141,13 @@ class NetworkSyncer:
         # inert (inline path) under sims, without the extension, or for
         # small frames — see DataPlaneOffload.should_offload.
         self.dataplane_offload = DataPlaneOffload(metrics=metrics)
+        # block_stage_seconds{stage}: what part of a round the verification
+        # path is — receive / verify / dag_add, one sample a batch, always
+        # on (the per-block spans of the same names stay opt-in).
+        self._block_stages = None
+        if metrics is not None:
+            self._block_stages = spans.StageClock(spans.BLOCK_PATH_STAGES)
+            metrics.block_stages.attach(self._block_stages)
         # Bound once: _decode_fresh is per-incoming-frame hot.
         self._utilization_timer = (
             metrics.utilization_timer
@@ -692,7 +699,7 @@ class NetworkSyncer:
         attributes malformed payloads (undecodable bytes name no author —
         the DELIVERING connection is the misbehaving party)."""
         tracer = spans.active()
-        t_recv = tracer.now() if tracer is not None else 0.0
+        t_recv = spans.runtime_now()
         timer = self._utilization_timer
         offload = self.dataplane_offload
         if offload is not None and offload.should_offload(
@@ -770,6 +777,8 @@ class NetworkSyncer:
                     "receive", block.reference, t_recv,
                     authority=self.core.authority,
                 )
+        if self._block_stages is not None:
+            self._block_stages.book_since("receive", t_recv)
         return verified
 
     async def _verify_accepted(
@@ -778,8 +787,12 @@ class NetworkSyncer:
         """Stage 2 (accelerator): signature + application check through the
         pluggable verifier (batched across connections on TPU)."""
         tracer = spans.active()
-        t_verify = tracer.now() if tracer is not None else 0.0
+        t_verify = spans.runtime_now()
         results = await self.block_verifier.verify_blocks(verified)
+        if self._block_stages is not None:
+            # Block received -> verdict: the collector's window plus the
+            # request's round trip to the verifier.
+            self._block_stages.book_since("verify", t_verify)
         accepted = [b for b, ok in zip(verified, results) if ok]
         if tracer is not None:
             for block in accepted:
@@ -809,11 +822,11 @@ class NetworkSyncer:
     async def _add_accepted(self, accepted: List[StatementBlock], origin) -> None:
         """Stage 3: hand to the core, chase missing causal history."""
         tracer = spans.active()
+        t = spans.runtime_now()
         if tracer is not None:
             # Closed by Core.add_blocks when the block is actually inserted,
             # so the span covers the core-task queue AND any time parked on
             # missing parents.
-            t = tracer.now()
             for block in accepted:
                 tracer.begin_span(
                     "dag_add", block.reference,
@@ -822,6 +835,11 @@ class NetworkSyncer:
         missing = await self.dispatcher.add_blocks(
             accepted, self.connected_authorities.copy()
         )
+        if self._block_stages is not None:
+            # The batch's view of dag_add: the core-task queue plus the
+            # insertion (a block parked on missing parents ends later, in
+            # its own span).
+            self._block_stages.book_since("dag_add", t)
         if accepted and any(
             d.relay_serving for d in self._disseminators.values()
         ):

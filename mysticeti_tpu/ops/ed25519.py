@@ -22,6 +22,7 @@ from __future__ import annotations
 import hashlib
 import os
 import threading
+import time
 from typing import Optional, Sequence, Tuple
 
 import numpy as np
@@ -29,6 +30,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import spans
 from . import field as F
 from . import scalar as SC
 from . import sha512 as H
@@ -315,17 +317,24 @@ def prepare_fused(
     host hashlib loop — the reference's serial path, crypto.rs:174-189) with
     the encoding parse and the canonicity checks (s < L, A < p).  R canonicity
     needs no explicit check: the final compare is exact on raw limbs.
+
+    The scopes name this glue in a profile: it runs as XLA ops around the
+    ladder (a fifth of a launch's device time on a v5e), and without them
+    its ``while`` and ``dynamic-slice`` ops say nothing of what they are.
     """
-    dig = H.sha512_96(msg_words)
-    k = SC.mod_L(SC.words_to_limbs(SC.digest_words_to_le(dig), 40))
-    k_windows = SC.windows4(k)
+    with jax.named_scope("ed25519_challenge_hash"):
+        dig = H.sha512_96(msg_words)
+        k = SC.mod_L(SC.words_to_limbs(SC.digest_words_to_le(dig), 40))
+        k_windows = SC.windows4(k)
 
-    r_y, r_sign, _ = _parse_point_words(SC.bswap32(msg_words[..., :8]))
-    a_y, a_sign, a_canonical = _parse_point_words(SC.bswap32(msg_words[..., 8:16]))
-
-    s_limbs = SC.words_to_limbs(s_words, F.NLIMBS)
-    s_ok = SC.lt_L(s_limbs)
-    s_windows = SC.windows4(s_limbs)
+    with jax.named_scope("ed25519_bytes_to_limbs"):
+        r_y, r_sign, _ = _parse_point_words(SC.bswap32(msg_words[..., :8]))
+        a_y, a_sign, a_canonical = _parse_point_words(
+            SC.bswap32(msg_words[..., 8:16])
+        )
+        s_limbs = SC.words_to_limbs(s_words, F.NLIMBS)
+        s_ok = SC.lt_L(s_limbs)
+        s_windows = SC.windows4(s_limbs)
 
     ok = host_ok & a_canonical & s_ok
     return a_y, a_sign, r_y, r_sign, s_windows, k_windows, ok
@@ -462,12 +471,13 @@ def pack_blob_indexed(
 def indexed_to_msg_words(blob: jnp.ndarray, table: jnp.ndarray):
     """Rebuild the fused-kernel inputs from an indexed blob + key table:
     gather the A words by index and splice them between R and M."""
-    idx = blob[..., 24].astype(jnp.int32)
-    a_words = table[jnp.clip(idx, 0, table.shape[0] - 1)]
-    msg_words = jnp.concatenate(
-        [blob[..., :8], a_words, blob[..., 8:16]], axis=-1
-    )
-    return msg_words, blob[..., 16:24], blob[..., 25] != 0
+    with jax.named_scope("ed25519_gather_keys"):
+        idx = blob[..., 24].astype(jnp.int32)
+        a_words = table[jnp.clip(idx, 0, table.shape[0] - 1)]
+        msg_words = jnp.concatenate(
+            [blob[..., :8], a_words, blob[..., 8:16]], axis=-1
+        )
+        return msg_words, blob[..., 16:24], blob[..., 25] != 0
 
 
 def verify_fused_indexed_impl(blob: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
@@ -673,6 +683,7 @@ def _dispatch_indexed_keyed(chunk: np.ndarray, table: "KeyTable", bucket: int):
 
     tile = min(PK.default_tile(), bucket)
     acomb, valid = table.neg_combs()
+    spans.request_stage("service_pack")  # tile grouping
     if not valid.all():
         # Lanes under an off-curve committee key must reject exactly like the
         # generic kernel's decompression failure; the identity comb entries
@@ -688,6 +699,7 @@ def _dispatch_indexed_keyed(chunk: np.ndarray, table: "KeyTable", bucket: int):
     # the device only needed them for a final gather, so only the grouped
     # blob and the per-tile key ids count as upload traffic.  The narrower
     # 96 B/sig flat layout (verify_keyed_flat) is not the deployed path.
+    spans.request_stage("service_launch")
     _note_transfer("to_device", grouped.nbytes + tile_keys.nbytes)
     _note_kernel("keyed", bucket, "pallas")
     handle = PK.verify_keyed_blob(
@@ -712,7 +724,9 @@ def dispatch_indexed_chunks(blob: np.ndarray, table: "KeyTable"):
         chunk = blob[start : start + count]
         hp = _dispatch_indexed_keyed(chunk, table, b) if keyed else None
         if hp is None:
+            spans.request_stage("service_pack")
             padded = _pad_to(chunk, b)
+            spans.request_stage("service_launch")
             _note_transfer("to_device", padded.nbytes)
             h = _dispatch_indexed(jnp.asarray(padded), table.words)
             handles.append((count, h))
@@ -745,6 +759,7 @@ class VerifyDispatch:
         self._patches = tuple(patches)
 
     def result(self) -> np.ndarray:
+        spans.request_stage("service_fetch")
         out = fetch_handles(self._entries)
         for rows, handle in self._patches:
             out[rows] = handle.result()
@@ -765,6 +780,11 @@ def dispatch_batch_table(
         return VerifyDispatch([])
     if not all(len(m) == 32 for m in messages):
         return dispatch_batch(public_keys, messages, signatures)
+    # Inside the verifier service the request is in service_pack from here
+    # and in service_launch around each jitted call (spans.request_stage:
+    # a stage lasts until the next is named; outside a request it is a
+    # no-op).
+    spans.request_stage("service_pack")
     idx = table.indices_for(public_keys)
     known = idx >= 0
     blob = pack_blob_indexed(idx, messages, signatures, num_keys=len(table))
@@ -924,10 +944,15 @@ def _on_event(event: str, **kwargs) -> None:
 
 
 def _on_duration(event: str, duration: float, **kwargs) -> None:
-    if event.endswith("backend_compile_duration"):
-        COMPILE_STATS["backend_compile_s"] += max(0.0, duration)
+    # One backend compile (or load from the persistent cache) a program.
+    # Every other duration JAX reports lives under ``/jax/core/compile/``
+    # too — one ``jaxpr_trace_duration`` a jitted function traced, tens of
+    # thousands a kernel — and is no compile.
+    if not event.endswith("backend_compile_duration"):
+        return
+    COMPILE_STATS["backend_compile_s"] += max(0.0, duration)
     m = _attr_metrics
-    if m is not None and "compil" in event:  # compile/compilation variants
+    if m is not None:
         m.mysticeti_jax_compiles_total.inc()
         m.mysticeti_jax_compile_seconds_total.inc(max(0.0, duration))
 
@@ -962,14 +987,41 @@ def install_device_attribution(metrics) -> bool:
         return False
 
 
+_TRANSFER_DIRECTIONS = ("to_device", "from_device")
+_TRANSFER_FLUSH_S = 0.5
+# Per thread: [bytes to the device, bytes from it, when last moved to the
+# registry], not yet in the registry.
+_transfer_local = threading.local()
+
+
 def _note_transfer(direction: str, nbytes: int) -> None:
     """Count host<->device bytes at the dispatch/fetch seams: JAX exposes no
     portable transfer counter, but every verifier transfer flows through
     dispatch_blob_chunks / dispatch_batch / fetch_handles, so counting the
-    (padded) array sizes there IS the device link traffic."""
+    (padded) array sizes there IS the device link traffic.
+
+    Called two or three times a request from the service's sixteen pool
+    threads, so each thread adds to a list of its own and moves its sums
+    into the registry twice a second (a thread that falls idle keeps what
+    it noted since, until its next transfer): one locked prometheus child a
+    call cost the service 3.6% of its throughput on the chip's host
+    (PERF.md, PR 24)."""
     m = _attr_metrics
-    if m is not None and nbytes > 0:
-        m.mysticeti_device_transfer_bytes_total.labels(direction).inc(nbytes)
+    if m is None or nbytes <= 0:
+        return
+    try:
+        pending = _transfer_local.pending
+    except AttributeError:
+        pending = _transfer_local.pending = [0, 0, 0.0]
+    pending[direction == "from_device"] += nbytes
+    now = time.monotonic()
+    if now - pending[2] < _TRANSFER_FLUSH_S:  # lint: ignore[sim-taint] — when a byte counter reaches the registry; nothing reads it back
+        return
+    pending[2] = now
+    for i, name in enumerate(_TRANSFER_DIRECTIONS):
+        if pending[i]:
+            m.mysticeti_device_transfer_bytes_total.labels(name).inc(pending[i])
+            pending[i] = 0
 
 
 def _dispatch_packed(*arrays) -> jnp.ndarray:
@@ -1026,7 +1078,9 @@ def dispatch_blob_chunks(blob: np.ndarray):
     force with np.asarray(handle)[:count]."""
     out = []
     for start, count, b in iter_buckets(blob.shape[0]):
+        spans.request_stage("service_pack")
         padded = _pad_to(blob[start : start + count], b)
+        spans.request_stage("service_launch")
         _note_transfer("to_device", padded.nbytes)
         out.append((count, _dispatch_blob(jnp.asarray(padded))))
     return out
@@ -1088,6 +1142,7 @@ def dispatch_batch(
     n = len(signatures)
     if n == 0:
         return VerifyDispatch([])
+    spans.request_stage("service_pack")
     fused = all(len(m) == 32 for m in messages)
     if fused:
         blob = pack_blob(public_keys, messages, signatures)
@@ -1099,7 +1154,9 @@ def dispatch_batch(
     arrays = pack_batch(public_keys, messages, signatures)
     handles = []
     for start, count, b in iter_buckets(n):
+        spans.request_stage("service_pack")
         padded = [_pad_to(x[start : start + count], b) for x in arrays]
+        spans.request_stage("service_launch")
         _note_transfer("to_device", sum(p.nbytes for p in padded))
         handles.append(
             (count, _dispatch_packed(*[jnp.asarray(p) for p in padded]))

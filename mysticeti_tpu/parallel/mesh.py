@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as PSpec
 
+from .. import spans
 from ..ops import ed25519 as E
 
 
@@ -189,9 +190,10 @@ def dispatch_sharded_indexed(
     n = len(signatures)
     if n == 0:
         return E.VerifyDispatch([])
+    kernel = _cached_indexed_kernel(mesh)
+    spans.request_stage("service_pack")
     idx = table.indices_for(public_keys)
     known = idx >= 0
-    kernel = _cached_indexed_kernel(mesh)
     blob = E.pack_blob_indexed(idx, messages, signatures, num_keys=len(table))
     # The psum'd per-chunk total is compiled and executed (the ICI collective
     # is part of the sharded program) but not fetched: padded lanes are
@@ -200,14 +202,11 @@ def dispatch_sharded_indexed(
     handles = []
     for start, count, b in E.iter_buckets(n):
         lanes = mesh_lanes(mesh, b)
+        spans.request_stage("service_pack")
+        padded = E._pad_to(blob[start : start + count], lanes)
+        spans.request_stage("service_launch")
         E._note_kernel("mesh-indexed", lanes, E._backend())
-        handles.append((
-            count,
-            kernel(
-                jnp.asarray(E._pad_to(blob[start : start + count], lanes)),
-                table.words,
-            )[0],
-        ))
+        handles.append((count, kernel(jnp.asarray(padded), table.words)[0]))
     patches = []
     if not known.all():
         stragglers = np.flatnonzero(~known)

@@ -35,14 +35,27 @@ the trace at shutdown.  A daemon thread flushes the file atomically every
 few seconds so a SIGKILL'd benchmark node still leaves a complete snapshot
 (same posture as ``profiling.SamplingProfiler``).  ``tools/trace_report.py``
 prints per-stage latency breakdowns from a trace file.
+
+Besides the opt-in per-block tracer there is one ALWAYS-ON stage clock
+(:class:`StageClock`, :func:`request_stage`, :class:`stage`): the same stage
+names, aggregated instead of recorded — a histogram a stage, CPU seconds a
+working stage, and in the verifier service a ring of whole seconds that the
+service writes into its report.  It is what the benchmark's per-layer
+metrics read, so it is cheap: a few clock reads a stage, one request in
+``SAMPLE_ONE_IN`` clocked in the service, no lock, nothing allocated that
+outlives the call.  With a tracer the same calls also record the spans.
 """
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
+import time
+from array import array
+from bisect import bisect_left
 from contextlib import contextmanager
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 from .runtime import now as runtime_now
 from .tracing import current_authority
@@ -71,6 +84,20 @@ STAGES = (
     "proposal_wait",
     "commit",
     "finalize",
+    # The verifier service's stages of one VERIFY/RAW request
+    # (verifier_service.py, ops/ed25519.py; SERVICE_STAGES below): always on
+    # through StageClock, and spans keyed by (connection, req_id) when a
+    # tracer is active.
+    "service_decode",
+    "service_pool_wait",
+    "service_unpack",
+    "service_pack",
+    "service_launch",
+    "service_fetch",
+    "service_reply_build",
+    "service_reply_wait",
+    "service_gc",
+    "service_loop_lag",
 )
 
 # The per-block pipeline proper: the stages every committed block crosses.
@@ -91,7 +118,10 @@ _UNTRACKED_TID = 1 << 20
 
 
 def format_ref(ref) -> str:
-    """Stable human-readable block-reference label for trace args."""
+    """Stable human-readable label for trace args: a block reference, or a
+    service request's ``(connection label, req_id)``."""
+    if isinstance(ref, tuple):
+        return f"{ref[0]}#{ref[1]}"
     return f"A{ref.authority}R{ref.round}#{ref.digest[:4].hex()}"
 
 
@@ -414,6 +444,571 @@ def stop_from_env() -> None:
         return
     _active.stop()
     _active = None
+
+
+# ---------------------------------------------------------------------------
+# The always-on stage clock.
+#
+# Clocks: ``time.monotonic`` for wall time (the clock every process of a
+# host shares, and the runtime clock outside the simulator), and for a
+# WORKING stage ``time.thread_time`` of the thread that does it: wall minus
+# CPU is then time spent blocked or waiting for the GIL.  Where the kernel
+# moves a thread's CPU clock in scheduler ticks (10 ms on the sandboxed
+# hosts the chips sit in) one reading says nothing and only sums do: a
+# stage's CPU is the ticks that happened to land in it, right in the mean
+# and no finer than 1/sqrt(ticks summed).  So no sample's CPU is held to
+# its wall, and what a whole thread used is read once a second beside it
+# (``StageClock.stamp``).
+
+# The verifier service's stages, in the order a request crosses them
+# (``service_gc`` and ``service_loop_lag`` belong to the process, not to a
+# request).  Every name is also in STAGES.
+SERVICE_STAGES = STAGES[STAGES.index("service_decode"):]
+# Per request, booked together when a pool thread is done with it — each
+# exactly once a request, with zero seconds where the backend has no such
+# stage (a host oracle packs and launches nothing).
+REQUEST_STAGES = SERVICE_STAGES[1:7]
+# The eight stages of a request, header read to reply written: clocked for
+# the requests that are sampled (SAMPLE_ONE_IN), so their counts are of
+# those; ``service_gc`` and ``service_loop_lag`` see every occurrence.
+SAMPLED_STAGES = SERVICE_STAGES[:8]
+# A validator's verification path, one sample a received batch of blocks
+# (net_sync.py): the always-on twins of the per-block spans of those names.
+BLOCK_PATH_STAGES = ("receive", "verify", "dag_add")
+# Stages in which the thread waits (for a pool thread, the device, the
+# loop, the GIL): wall time only, no CPU clock and no profiler annotation —
+# the runtime's own events mark them in a trace already.
+WAITING_STAGES = frozenset({
+    "service_pool_wait", "service_fetch", "service_reply_wait",
+    "service_loop_lag",
+})
+
+# The verifier service clocks one request in this many through its stages,
+# whoever listens (a tracer, a profiler): the first and every 32nd after
+# it.  Clocking every request cost the service 8% of its throughput on the
+# chip's host, where ``time.thread_time`` is a 6 us system call (0.3 us on
+# plain Linux) and an idle profiler annotation a stage another 3.5%
+# (PERF.md, PR 24).  A sampled request is clocked whole, header read to
+# reply written, so its stages still tile it; what is answered is counted
+# for every request.  At 700 requests a second a 20 s window still holds
+# over 400 clocked requests.
+SAMPLE_ONE_IN = 32
+# Upper bounds of the histogram every stage clock keeps (seconds).
+STAGE_BUCKETS = (
+    0.00002, 0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+    0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 5.0,
+)
+
+
+class _ThreadState:
+    """What the stage clock knows of one thread: the frame it clocks its
+    requests in, whether a profiler annotation is open on it (annotations
+    are flat, never nested), and the CPU seconds it has spent collecting
+    garbage (which a stage that a collection interrupted takes off its
+    own)."""
+
+    __slots__ = ("own_frame", "annotated", "gc_cpu", "gc_t0",
+                 "gc_annotation")
+
+    def __init__(self) -> None:
+        self.own_frame = None
+        self.annotated = False
+        self.gc_cpu = 0.0
+        self.gc_t0 = self.gc_annotation = None
+
+
+class _Local(threading.local):
+    """``state``: the thread's _ThreadState, made at its first use;
+    ``frame``: the _Frame of the clocked request the thread works for now,
+    None between such requests (a class default, so that reading it costs
+    a thread that never clocked one nothing)."""
+
+    state = None
+    frame = None
+
+
+_tls = _Local()
+_trace_annotation = None
+
+
+def _state() -> _ThreadState:
+    state = _tls.state
+    if state is None:
+        state = _tls.state = _ThreadState()
+    return state
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` of a working stage, in a process
+    that has JAX already (validators stay off it: never imported here): the
+    stage then shows on a profiler's host plane under its own name.  Made
+    whether or not a profile is being taken — a clocked request does the
+    same work either way."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation(name)
+
+
+class _Frame:
+    """One thread's accumulators for the request it works for, and the
+    stage that request is in; made once a thread and reused, so a request
+    leaves no object behind."""
+
+    __slots__ = ("clock", "state", "ref", "wall", "cpu", "end", "slot", "t0",
+                 "c0", "g0", "annotation")
+
+    def __init__(self, clock: "StageClock", state: _ThreadState) -> None:
+        self.clock = clock
+        self.state = state
+        self.ref = None
+        n = len(clock.stages)
+        self.wall = [0.0] * n
+        self.cpu = [0.0] * n
+        self.end = [0.0] * n
+        self.slot = 0
+        self.t0 = self.c0 = self.g0 = 0.0
+        self.annotation = None
+
+    def switch(self, slot: int) -> float:
+        """The request leaves the stage it is in and enters ``slot`` (-1:
+        none, its reply is built).  One read of each clock serves both
+        sides of the boundary, so a request's stages tile its time on the
+        pool thread exactly; returns the boundary's instant."""
+        clock, state = self.clock, self.state
+        cur = self.slot
+        working = clock._working
+        cpu_now = time.thread_time()
+        now = time.monotonic()
+        self.wall[cur] += now - self.t0
+        self.end[cur] = now
+        if working[cur]:
+            self.cpu[cur] += cpu_now - self.c0 - (state.gc_cpu - self.g0)
+            if self.annotation is not None:
+                self.annotation.__exit__(None, None, None)
+                self.annotation = None
+                state.annotated = False
+        if clock.tracer is not None:
+            clock.tracer.record_span(clock.stages[cur], self.ref, self.t0, now)
+        self.slot, self.t0, self.c0, self.g0 = slot, now, cpu_now, state.gc_cpu
+        if slot >= 0 and working[slot]:
+            annotation = self.annotation = _annotation(clock.stages[slot])
+            if annotation is not None:
+                state.annotated = True
+                annotation.__enter__()
+        return now
+
+
+def request_stage(name: str) -> None:
+    """The clocked request this thread works for is in stage ``name`` from
+    now on, until the next stage is named or its reply is built
+    (``StageClock.end_request``).  For a request that is not clocked, and
+    outside any — a backend used in process, a warm-up — this does
+    nothing."""
+    frame = _tls.frame
+    if frame is None:
+        return
+    slot = frame.clock._slot[name]
+    if slot != frame.slot:
+        frame.switch(slot)
+
+
+class _Books:
+    """One thread's books of one clock: nobody else writes them, so
+    booking takes no lock (a lock a booking is held across a forced GIL
+    switch now and then, and sixteen threads then queue behind it)."""
+
+    __slots__ = ("totals", "buckets", "ring", "second", "gc")
+
+    def __init__(self, stages: int, nbuckets: int, rows: int) -> None:
+        # Cumulative, per stage: [count, wall_s, cpu_s], and one count a
+        # bucket of STAGE_BUCKETS plus the overflow.
+        self.totals = array("d", bytes(8 * 3 * stages))
+        self.buckets = array("q", bytes(8 * nbuckets * stages))
+        self.ring = array("d", bytes(8 * rows * 4 * stages))
+        self.second = array("q", [-1]) * rows
+        self.gc = array("d", bytes(8 * 6))  # [collections, seconds] * 3
+
+
+_thread_cpu_clock = getattr(time, "pthread_getcpuclockid", None)
+
+
+class StageClock:
+    """Where a process's time goes, by stage.  Per stage, cumulatively: a
+    count, wall seconds, CPU seconds and a histogram of the wall seconds
+    (``metrics.StageSeries`` renders them at scrape time); and — with
+    ``ring_seconds`` — a ring of the last whole seconds of
+    ``time.monotonic``, each holding per stage ``[count, wall_s, cpu_s,
+    max_wall_s]`` and, from ``stamp``, what was answered and what CPU was
+    used in that second.  A sample is booked to the second its stage ENDED
+    in.  Every thread books into preallocated arrays of its own (made when
+    it is adopted, or at its first sample), summed when read: booking takes
+    no lock and allocates nothing that outlives the call.  One request in
+    ``sample_one_in`` is clocked through its stages (``sampled``).  With a
+    ``tracer`` every clocked stage that has a reference is also recorded as
+    a span (the tracer that was live when the clock was made, not whichever
+    is live later: a clock outlives a test, a tracer must not hear it)."""
+
+    RING_SECONDS = 600
+    COLUMNS = ("count", "wall_s", "cpu_s", "max_wall_s")
+    # What ``stamp`` reads once a second, cumulative; the ring's seconds
+    # hold the growth from one stamp to the next.
+    STAMPS = ("requests", "signatures", "process_cpu_s", "threads_cpu_s",
+              "loop_cpu_s")
+
+    def __init__(self, stages: Sequence[str], ring_seconds: int = 0,
+                 tracer: Optional[SpanTracer] = None,
+                 sample_one_in: int = 1) -> None:
+        self.stages = tuple(stages)
+        self.tracer = tracer
+        self.sample_one_in = sample_one_in
+        # Replies written and the signatures in them, clocked or not: plain
+        # sums of the one thread that writes replies (which also stamps).
+        self.requests = 0
+        self.signatures = 0
+        self._slot = {name: i for i, name in enumerate(self.stages)}
+        self._request_slots = [
+            self._slot[name] for name in REQUEST_STAGES if name in self._slot
+        ]
+        self._working = [name not in WAITING_STAGES for name in self.stages]
+        self._nbuckets = len(STAGE_BUCKETS) + 1
+        self._rows = ring_seconds
+        self._width = len(self.COLUMNS) * len(self.stages)
+        self._zeros = array("d", bytes(8 * self._width))
+        self._local = threading.local()
+        self._all_books: List[_Books] = []
+        # [CPU clock id, CPU seconds when adopted, last reading] of every
+        # thread that books here.
+        self._thread_clocks: List[list] = []
+        self._lock = threading.Lock()  # guards the two lists alone
+        self._turn = 0  # of the requests: which are clocked
+        self._stamped = -1
+        self._stamp_second = array("q", [-1]) * ring_seconds
+        self._stamps = array("d", bytes(8 * ring_seconds * len(self.STAMPS)))
+        if ring_seconds:
+            self.stamp(time.monotonic())
+
+    def adopt_thread(self) -> _Books:
+        """Make the calling thread's books and note its CPU clock, so that
+        ``stamp`` counts what it uses from now on (a pool's ``initializer``;
+        any other thread is adopted at its first sample)."""
+        books = self._local.books = _Books(
+            len(self.stages), self._nbuckets, self._rows)
+        entry = None
+        if _thread_cpu_clock is not None and self._rows:
+            used = time.thread_time()
+            entry = [_thread_cpu_clock(threading.get_ident()), used, used]
+        with self._lock:
+            self._all_books.append(books)
+            if entry is not None:
+                self._thread_clocks.append(entry)
+        return books
+
+    def _books(self) -> _Books:
+        try:
+            return self._local.books
+        except AttributeError:
+            return self.adopt_thread()
+
+    def sampled(self) -> bool:
+        """Whether the next request is one that is clocked through its
+        stages: the first and every ``sample_one_in``-th after it (called
+        by the one thread that reads the requests)."""
+        turn = self._turn
+        self._turn = turn + 1
+        return turn % self.sample_one_in == 0
+
+    # -- booking --
+
+    def _book(self, books: _Books, slot: int, end: float, wall: float,
+              cpu: float) -> None:
+        """One sample into the calling thread's ``books``."""
+        totals = books.totals
+        at = 3 * slot
+        totals[at] += 1.0
+        totals[at + 1] += wall
+        totals[at + 2] += cpu
+        books.buckets[self._nbuckets * slot
+                      + bisect_left(STAGE_BUCKETS, wall)] += 1
+        if not self._rows:
+            return
+        # The row of ``end``'s second in the thread's ring, cleared if the
+        # ring has come round to it; a second overwritten already is lost.
+        second = int(end)
+        row = second % self._rows
+        base = row * self._width
+        held = books.second[row]
+        if held != second:
+            if held > second:
+                return
+            books.ring[base:base + self._width] = self._zeros
+            books.second[row] = second
+        ring = books.ring
+        at = base + 4 * slot
+        ring[at] += 1.0
+        ring[at + 1] += wall
+        ring[at + 2] += cpu
+        ring[at + 3] = max(ring[at + 3], wall)
+
+    def book(self, name: str, end: float, wall: float,
+             cpu: float = 0.0) -> None:
+        """One sample of one stage, ended at ``end``."""
+        self._book(self._books(), self._slot[name], end, wall, cpu)
+
+    def book_since(self, name: str, since: float) -> None:
+        """One sample of a stage that began at ``since`` on the runtime
+        clock (``SpanTracer.now``) and ends now: two clock reads and one
+        booking at the call site."""
+        end = runtime_now()
+        self.book(name, end, end - since)
+
+    # -- once a second --
+
+    def _read_stamps(self) -> tuple:
+        """STAMPS now.  A thread's CPU clock can be read from another
+        thread; one that has ended keeps what it had used."""
+        threads = 0.0
+        for entry in self._thread_clocks:
+            try:
+                entry[2] = time.clock_gettime(entry[0])
+            except OSError:
+                pass
+            threads += entry[2] - entry[1]
+        return (self.requests, self.signatures, time.process_time(), threads,
+                time.thread_time())
+
+    def stamp(self, now: float) -> None:
+        """In the first call of a whole second of ``now``, read STAMPS into
+        the ring (one CPU clock read a thread, a second); any other call
+        returns at once.  Called by the thread that counts ``requests``,
+        several times a second."""
+        second = int(now)
+        if second == self._stamped or not self._rows:
+            return
+        self._stamped = second
+        row = second % self._rows
+        n = len(self.STAMPS)
+        self._stamps[row * n:(row + 1) * n] = array("d", self._read_stamps())
+        self._stamp_second[row] = second
+
+    def loop_lag(self, lag: float) -> None:
+        """For ``hostattr.LoopLagProbe``: one sample of
+        ``service_loop_lag``, and the tick that ``stamp`` needs."""
+        now = time.monotonic()
+        self.book("service_loop_lag", now, lag)
+        self.stamp(now)
+
+    # -- a request on a pool thread --
+
+    def begin_request(self, ref, handed: float) -> None:
+        """This thread works for request ``ref`` (``(connection label,
+        req_id)``) from now on, and the request is in ``service_pool_wait``
+        since ``handed``, when it was given to the pool: name its next
+        stage with ``request_stage``."""
+        state = _state()
+        frame = state.own_frame
+        if frame is None or frame.clock is not self:
+            frame = state.own_frame = _Frame(self, state)
+        frame.ref = ref
+        frame.slot = self._slot["service_pool_wait"]
+        frame.t0 = handed
+        _tls.frame = frame
+
+    def end_request(self) -> float:
+        """The reply is built: book every REQUEST_STAGES stage of this
+        thread's request, once each (zero seconds where it never was in
+        one); returns the instant."""
+        frame = _tls.frame
+        _tls.frame = None
+        done = frame.switch(-1)
+        walls, cpus, ended = frame.wall, frame.cpu, frame.end
+        books = self._books()
+        for slot in self._request_slots:
+            self._book(books, slot, ended[slot] or done, walls[slot],
+                       cpus[slot])
+            walls[slot] = cpus[slot] = ended[slot] = 0.0
+        return done
+
+    # -- garbage collection --
+
+    def gc_callback(self, phase: str, info: dict) -> None:
+        """For ``gc.callbacks``: a collection stops every thread, on
+        whichever thread tripped it."""
+        state = _state()
+        if phase == "start":
+            state.gc_t0 = time.monotonic()
+            if not state.annotated:
+                annotation = state.gc_annotation = _annotation("service_gc")
+                if annotation is not None:
+                    annotation.__enter__()
+            return
+        t0 = state.gc_t0
+        if t0 is None:
+            return  # installed in the middle of a collection
+        t1 = time.monotonic()
+        # A collection computes and never waits: its CPU is its wall (the
+        # young generations are collected two dozen times a second, and the
+        # CPU clock may be a slow system call).
+        cpu = t1 - t0
+        if state.gc_annotation is not None:
+            state.gc_annotation.__exit__(None, None, None)
+            state.gc_annotation = None
+        state.gc_t0 = None
+        state.gc_cpu += cpu
+        generation = min(2, max(0, int(info.get("generation", 2))))
+        books = self._books()
+        books.gc[2 * generation] += 1.0
+        books.gc[2 * generation + 1] += t1 - t0
+        self._book(books, self._slot["service_gc"], t1, t1 - t0, cpu)
+        if self.tracer is not None:
+            self.tracer.record_span("service_gc", ("gc", generation), t0, t1)
+
+    # -- export --
+
+    def _snapshot(self) -> List[_Books]:
+        with self._lock:
+            return list(self._all_books)
+
+    def totals(self) -> Dict[str, dict]:
+        """Cumulative ``{stage: {"count", "wall_s", "cpu_s", "buckets"}}``
+        over every thread, and ``"answered"``: the requests answered,
+        clocked or not.  ``buckets`` holds one count a bound of
+        STAGE_BUCKETS plus the overflow (not cumulative over the bounds)."""
+        n = self._nbuckets
+        totals = [0.0] * (3 * len(self.stages))
+        buckets = [0] * (n * len(self.stages))
+        for books in self._snapshot():
+            totals = [a + b for a, b in zip(totals, books.totals)]
+            buckets = [a + b for a, b in zip(buckets, books.buckets)]
+        out: Dict[str, dict] = {
+            name: {
+                "count": int(totals[3 * slot]),
+                "wall_s": totals[3 * slot + 1],
+                "cpu_s": totals[3 * slot + 2],
+                "buckets": buckets[n * slot: n * slot + n],
+            }
+            for slot, name in enumerate(self.stages)
+        }
+        out["answered"] = self.requests
+        return out
+
+    def export(self) -> dict:
+        """The ring as the service's report carries it: ``seconds`` maps a
+        whole second of ``clock`` to ``{stage: [count, wall_s, cpu_s,
+        max_wall_s]}``, stages that saw nothing left out, and — for a
+        second that was stamped — STAMPS: ``requests`` and ``signatures``
+        answered, and the CPU seconds the process (``process_cpu_s``), the
+        threads that book here (``threads_cpu_s``; left out where a
+        thread's CPU clock cannot be read from outside it) and the stamping
+        thread itself (``loop_cpu_s``) used, each from that second's stamp
+        to the next one's (the last: to now).  A request stage's ``count``
+        is of the requests that were clocked (one in ``sample_one_in``) and
+        its ``cpu_s`` theirs alone.  Sum the seconds inside a window."""
+        width = self._width
+        rows: Dict[int, list] = {}  # second -> the threads' rows, summed
+        collections = [0.0] * 6
+        for books in self._snapshot():
+            ring, seconds = books.ring[:], books.second[:]
+            collections = [a + b for a, b in zip(collections, books.gc)]
+            for row, second in enumerate(seconds):
+                if second < 0:
+                    continue
+                mine = ring[row * width: (row + 1) * width]
+                into = rows.get(second)
+                if into is None:
+                    rows[second] = list(mine)
+                    continue
+                for at in range(0, width, 4):
+                    longest = max(into[at + 3], mine[at + 3])
+                    for column in (0, 1, 2):
+                        into[at + column] += mine[at + column]
+                    into[at + 3] = longest
+        n = len(self.STAMPS)
+        stamps = sorted(
+            (second, self._stamps[row * n:(row + 1) * n])
+            for row, second in enumerate(self._stamp_second) if second >= 0
+        )
+        stamps.append((None, self._read_stamps()))
+        out: Dict[str, dict] = {}
+        newest = max(rows, default=0)
+        for second in sorted(rows):
+            if second <= newest - self._rows:
+                continue  # a thread long idle still holds it
+            row = rows[second]
+            entry = out[str(second)] = {}
+            for slot, name in enumerate(self.stages):
+                cell = row[4 * slot: 4 * slot + 4]
+                if cell[0]:
+                    entry[name] = [int(cell[0]), cell[1], cell[2], cell[3]]
+        for (second, at), (_, then) in zip(stamps, stamps[1:]):
+            entry = out.setdefault(str(second), {})
+            for i, name in enumerate(self.STAMPS):
+                grown = then[i] - at[i]
+                entry[name] = int(grown) if i < 2 else grown
+            if not self._thread_clocks:
+                del entry["threads_cpu_s"]
+        return {
+            "clock": "time.monotonic",
+            "columns": list(self.COLUMNS),
+            "sample_one_in": self.sample_one_in,
+            "seconds": {s: out[s] for s in sorted(out, key=int)},
+            "gc_generations": {
+                str(g): [int(collections[2 * g]), collections[2 * g + 1]]
+                for g in range(3)
+            },
+        }
+
+
+class stage:  # noqa: N801 - reads as a statement: ``with stage(...):``
+    """One occurrence of a stage that stands alone (not one of a pool
+    thread's request) and books itself into ``clock``::
+
+        with spans.stage("service_decode", clock, since=t_header) as decode:
+            ...
+            decode.ref = (connection, req_id)
+
+    ``since`` moves the stage's start back (it began in a wait); ``ref``,
+    set inside the block, names the span for the clock's tracer; ``end`` is
+    the instant the block was left."""
+
+    __slots__ = ("name", "clock", "since", "ref", "end", "_state", "_c0",
+                 "_g0", "_annotation")
+
+    def __init__(self, name: str, clock: StageClock,
+                 since: Optional[float] = None) -> None:
+        self.name = name
+        self.clock = clock
+        self.since = time.monotonic() if since is None else since
+        self.ref = None
+
+    def __enter__(self) -> "stage":
+        state = self._state = _state()
+        self._annotation = None
+        if not state.annotated:
+            annotation = self._annotation = _annotation(self.name)
+            if annotation is not None:
+                state.annotated = True
+                annotation.__enter__()
+        self._g0 = state.gc_cpu
+        self._c0 = time.thread_time()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        state = self._state
+        cpu = time.thread_time() - self._c0 - (state.gc_cpu - self._g0)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            state.annotated = False
+        self.end = t1 = time.monotonic()
+        clock = self.clock
+        clock.book(self.name, t1, t1 - self.since, cpu)
+        if clock.tracer is not None and self.ref is not None:
+            clock.tracer.record_span(self.name, self.ref, self.since, t1)
+        return False
 
 
 # ---------------------------------------------------------------------------

@@ -114,8 +114,11 @@ def _make_verifier(kind: str, committee: Committee, metrics=None):
             if metrics is not None:
                 # In-process JAX: wire device-side attribution (compile
                 # events, cache hits/misses, transfer bytes) into this
-                # node's registry.  Service-socket validators skip this —
-                # their process never imports jax.
+                # node's registry.  Behind the service socket this process
+                # never imports jax and has nothing to count: the service,
+                # the one process that compiles and transfers, wires the
+                # same series into its own registry (run_service with
+                # --metrics-port).
                 from .ops import ed25519 as _ed25519
 
                 _ed25519.install_device_attribution(metrics)

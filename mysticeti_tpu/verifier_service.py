@@ -66,6 +66,8 @@ cheaper and more accurate.
 from __future__ import annotations
 
 import asyncio
+import contextlib
+import gc
 import itertools
 import json
 import os
@@ -77,11 +79,13 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import List, Optional, Sequence, Tuple
 
+from . import spans
 from .block_validator import (
     CpuSignatureVerifier,
     SignatureVerifier,
     VerifierProtocolError,
 )
+from .hostattr import LoopLagProbe
 from .network import jittered_backoff
 from .verify_pipeline import CompletedDispatch, DeferredDispatch
 from .tracing import logger
@@ -98,6 +102,8 @@ T_ERR = 255
 
 _IDX_REC = 2 + 32 + 64  # u16 idx | digest | sig
 _RAW_REC = 32 + 32 + 64
+# Stands where spans.stage would, for a request that is not clocked.
+_NOT_CLOCKED = contextlib.nullcontext()
 
 ENV_SOCKET = "MYSTICETI_VERIFIER_SOCKET"
 
@@ -202,6 +208,19 @@ class VerifierServer:
         # dispatch shape series, scrapeable when the service CLI runs with
         # --metrics-port (the fleet's verify queue was invisible before).
         self.metrics = metrics
+        # Where a request's milliseconds and the process's core-seconds go,
+        # by stage (spans.SERVICE_STAGES): always on, one request in
+        # spans.SAMPLE_ONE_IN clocked whole, scraped through ``metrics``
+        # when there is one, and written as the last 600 whole seconds of
+        # time.monotonic into the report at ``stop``.
+        self.stages = spans.StageClock(
+            spans.SERVICE_STAGES,
+            ring_seconds=spans.StageClock.RING_SECONDS,
+            tracer=spans.active(),
+            sample_one_in=spans.SAMPLE_ONE_IN,
+        )
+        if metrics is not None:
+            metrics.verifier_service_stages.attach(self.stages)
         self._conn_ids = itertools.count()
         self._warmed = threading.Event()
         self._warm_lock = threading.Lock()
@@ -209,7 +228,8 @@ class VerifierServer:
         # worker thread on the device fetch, and overlapping those
         # round-trips is the entire point of sharing the runtime.
         self._pool = ThreadPoolExecutor(
-            max_workers=16, thread_name_prefix="verify-dispatch"
+            max_workers=16, thread_name_prefix="verify-dispatch",
+            initializer=self.stages.adopt_thread,
         )
         self._server: Optional[asyncio.AbstractServer] = None
         self._writers: set = set()
@@ -287,6 +307,7 @@ class VerifierServer:
         report = describe()
         report["warm_seconds"] = round(self._warm_seconds, 3)
         report["calibration"] = self._calibration
+        report["stages"] = self.stages.export()
         path = report_path(self.socket_path)
         with open(path + ".tmp", "w") as f:
             json.dump(report, f, indent=1)
@@ -349,7 +370,8 @@ class VerifierServer:
         conn_label = f"c{next(self._conn_ids)}"
         replies: asyncio.Queue = asyncio.Queue(maxsize=self.PIPELINE_DEPTH)
         reply_task = spawn_logged(
-            self._reply_writer(replies, writer), log, name="verifier-replies"
+            self._reply_writer(replies, writer, conn_label), log,
+            name="verifier-replies",
         )
 
         def _accounted():
@@ -391,7 +413,8 @@ class VerifierServer:
                 # otherwise cost a device round-trip per queued frame).
                 return None
             return await loop.run_in_executor(
-                self._pool, self._result_reply, type_, req_id, n, body
+                self._pool, self._result_reply, type_, req_id, n, body,
+                conn_label, time.monotonic(),
             )
 
         try:
@@ -400,6 +423,7 @@ class VerifierServer:
                     header = await reader.readexactly(5)
                 except asyncio.IncompleteReadError:
                     return
+                t_header = time.monotonic()
                 if reply_task.done():
                     return  # writer died (client gone, backend crash)
                 length, type_ = struct.unpack("<IB", header)
@@ -428,39 +452,51 @@ class VerifierServer:
                     last_hello = fut
                     await replies.put((fut, None, False))
                 elif type_ in (T_VERIFY, T_RAW):
-                    if length < 8:
+                    # service_decode: header read -> frame checked and
+                    # handed to the pool (the CPU clock and the profiler's
+                    # annotation start here, with the payload in hand: no
+                    # await lies between this line and the hand-over).
+                    # One request in spans.SAMPLE_ONE_IN is clocked, whole:
+                    # ``decode`` is None for the others.
+                    malformed = False
+                    with (spans.stage("service_decode", self.stages,
+                                      since=t_header)
+                          if self.stages.sampled()
+                          else _NOT_CLOCKED) as decode:
+                        if length >= 8:
+                            req_id, n = struct.unpack_from("<II", payload)
+                            if decode is not None:
+                                decode.ref = (conn_label, req_id)
+                            # memoryview, not a bytes slice: the request
+                            # body is the bulk of every frame, and the
+                            # per-record digest/sig slices below stay views
+                            # too — the payload bytes the reader produced
+                            # are the LAST host copy before the backend
+                            # packs them device-ward.
+                            body = memoryview(payload)[8:]
+                            rec = _IDX_REC if type_ == T_VERIFY else _RAW_REC
+                        if length < 8 or len(body) != n * rec:
+                            malformed = True
+                        elif last_hello is not None and last_hello.done():
+                            rejected = last_hello.cancelled() or (
+                                last_hello.exception() is not None
+                                or last_hello.result()[4] == T_ERR
+                            )
+                            if rejected:
+                                # The writer is severing after the HELLO's
+                                # ERR: frames pipelined behind it must not
+                                # burn backend dispatches for replies that
+                                # will be discarded in drain mode.
+                                return
+                            last_hello = None  # accepted: no more gating
+                        if not malformed:
+                            done = _accounted()
+                    if malformed:
                         await replies.put(
                             (_frame(T_ERR, b"malformed verify frame"),
                              None, True)
                         )
                         return
-                    req_id, n = struct.unpack_from("<II", payload)
-                    # memoryview, not a bytes slice: the request body is the
-                    # bulk of every frame, and the per-record digest/sig
-                    # slices below stay views too — the payload bytes the
-                    # reader produced are the LAST host copy before the
-                    # backend packs them device-ward.
-                    body = memoryview(payload)[8:]
-                    rec = _IDX_REC if type_ == T_VERIFY else _RAW_REC
-                    if len(body) != n * rec:
-                        await replies.put(
-                            (_frame(T_ERR, b"malformed verify frame"),
-                             None, True)
-                        )
-                        return
-                    if last_hello is not None and last_hello.done():
-                        rejected = last_hello.cancelled() or (
-                            last_hello.exception() is not None
-                            or last_hello.result()[4] == T_ERR
-                        )
-                        if rejected:
-                            # The writer is severing after the HELLO's ERR:
-                            # frames pipelined behind it must not burn
-                            # backend dispatches for replies that will be
-                            # discarded in drain mode.
-                            return
-                        last_hello = None  # accepted: no more gating needed
-                    done = _accounted()
                     if last_hello is not None:
                         # Awaited by the reply writer in order, which
                         # observes its exception.
@@ -470,7 +506,8 @@ class VerifierServer:
                     else:
                         fut = loop.run_in_executor(
                             self._pool, self._result_reply,
-                            type_, req_id, n, body,
+                            type_, req_id, n, body, conn_label,
+                            None if decode is None else decode.end,
                         )
                     await replies.put((fut, done, False))
                 else:
@@ -547,7 +584,8 @@ class VerifierServer:
             writer.close()
 
     async def _reply_writer(self, replies: asyncio.Queue,
-                            writer: asyncio.StreamWriter) -> None:
+                            writer: asyncio.StreamWriter,
+                            conn_label: str = "") -> None:
         """Emit queued replies in request order; ``None`` ends the stream.
         Queue items are ``(frame_or_future, cleanup, close_after)``.  A
         dispatch failure or a dead client socket flips to drain mode —
@@ -555,7 +593,9 @@ class VerifierServer:
         and the transport is closed so the reader unblocks.
 
         A reply is either a prebuilt ``bytes`` frame (HELLO_OK, ERR) or a
-        ``(type, parts)`` tuple from the verify path: a fresh 5-byte header
+        ``(type, parts, built)`` tuple from the verify path (``built``: when
+        the pool thread was done with it, None for a request that is not
+        clocked): a fresh 5-byte header
         rides ``writer.writelines`` with the parts as-is — scatter-gather,
         no header+payload concatenation per reply.  The header must be a
         fresh immutable object per reply: since 3.12 the selector transport
@@ -563,6 +603,7 @@ class VerifierServer:
         backpressure, so a reused mutable scratch could be rewritten while
         frame N still sits unsent in the transport buffer."""
         dead = False
+        stages = self.stages
         while True:
             item = await replies.get()
             if item is None:
@@ -580,7 +621,7 @@ class VerifierServer:
                     writer.close()
                     continue
                 if isinstance(frame, tuple):
-                    type_, parts = frame
+                    type_, parts, built = frame
                 else:
                     type_, parts = frame[4], None
                 if type_ == T_ERR:
@@ -596,6 +637,28 @@ class VerifierServer:
                     else:
                         writer.write(frame)
                     await writer.drain()
+                    if parts is not None:
+                        # Counted for every request: two sums of this
+                        # thread's, which the clock's stamp reads once a
+                        # second.
+                        stages.requests += 1
+                        stages.signatures += len(parts[1])
+                    if parts is not None and built is not None:
+                        # service_reply_wait: reply built -> written, i.e.
+                        # the loop's wake-up, the earlier replies of this
+                        # connection, then the write.
+                        written = time.monotonic()
+                        stages.book(
+                            "service_reply_wait", written, written - built
+                        )
+                        tracer = stages.tracer
+                        if tracer is not None:
+                            tracer.record_span(
+                                "service_reply_wait",
+                                (conn_label,
+                                 struct.unpack("<I", parts[0])[0]),
+                                built, written,
+                            )
                 except (ConnectionResetError, BrokenPipeError, OSError):
                     dead = True
                     continue
@@ -632,15 +695,29 @@ class VerifierServer:
             payload += self._resolved_backend().encode("ascii", "replace")
         return _frame(T_HELLO_OK, payload)
 
-    def _result_reply(self, type_: int, req_id: int, n: int, body) -> tuple:
-        """Verify and return the reply as ``(T_RESULT, parts)`` — the writer
-        packs the frame header into its per-connection scratch and
+    def _result_reply(self, type_: int, req_id: int, n: int, body,
+                      conn_label: str = "", handed: Optional[float] = None,
+                      ) -> tuple:
+        """Verify and return the reply as ``(T_RESULT, parts, built)`` — the
+        writer packs the frame header into its per-connection scratch and
         scatter-gathers the parts, so the verdicts are copied exactly once
-        (list -> bytes) on their way out."""
-        oks = self._verify_payload(type_, n, body)
-        return (T_RESULT, (struct.pack("<I", req_id), bytes(oks)))
+        (list -> bytes) on their way out.  The pool thread works for this
+        request from here to ``built``: every ``spans.request_stage`` below, in
+        the backend too, names the stage it is in."""
+        if handed is None:  # not one of the requests that are clocked
+            return (T_RESULT, (struct.pack("<I", req_id),
+                               self._verify_payload(type_, n, body)), None)
+        stages = self.stages
+        stages.begin_request((conn_label, req_id), handed)
+        spans.request_stage("service_unpack")
+        try:
+            parts = (struct.pack("<I", req_id),
+                     self._verify_payload(type_, n, body))
+        finally:
+            built = stages.end_request()
+        return (T_RESULT, parts, built)
 
-    def _verify_payload(self, type_: int, n: int, body: bytes) -> List[int]:
+    def _verify_payload(self, type_: int, n: int, body: bytes) -> bytes:
         backend = self._ensure_backend(self._keys or [])
         pks, digests, sigs = [], [], []
         if type_ == T_VERIFY:
@@ -662,7 +739,13 @@ class VerifierServer:
                 pks.append(body[off: off + 32])
                 digests.append(body[off + 32: off + 64])
                 sigs.append(body[off + 64: off + 128])
+        # The backend's time is the fetch's (device run + transfer + getting
+        # the GIL back; a host oracle's whole work), but for the stages it
+        # names itself: the JAX backend packs and launches first
+        # (ops/ed25519.py) and fetches in VerifyDispatch.result.
+        spans.request_stage("service_fetch")
         oks = backend.verify_signatures(pks, digests, sigs)
+        spans.request_stage("service_reply_build")
         if self.metrics is not None:
             # The service owns the device, so it (not the jax-free clients)
             # is where dispatch shape and padding waste are measurable.
@@ -672,7 +755,7 @@ class VerifierServer:
                 self.metrics.verify_padding_wasted_total.labels(
                     "service"
                 ).inc(max(0, padder(n) - n))
-        return [1 if ok else 0 for ok in oks]
+        return bytes([1 if ok else 0 for ok in oks])
 
     # -- lifecycle --
 
@@ -1126,6 +1209,11 @@ class _RemoteDispatch:
         self._n = n
         self._args = (public_keys, digests, signatures)
 
+    @property
+    def req_id(self) -> int:
+        """What the service's spans of this request carry too."""
+        return self._req_id
+
     def result(self) -> List[bool]:
         client = self._client
         try:
@@ -1184,7 +1272,9 @@ def run_service(socket_path: str, committee_keys: Optional[Sequence[bytes]] = No
                 devices: Optional[int] = None) -> None:
     """Blocking entry point for the CLI subcommand.  With ``metrics_port``
     the service also exposes /metrics + /healthz (queue depth, per-connection
-    in-flight, dispatch batch sizes, padding waste).
+    in-flight, dispatch batch sizes, padding waste, the stages of a request,
+    JAX compiles and host<->device bytes) and probes its event loop's lag.
+    ``MYSTICETI_TRACE`` records every stage as a span, as in a validator.
 
     SIGTERM stops the server and returns, so the interpreter exits in order
     and the JAX runtime lets go of the chip before the next holder starts
@@ -1205,6 +1295,27 @@ def run_service(socket_path: str, committee_keys: Optional[Sequence[bytes]] = No
             socket_path, committee_keys=committee_keys, metrics=metrics,
             devices=devices,
         )
+        # service_gc: a collection stops all sixteen pool threads and the
+        # loop at once, whichever thread trips it.  A hook of the process,
+        # so it is set here and not by every VerifierServer a test builds.
+        gc.callbacks.append(server.stages.gc_callback)
+        # The loop reads every request and writes every reply while sixteen
+        # pool threads compete with it for the GIL: its lag is the part of
+        # a round trip that no stage of a request sees.  The probe books
+        # into the stage clock alone (scraped as
+        # verifier_service_stage_seconds{stage="service_loop_lag"}; the
+        # probe's own prometheus series and percentile sort would run on
+        # the loop at every tick), and its tick is what stamps the ring's
+        # seconds with the requests answered and the CPU used.
+        probe = LoopLagProbe(
+            interval_s=0.1, on_lag=server.stages.loop_lag
+        ).start()
+        if metrics is not None:
+            # This is the one process that compiles and transfers: the
+            # mysticeti_jax_* and device-transfer series count here.
+            from .ops.ed25519 import install_device_attribution
+
+            install_device_attribution(metrics)
         serving = asyncio.ensure_future(server.serve_forever())
         asyncio.get_running_loop().add_signal_handler(
             signal.SIGTERM, serving.cancel
@@ -1213,8 +1324,15 @@ def run_service(socket_path: str, committee_keys: Optional[Sequence[bytes]] = No
             await serving
         except asyncio.CancelledError:
             await server.stop()
+        finally:
+            gc.callbacks.remove(server.stages.gc_callback)
+            probe.stop()
+
+    spans.start_from_env()
 
     try:
         asyncio.run(_main())
     except KeyboardInterrupt:
         pass
+    finally:
+        spans.stop_from_env()

@@ -102,11 +102,6 @@ class VerifyPipeline:
         self._inflight = 0
         self.max_inflight = 0  # high-water mark (tests/telemetry)
         self._waiters: deque = deque()
-        # Host attribution plane: cumulative stage seconds, reduced to
-        # dispatch-occupancy fractions (device-busy vs host-pack vs
-        # fetch-wait) for mysticeti_verify_occupancy_fraction.
-        self._stage_totals = {STAGE_PACK: 0.0, STAGE_DEVICE: 0.0,
-                              STAGE_FETCH: 0.0}
 
     # -- depth policy --
 
@@ -159,27 +154,13 @@ class VerifyPipeline:
     # -- stage accounting --
 
     def note_stage(self, stage: str, seconds: float) -> None:
-        if stage in self._stage_totals:
-            self._stage_totals[stage] += max(0.0, seconds)
+        """One dispatch's seconds in one stage.  The shares of dispatch time
+        (device-busy / host-pack / fetch-wait) are this histogram's sums over
+        their total: ``tools/perf_attr.py`` derives them from a scrape."""
         if self.metrics is not None:
             self.metrics.verify_pipeline_stage_seconds.labels(stage).observe(
                 seconds
             )
-            for phase, fraction in self.occupancy().items():
-                self.metrics.mysticeti_verify_occupancy_fraction.labels(
-                    phase
-                ).set(round(fraction, 6))
-
-    def occupancy(self) -> dict:
-        """Where dispatch wall time goes: {pack, device, fetch} fractions of
-        the cumulative stage seconds (all zero before the first dispatch)."""
-        total = sum(self._stage_totals.values())
-        if total <= 0:
-            return {stage: 0.0 for stage in self._stage_totals}
-        return {
-            stage: seconds / total
-            for stage, seconds in self._stage_totals.items()
-        }
 
 
 class _PipelineSlot:

@@ -309,6 +309,55 @@ def test_loop_lag_probe_measures_a_blocked_loop():
     assert probe.percentile(99) >= 0.05  # saw the 80 ms hold
 
 
+def test_loop_lag_probe_tells_a_listener_every_sample():
+    """The verifier service books the probe's samples as its
+    ``service_loop_lag`` stage through ``on_lag``."""
+    from mysticeti_tpu import spans
+
+    clock = spans.StageClock(spans.SERVICE_STAGES, ring_seconds=8)
+
+    async def drive():
+        probe = LoopLagProbe(
+            interval_s=0.01,
+            on_lag=lambda lag: clock.book(
+                "service_loop_lag", time.monotonic(), lag),
+        ).start()
+        await asyncio.sleep(0.03)
+        time.sleep(0.08)
+        await asyncio.sleep(0.03)
+        probe.stop()
+        return probe
+
+    probe = asyncio.run(drive())
+    row = clock.totals()["service_loop_lag"]
+    assert row["count"] == probe.sample_count() >= 3
+    assert row["wall_s"] >= 0.05 and row["cpu_s"] == 0.0
+
+
+def test_perf_attr_derives_the_occupancy_shares_from_the_stage_sums(
+        monkeypatch):
+    """No gauge carries the shares any more: they are each stage's part of
+    the ``verify_pipeline_stage_seconds`` sums of a scrape."""
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    import perf_attr
+
+    text = "\n".join([
+        'verify_pipeline_stage_seconds_sum{stage="pack"} 1.0',
+        'verify_pipeline_stage_seconds_sum{stage="device"} 1.0',
+        'verify_pipeline_stage_seconds_sum{stage="fetch"} 6.0',
+        'verify_pipeline_stage_seconds_count{stage="fetch"} 9',
+    ])
+    monkeypatch.setattr(
+        perf_attr, "_http_get",
+        lambda host, port, path, timeout=3.0:
+        text if path == "/metrics" else None)
+    scrape = perf_attr.scrape_node("127.0.0.1", 1)
+    assert scrape["occupancy"] == {"pack": 0.125, "device": 0.125,
+                                   "fetch": 0.75}
+    doc = perf_attr.aggregate({"n0": scrape}, {})
+    assert doc["device"]["occupancy_fractions"]["fetch"] == 0.75
+
+
 def test_host_monitor_state_shape():
     monitor = HostMonitor(blocking_threshold_ms=50.0)
     state = monitor.state()

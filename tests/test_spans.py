@@ -84,6 +84,72 @@ def test_event_cap_drops_instead_of_growing():
     assert tracer.dropped == 2
 
 
+# -- the always-on stage clock ------------------------------------------------
+
+
+def test_the_service_stages_are_registered_stage_names():
+    """Every name the stage clock books is in the central registry (the
+    ``span-names`` lint parses it), and the sets that sort them agree."""
+    assert spans.SERVICE_STAGES == (
+        "service_decode", "service_pool_wait", "service_unpack",
+        "service_pack", "service_launch", "service_fetch",
+        "service_reply_build", "service_reply_wait", "service_gc",
+        "service_loop_lag",
+    )
+    assert set(spans.SERVICE_STAGES) <= set(spans.STAGES)
+    assert set(spans.REQUEST_STAGES) < set(spans.SERVICE_STAGES)
+    assert spans.WAITING_STAGES < set(spans.SERVICE_STAGES)
+    assert set(spans.BLOCK_PATH_STAGES) <= set(PIPELINE_STAGES)
+    # A service request's span is labelled by (connection, req_id).
+    assert format_ref(("c3", 17)) == "c3#17"
+
+
+def test_a_stage_that_stands_alone_books_itself_and_its_span():
+    tracer = SpanTracer()
+    clock = spans.StageClock(spans.SERVICE_STAGES, ring_seconds=8,
+                             tracer=tracer)
+    with spans.stage("service_decode", clock) as decode:
+        decode.since -= 0.5  # it began in a wait, half a second ago
+        decode.ref = ("c0", 9)
+    row = clock.totals()["service_decode"]
+    assert row["count"] == 1 and 0.5 <= row["wall_s"] < 0.6
+    assert row["cpu_s"] < 0.1  # the wait is wall, not CPU
+    (event,) = [e for e in tracer.chrome_trace()["traceEvents"]
+                if e["ph"] == "X"]
+    assert (event["name"], event["args"]["block"]) == ("service_decode", "c0#9")
+    (second,) = clock.export()["seconds"].values()
+    assert second["service_decode"][0] == 1
+    assert second["service_decode"][3] == row["wall_s"]
+
+
+def test_block_path_stages_are_always_on_and_virtual_under_the_sim(tmp_path):
+    """Without any tracer a validator books receive / verify / dag_add per
+    received batch into ``block_stage_seconds``; under the simulator the
+    clock is virtual, so two same-seed runs scrape identical text."""
+    from prometheus_client import generate_latest
+
+    from mysticeti_tpu.metrics import Metrics
+
+    def scrape(name):
+        (tmp_path / name).mkdir()
+        metrics = [Metrics() for _ in range(4)]
+        run_simulation(
+            _run_nodes(4, str(tmp_path / name), 3.0, metrics=metrics), seed=5)
+        return [
+            line for line in
+            generate_latest(metrics[0].registry).decode().splitlines()
+            if line.startswith("block_stage_seconds")
+        ]
+
+    first, second = scrape("a"), scrape("b")
+    assert first == second
+    counts = {line.split('stage="')[1].split('"')[0]: float(line.split()[-1])
+              for line in first if line.startswith("block_stage_seconds_count")}
+    assert set(counts) == set(spans.BLOCK_PATH_STAGES)
+    assert all(count > 10 for count in counts.values()), counts
+    assert spans.active() is None
+
+
 # -- the 10-node deterministic-sim acceptance path ---------------------------
 
 class _SimNodeNetwork:
@@ -94,7 +160,8 @@ class _SimNodeNetwork:
         pass
 
 
-def _build_node(committee, signers, authority, tmp_dir, sim_net, parameters):
+def _build_node(committee, signers, authority, tmp_dir, sim_net, parameters,
+                metrics=None):
     wal_writer, wal_reader = walf(os.path.join(tmp_dir, f"wal-{authority}"))
     recovered, observer_recovered = BlockStore.open(
         authority, wal_reader, wal_writer, committee
@@ -122,16 +189,18 @@ def _build_node(committee, signers, authority, tmp_dir, sim_net, parameters):
         observer,
         _SimNodeNetwork(sim_net.node_connections[authority]),
         parameters=parameters,
+        metrics=metrics,
     )
 
 
-async def _run_nodes(n, tmp_dir, virtual_seconds):
+async def _run_nodes(n, tmp_dir, virtual_seconds, metrics=None):
     committee = Committee.new_test([1] * n)
     signers = Committee.benchmark_signers(n)
     parameters = Parameters(leader_timeout_s=1.0)
     sim_net = SimulatedNetwork(n)
     nodes = [
-        _build_node(committee, signers, a, tmp_dir, sim_net, parameters)
+        _build_node(committee, signers, a, tmp_dir, sim_net, parameters,
+                    metrics=metrics[a] if metrics else None)
         for a in range(n)
     ]
     for node in nodes:

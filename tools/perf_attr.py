@@ -82,8 +82,8 @@ def scrape_node(host: str, port: int) -> Optional[dict]:
             out["loop_lag_p99_s"] = value
         elif name == "mysticeti_gil_convoy_ratio":
             out["gil_convoy_ratio"] = value
-        elif name == "mysticeti_verify_occupancy_fraction":
-            out["occupancy"][labels.get("phase", "?")] = value
+        elif name == "verify_pipeline_stage_seconds_sum":
+            out["occupancy"][labels.get("stage", "?")] = value
         elif name in (
             "mysticeti_jax_compiles_total",
             "mysticeti_jax_compile_seconds_total",
@@ -100,6 +100,12 @@ def scrape_node(host: str, port: int) -> Optional[dict]:
             out["slo_alerts"][kind] = out["slo_alerts"].get(kind, 0.0) + value
     # /health is served on the node's event loop, so under saturating load
     # it lags far behind the thread-served /metrics route — give it room.
+    # Dispatch occupancy: each stage's share of the cumulative stage seconds.
+    stage_total = sum(out["occupancy"].values())
+    out["occupancy"] = {
+        stage: (seconds / stage_total if stage_total > 0 else 0.0)
+        for stage, seconds in out["occupancy"].items()
+    }
     health = _http_get(host, port, "/health", timeout=10.0)
     if health:
         try:
