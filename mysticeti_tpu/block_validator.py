@@ -331,6 +331,18 @@ class TpuSignatureVerifier(SignatureVerifier):
             "dispatches": E.dispatch_counts(),
         }
 
+    def warmed_batch(self) -> int:
+        """The most signatures one call can hold without reaching a bucket
+        that ``warmup`` did not compile (the smallest bucket, unless it
+        warmed every shape): what the verifier service holds a launch of
+        several validators' requests to."""
+        from .ops.ed25519 import BUCKETS
+
+        return max(
+            (entry["bucket"] for entry in self.kernel_report),
+            default=BUCKETS[0],
+        )
+
     def padded_batch(self, n: int) -> int:
         """Lanes dispatched for n signatures under the kernel's fixed bucket
         shapes (``ops.ed25519.iter_buckets`` is the single source of truth,

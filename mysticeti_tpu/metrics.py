@@ -386,8 +386,15 @@ class Metrics:
         self.verify_dispatch_batch_size = histogram(
             "verify_dispatch_batch_size",
             "signatures per ACTUAL backend dispatch (after aggregation "
-            "skips; verify_batch_size is the collector flush size)",
+            "skips; verify_batch_size is the collector flush size; in the "
+            "verifier service: per launch, every request it carries)",
             buckets=[1, 8, 32, 64, 128, 256, 512, 1024, 4096],
+        )
+        self.verifier_service_coalesced_requests = histogram(
+            "verifier_service_coalesced_requests",
+            "requests one verifier-service launch carried: every request "
+            "pending when a dispatcher thread came free (1 = launched alone)",
+            buckets=[1, 2, 4, 8, 16, 32, 64, 128, 256],
         )
         self.verify_padding_wasted_total = counter(
             "verify_padding_wasted_total",

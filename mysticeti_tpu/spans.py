@@ -464,9 +464,13 @@ def stop_from_env() -> None:
 # (``service_gc`` and ``service_loop_lag`` belong to the process, not to a
 # request).  Every name is also in STAGES.
 SERVICE_STAGES = STAGES[STAGES.index("service_decode"):]
-# Per request, booked together when a pool thread is done with it — each
+# Per request, booked together when the launch it rode is done — each
 # exactly once a request, with zero seconds where the backend has no such
-# stage (a host oracle packs and launches nothing).
+# stage (a host oracle packs and launches nothing).  A launch carries every
+# request that was pending when a dispatcher thread came free: each clocked
+# one of them books the launch's stages with their wall whole (it did wait
+# that long) and their CPU divided by the requests in the launch, so that
+# the working stages' CPU times the request rate still sums to cores.
 REQUEST_STAGES = SERVICE_STAGES[1:7]
 # The eight stages of a request, header read to reply written: clocked for
 # the requests that are sampled (SAMPLE_ONE_IN), so their counts are of
@@ -475,8 +479,8 @@ SAMPLED_STAGES = SERVICE_STAGES[:8]
 # A validator's verification path, one sample a received batch of blocks
 # (net_sync.py): the always-on twins of the per-block spans of those names.
 BLOCK_PATH_STAGES = ("receive", "verify", "dag_add")
-# Stages in which the thread waits (for a pool thread, the device, the
-# loop, the GIL): wall time only, no CPU clock and no profiler annotation —
+# Stages in which a request waits (for a launch, the device, the loop, the
+# GIL): wall time only, no CPU clock and no profiler annotation —
 # the runtime's own events mark them in a trace already.
 WAITING_STAGES = frozenset({
     "service_pool_wait", "service_fetch", "service_reply_wait",
@@ -502,7 +506,7 @@ STAGE_BUCKETS = (
 
 class _ThreadState:
     """What the stage clock knows of one thread: the frame it clocks its
-    requests in, whether a profiler annotation is open on it (annotations
+    launches in, whether a profiler annotation is open on it (annotations
     are flat, never nested), and the CPU seconds it has spent collecting
     garbage (which a stage that a collection interrupted takes off its
     own)."""
@@ -519,9 +523,9 @@ class _ThreadState:
 
 class _Local(threading.local):
     """``state``: the thread's _ThreadState, made at its first use;
-    ``frame``: the _Frame of the clocked request the thread works for now,
-    None between such requests (a class default, so that reading it costs
-    a thread that never clocked one nothing)."""
+    ``frame``: the _Frame of the launch the thread works for now, if a
+    clocked request rides it, None otherwise (a class default, so that
+    reading it costs a thread that never clocked one nothing)."""
 
     state = None
     frame = None
@@ -555,46 +559,51 @@ def _annotation(name: str):
 
 
 class _Frame:
-    """One thread's accumulators for the request it works for, and the
-    stage that request is in; made once a thread and reused, so a request
-    leaves no object behind."""
+    """One thread's accumulators for the launch it works for, and the
+    stage that launch is in; made once a thread and reused, so a launch
+    leaves no object behind.  ``members``: ``(ref, handed)`` of every
+    clocked request that rides the launch."""
 
-    __slots__ = ("clock", "state", "ref", "wall", "cpu", "end", "slot", "t0",
-                 "c0", "g0", "annotation")
+    __slots__ = ("clock", "state", "members", "taken", "wall", "cpu", "end",
+                 "slot", "t0", "c0", "g0", "annotation")
 
     def __init__(self, clock: "StageClock", state: _ThreadState) -> None:
         self.clock = clock
         self.state = state
-        self.ref = None
+        self.members = ()
         n = len(clock.stages)
         self.wall = [0.0] * n
         self.cpu = [0.0] * n
         self.end = [0.0] * n
-        self.slot = 0
-        self.t0 = self.c0 = self.g0 = 0.0
+        self.slot = -1
+        self.taken = self.t0 = self.c0 = self.g0 = 0.0
         self.annotation = None
 
     def switch(self, slot: int) -> float:
-        """The request leaves the stage it is in and enters ``slot`` (-1:
-        none, its reply is built).  One read of each clock serves both
-        sides of the boundary, so a request's stages tile its time on the
-        pool thread exactly; returns the boundary's instant."""
+        """The launch leaves the stage it is in (-1: none yet) and enters
+        ``slot`` (-1: none, its replies are built).  One read of each clock
+        serves both sides of the boundary, so a launch's stages tile its
+        time on the thread exactly; returns the boundary's instant."""
         clock, state = self.clock, self.state
         cur = self.slot
         working = clock._working
         cpu_now = time.thread_time()
         now = time.monotonic()
-        self.wall[cur] += now - self.t0
-        self.end[cur] = now
-        if working[cur]:
-            self.cpu[cur] += cpu_now - self.c0 - (state.gc_cpu - self.g0)
-            if self.annotation is not None:
-                self.annotation.__exit__(None, None, None)
-                self.annotation = None
-                state.annotated = False
-        if clock.tracer is not None:
-            clock.tracer.record_span(clock.stages[cur], self.ref, self.t0, now)
-        self.slot, self.t0, self.c0, self.g0 = slot, now, cpu_now, state.gc_cpu
+        if cur >= 0:
+            self.wall[cur] += now - self.t0
+            self.end[cur] = now
+            if working[cur]:
+                self.cpu[cur] += cpu_now - self.c0 - (state.gc_cpu - self.g0)
+                if self.annotation is not None:
+                    self.annotation.__exit__(None, None, None)
+                    self.annotation = None
+                    state.annotated = False
+            if clock.tracer is not None:
+                for ref, _ in self.members:
+                    clock.tracer.record_span(
+                        clock.stages[cur], ref, self.t0, now)
+        self.slot = slot
+        self.t0, self.c0, self.g0 = now, cpu_now, state.gc_cpu
         if slot >= 0 and working[slot]:
             annotation = self.annotation = _annotation(clock.stages[slot])
             if annotation is not None:
@@ -604,11 +613,11 @@ class _Frame:
 
 
 def request_stage(name: str) -> None:
-    """The clocked request this thread works for is in stage ``name`` from
-    now on, until the next stage is named or its reply is built
-    (``StageClock.end_request``).  For a request that is not clocked, and
-    outside any — a backend used in process, a warm-up — this does
-    nothing."""
+    """The launch this thread works for is in stage ``name`` from now on,
+    until the next stage is named or its replies are built
+    (``StageClock.end_launch``).  For a launch that no clocked request
+    rides, and outside any — a backend used in process, a warm-up — this
+    does nothing."""
     frame = _tls.frame
     if frame is None:
         return
@@ -656,9 +665,10 @@ class StageClock:
     RING_SECONDS = 600
     COLUMNS = ("count", "wall_s", "cpu_s", "max_wall_s")
     # What ``stamp`` reads once a second, cumulative; the ring's seconds
-    # hold the growth from one stamp to the next.
-    STAMPS = ("requests", "signatures", "process_cpu_s", "threads_cpu_s",
-              "loop_cpu_s")
+    # hold the growth from one stamp to the next (whole numbers, but for
+    # the seconds, ``*_s``).
+    STAMPS = ("requests", "signatures", "launches", "process_cpu_s",
+              "threads_cpu_s", "loop_cpu_s")
 
     def __init__(self, stages: Sequence[str], ring_seconds: int = 0,
                  tracer: Optional[SpanTracer] = None,
@@ -666,10 +676,12 @@ class StageClock:
         self.stages = tuple(stages)
         self.tracer = tracer
         self.sample_one_in = sample_one_in
-        # Replies written and the signatures in them, clocked or not: plain
-        # sums of the one thread that writes replies (which also stamps).
+        # Replies written, the signatures in them and the launches that
+        # answered them, clocked or not: plain sums of the one thread that
+        # writes replies (which also stamps).
         self.requests = 0
         self.signatures = 0
+        self.launches = 0
         self._slot = {name: i for i, name in enumerate(self.stages)}
         self._request_slots = [
             self._slot[name] for name in REQUEST_STAGES if name in self._slot
@@ -694,8 +706,8 @@ class StageClock:
 
     def adopt_thread(self) -> _Books:
         """Make the calling thread's books and note its CPU clock, so that
-        ``stamp`` counts what it uses from now on (a pool's ``initializer``;
-        any other thread is adopted at its first sample)."""
+        ``stamp`` counts what it uses from now on (a dispatcher thread's
+        first call; any other thread is adopted at its first sample)."""
         books = self._local.books = _Books(
             len(self.stages), self._nbuckets, self._rows)
         entry = None
@@ -778,8 +790,8 @@ class StageClock:
             except OSError:
                 pass
             threads += entry[2] - entry[1]
-        return (self.requests, self.signatures, time.process_time(), threads,
-                time.thread_time())
+        return (self.requests, self.signatures, self.launches,
+                time.process_time(), threads, time.thread_time())
 
     def stamp(self, now: float) -> None:
         """In the first call of a whole second of ``now``, read STAMPS into
@@ -802,34 +814,47 @@ class StageClock:
         self.book("service_loop_lag", now, lag)
         self.stamp(now)
 
-    # -- a request on a pool thread --
+    # -- a launch on a dispatcher thread --
 
-    def begin_request(self, ref, handed: float) -> None:
-        """This thread works for request ``ref`` (``(connection label,
-        req_id)``) from now on, and the request is in ``service_pool_wait``
-        since ``handed``, when it was given to the pool: name its next
+    def begin_launch(self, members: Sequence[tuple]) -> None:
+        """This thread works from now on for a launch that the clocked
+        requests ``members`` ride — ``(ref, handed)`` each: ``(connection
+        label, req_id)`` and when the request was handed over.  Each was in
+        ``service_pool_wait`` from then until now; name the launch's next
         stage with ``request_stage``."""
         state = _state()
         frame = state.own_frame
         if frame is None or frame.clock is not self:
             frame = state.own_frame = _Frame(self, state)
-        frame.ref = ref
-        frame.slot = self._slot["service_pool_wait"]
-        frame.t0 = handed
+        frame.members = members
+        frame.slot = -1
+        frame.taken = taken = time.monotonic()
+        if self.tracer is not None:
+            for ref, handed in members:
+                self.tracer.record_span(
+                    "service_pool_wait", ref, handed, taken)
         _tls.frame = frame
 
-    def end_request(self) -> float:
-        """The reply is built: book every REQUEST_STAGES stage of this
-        thread's request, once each (zero seconds where it never was in
-        one); returns the instant."""
+    def end_launch(self, riders: int) -> float:
+        """The replies are built: for every clocked request of this
+        thread's launch book every REQUEST_STAGES stage, once each (zero
+        seconds where the launch never was in one) — its own wait for the
+        launch, then the launch's stages with their wall whole and their
+        CPU divided by ``riders``, the requests the launch carried, clocked
+        or not; returns the instant."""
         frame = _tls.frame
         _tls.frame = None
         done = frame.switch(-1)
         walls, cpus, ended = frame.wall, frame.cpu, frame.end
         books = self._books()
+        waited = self._slot["service_pool_wait"]
+        ended[waited] = taken = frame.taken
+        for _, handed in frame.members:
+            walls[waited] = taken - handed  # its own; a wait has no CPU
+            for slot in self._request_slots:
+                self._book(books, slot, ended[slot] or done, walls[slot],
+                           cpus[slot] / riders)
         for slot in self._request_slots:
-            self._book(books, slot, ended[slot] or done, walls[slot],
-                       cpus[slot])
             walls[slot] = cpus[slot] = ended[slot] = 0.0
         return done
 
@@ -901,13 +926,16 @@ class StageClock:
         whole second of ``clock`` to ``{stage: [count, wall_s, cpu_s,
         max_wall_s]}``, stages that saw nothing left out, and — for a
         second that was stamped — STAMPS: ``requests`` and ``signatures``
-        answered, and the CPU seconds the process (``process_cpu_s``), the
-        threads that book here (``threads_cpu_s``; left out where a
+        answered and the ``launches`` that answered them (one backend call
+        carries every request that was pending when a dispatcher thread
+        came free), and the CPU seconds the process (``process_cpu_s``),
+        the threads that book here (``threads_cpu_s``; left out where a
         thread's CPU clock cannot be read from outside it) and the stamping
         thread itself (``loop_cpu_s``) used, each from that second's stamp
         to the next one's (the last: to now).  A request stage's ``count``
         is of the requests that were clocked (one in ``sample_one_in``) and
-        its ``cpu_s`` theirs alone.  Sum the seconds inside a window."""
+        its ``cpu_s`` their share of their launches' (``end_launch``).  Sum
+        the seconds inside a window."""
         width = self._width
         rows: Dict[int, list] = {}  # second -> the threads' rows, summed
         collections = [0.0] * 6
@@ -948,7 +976,7 @@ class StageClock:
             entry = out.setdefault(str(second), {})
             for i, name in enumerate(self.STAMPS):
                 grown = then[i] - at[i]
-                entry[name] = int(grown) if i < 2 else grown
+                entry[name] = grown if name.endswith("_s") else int(grown)
             if not self._thread_clocks:
                 del entry["threads_cpu_s"]
         return {
@@ -964,8 +992,8 @@ class StageClock:
 
 
 class stage:  # noqa: N801 - reads as a statement: ``with stage(...):``
-    """One occurrence of a stage that stands alone (not one of a pool
-    thread's request) and books itself into ``clock``::
+    """One occurrence of a stage that stands alone (not one of a dispatcher
+    thread's launch) and books itself into ``clock``::
 
         with spans.stage("service_decode", clock, since=t_header) as decode:
             ...

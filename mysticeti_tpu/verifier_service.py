@@ -15,7 +15,10 @@ process, shared by every co-located validator.
   * :class:`VerifierServer` — owns a single :class:`TpuSignatureVerifier`
     (one PJRT client, one compile cache, warmed once), serves signature
     batches over a unix-domain socket.  Requests from different validators
-    dispatch concurrently (async device dispatch overlaps their round-trips).
+    share launches: a few dispatcher threads each take every request that
+    is pending when they come free, up to the bucket the backend warmed,
+    and verify them with ONE backend call (group commit: no timer, a
+    request that finds the service idle is launched at once, alone).
   * :class:`RemoteSignatureVerifier` — the validator-side
     :class:`SignatureVerifier` that forwards batches to the service.  It
     never imports jax: a validator process using it boots import-light, and
@@ -66,6 +69,7 @@ cheaper and more accurate.
 from __future__ import annotations
 
 import asyncio
+import collections
 import contextlib
 import gc
 import itertools
@@ -180,6 +184,29 @@ def _abandoned_reply(fut: asyncio.Future, cleanup) -> None:
         cleanup()
 
 
+class _Pending:
+    """One decoded VERIFY/RAW request between its connection's reader and
+    the launch that answers it.  ``future`` is what the connection's reply
+    queue awaits; ``handed`` is when it was handed over, None for a request
+    that is not clocked; ``alone``: it found a launch slot asleep while the
+    service held at most one request more than it has slots, so it is
+    launched by itself."""
+
+    __slots__ = ("type_", "req_id", "n", "body", "conn_label", "handed",
+                 "future", "alone")
+
+    def __init__(self, type_, req_id, n, body, conn_label, handed,
+                 future) -> None:
+        self.alone = False
+        self.type_ = type_
+        self.req_id = req_id
+        self.n = n
+        self.body = body
+        self.conn_label = conn_label
+        self.handed = handed
+        self.future = future
+
+
 # ---------------------------------------------------------------------------
 # Server
 
@@ -188,10 +215,21 @@ class VerifierServer:
     """One accelerator runtime serving every validator on the host."""
 
     # Per-connection staged request window: the reader decodes request N+1
-    # while N computes in the pool; replies are written strictly in request
-    # order by a dedicated writer task.  The bound backpressures a client
-    # pipelining faster than the backend drains.
+    # while N waits for or rides a launch; replies are written strictly in
+    # request order by a dedicated writer task.  The bound backpressures a
+    # client pipelining faster than the backend drains.
     PIPELINE_DEPTH = 8
+    # Launch slots: each is a thread that takes everything pending when it
+    # comes free.  The fewer there are, the more requests share a launch
+    # and the fewer threads queue for the GIL with the loop: on the chip
+    # one slot verified a third more signatures a second than two or three
+    # (PERF.md, PR 25, has the pairs).  But with one slot launches run one
+    # after another, so a slow backend call makes the service stop-and-wait
+    # and holds up every connection, and with two a client that keeps four
+    # requests in flight (the deepest a validator's verify pipeline goes)
+    # finds two of them merged; with three each of its requests is launched
+    # at once and alone, as a lightly loaded service should.
+    DISPATCHERS = 3
 
     def __init__(self, socket_path: str, committee_keys: Optional[Sequence[bytes]] = None,
                  backend=None, metrics=None, devices: Optional[int] = None) -> None:
@@ -224,19 +262,32 @@ class VerifierServer:
         self._conn_ids = itertools.count()
         self._warmed = threading.Event()
         self._warm_lock = threading.Lock()
-        # Sized for a 10+ validator fleet: each in-flight request blocks a
-        # worker thread on the device fetch, and overlapping those
-        # round-trips is the entire point of sharing the runtime.
-        self._pool = ThreadPoolExecutor(
-            max_workers=16, thread_name_prefix="verify-dispatch",
-            initializer=self.stages.adopt_thread,
+        # Decoded requests that no launch has taken yet, in arrival order.
+        # The loop appends (``_submit``), the dispatcher threads take
+        # (``_take``), both under the condition, on which a dispatcher
+        # with nothing to take sleeps.
+        self._pending: collections.deque = collections.deque()
+        self._pending_cond = threading.Condition()
+        self._idle = 0  # dispatchers asleep on the condition
+        self._promised = 0  # pending requests that each woke one of them
+        self._in_service = 0  # handed over and not yet resolved (the loop's)
+        self._stopping = False
+        self._dispatchers: List[threading.Thread] = []
+        # The most signatures one launch may hold: what the backend warmed.
+        # None until it is warm; a request that arrives before that is
+        # launched alone and waits for the warm-up in ``_ensure_backend``.
+        self._launch_cap: Optional[int] = None
+        # HELLO and warm-up have a thread of their own: a warm-up takes
+        # minutes, and HELLOs behind it wait for it anyway.
+        self._hello_pool = ThreadPoolExecutor(
+            max_workers=1, thread_name_prefix="verify-hello",
         )
         self._server: Optional[asyncio.AbstractServer] = None
         self._writers: set = set()
         self._calibration: Optional[Tuple[float, float]] = None
         # A backend that cannot warm is fatal to the service: the failing
-        # pool thread records the cause and wakes serve_forever, which
-        # raises it.
+        # thread records the cause and wakes serve_forever, which raises
+        # it.
         self._fatal: Optional[BaseException] = None
         self._warm_seconds: Optional[float] = None
         self._failed = asyncio.Event()
@@ -285,8 +336,22 @@ class VerifierServer:
                 except BaseException as exc:
                     self._fail(exc)
                     raise
+                self._launch_cap = self._warmed_signatures()
                 self._warmed.set()
             return self._backend
+
+    def _warmed_signatures(self) -> int:
+        """The most signatures one backend call can hold without reaching
+        a shape the warm-up did not compile: a wider launch would compile
+        for seconds in the middle of serving.  A backend that says nothing
+        (a host oracle compiles nothing) gets the kernels' smallest
+        bucket, which is what the JAX backend warms."""
+        warmed = getattr(self._backend, "warmed_batch", None)
+        if warmed is not None:
+            return warmed()
+        from .ops.ed25519 import BUCKETS
+
+        return BUCKETS[0]
 
     def _fail(self, exc: BaseException) -> None:
         log.error("verifier service backend failed to warm", exc_info=exc)
@@ -361,10 +426,11 @@ class VerifierServer:
             writer.close()
             return
         # Staged per-connection request pipeline: the reader decodes and
-        # submits request N+1 while request N computes in the pool; a
-        # dedicated writer task emits replies strictly in request order (the
-        # protocol contract clients rely on), so the service is no longer a
-        # stop-and-wait RPC for a client that pipelines its frames.
+        # hands over request N+1 while request N waits for or rides a
+        # launch; a dedicated writer task emits replies strictly in request
+        # order (the protocol contract clients rely on, whichever launches
+        # the requests rode), so the service is no stop-and-wait RPC for a
+        # client that pipelines its frames.
         loop = asyncio.get_running_loop()
         self._writers.add(writer)
         conn_label = f"c{next(self._conn_ids)}"
@@ -378,11 +444,11 @@ class VerifierServer:
             metrics = self.metrics
             if metrics is None:
                 return None
-            # Depth = requests handed to the pool and not yet answered
-            # (queued behind the 16 workers or mid-dispatch); inflight
-            # splits it per client connection so one flooding validator is
-            # attributable.  Decremented by the writer once the reply is
-            # built (cleanup runs even when the dispatch raised).
+            # Depth = requests handed over and not yet answered (pending,
+            # or riding a launch); inflight splits it per client connection
+            # so one flooding validator is attributable.  Decremented by
+            # the writer once the reply is built (cleanup runs even when
+            # the launch raised).
             metrics.verifier_service_queue_depth.inc()
             metrics.verifier_service_inflight.labels(conn_label).inc()
 
@@ -393,14 +459,14 @@ class VerifierServer:
             return _done
 
         # A pipelined client may send VERIFY frames behind a HELLO without
-        # waiting for HELLO_OK; pool threads run jobs in any order, so a
-        # verify must not EXECUTE before the HELLO that establishes the
+        # waiting for HELLO_OK; the HELLO runs on a thread of its own, so a
+        # verify must not be LAUNCHED before the HELLO that establishes the
         # committee finished (it would see no keys and report every slot
-        # invalid).  Replies stay ordered by the queue; execution is gated
-        # on the connection's last unresolved HELLO only.
+        # invalid).  Replies stay ordered by the queue; the hand-over is
+        # gated on the connection's last unresolved HELLO only.
         last_hello: Optional[asyncio.Future] = None
 
-        async def _after_hello(gate, type_, req_id, n, body):
+        async def _after_hello(gate, type_, req_id, n, body, clocked):
             try:
                 hello_frame = await asyncio.shield(gate)
             except Exception:  # noqa: BLE001 - HELLO's own reply carries it
@@ -412,9 +478,9 @@ class VerifierServer:
                 # for it (a reconnect-looping misconfigured client would
                 # otherwise cost a device round-trip per queued frame).
                 return None
-            return await loop.run_in_executor(
-                self._pool, self._result_reply, type_, req_id, n, body,
-                conn_label, time.monotonic(),
+            return await self._submit(
+                loop, type_, req_id, n, body, conn_label,
+                time.monotonic() if clocked else None,
             )
 
         try:
@@ -447,13 +513,13 @@ class VerifierServer:
                     # a client that pipelines frames must never see HELLO_OK
                     # overtake an earlier RESULT.
                     fut = loop.run_in_executor(
-                        self._pool, self._hello_reply, keys
+                        self._hello_pool, self._hello_reply, keys
                     )
                     last_hello = fut
                     await replies.put((fut, None, False))
                 elif type_ in (T_VERIFY, T_RAW):
                     # service_decode: header read -> frame checked and
-                    # handed to the pool (the CPU clock and the profiler's
+                    # handed over (the CPU clock and the profiler's
                     # annotation start here, with the payload in hand: no
                     # await lies between this line and the hand-over).
                     # One request in spans.SAMPLE_ONE_IN is clocked, whole:
@@ -500,13 +566,13 @@ class VerifierServer:
                     if last_hello is not None:
                         # Awaited by the reply writer in order, which
                         # observes its exception.
-                        fut = asyncio.ensure_future(
-                            _after_hello(last_hello, type_, req_id, n, body)
-                        )
+                        fut = asyncio.ensure_future(_after_hello(
+                            last_hello, type_, req_id, n, body,
+                            decode is not None,
+                        ))
                     else:
-                        fut = loop.run_in_executor(
-                            self._pool, self._result_reply,
-                            type_, req_id, n, body, conn_label,
+                        fut = self._submit(
+                            loop, type_, req_id, n, body, conn_label,
                             None if decode is None else decode.end,
                         )
                     await replies.put((fut, done, False))
@@ -530,7 +596,7 @@ class VerifierServer:
             except Exception:  # noqa: BLE001 - writer logged its own failure
                 pass
             # Anything left unqueued-for-write still owes its cleanup, but
-            # its dispatch may still be running on a pool thread: releasing
+            # its launch may still be running on a dispatcher: releasing
             # the gauges now would show an idle service during real device
             # work, and abandoning the future would leave its exception
             # unretrieved.  Defer both to the dispatch's own completion.
@@ -594,7 +660,7 @@ class VerifierServer:
 
         A reply is either a prebuilt ``bytes`` frame (HELLO_OK, ERR) or a
         ``(type, parts, built)`` tuple from the verify path (``built``: when
-        the pool thread was done with it, None for a request that is not
+        its launch was done with it, None for a request that is not
         clocked): a fresh 5-byte header
         rides ``writer.writelines`` with the parts as-is — scatter-gather,
         no header+payload concatenation per reply.  The header must be a
@@ -616,7 +682,9 @@ class VerifierServer:
                     except Exception:  # noqa: BLE001 - logged, conn severed
                         log.exception("verifier service dispatch failed")
                         frame = None
-                if dead or frame is None:
+                if dead or frame is None or writer.is_closing():
+                    # (closing: stop() severed the connection under a
+                    # launch; a write to its transport would raise.)
                     dead = True
                     writer.close()
                     continue
@@ -678,8 +746,8 @@ class VerifierServer:
         return "cpu" if resolve is None else str(resolve())
 
     def _hello_reply(self, keys: List[bytes]) -> bytes:
-        """Pool-side HELLO handling: warm (or adopt/upgrade) the backend and
-        frame the reply — HELLO_OK with the calibration + resolved-backend
+        """HELLO handling, on its own thread: warm (or adopt/upgrade) the
+        backend and frame the reply — HELLO_OK with the calibration + resolved-backend
         advertisement, or ERR on a committee mismatch (which also severs the
         connection client-side).  The backend suffix rides only behind a
         calibration: old clients check ``len == 16`` and fall back to their
@@ -695,50 +763,144 @@ class VerifierServer:
             payload += self._resolved_backend().encode("ascii", "replace")
         return _frame(T_HELLO_OK, payload)
 
-    def _result_reply(self, type_: int, req_id: int, n: int, body,
-                      conn_label: str = "", handed: Optional[float] = None,
-                      ) -> tuple:
-        """Verify and return the reply as ``(T_RESULT, parts, built)`` — the
-        writer packs the frame header into its per-connection scratch and
-        scatter-gathers the parts, so the verdicts are copied exactly once
-        (list -> bytes) on their way out.  The pool thread works for this
-        request from here to ``built``: every ``spans.request_stage`` below, in
-        the backend too, names the stage it is in."""
-        if handed is None:  # not one of the requests that are clocked
-            return (T_RESULT, (struct.pack("<I", req_id),
-                               self._verify_payload(type_, n, body)), None)
-        stages = self.stages
-        stages.begin_request((conn_label, req_id), handed)
-        spans.request_stage("service_unpack")
-        try:
-            parts = (struct.pack("<I", req_id),
-                     self._verify_payload(type_, n, body))
-        finally:
-            built = stages.end_request()
-        return (T_RESULT, parts, built)
+    # -- the coalescer: pending requests -> launches --
 
-    def _verify_payload(self, type_: int, n: int, body: bytes) -> bytes:
+    def _submit(self, loop, type_: int, req_id: int, n: int, body,
+                conn_label: str, handed: Optional[float]) -> asyncio.Future:
+        """Hand a decoded request over (on the loop): it joins the pending
+        list, and the future resolves to its reply, ``(T_RESULT, parts,
+        built)``, once the launch that took it is done.  ``handed``: the
+        instant, for a request that is clocked."""
+        future = loop.create_future()
+        item = _Pending(type_, req_id, n, body, conn_label, handed, future)
+        with self._pending_cond:
+            if self._stopping:
+                future.cancel()
+            else:
+                self._in_service += 1
+                self._pending.append(item)
+                if self._idle > self._promised:
+                    # A slot is asleep and nobody has woken it yet: this
+                    # request does.  While the service holds at most one
+                    # request more than it has slots, each can have a
+                    # launch to itself (one waits its turn) and sharing
+                    # gains nothing: it is launched alone, with the kernel
+                    # of its own shape (what arrives before that slot is up
+                    # does not ride with it).  With more in the service,
+                    # the slot takes all that is pending when it is up: a
+                    # queue is draining.
+                    if self._in_service <= self.DISPATCHERS + 1:
+                        item.alone = True
+                        self._promised += 1
+                    self._pending_cond.notify()
+        return future
+
+    def _take(self) -> List[_Pending]:
+        """Everything pending, in arrival order, while the signatures sum
+        to at most what the backend warmed: whole requests only, and the
+        first whatever it holds (a request over the cap goes alone, and the
+        backend splits it by ``iter_buckets`` as it always did).  A request
+        marked ``alone`` (``_submit``) goes alone, whichever slot gets to
+        it first.  Called with the condition held and something pending."""
+        pending = self._pending
+        first = pending.popleft()
+        batch = [first]
+        cap = self._launch_cap
+        if first.alone:
+            self._promised -= 1
+        elif cap is not None:
+            total = first.n
+            while (pending and not pending[0].alone
+                   and total + pending[0].n <= cap):
+                total += pending[0].n
+                batch.append(pending.popleft())
+        return batch
+
+    def _dispatch_loop(self) -> None:
+        """A launch slot: take what is pending, launch it, again; sleep
+        only when nothing is pending.  No timer anywhere: a launch holds
+        what queued while every slot was busy, and a request that finds a
+        slot asleep wakes it (``_submit``)."""
+        self.stages.adopt_thread()
+        cond, pending = self._pending_cond, self._pending
+        while True:
+            with cond:
+                while not pending:
+                    if self._stopping:
+                        return
+                    self._idle += 1
+                    cond.wait()
+                    self._idle -= 1
+                batch = self._take()
+            self._launch(batch)
+
+    def _launch(self, batch: List[_Pending]) -> None:
+        """One backend call for every request of ``batch``, then each
+        request's reply to its future, with one wake-up of the loop.  The
+        thread works for the launch from here to ``built``: every
+        ``spans.request_stage`` below, in the backend too, names the stage
+        it is in, for the clocked requests that ride it.  A launch that
+        raises fails each of its requests and nothing else."""
+        stages = self.stages
+        clocked = [((item.conn_label, item.req_id), item.handed)
+                   for item in batch if item.handed is not None]
+        if clocked:
+            stages.begin_launch(clocked)
+            spans.request_stage("service_unpack")
+        replies = error = built = None
+        try:
+            replies = self._verify_batch(batch)
+        except Exception as exc:  # noqa: BLE001 - the reply writers log it
+            error = exc
+        finally:
+            if clocked:
+                built = stages.end_launch(len(batch))
+        try:
+            self._loop.call_soon_threadsafe(
+                self._resolve, batch, replies, error, built)
+        except RuntimeError:
+            pass  # the loop closed under a launch that stop() did not await
+
+    def _resolve(self, batch: List[_Pending], replies, error, built) -> None:
+        """On the loop: a launch is done."""
+        self.stages.launches += 1
+        self._in_service -= len(batch)
+        for i, item in enumerate(batch):
+            future = item.future
+            if future.done():
+                continue  # cancelled: its connection's writer was, or stop()
+            if error is not None:
+                future.set_exception(error)
+            else:
+                future.set_result((
+                    T_RESULT, replies[i],
+                    built if item.handed is not None else None,
+                ))
+
+    def _verify_batch(self, batch: List[_Pending]) -> List[tuple]:
+        """Verify every signature of every request of ``batch`` with one
+        backend call and return each request's reply parts, ``(req_id
+        bytes, verdict bytes)`` — the writer scatter-gathers them behind a
+        fresh header, so the verdicts are copied exactly once (list ->
+        bytes) on their way out."""
         backend = self._ensure_backend(self._keys or [])
+        keys = self._keys or []
         pks, digests, sigs = [], [], []
-        if type_ == T_VERIFY:
-            keys = self._keys or []
-            for i in range(n):
-                off = i * _IDX_REC
-                (idx,) = struct.unpack_from("<H", body, off)
-                if idx >= len(keys):
+        for item in batch:
+            body = item.body
+            if item.type_ == T_VERIFY:
+                for off in range(0, item.n * _IDX_REC, _IDX_REC):
+                    (idx,) = struct.unpack_from("<H", body, off)
                     # An out-of-range index cannot verify; reject that slot
-                    # rather than the whole batch.
-                    pks.append(bytes(32))
-                else:
-                    pks.append(keys[idx])
-                digests.append(body[off + 2: off + 34])
-                sigs.append(body[off + 34: off + 98])
-        else:
-            for i in range(n):
-                off = i * _RAW_REC
-                pks.append(body[off: off + 32])
-                digests.append(body[off + 32: off + 64])
-                sigs.append(body[off + 64: off + 128])
+                    # rather than the whole request (or launch).
+                    pks.append(keys[idx] if idx < len(keys) else bytes(32))
+                    digests.append(body[off + 2: off + 34])
+                    sigs.append(body[off + 34: off + 98])
+            else:
+                for off in range(0, item.n * _RAW_REC, _RAW_REC):
+                    pks.append(body[off: off + 32])
+                    digests.append(body[off + 32: off + 64])
+                    sigs.append(body[off + 64: off + 128])
         # The backend's time is the fetch's (device run + transfer + getting
         # the GIL back; a host oracle's whole work), but for the stages it
         # names itself: the JAX backend packs and launches first
@@ -746,16 +908,29 @@ class VerifierServer:
         spans.request_stage("service_fetch")
         oks = backend.verify_signatures(pks, digests, sigs)
         spans.request_stage("service_reply_build")
+        total = len(sigs)
+        if len(oks) != total:
+            raise RuntimeError(
+                f"backend returned {len(oks)} verdicts for {total} signatures"
+            )
         if self.metrics is not None:
             # The service owns the device, so it (not the jax-free clients)
-            # is where dispatch shape and padding waste are measurable.
-            self.metrics.verify_dispatch_batch_size.observe(n)
+            # is where launch shape and padding waste are measurable.
+            self.metrics.verify_dispatch_batch_size.observe(total)
+            self.metrics.verifier_service_coalesced_requests.observe(
+                len(batch))
             padder = getattr(backend, "padded_batch", None)
             if padder is not None:
                 self.metrics.verify_padding_wasted_total.labels(
                     "service"
-                ).inc(max(0, padder(n) - n))
-        return bytes([1 if ok else 0 for ok in oks])
+                ).inc(max(0, padder(total) - total))
+        verdicts = bytes([1 if ok else 0 for ok in oks])
+        replies, at = [], 0
+        for item in batch:
+            replies.append((struct.pack("<I", item.req_id),
+                            verdicts[at: at + item.n]))
+            at += item.n
+        return replies
 
     # -- lifecycle --
 
@@ -784,6 +959,15 @@ class VerifierServer:
         self._secure_socket_dir(self.socket_path)
         if os.path.exists(self.socket_path):
             os.unlink(self.socket_path)
+        self._dispatchers = [
+            threading.Thread(
+                target=self._dispatch_loop, name=f"verify-dispatch_{i}",
+                daemon=True,
+            )
+            for i in range(self.DISPATCHERS)
+        ]
+        for thread in self._dispatchers:
+            thread.start()
         self._server = await asyncio.start_unix_server(
             self._handle, path=self.socket_path
         )
@@ -797,7 +981,7 @@ class VerifierServer:
         if self._keys is not None and not self._warmed.is_set():
             # Warm while validators boot: their HELLOs block until done.
             await asyncio.get_running_loop().run_in_executor(
-                self._pool, self.prewarm
+                self._hello_pool, self.prewarm
             )
             log.info("verifier service warmed (%d committee keys)",
                      len(self._keys))
@@ -817,6 +1001,16 @@ class VerifierServer:
             ) from self._fatal
 
     async def stop(self) -> None:
+        # Nothing pending is launched from here on: its future is cancelled
+        # (the connection's writer ends with it), and the dispatchers end
+        # once the launch they are in returns.
+        with self._pending_cond:
+            self._stopping = True
+            abandoned = list(self._pending)
+            self._pending.clear()
+            self._pending_cond.notify_all()
+        for item in abandoned:
+            item.future.cancel()
         if self._server is not None:
             self._server.close()
             # Sever live client connections first: since 3.12,
@@ -825,7 +1019,7 @@ class VerifierServer:
             for writer in list(self._writers):
                 writer.close()
             await self._server.wait_closed()
-        self._pool.shutdown(wait=False)
+        self._hello_pool.shutdown(wait=False)
         self._write_report()
         if os.path.exists(self.socket_path):
             os.unlink(self.socket_path)
@@ -1295,13 +1489,13 @@ def run_service(socket_path: str, committee_keys: Optional[Sequence[bytes]] = No
             socket_path, committee_keys=committee_keys, metrics=metrics,
             devices=devices,
         )
-        # service_gc: a collection stops all sixteen pool threads and the
+        # service_gc: a collection stops the dispatcher threads and the
         # loop at once, whichever thread trips it.  A hook of the process,
         # so it is set here and not by every VerifierServer a test builds.
         gc.callbacks.append(server.stages.gc_callback)
-        # The loop reads every request and writes every reply while sixteen
-        # pool threads compete with it for the GIL: its lag is the part of
-        # a round trip that no stage of a request sees.  The probe books
+        # The loop reads every request and writes every reply while the
+        # dispatcher threads compete with it for the GIL: its lag is the
+        # part of a round trip that no stage of a request sees.  The probe books
         # into the stage clock alone (scraped as
         # verifier_service_stage_seconds{stage="service_loop_lag"}; the
         # probe's own prometheus series and percentile sort would run on

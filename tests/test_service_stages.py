@@ -125,14 +125,25 @@ def _ring_sums(report):
 
 
 class SleepingBackend(SignatureVerifier):
-    """Accepts everything after a sleep that the request's size selects."""
+    """Accepts everything, after the longest sleep that a digest of the
+    call selects (a call may hold several requests), else ``default``."""
 
-    def __init__(self, sleep_by_size):
-        self.sleep_by_size = sleep_by_size
+    def __init__(self, sleep_by_digest=None, default=0.0):
+        self.sleep_by_digest = sleep_by_digest or {}
+        self.default = default
+        self.calls = -2  # of the launches: the service calibrates with two
 
     def verify_signatures(self, public_keys, digests, signatures):
-        time.sleep(self.sleep_by_size.get(len(signatures), 0.0))
+        self.calls += 1
+        time.sleep(max(
+            (self.sleep_by_digest.get(bytes(d), self.default)
+             for d in digests), default=0.0))
         return [True] * len(signatures)
+
+
+def _first_digest(marker):
+    """The digest ``_records(n, marker)`` puts first."""
+    return crypto.blake2b_256(b"m%d-0" % marker)
 
 
 class ReportingBackend(CpuSignatureVerifier):
@@ -197,58 +208,89 @@ def test_every_stage_counts_every_request_and_their_walls_tile_it(tmp_path):
             label, tiled, last - first)  # microseconds
 
 
-def test_pool_wait_grows_past_sixteen_outstanding(tmp_path):
-    """Sixteen pool threads: with forty requests outstanding on a backend
-    that sleeps, the later ones wait for a thread; with twelve none does."""
+def test_pool_wait_is_the_wait_for_a_launch_slot(tmp_path):
+    """A request waits in service_pool_wait from its hand-over until a
+    launch takes it: not at all while a dispatcher thread sleeps, and for
+    the launches in its way once every slot is busy - and then whatever
+    queued meanwhile shares the next launch."""
+    slots = VerifierServer.DISPATCHERS
+    nap = 0.4
 
-    def run(n_conns, name):
+    def run(late_conns, name):
+        backend = SleepingBackend(default=nap)
+
         async def scenario(server):
-            frames = [_verify_frame(i + 1, 1, _records(1, i))
-                      for i in range(4)]
-            await asyncio.gather(*(
-                asyncio.to_thread(_pipelined, server.socket_path, frames)
-                for _ in range(n_conns)
-            ))
+            (await asyncio.to_thread(_connect, server.socket_path)).close()
+            one = [_verify_frame(1, 1, _records(1))]
+            four = [_verify_frame(i + 1, 1, _records(1, i)) for i in range(4)]
+            clients = []
+            for _ in range(slots):  # one request a slot: each goes alone
+                clients.append(asyncio.ensure_future(asyncio.to_thread(
+                    _pipelined, server.socket_path, one)))
+                await asyncio.sleep(0.05)
+            clients += [  # every slot is busy for another 0.3 s
+                asyncio.ensure_future(asyncio.to_thread(
+                    _pipelined, server.socket_path, four))
+                for _ in range(late_conns)]
+            await asyncio.gather(*clients)
             return _ring_sums(server.stages.export())[0]
 
         path = tmp_path / name
         path.mkdir()
-        return asyncio.run(_serve(path, SleepingBackend({1: 0.1}), scenario))
+        return asyncio.run(_serve(path, backend, scenario)), backend.calls
 
-    few = run(3, "few")
-    many = run(10, "many")
+    few, few_calls = run(0, "few")
+    many, many_calls = run(10, "many")
+    assert few_calls == slots
     assert few["service_pool_wait"][3] < 0.05
-    assert many["service_pool_wait"][3] > 0.08
-    assert many["service_pool_wait"][1] > 10 * few["service_pool_wait"][1]
-    # The sleep itself is the fetch's, in both.
-    assert few["service_fetch"][1] >= 12 * 0.1
-    assert many["service_fetch"][1] >= 40 * 0.1
+    assert many["service_pool_wait"][0] == slots + 40
+    assert many["service_pool_wait"][3] > nap / 4
+    assert many["service_pool_wait"][1] > 40 * nap / 8
+    # Forty requests queued behind the busy slots: the first slot to come
+    # free took all that were there by then (40 signatures are well under
+    # a launch's cap), the stragglers rode the next one or two.
+    assert slots + 1 <= many_calls <= slots + 3
+    # The sleep itself is the fetch's, whole for every request of a launch.
+    assert few["service_fetch"][1] >= slots * nap
+    assert many["service_fetch"][1] >= (slots + 40) * nap
 
 
 def test_reply_wait_is_the_line_behind_a_slow_request(tmp_path):
     """Replies leave in request order: a fast request behind a slow one of
-    its connection waits for it, one on another connection does not."""
+    its connection waits for it, one on another connection does not.  The
+    fast one is sent once the slow one's launch has left, so that it rides
+    a launch of its own."""
     tracer = spans.SpanTracer()
     slow = 0.4
 
+    def behind(path):
+        sock = _connect(path)
+        try:
+            sock.sendall(_verify_frame(1, 2, _records(2, 1)))  # sleeps
+            time.sleep(0.1)
+            sock.sendall(_verify_frame(2, 1, _records(1, 2)))  # fast
+            for req_id in (1, 2):  # in request order
+                type_, payload = _read_frame(sock)
+                assert type_ == T_RESULT
+                assert struct.unpack("<I", payload[:4])[0] == req_id
+        finally:
+            sock.close()
+
     async def scenario(server):
-        behind = asyncio.to_thread(_pipelined, server.socket_path, [
-            _verify_frame(1, 2, _records(2, 1)),  # sleeps
-            _verify_frame(2, 1, _records(1, 2)),  # fast, same connection
-        ])
+        line = asyncio.to_thread(behind, server.socket_path)
         await asyncio.sleep(0.05)
         alone = asyncio.to_thread(_pipelined, server.socket_path, [
             _verify_frame(7, 1, _records(1, 3)),  # fast, its own connection
         ])
-        await asyncio.gather(behind, alone)
+        await asyncio.gather(line, alone)
 
-    asyncio.run(_serve(tmp_path, SleepingBackend({2: slow}), scenario,
-                       tracer=tracer))
+    asyncio.run(_serve(tmp_path, SleepingBackend({_first_digest(1): slow}),
+                       scenario, tracer=tracer))
     waits = {}
     for event in tracer.chrome_trace()["traceEvents"]:
         if event.get("name") == "service_reply_wait":
             waits[event["args"]["block"].split("#")[1]] = event["dur"] / 1e6
-    assert waits["2"] > slow - 0.1      # head of line behind request 1
+    assert waits["2"] > slow - 0.2      # head of line behind request 1
     assert waits["1"] < 0.05            # the slow one itself: written at once
     assert waits["7"] < 0.05            # another connection: no line
 
@@ -305,7 +347,9 @@ def test_the_report_ring_sums_to_the_scraped_series(tmp_path):
     process = sum(second["process_cpu_s"] for second in stamped)
     threads = sum(second["threads_cpu_s"] for second in stamped)
     loop = sum(second["loop_cpu_s"] for second in stamped)
-    assert process >= threads - 1e-3 and threads >= loop > 0.0
+    # (The loop's own clock counts in ``threads`` from its first sample on,
+    # in ``loop`` from the clock's making: no order between the two here.)
+    assert process >= threads - 1e-3 and threads > 0.0 and loop > 0.0
     series = harness.parse_metrics(generate_latest(metrics.registry).decode())
     for stage in PER_REQUEST:
         count = harness.series_sum(
@@ -406,20 +450,23 @@ def test_a_seconds_stamp_holds_what_was_answered_and_the_cpu_used():
     assert "service_decode" not in first  # no stage was booked
 
 
-def test_the_pool_threads_are_adopted_at_birth(tmp_path):
-    """Every pool thread's CPU counts from its first request on, clocked or
-    not (one request in 32 is): the executor adopts each as it starts."""
+def test_the_dispatcher_threads_are_adopted_at_birth(tmp_path):
+    """Every dispatcher thread's CPU counts from its start on, whether or
+    not a clocked request ever rides one of its launches (one request in
+    32 is clocked): each adopts itself before its first launch."""
     async def scenario(server):
         frames = [_verify_frame(i + 1, 1, _records(1, i)) for i in range(4)]
         await asyncio.gather(*(
             asyncio.to_thread(_pipelined, server.socket_path, frames)
             for _ in range(3)))
-        return len(server.stages._thread_clocks), len(server._pool._threads)
+        return (len(server.stages._thread_clocks),
+                [thread.is_alive() for thread in server._dispatchers])
 
-    clocks, threads = asyncio.run(_serve(
-        tmp_path, SleepingBackend({1: 0.05}), scenario, every_request=False))
-    assert threads >= 3
-    assert clocks == threads + 1  # and the loop's own
+    clocks, alive = asyncio.run(_serve(
+        tmp_path, SleepingBackend(default=0.05), scenario,
+        every_request=False))
+    assert alive == [True] * VerifierServer.DISPATCHERS
+    assert clocks == len(alive) + 1  # and the loop's own
 
 
 def test_transfer_bytes_from_many_threads_lose_nothing(monkeypatch):
@@ -561,20 +608,148 @@ def test_one_request_in_thirty_two_is_clocked_and_all_are_counted(tmp_path):
         stage="service_unpack") == 3
 
 
-def test_request_stage_outside_a_request_does_nothing():
+def test_request_stage_outside_a_launch_does_nothing():
     spans.request_stage("service_pack")  # no frame on this thread
     clock = spans.StageClock(spans.SERVICE_STAGES)
-    clock.begin_request(("c0", 1), time.monotonic())
+    clock.begin_launch([(("c0", 1), time.monotonic())])
     spans.request_stage("service_pack")
     spans.request_stage("service_pack")  # the same stage: one occurrence
     spans.request_stage("service_launch")
-    clock.end_request()
-    spans.request_stage("service_fetch")  # the request is over
+    clock.end_launch(1)
+    spans.request_stage("service_fetch")  # the launch is over
     totals = clock.totals()
     for stage in spans.REQUEST_STAGES:
         assert totals[stage]["count"] == 1, stage
     assert totals["service_decode"]["count"] == 0
     assert totals["service_fetch"]["wall_s"] == 0.0
+
+
+def _burn(seconds):
+    """Use the calling thread's CPU for ``seconds`` of its own clock."""
+    until = time.thread_time() + seconds
+    while time.thread_time() < until:
+        sum(range(500))
+
+
+@pytest.mark.parametrize("riders", [1, 2, 5])
+def test_a_clocked_request_books_its_launch_wall_whole_and_cpu_shared(riders):
+    """Two clocked requests ride a launch of ``riders``: each books every
+    REQUEST_STAGES stage exactly once - its own wait for the launch, then
+    the launch's stages with their wall whole and their CPU divided by the
+    requests the launch carried, so that CPU a clocked request times the
+    request rate is still cores."""
+    tracer = spans.SpanTracer()
+    clock = spans.StageClock(spans.SERVICE_STAGES, ring_seconds=8,
+                             tracer=tracer)
+    t0 = time.monotonic()
+    members = [(("c0", 1), t0 - 0.30), (("c1", 9), t0 - 0.10)]
+    clock.begin_launch(members)
+    spans.request_stage("service_unpack")
+    _burn(0.02)
+    spans.request_stage("service_pack")
+    _burn(0.04)
+    spans.request_stage("service_fetch")
+    time.sleep(0.05)
+    spans.request_stage("service_reply_build")
+    done = clock.end_launch(riders)
+    totals = clock.totals()
+    for stage in spans.REQUEST_STAGES:
+        assert totals[stage]["count"] == 2, stage
+    # Each waited from ITS hand-over until the launch took both.
+    assert totals["service_pool_wait"]["wall_s"] == pytest.approx(
+        0.40, abs=0.02)
+    assert totals["service_pool_wait"]["cpu_s"] == 0.0
+    # Wall whole: twice the launch's, whatever it carried.
+    assert totals["service_unpack"]["wall_s"] == pytest.approx(
+        2 * 0.02, abs=0.02)
+    assert totals["service_pack"]["wall_s"] == pytest.approx(
+        2 * 0.04, abs=0.02)
+    assert totals["service_fetch"]["wall_s"] == pytest.approx(
+        2 * 0.05, abs=0.03)
+    assert totals["service_launch"]["wall_s"] == 0.0  # never entered
+    # CPU divided by the riders, clocked or not.
+    assert totals["service_unpack"]["cpu_s"] == pytest.approx(
+        2 * 0.02 / riders, rel=0.35)
+    assert totals["service_pack"]["cpu_s"] == pytest.approx(
+        2 * 0.04 / riders, rel=0.35)
+    assert totals["service_fetch"]["cpu_s"] == 0.0  # a wait
+    assert done >= t0 + 0.11
+    # Both requests have the launch's spans under their own labels, and
+    # their stages tile (hand-over -> replies built).
+    by_request = {}
+    for event in tracer.chrome_trace()["traceEvents"]:
+        if event.get("ph") == "X":
+            by_request.setdefault(event["args"]["block"], []).append(event)
+    assert sorted(by_request) == ["c0#1", "c1#9"]
+    for label, events in by_request.items():
+        assert sorted(e["name"] for e in events) == sorted(
+            s for s in spans.REQUEST_STAGES if s != "service_launch"), label
+        first = min(e["ts"] for e in events)
+        last = max(e["ts"] + e["dur"] for e in events)
+        assert sum(e["dur"] for e in events) == pytest.approx(
+            last - first, rel=0.01)
+    # The next launch of this thread starts from nothing.
+    clock.begin_launch([(("c0", 2), time.monotonic())])
+    clock.end_launch(1)
+    again = clock.totals()
+    assert again["service_pack"]["count"] == 3
+    assert again["service_pack"]["wall_s"] == totals["service_pack"]["wall_s"]
+
+
+def test_a_seconds_stamp_counts_the_launches():
+    """``launches`` rides the ring's stamp beside ``requests`` and
+    ``signatures``: a whole number a second, the growth to the next."""
+    clock = spans.StageClock(spans.SERVICE_STAGES, ring_seconds=600)
+    assert clock.STAMPS[:3] == ("requests", "signatures", "launches")
+    base = int(time.monotonic()) + 10
+    clock.requests, clock.signatures, clock.launches = 40, 320, 4
+    clock.stamp(base + 0.0)
+    clock.requests, clock.signatures, clock.launches = 100, 800, 9
+    clock.stamp(base + 1.0)
+    clock.launches = 10
+    seconds = clock.export()["seconds"]
+    assert seconds[str(base)]["launches"] == 5
+    assert seconds[str(base + 1)]["launches"] == 1
+    assert isinstance(seconds[str(base)]["launches"], int)
+
+
+def test_the_service_counts_launches_and_shares_cpu(tmp_path):
+    """Over the socket: forty requests behind two busy slots ride a few
+    launches; the ring's seconds carry how many, every clocked request
+    booked every stage once, and the scraped histogram of requests a
+    launch sums to the requests."""
+    from prometheus_client import generate_latest
+
+    from benchmark import harness
+
+    metrics = Metrics()
+    backend = SleepingBackend(default=0.05)
+
+    async def scenario(server):
+        frames = [_verify_frame(i + 1, 2, _records(2, i)) for i in range(4)]
+        await asyncio.gather(*(
+            asyncio.to_thread(_pipelined, server.socket_path, frames)
+            for _ in range(10)))
+        return server.stages.export(), server.stages.launches
+
+    report, launches = asyncio.run(
+        _serve(tmp_path, backend, scenario, metrics=metrics))
+    sums, requests, signatures = _ring_sums(report)
+    assert (requests, signatures) == (40, 80)
+    assert launches == backend.calls < 40
+    assert sum(entry.get("launches", 0)
+               for entry in report["seconds"].values()) == launches
+    for stage in PER_REQUEST:
+        assert sums[stage][0] == 40, stage
+    series = harness.parse_metrics(generate_latest(metrics.registry).decode())
+    assert harness.series_sum(
+        series, "verifier_service_coalesced_requests_count") == launches
+    assert harness.series_sum(
+        series, "verifier_service_coalesced_requests_sum") == 40
+    assert harness.series_sum(
+        series, "verify_dispatch_batch_size_sum") == 80
+    assert harness.series_sum(
+        series, "verify_dispatch_batch_size_count") == launches
 
 
 def test_the_profiler_sees_flat_pack_and_launch(tmp_path):
@@ -602,12 +777,12 @@ def test_the_profiler_sees_flat_pack_and_launch(tmp_path):
     jax.profiler.start_trace(str(tmp_path), profiler_options=options)
     try:
         assert [clock.sampled() for _ in range(3)] == [True, False, False]
-        clock.begin_request(("c0", 1), time.monotonic())
+        clock.begin_launch([(("c0", 1), time.monotonic())])
         spans.request_stage("service_unpack")
         handle = E.dispatch_batch_table(table, pks, digests, sigs)
         assert list(handle.result()) == [True] * 3  # names the fetch itself
         spans.request_stage("service_reply_build")
-        clock.end_request()
+        clock.end_launch(1)
         with spans.stage("service_decode", clock):
             pass
         # A request that is not clocked leaves no event.
