@@ -25,10 +25,11 @@ from typing import List, Optional, Sequence, Tuple
 
 from . import spans
 from .committee import Committee
+from .execution import SIGNED_MAGIC, parse_signed_tx
 from .network import jittered_backoff
 from .tracing import logger
 from .runtime import is_simulated
-from .types import StatementBlock, VerificationError
+from .types import Share, StatementBlock, VerificationError
 from .utils.tasks import spawn_logged
 from .verify_pipeline import (
     STAGE_DEVICE,
@@ -1220,6 +1221,15 @@ class ThresholdAggregateVerifier(BlockVerifier):
             note(committee)
 
 
+def _count_verdicts(series, labels: tuple, verdicts) -> None:
+    """``verdicts`` onto ``series{*labels, outcome}``."""
+    accepted = sum(bool(ok) for ok in verdicts)
+    if accepted:
+        series.labels(*labels, "accepted").inc(accepted)
+    if accepted < len(verdicts):
+        series.labels(*labels, "rejected").inc(len(verdicts) - accepted)
+
+
 def _observe_orphan(fut) -> None:
     """Retrieve an orphaned executor future's exception so a backend crash
     after the awaiting flush was cancelled is logged, not swallowed into an
@@ -1327,6 +1337,14 @@ class BatchedSignatureVerifier(BlockVerifier):
         # slower than EMA_OUTLIER_S (one-time JAX compiles) are not fed into
         # the EMA at all.
         self._dispatch_ema_s = 0.0
+        # Signed transactions (Parameters.signed_transactions): a received
+        # block then brings 1 + (its signed transactions) signatures to the
+        # window's batch and is accepted only if every one verifies.
+        # ``_tx_verified``, where an ingress plane gave one, says which
+        # transactions this validator's own gateway has verified already;
+        # those are not sent again.
+        self.transaction_signatures = False
+        self._tx_verified = None
         # Arrival-rate EMA (loop-clocked, so it reads VIRTUAL time under the
         # deterministic simulator and seeded sims stay byte-identical): the
         # collection window only pays off when more arrivals are coming.
@@ -1351,6 +1369,40 @@ class BatchedSignatureVerifier(BlockVerifier):
         and need no rebuild; only the quorum-endorsement stake math and
         per-author key lookups follow the new committee object."""
         self.committee = committee
+
+    def require_transaction_signatures(self) -> None:
+        """From now on a block's signed transactions are verified with the
+        block (``NetworkSyncer`` calls this where
+        ``Parameters.signed_transactions`` is set, before it receives a
+        block).  Quorum-endorsement skipping is off then: an endorsed
+        block's own signature may be implied, its transactions' signatures
+        are not."""
+        self.transaction_signatures = True
+        self.aggregate = False
+
+    def skip_verified_at_gateway(self, verified) -> None:
+        """``verified(transaction) -> bool``: this validator's own gateway
+        has checked that transaction's signature already (ingress.py);
+        receipt does not send those again."""
+        self._tx_verified = verified
+
+    def _transaction_signatures(self, block, pks, digests, sigs) -> None:
+        """Append (signer, digest, signature) of every signed transaction
+        of ``block`` that is not known to be verified."""
+        verified = self._tx_verified
+        for st in block.statements:
+            if not isinstance(st, Share):
+                continue
+            payload = st.transaction
+            if payload[:len(SIGNED_MAGIC)] != SIGNED_MAGIC:
+                continue
+            payload = bytes(payload)
+            signed = parse_signed_tx(payload)
+            if signed is None or (verified is not None and verified(payload)):
+                continue
+            pks.append(signed.tx.account)
+            digests.append(signed.digest)
+            sigs.append(signed.signature)
 
     def _pipeline_fixed_cost(self) -> float:
         """Fixed dispatch cost estimate for the adaptive pipeline depth: the
@@ -1514,6 +1566,21 @@ class BatchedSignatureVerifier(BlockVerifier):
             padded = padder(n) if padder is not None else n
         return out, label, padded
 
+    def _join_transaction_verdicts(self, out, tx_ranges) -> List[bool]:
+        """A block's verdict where its transactions are signed: its own
+        signature and every one of theirs.  Counts which of the two
+        rejected it."""
+        joined = []
+        for i, (start, stop) in enumerate(tx_ranges):
+            own = bool(out[i])
+            ok = own and all(out[start:stop])
+            if not ok and self.metrics is not None:
+                self.metrics.verify_rejected_blocks_total.labels(
+                    "transaction_signature" if own else "block_signature"
+                ).inc()
+            joined.append(ok)
+        return joined
+
     async def _flush(self, batch=None) -> None:
         if batch is None:
             with self._lock:
@@ -1541,6 +1608,13 @@ class BatchedSignatureVerifier(BlockVerifier):
             ]
             digests = [b.signed_digest() for b in sub_blocks]
             sigs = [b.signature for b in sub_blocks]
+            tx_ranges = None
+            if self.transaction_signatures:
+                tx_ranges = []
+                for block in sub_blocks:
+                    start = len(sigs)
+                    self._transaction_signatures(block, pks, digests, sigs)
+                    tx_ranges.append((start, len(sigs)))
             self.pipeline.note_stage(
                 STAGE_PACK, time.monotonic() - pack_started
             )
@@ -1659,15 +1733,17 @@ class BatchedSignatureVerifier(BlockVerifier):
                 self.metrics.verify_padding_wasted_total.labels(label).inc(
                     max(0, padded - len(sigs))
                 )
-                accepted = sum(bool(ok) for ok in out)
-                if accepted:
-                    self.metrics.verified_signatures_total.labels(
-                        label, "accepted"
-                    ).inc(accepted)
-                if accepted < len(out):
-                    self.metrics.verified_signatures_total.labels(
-                        label, "rejected"
-                    ).inc(len(out) - accepted)
+                # Block signatures; their transactions' signatures (behind
+                # them in the batch) have a series of their own.
+                blocks_n = len(sub_blocks)
+                _count_verdicts(
+                    self.metrics.verified_signatures_total, (label,),
+                    out[:blocks_n])
+                _count_verdicts(
+                    self.metrics.verified_tx_signatures_total,
+                    (label, "receipt"), out[blocks_n:])
+            if tx_ranges is not None:
+                out = self._join_transaction_verdicts(out, tx_ranges)
             return out
 
         def _account(aggregated: int, direct: int) -> None:

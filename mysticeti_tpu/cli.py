@@ -241,6 +241,19 @@ def main(argv: Optional[List[str]] = None) -> int:
     g.add_argument("--ips", nargs="+", required=True)
     g.add_argument("--working-directory", default="genesis")
 
+    ga = sub.add_parser(
+        "genesis",
+        help="write a genesis allocation: funded accounts for a deployment "
+        "with signed transactions (Parameters.genesis_allocation)",
+    )
+    ga.add_argument("--accounts", type=int, required=True,
+                    help="how many accounts to fund")
+    ga.add_argument("--seed", type=int, default=0,
+                    help="account i's key is derived from (seed, i)")
+    ga.add_argument("--balance", type=int, default=1_000_000,
+                    help="every account's starting balance")
+    ga.add_argument("--out", required=True, help="the allocation file")
+
     def add_storage_flags(p):
         p.add_argument("--gc-depth", type=int, default=None,
                        help="rounds retained behind the last committed "
@@ -435,6 +448,15 @@ def main(argv: Optional[List[str]] = None) -> int:
     if args.command == "benchmark-genesis":
         benchmark_genesis(args.ips, args.working_directory)
         print(f"genesis written to {args.working_directory}")
+        return 0
+    if args.command == "genesis":
+        from .execution import write_genesis_allocation
+
+        write_genesis_allocation(
+            args.out, args.accounts, args.seed, args.balance
+        )
+        print(f"{args.accounts} accounts funded with {args.balance} each "
+              f"in {args.out}")
         return 0
     if args.command == "run":
         asyncio.run(

@@ -210,6 +210,17 @@ class Parameters:
     # payload scan, and the checkpoint/manifest soft tail grows with the
     # account table.
     execution: bool = False
+    # Signed transactions (execution.py, docs/execution.md): with the
+    # execution plane on, every execution transaction rides a signed
+    # envelope whose Ed25519 signature the gateway verifies before it
+    # acknowledges and every validator verifies again when it receives the
+    # block; a bare EXECTX folds as the typed no-op ``unsigned``.  Off by
+    # default: nothing of the unsigned deployment changes.
+    signed_transactions: bool = False
+    # Genesis allocation (``python -m mysticeti_tpu genesis``): a file of
+    # account keys and one starting balance, loaded by every validator
+    # before height 1.  Empty: no account exists until a CREATE commits.
+    genesis_allocation: str = ""
     # Legacy spellings of the storage block's knobs: accepted at construction
     # and in YAML for back-compat, migrated into ``storage`` by __post_init__
     # (which then rebinds these names to the storage block's values, so every

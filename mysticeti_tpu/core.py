@@ -123,7 +123,15 @@ class Core:
         if parameters.execution:
             from .execution import ExecutionState
 
-            self.execution = ExecutionState(metrics=metrics)
+            self.execution = ExecutionState(
+                metrics=metrics, signed=parameters.signed_transactions
+            )
+            if parameters.genesis_allocation:
+                from .execution import read_genesis_allocation
+
+                self.execution.load_genesis(
+                    *read_genesis_allocation(parameters.genesis_allocation)
+                )
             self.execution.recover(recovered.exec_state)
 
         if recovered.last_own_block is not None:

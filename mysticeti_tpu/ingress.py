@@ -64,8 +64,9 @@ from .network import (
     decode_message,
     encode_message,
 )
+from . import spans
 from .finality import FinalityTracker
-from .runtime import now as runtime_now, timestamp_utc
+from .runtime import is_simulated, now as runtime_now, timestamp_utc
 from .tracing import logger
 from .utils.tasks import spawn_logged
 
@@ -93,7 +94,14 @@ SHED_BAD_NONCE = "bad_nonce"
 SHED_INSUFFICIENT_BALANCE = "insufficient_balance"
 SHED_UNKNOWN_ACCOUNT = "unknown_account"
 SHED_ACCOUNT_EXISTS = "account_exists"
+# Where signatures are required (Parameters.signed_transactions): a signed
+# envelope whose Ed25519 signature the verifier rejected, and a bare EXECTX.
+# Neither is ever admitted; the names are execution.py's verdicts.
+SHED_BAD_SIGNATURE = "bad_signature"
+SHED_UNSIGNED = "unsigned"
 _EXEC_SHED_REASONS = (
+    SHED_BAD_SIGNATURE,
+    SHED_UNSIGNED,
     SHED_BAD_NONCE,
     SHED_INSUFFICIENT_BALANCE,
     SHED_UNKNOWN_ACCOUNT,
@@ -516,6 +524,17 @@ class IngressPlane:
         # between handle_commit and handle_committed_subdag in the same
         # synchronous syncer pass, so it stays tiny.
         self.execution = None
+        # Signed transactions (execution.signed): the backend that checks a
+        # submission's signatures (the validator's own verifier, through
+        # the block verifier attach() is given), the ingress keys of what
+        # it accepted — the collector does not verify those again when a
+        # block carries them — and the gateway check's stage.
+        self._tx_verifier = None
+        self._verified: "OrderedDict[bytes, None]" = OrderedDict()
+        self._admit_stages = None
+        if metrics is not None:
+            self._admit_stages = spans.StageClock(("admit_verify",))
+            metrics.block_stages.attach(self._admit_stages)
         self._pending_exec: Deque[Tuple[int, List[bytes], dict]] = deque()
         self.executed_height = 0
         self.executed_root = b""
@@ -544,6 +563,14 @@ class IngressPlane:
             self._net_syncer = net_syncer
         if block_verifier is not None:
             self._block_verifier = block_verifier
+        if self.signed and block_verifier is not None:
+            # One backend for both checks.  Whether received blocks'
+            # transactions are checked is the validator's decision
+            # (NetworkSyncer, from Parameters.signed_transactions), not
+            # this plane's: it only tells the collector what its gateway
+            # has verified already.
+            self._tx_verifier = block_verifier.verifier
+            block_verifier.skip_verified_at_gateway(self.verified_at_gateway)
         if health is not None:
             self._health = health
         return self
@@ -571,17 +598,131 @@ class IngressPlane:
     def max_per_proposal(self) -> int:
         return self.params.max_per_proposal
 
+    @property
+    def signed(self) -> bool:
+        """Whether every execution transaction must carry a signature."""
+        return self.execution is not None and self.execution.signed
+
+    def verified_at_gateway(self, transaction: bytes) -> bool:
+        """Whether this plane verified ``transaction``'s signature when it
+        was submitted here (within the dedup window)."""
+        key = ingress_key(transaction)
+        with self._accounting_lock:
+            return key in self._verified
+
+    def _unverified(self, transactions: List[bytes]) -> List[tuple]:
+        """(position, ingress key, SignedTx) of every signed envelope of a
+        submission whose signature this plane has not checked yet; nothing
+        where no signature is required."""
+        from .execution import SIGNED_MAGIC, parse_signed_tx
+
+        found: List[tuple] = []
+        if not self.signed:
+            return found
+        if self._tx_verifier is None:
+            raise RuntimeError(
+                "signatures are required and this ingress plane was "
+                "attached to no verifier")
+        for at, tx in enumerate(transactions):
+            if not tx.startswith(SIGNED_MAGIC):
+                continue
+            signed = parse_signed_tx(tx)
+            if signed is None:
+                continue
+            key = ingress_key(tx)
+            with self._accounting_lock:
+                known = key in self._verified
+            if not known:
+                found.append((at, key, signed))
+        return found
+
+    def _verify_transactions(self, found: List[tuple]) -> List[bool]:
+        """The signatures of ``found`` as ONE batch through the verifier
+        (blocks until the verdicts are back)."""
+        return self._tx_verifier.verify_signatures(
+            [signed.tx.account for _, _, signed in found],
+            [signed.digest for _, _, signed in found],
+            [signed.signature for _, _, signed in found],
+        )
+
+    def _settle_signatures(self, transactions: List[bytes], found: List[tuple],
+                           oks, started: float):
+        """Drop what failed: (the rest, {bad_signature: count})."""
+        bad = {at for (at, _, _), ok in zip(found, oks) if not ok}
+        with self._accounting_lock:
+            verified = self._verified
+            for (at, key, _), ok in zip(found, oks):
+                if ok:
+                    verified[key] = None
+            while len(verified) > self.params.dedup_window:
+                verified.popitem(last=False)
+        if self._admit_stages is not None:
+            self._admit_stages.book_since("admit_verify", started)
+        if self.metrics is not None:
+            label = getattr(self._tx_verifier, "backend_label",
+                            type(self._tx_verifier).__name__)
+            series = self.metrics.verified_tx_signatures_total
+            if len(found) > len(bad):
+                series.labels(label, "gateway", "accepted").inc(
+                    len(found) - len(bad))
+            if bad:
+                series.labels(label, "gateway", "rejected").inc(len(bad))
+        if not bad:
+            return transactions, {}
+        kept = [tx for at, tx in enumerate(transactions) if at not in bad]
+        return kept, {SHED_BAD_SIGNATURE: len(bad)}
+
     def submit(
         self, client: str, transactions: List[bytes], priority: bool = False
     ) -> SubmitResult:
-        n = len(transactions)
+        """Admit a submission.  Where signatures are required, every signed
+        envelope is verified first, here and now (the caller waits for the
+        verifier); the gateway's loop uses :meth:`submit_checked`."""
+        refused: Dict[str, int] = {}
+        found = self._unverified(transactions)
+        if found:
+            started = spans.runtime_now()
+            transactions, refused = self._settle_signatures(
+                transactions, found, self._verify_transactions(found),
+                started)
+        return self._submit(client, transactions, priority, refused)
+
+    async def submit_checked(
+        self, client: str, transactions: List[bytes], priority: bool = False
+    ) -> SubmitResult:
+        """:meth:`submit` for a caller on the event loop: the submission's
+        signatures go to the verifier as one batch on an executor thread,
+        so only this submission's reply waits for the verdicts.  Without
+        required signatures it is :meth:`submit`, with no await."""
+        refused: Dict[str, int] = {}
+        found = self._unverified(transactions)
+        if found:
+            started = spans.runtime_now()
+            if is_simulated():
+                # No executor hop under the virtual clock (the collector's
+                # rule, block_validator.py).
+                oks = self._verify_transactions(found)
+            else:
+                oks = await asyncio.get_running_loop().run_in_executor(
+                    None, self._verify_transactions, found)
+            transactions, refused = self._settle_signatures(
+                transactions, found, oks, started)
+        return self._submit(client, transactions, priority, refused)
+
+    def _submit(
+        self, client: str, transactions: List[bytes], priority: bool,
+        refused: Dict[str, int],
+    ) -> SubmitResult:
+        """Admission, lanes and the pool for what passed the signature
+        check; ``refused`` is what did not, by reason."""
+        n = len(transactions) + sum(refused.values())
         if n == 0:
             return SubmitResult(GATEWAY_ACK, 0, 0)
         t_submit = self.clock()
-        admitted_n, retry_ms = self.controller.admit(n)
-        sheds: Dict[str, int] = {}
-        if admitted_n < n:
-            sheds[SHED_ADMISSION] = n - admitted_n
+        admitted_n, retry_ms = self.controller.admit(len(transactions))
+        sheds: Dict[str, int] = dict(refused)
+        if admitted_n < len(transactions):
+            sheds[SHED_ADMISSION] = len(transactions) - admitted_n
         admitted = transactions[:admitted_n]
         if self.execution is not None:
             lanes = self._route_execution(client, admitted, sheds)
@@ -649,13 +790,16 @@ class IngressPlane:
         the execution lock internally and ``Mempool.submit`` is called after
         (lock-order discipline).
         """
-        from .execution import parse_exec_tx
+        from .execution import REJECT_UNSIGNED
 
         lanes: "OrderedDict[str, List[bytes]]" = OrderedDict()
         for tx in transactions:
-            parsed = parse_exec_tx(tx)
+            parsed = self.execution.transaction_of(tx)
             if parsed is None:
                 lanes.setdefault(client, []).append(tx)
+                continue
+            if parsed is REJECT_UNSIGNED:
+                sheds[SHED_UNSIGNED] = sheds.get(SHED_UNSIGNED, 0) + 1
                 continue
             verdict = self.execution.admission_verdict(parsed)
             if verdict is not None:
@@ -971,8 +1115,27 @@ class IngressGateway:
         async def write_loop() -> None:
             while True:
                 msg = await outbound.get()
+                if asyncio.isfuture(msg):
+                    # A submit's reply (``checked_reply``), written in its
+                    # turn.  A submission that raised — a verifier that
+                    # failed has no verdict to report — closes the
+                    # connection (the task logged why).
+                    try:
+                        msg = await msg
+                    except Exception:  # noqa: BLE001
+                        writer.close()
+                        return
                 _write_frame(writer, encode_message(msg))
                 await writer.drain()
+
+        async def checked_reply(lane, msg) -> GatewaySubmitReply:
+            result = await self.plane.submit_checked(
+                lane, list(msg.transactions), priority=bool(msg.priority)
+            )
+            return GatewaySubmitReply(
+                result.status, result.accepted, result.shed,
+                result.retry_after_ms, result.reason.encode(),
+            )
 
         writer_task = spawn_logged(
             write_loop(), log, name=f"gateway-writer-{conn_id}"
@@ -987,20 +1150,17 @@ class IngressGateway:
                         if msg.client
                         else default_lane
                     )
-                    result = self.plane.submit(
-                        lane,
-                        list(msg.transactions),
-                        priority=bool(msg.priority),
-                    )
-                    await outbound.put(
-                        GatewaySubmitReply(
-                            result.status,
-                            result.accepted,
-                            result.shed,
-                            result.retry_after_ms,
-                            result.reason.encode(),
-                        )
-                    )
+                    # One path, signatures required or not: the frame's
+                    # reply is a task, written in the connection's reply
+                    # order when it is done.  Where signatures are required
+                    # it waits for the verifier's verdicts on the frame's
+                    # one batch; the loop, the other connections and this
+                    # connection's next frames do not (the outbound queue
+                    # bounds how many wait).
+                    await outbound.put(spawn_logged(
+                        checked_reply(lane, msg), log,
+                        name=f"gateway-submit-{conn_id}",
+                    ))
                 elif isinstance(msg, GatewaySubscribeCommits):
                     # A later subscribe on the same connection REPLACES the
                     # filter (wire-format §5b): silently ignoring it would

@@ -378,6 +378,23 @@ class Metrics:
             "verified_signatures_total", "batched signature verifications",
             labels=("backend", "outcome"),
         )
+        self.verified_tx_signatures_total = counter(
+            "verified_tx_signatures_total",
+            "signatures on client transactions verified where signatures "
+            "are required (Parameters.signed_transactions), by where: "
+            "gateway (a submission's signatures, one batch a frame, before "
+            "the reply) or receipt (a received block's, in the collector's "
+            "batch beside the block's own); block signatures stay on "
+            "verified_signatures_total",
+            labels=("backend", "where", "outcome"),
+        )
+        self.verify_rejected_blocks_total = counter(
+            "verify_rejected_blocks_total",
+            "received blocks the collector rejected where transactions are "
+            "signed, by cause: block_signature (the author's own) or "
+            "transaction_signature (a transaction in it)",
+            labels=("cause",),
+        )
         self.verify_batch_size = histogram(
             "verify_batch_size", "signature batch sizes",
             buckets=[1, 8, 32, 64, 128, 256, 512, 1024, 4096],
@@ -444,7 +461,10 @@ class Metrics:
             "block_stage_seconds",
             "wall seconds of a received batch of blocks in receive (decode "
             "+ dedup + structure), verify (collector window + the round "
-            "trip to the verifier) and dag_add (core-task queue + insertion)",
+            "trip to the verifier) and dag_add (core-task queue + "
+            "insertion); and of a gateway submission in admit_verify (its "
+            "signatures' round trip to the verifier, where signatures are "
+            "required)",
         )
         r.register(self.block_stages)
         # Staged dispatch pipeline (verify_pipeline.py): the collector may
@@ -678,7 +698,9 @@ class Metrics:
             "transactions refused (or deferred) by the ingress plane, by "
             "reason: admission (AIMD rate), mempool_transactions / "
             "mempool_bytes (pool caps), lane_cap (per-client fairness "
-            "lane), duplicate (dedup window), notify_backpressure (commit "
+            "lane), duplicate (dedup window), bad_signature / unsigned "
+            "(signatures required: the verifier rejected it / a bare "
+            "EXECTX), notify_backpressure (commit "
             "notifications a slow gateway client lost), soft_cap_deferred "
             "(re-queued for the NEXT proposal — deferred, not lost)",
             labels=("reason",),

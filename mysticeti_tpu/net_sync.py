@@ -135,6 +135,20 @@ class NetworkSyncer:
         self.core = core
         self.network = network
         self.block_verifier = block_verifier or AcceptAllBlockVerifier()
+        if self.parameters.signed_transactions:
+            # The content check of the reference's BlockVerifier seam: on,
+            # before a block is received, in every wiring of a validator —
+            # with or without an ingress plane.  A verifier that cannot
+            # check transactions (``--verifier accept``) is no deployment
+            # of signed transactions.
+            require = getattr(
+                self.block_verifier, "require_transaction_signatures", None)
+            if require is None:
+                raise ValueError(
+                    "Parameters.signed_transactions needs a block verifier "
+                    "that checks transaction signatures; "
+                    f"{type(self.block_verifier).__name__} checks none")
+            require()
         self.metrics = metrics
         self.dispatcher = CoreTaskDispatcher(self.syncer, metrics=metrics)
         # Batched native decode+digest off the event loop (core_task.py):

@@ -746,24 +746,16 @@ class VerifyDispatch:
     Between the two, the caller can pack and submit further batches — the
     device streams chunk after chunk instead of idling a full round-trip
     per dispatch.
-
-    ``patches`` carries straggler sub-dispatches (unknown-key items routed
-    through the generic kernel): ``(row indices, handle)`` pairs whose
-    results overwrite those rows at fetch time.
     """
 
-    __slots__ = ("_entries", "_patches")
+    __slots__ = ("_entries",)
 
-    def __init__(self, entries, patches=()) -> None:
+    def __init__(self, entries) -> None:
         self._entries = list(entries)
-        self._patches = tuple(patches)
 
     def result(self) -> np.ndarray:
         spans.request_stage("service_fetch")
-        out = fetch_handles(self._entries)
-        for rows, handle in self._patches:
-            out[rows] = handle.result()
-        return out
+        return fetch_handles(self._entries)
 
 
 def dispatch_batch_table(
@@ -774,7 +766,12 @@ def dispatch_batch_table(
 ) -> VerifyDispatch:
     """Non-blocking committee-indexed dispatch: pack (host) + submit every
     bucket chunk asynchronously; the returned handle fetches on demand.
-    Items whose pk is not in the table ride a generic-path patch."""
+
+    A batch that holds ANY signer the table does not know goes whole to the
+    unknown-signer kernel (``dispatch_batch``), its committee keys as raw
+    bytes like the rest: one launch, where an indexed launch over every
+    lane plus a generic launch of the strangers would run the ladder twice
+    for a block whose transactions are signed by accounts."""
     n = len(signatures)
     if n == 0:
         return VerifyDispatch([])
@@ -786,18 +783,10 @@ def dispatch_batch_table(
     # no-op).
     spans.request_stage("service_pack")
     idx = table.indices_for(public_keys)
-    known = idx >= 0
+    if (idx < 0).any():
+        return dispatch_batch(public_keys, messages, signatures)
     blob = pack_blob_indexed(idx, messages, signatures, num_keys=len(table))
-    handles = dispatch_indexed_chunks(blob, table)
-    if known.all():
-        return VerifyDispatch(handles)
-    stragglers = np.flatnonzero(~known)
-    generic = dispatch_batch(
-        [public_keys[i] for i in stragglers],
-        [messages[i] for i in stragglers],
-        [signatures[i] for i in stragglers],
-    )
-    return VerifyDispatch(handles, [(stragglers, generic)])
+    return VerifyDispatch(dispatch_indexed_chunks(blob, table))
 
 
 def verify_batch_table(

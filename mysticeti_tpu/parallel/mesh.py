@@ -193,7 +193,10 @@ def dispatch_sharded_indexed(
     kernel = _cached_indexed_kernel(mesh)
     spans.request_stage("service_pack")
     idx = table.indices_for(public_keys)
-    known = idx >= 0
+    if (idx < 0).any():
+        # A signer the table does not know: the batch goes whole to the
+        # raw-bytes kernel, one launch (ops.ed25519.dispatch_batch_table).
+        return dispatch_sharded_fused(mesh, public_keys, messages, signatures)
     blob = E.pack_blob_indexed(idx, messages, signatures, num_keys=len(table))
     # The psum'd per-chunk total is compiled and executed (the ICI collective
     # is part of the sharded program) but not fetched: padded lanes are
@@ -207,21 +210,7 @@ def dispatch_sharded_indexed(
         spans.request_stage("service_launch")
         E._note_kernel("mesh-indexed", lanes, E._backend())
         handles.append((count, kernel(jnp.asarray(padded), table.words)[0]))
-    patches = []
-    if not known.all():
-        stragglers = np.flatnonzero(~known)
-        patches.append(
-            (
-                stragglers,
-                dispatch_sharded_fused(
-                    mesh,
-                    [public_keys[i] for i in stragglers],
-                    [messages[i] for i in stragglers],
-                    [signatures[i] for i in stragglers],
-                ),
-            )
-        )
-    return E.VerifyDispatch(handles, patches)
+    return E.VerifyDispatch(handles)
 
 
 def sharded_verify_batch_indexed(

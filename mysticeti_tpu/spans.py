@@ -84,6 +84,9 @@ STAGES = (
     "proposal_wait",
     "commit",
     "finalize",
+    # The gateway's check of a submission's signatures (ingress.py; always
+    # on through a StageClock of its own, where signatures are required).
+    "admit_verify",
     # The verifier service's stages of one VERIFY/RAW request
     # (verifier_service.py, ops/ed25519.py; SERVICE_STAGES below): always on
     # through StageClock, and spans keyed by (connection, req_id) when a

@@ -770,7 +770,42 @@ class VerifierServer:
         """Hand a decoded request over (on the loop): it joins the pending
         list, and the future resolves to its reply, ``(T_RESULT, parts,
         built)``, once the launch that took it is done.  ``handed``: the
-        instant, for a request that is clocked."""
+        instant, for a request that is clocked.
+
+        A request wider than what the backend warmed is cut here into
+        pieces of at most that width, each pending like a request of its
+        own (the last, short one rides with whatever else is pending), and
+        its reply is their verdicts rejoined: no launch is ever wider than
+        what boot compiled, so a wide request — a collector window of
+        blocks full of signed transactions — compiles nothing."""
+        cap = self._launch_cap
+        if cap is None or n <= cap:
+            return self._enqueue(
+                loop, type_, req_id, n, body, conn_label, handed)
+        rec = _IDX_REC if type_ == T_VERIFY else _RAW_REC
+        pieces = [
+            self._enqueue(
+                loop, type_, req_id, min(cap, n - at),
+                body[at * rec: (at + cap) * rec], conn_label,
+                handed if at == 0 else None,
+            )
+            for at in range(0, n, cap)
+        ]
+        return asyncio.ensure_future(self._rejoin(pieces))
+
+    @staticmethod
+    async def _rejoin(pieces: List[asyncio.Future]) -> tuple:
+        """The reply of a request that was cut into ``pieces``: verdicts in
+        order; a clocked request's ``built`` is its first piece's (the
+        others are not clocked)."""
+        results = await asyncio.gather(*pieces)
+        _, (req_id, _), built = results[0]
+        return (T_RESULT,
+                (req_id, b"".join(parts[1] for _, parts, _ in results)),
+                built)
+
+    def _enqueue(self, loop, type_: int, req_id: int, n: int, body,
+                 conn_label: str, handed: Optional[float]) -> asyncio.Future:
         future = loop.create_future()
         item = _Pending(type_, req_id, n, body, conn_label, handed, future)
         with self._pending_cond:
@@ -798,8 +833,9 @@ class VerifierServer:
     def _take(self) -> List[_Pending]:
         """Everything pending, in arrival order, while the signatures sum
         to at most what the backend warmed: whole requests only, and the
-        first whatever it holds (a request over the cap goes alone, and the
-        backend splits it by ``iter_buckets`` as it always did).  A request
+        first whatever it holds (only a request that arrived before the
+        backend was warm can be over the cap: ``_submit`` cuts the others).
+        A request
         marked ``alone`` (``_submit``) goes alone, whichever slot gets to
         it first.  Called with the condition held and something pending."""
         pending = self._pending

@@ -1060,8 +1060,9 @@ def test_pipelined_replies_keep_request_order_across_launches(
 def test_a_launch_holds_whole_requests_up_to_what_the_backend_warmed(
         tmp_path, signers):
     """Pending work over 256 signatures splits on request boundaries, in
-    arrival order, into launches of at most 256; one request of 300 goes
-    alone and whole."""
+    arrival order, into launches of at most 256; one request of 300 is cut
+    into 256 and 44, the 44 ride with what is pending behind them, and its
+    reply is whole and in order: no launch is wider than what was warmed."""
     from mysticeti_tpu.ops.ed25519 import BUCKETS
 
     keys = [s.public_key.bytes for s in signers]
@@ -1073,13 +1074,15 @@ def test_a_launch_holds_whole_requests_up_to_what_the_backend_warmed(
         warm.close()
         assert server._launch_cap == BUCKETS[0] == 256
         plugs = await _plug_the_slots(server, backend, keys, signers)
-        conns = []
+        conns, pieces = [], 0
         for i, n in enumerate(sizes):  # one connection each, in this order
             conn = await asyncio.to_thread(_RawConn, server, keys)
             conn.send(_verify_frame(i, keys,
                                     _indexed(n, signers, b"cap%d" % i)))
             conns.append(conn)
-            await _until(lambda: len(server._pending) == i + 1, "handed over")
+            pieces += -(-n // BUCKETS[0])
+            await _until(lambda: len(server._pending) == pieces,
+                         "handed over")
         backend.gate.set()
         for i, (conn, n) in enumerate(zip(conns, sizes)):
             assert await asyncio.to_thread(conn.read) == (i, [1] * n)
@@ -1088,8 +1091,8 @@ def test_a_launch_holds_whole_requests_up_to_what_the_backend_warmed(
         for conn in conns + plugs:
             conn.close()
         launches = sorted(backend.sizes)
-        assert launches == sorted([1] * len(plugs) + [200, 100, 300, 20])
-        assert [n for n in launches if n > BUCKETS[0]] == [300]
+        assert launches == sorted([1] * len(plugs) + [200, 100, 256, 64])
+        assert max(launches) <= BUCKETS[0]
 
     asyncio.run(_with_server(tmp_path, keys, backend, scenario))
 
