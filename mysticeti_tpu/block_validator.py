@@ -365,11 +365,13 @@ class TpuSignatureVerifier(SignatureVerifier):
         handle.  ``result()`` pays the single combined fetch — so large
         catch-up batches stream bucket-sized sub-dispatches through the
         device while the caller packs the next batch."""
+        from .ops import ed25519
+
         mesh = self._resolve_mesh()
         # The fused sharded kernel requires 32-byte messages (block digests);
         # other lengths fall back to the single-device host-hash path so the
         # result never depends on the device count.
-        if mesh is not None and all(len(d) == 32 for d in digests):
+        if mesh is not None and ed25519._all_digests(digests):
             if self._table is not None:
                 from .parallel.mesh import dispatch_sharded_indexed
 
@@ -381,8 +383,6 @@ class TpuSignatureVerifier(SignatureVerifier):
             return dispatch_sharded_fused(
                 mesh, public_keys, digests, signatures
             )
-        from .ops import ed25519
-
         if self._table is not None:
             return ed25519.dispatch_batch_table(
                 self._table, public_keys, digests, signatures
@@ -390,11 +390,12 @@ class TpuSignatureVerifier(SignatureVerifier):
         return ed25519.dispatch_batch(public_keys, digests, signatures)
 
     def verify_signatures(self, public_keys, digests, signatures):
-        return list(
-            self.verify_signatures_async(
-                public_keys, digests, signatures
-            ).result()
-        )
+        """The three columns are sequences of bytes objects or (n, width)
+        uint8 arrays (the verifier service's: ``ops.ed25519`` packs either
+        form into the same blob); the verdicts are Python bools."""
+        return self.verify_signatures_async(
+            public_keys, digests, signatures
+        ).result().tolist()
 
 
 def _update_ema(current: float, sample: float, outlier_s: float) -> float:

@@ -688,6 +688,15 @@ class Metrics:
             "second",
             labels=("direction",),
         )
+        self.verify_pack_rows_total = counter(
+            "verify_pack_rows_total",
+            "pack calls of the dispatch path (ops/ed25519.py: pack_blob, "
+            "pack_blob_indexed) by the form their rows arrived in: array = "
+            "column slices of the wire records, nothing run once a "
+            "signature in Python; objects = sequences of bytes objects; "
+            "summed in the dispatch path and moved here twice a second",
+            labels=("form",),
+        )
 
         # Overload-resilient ingress plane (ingress.py): the admission-
         # controlled mempool's accounting.  Every transaction a node refuses

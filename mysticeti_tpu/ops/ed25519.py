@@ -348,15 +348,37 @@ def verify_fused_impl(msg_words, s_words, host_ok) -> jnp.ndarray:
 verify_fused_kernel = jax.jit(verify_fused_impl)
 
 
-def _pack_fixed_rows(items: Sequence[bytes], width: int) -> Tuple[np.ndarray, np.ndarray]:
-    """(n, width) uint8 rows + per-row well-formedness.  Vectorized single
-    concatenation when every item has the right length; rows of wrong length
-    zero-fill (callers mask them via host_ok — verify-returns-False
-    semantics, never an exception)."""
+def _is_rows(items) -> bool:
+    """Whether ``items`` arrived as rows of a byte array (the verifier
+    service slices them off the wire records) and not as a sequence of
+    bytes objects."""
+    return (
+        isinstance(items, np.ndarray)
+        and items.ndim == 2
+        and items.dtype == np.uint8
+    )
+
+
+def _pack_fixed_rows(items, width: int) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+    """(n, width) uint8 rows + per-row well-formedness (None: every row is
+    well-formed): the one seam both forms of a batch come through.
+
+    Rows that arrive as an (n, width) uint8 array are used as they are, all
+    well-formed (an array row cannot have another length; an array of
+    another width is malformed in every row).  A sequence of bytes objects
+    is joined with one concatenation when every item has the right length;
+    items of wrong length zero-fill (callers mask them via host_ok —
+    verify-returns-False semantics, never an exception)."""
     n = len(items)
+    if _is_rows(items):
+        if items.shape[1] != width:
+            return np.zeros((n, width), np.uint8), np.zeros(n, bool)
+        if items.strides[1] != 1:  # the packers view a row's bytes as words
+            items = np.ascontiguousarray(items)
+        return items, None
     ok = np.fromiter((len(x) == width for x in items), bool, count=n)
     if ok.all():
-        return np.frombuffer(b"".join(items), np.uint8).reshape(n, width), ok
+        return np.frombuffer(b"".join(items), np.uint8).reshape(n, width), None
     arr = np.zeros((n, width), np.uint8)
     for i in range(n):
         if ok[i]:
@@ -364,27 +386,25 @@ def _pack_fixed_rows(items: Sequence[bytes], width: int) -> Tuple[np.ndarray, np
     return arr, ok
 
 
-def pack_bytes(
-    public_keys: Sequence[bytes],
-    messages: Sequence[bytes],
-    signatures: Sequence[bytes],
-) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Host-side packing for the fused kernel: pure byte concatenation.
+def _host_ok(*oks: Optional[np.ndarray]):
+    """The lanes every one of ``oks`` admits (each an (n,) bool array, or
+    None for all): an array, or 1 where all are None."""
+    given = [ok for ok in oks if ok is not None]
+    if not given:
+        return 1
+    out = given[0]
+    for ok in given[1:]:
+        out = out & ok
+    return out
 
-    Requires 32-byte messages (the framework always signs a blake2b-256 block
-    digest, types.py signed_digest); malformed-length items are masked out via
-    host_ok rather than raising, matching verify-returns-False semantics.
-    """
-    sig_arr, sig_ok = _pack_fixed_rows(signatures, 64)
-    pk_arr, pk_ok = _pack_fixed_rows(public_keys, 32)
-    msg_arr, msg_ok = _pack_fixed_rows(messages, 32)
-    host_ok = sig_ok & pk_ok & msg_ok
-    blob = np.ascontiguousarray(
-        np.concatenate([sig_arr[:, :32], pk_arr, msg_arr], axis=1)
-    )
-    msg_words = blob.view(">u4").astype(np.uint32)  # (n, 24) big-endian words
-    s_words = np.ascontiguousarray(sig_arr[:, 32:]).view("<u4").astype(np.uint32)
-    return msg_words, s_words, host_ok
+
+def _all_digests(messages) -> bool:
+    """Whether every message is a 32-byte digest (the fused kernels' only
+    input; anything else is hashed on the host): a look at the shape for
+    rows of an array, a walk for a sequence."""
+    if _is_rows(messages):
+        return messages.shape[1] == 32
+    return all(len(m) == 32 for m in messages)
 
 
 def pack_blob(
@@ -395,12 +415,37 @@ def pack_blob(
     """Pack a batch into ONE (n, 33) uint32 array: columns 0-23 the big-endian
     R||A||M words, 24-31 the little-endian s words, 32 the host_ok flag.
 
-    One array means one host->device transfer per dispatch.
+    One array means one host->device transfer per dispatch.  Requires
+    32-byte messages (the framework always signs a blake2b-256 block digest,
+    types.py signed_digest); malformed-length items are masked out via
+    host_ok rather than raising, matching verify-returns-False semantics.
+    The three inputs are sequences of bytes objects or (n, width) uint8
+    arrays (``_pack_fixed_rows``); either way each column's bytes are read
+    as words where they lie and written once, into the blob.
     """
-    msg_words, s_words, host_ok = pack_bytes(public_keys, messages, signatures)
-    return np.concatenate(
-        [msg_words, s_words, host_ok[:, None].astype(np.uint32)], axis=1
-    )
+    sig_arr, sig_ok = _pack_fixed_rows(signatures, 64)
+    pk_arr, pk_ok = _pack_fixed_rows(public_keys, 32)
+    msg_arr, msg_ok = _pack_fixed_rows(messages, 32)
+    _note_pack(public_keys, messages, signatures)
+    blob = np.empty((len(sig_arr), 33), np.uint32)
+    blob[:, :8] = sig_arr[:, :32].view(">u4")
+    blob[:, 8:16] = pk_arr.view(">u4")
+    blob[:, 16:24] = msg_arr.view(">u4")
+    blob[:, 24:32] = sig_arr[:, 32:].view("<u4")
+    blob[:, 32] = _host_ok(sig_ok, pk_ok, msg_ok)
+    return blob
+
+
+def pack_bytes(
+    public_keys: Sequence[bytes],
+    messages: Sequence[bytes],
+    signatures: Sequence[bytes],
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """``pack_blob``'s columns apart, for the kernels that take them so:
+    (n, 24) big-endian R||A||M words, (n, 8) little-endian s words, (n,)
+    host_ok."""
+    blob = pack_blob(public_keys, messages, signatures)
+    return blob[:, :24], blob[:, 24:32], blob[:, 32] != 0
 
 
 def verify_fused_blob_impl(blob: jnp.ndarray) -> jnp.ndarray:
@@ -443,29 +488,22 @@ def pack_blob_indexed(
     ``KeyTable.indices_for``) are masked host_ok=False here — never silently
     verified against some other table row.
     """
-    n = len(signatures)
     idx = np.asarray(indices, np.int64)
-    ok = np.ones(n, bool) if host_ok is None else np.asarray(host_ok, bool).copy()
-    ok &= idx >= 0
+    ok = idx >= 0
     if num_keys is not None:
         ok &= idx < num_keys
+    if host_ok is not None:
+        ok &= np.asarray(host_ok, bool)
     sig_arr, sig_ok = _pack_fixed_rows(signatures, 64)
     msg_arr, msg_ok = _pack_fixed_rows(messages, 32)
-    ok &= sig_ok & msg_ok
-    rm = np.ascontiguousarray(
-        np.concatenate([sig_arr[:, :32], msg_arr], axis=1)
-    )
-    rm_words = rm.view(">u4").astype(np.uint32)  # (n, 16) R then M
-    s_words = np.ascontiguousarray(sig_arr[:, 32:]).view("<u4").astype(np.uint32)
-    return np.concatenate(
-        [
-            rm_words,
-            s_words,
-            np.clip(idx, 0, None).astype(np.uint32)[:, None],
-            ok[:, None].astype(np.uint32),
-        ],
-        axis=1,
-    )
+    _note_pack(messages, signatures)
+    blob = np.empty((len(sig_arr), 26), np.uint32)
+    blob[:, :8] = sig_arr[:, :32].view(">u4")
+    blob[:, 8:16] = msg_arr.view(">u4")
+    blob[:, 16:24] = sig_arr[:, 32:].view("<u4")
+    blob[:, 24] = np.maximum(idx, 0)
+    blob[:, 25] = _host_ok(ok, sig_ok, msg_ok)
+    return blob
 
 
 def indexed_to_msg_words(blob: jnp.ndarray, table: jnp.ndarray):
@@ -645,17 +683,40 @@ class KeyTable:
         self.words = jnp.asarray(pk_table_words(public_keys))
         self._index = {pk: i for i, pk in enumerate(public_keys)}
         self._keys = [bytes(pk) for pk in public_keys]
+        # The same lookup for keys that arrive as rows of an array: the
+        # table's distinct keys sorted as 32-byte strings, and the index the
+        # dict gives each (the last of its rows, where a key is held twice).
+        distinct = np.frombuffer(b"".join(self._index), "S32")
+        order = np.argsort(distinct)
+        self._sorted_keys = distinct[order]
+        self._sorted_index = np.fromiter(
+            self._index.values(), np.int64, count=len(order)
+        )[order]
         self._neg_combs: Optional[Tuple[jnp.ndarray, np.ndarray]] = None
 
     def __len__(self) -> int:
         return self.words.shape[0]
 
     def indices_for(self, public_keys: Sequence[bytes]) -> np.ndarray:
-        return np.fromiter(
-            (self._index.get(pk, -1) for pk in public_keys),
-            np.int64,
-            count=len(public_keys),
-        )
+        """The table row of each key, -1 where the table holds no such key:
+        one dict lookup a key for a sequence of bytes objects, one binary
+        search of the sorted table for an (n, 32) uint8 array — the same
+        answer either way."""
+        n = len(public_keys)
+        if not _is_rows(public_keys):
+            return np.fromiter(
+                (self._index.get(pk, -1) for pk in public_keys),
+                np.int64,
+                count=n,
+            )
+        if public_keys.shape[1] != 32:
+            return np.full(n, -1, np.int64)
+        # A fixed-width byte string compares whole (numpy ignores trailing
+        # NULs on both sides alike, so equal means equal in all 32 bytes).
+        rows = np.ascontiguousarray(public_keys).view("S32")[:, 0]
+        at = self._sorted_keys.searchsorted(rows)
+        hit = self._sorted_keys.take(at, mode="clip") == rows
+        return np.where(hit, self._sorted_index.take(at, mode="clip"), -1)
 
     def neg_combs(self) -> Tuple[jnp.ndarray, np.ndarray]:
         """(device (K, 64, 3, NLIMBS, 16) comb array, (K,) host valid mask)."""
@@ -728,7 +789,7 @@ def dispatch_indexed_chunks(blob: np.ndarray, table: "KeyTable"):
             padded = _pad_to(chunk, b)
             spans.request_stage("service_launch")
             _note_transfer("to_device", padded.nbytes)
-            h = _dispatch_indexed(jnp.asarray(padded), table.words)
+            h = _dispatch_indexed(padded, table.words)
             handles.append((count, h))
         else:
             h, positions = hp
@@ -775,7 +836,7 @@ def dispatch_batch_table(
     n = len(signatures)
     if n == 0:
         return VerifyDispatch([])
-    if not all(len(m) == 32 for m in messages):
+    if not _all_digests(messages):
         return dispatch_batch(public_keys, messages, signatures)
     # Inside the verifier service the request is in service_pack from here
     # and in service_launch around each jitted call (spans.request_stage:
@@ -831,6 +892,12 @@ def pack_batch(
     digest path replaces this for 32-byte block digests), performs the cheap
     integer checks, and packs limb/bit arrays for :func:`verify_kernel`.
     """
+    # This path hashes on the host, a signature at a time: rows of an array
+    # become the bytes objects that takes.
+    public_keys, messages, signatures = (
+        [bytes(row) for row in c] if _is_rows(c) else c
+        for c in (public_keys, messages, signatures)
+    )
     n = len(signatures)
     a_y = np.zeros((n, F.NLIMBS), np.int32)
     a_sign = np.zeros(n, np.int32)
@@ -962,13 +1029,19 @@ def install_compile_listeners() -> None:
 
 def install_device_attribution(metrics) -> bool:
     """Wire JAX compile events, compile-cache hits/misses, and the transfer
-    byte counters below into the node's registry (``mysticeti_jax_*`` and
-    ``mysticeti_device_transfer_bytes_total``, metrics.py).  Called once by
+    byte and pack-call counters below into the node's registry
+    (``mysticeti_jax_*``, ``mysticeti_device_transfer_bytes_total`` and
+    ``verify_pack_rows_total``, metrics.py).  Called once by
     validators that verify in-process; re-calling swaps the target registry.
     Returns whether the ``jax.monitoring`` listeners landed (the module is
     semi-private, so every hook is best-effort)."""
     global _attr_metrics
     _attr_metrics = metrics
+    if metrics is not None:
+        # Both forms are scraped from the start: "no pack call took the
+        # objects form" reads 0, not an absent series.
+        for form in ("array", "objects"):
+            metrics.verify_pack_rows_total.labels(form)
     try:
         install_compile_listeners()
         return True
@@ -976,41 +1049,64 @@ def install_device_attribution(metrics) -> bool:
         return False
 
 
-_TRANSFER_DIRECTIONS = ("to_device", "from_device")
+# What the dispatch path counts for the registry, each thread for itself:
+# (series, label value) by slot of the thread's list.
+_NOTED = (
+    ("mysticeti_device_transfer_bytes_total", "to_device"),
+    ("mysticeti_device_transfer_bytes_total", "from_device"),
+    ("verify_pack_rows_total", "array"),
+    ("verify_pack_rows_total", "objects"),
+)
+_SLOT = {label: slot for slot, (_, label) in enumerate(_NOTED)}
 _TRANSFER_FLUSH_S = 0.5
-# Per thread: [bytes to the device, bytes from it, when last moved to the
-# registry], not yet in the registry.
+# Per thread: one sum a slot of ``_NOTED``, then when the sums last moved to
+# the registry.
 _transfer_local = threading.local()
+
+
+def _note(slot: int, amount: int) -> None:
+    """Add ``amount`` to the calling thread's sum for ``_NOTED[slot]`` and,
+    twice a second, move the thread's sums into the registry.
+
+    Called a few times a launch from the service's dispatcher threads, so
+    each thread adds to a list of its own (a thread that falls idle keeps
+    what it noted since, until its next call): one locked prometheus child
+    a call cost the service 3.6% of its throughput on the chip's host
+    (PERF.md, PR 24)."""
+    m = _attr_metrics
+    if m is None:
+        return
+    try:
+        pending = _transfer_local.pending
+    except AttributeError:
+        pending = _transfer_local.pending = [0] * len(_NOTED) + [0.0]
+    pending[slot] += amount
+    now = time.monotonic()
+    if now - pending[-1] < _TRANSFER_FLUSH_S:  # lint: ignore[sim-taint] — when a counter reaches the registry; nothing reads it back
+        return
+    pending[-1] = now
+    for i, (series, label) in enumerate(_NOTED):
+        if pending[i]:
+            getattr(m, series).labels(label).inc(pending[i])
+            pending[i] = 0
 
 
 def _note_transfer(direction: str, nbytes: int) -> None:
     """Count host<->device bytes at the dispatch/fetch seams: JAX exposes no
     portable transfer counter, but every verifier transfer flows through
     dispatch_blob_chunks / dispatch_batch / fetch_handles, so counting the
-    (padded) array sizes there IS the device link traffic.
+    (padded) array sizes there IS the device link traffic."""
+    if nbytes > 0:
+        _note(_SLOT[direction], nbytes)
 
-    Called two or three times a request from the service's sixteen pool
-    threads, so each thread adds to a list of its own and moves its sums
-    into the registry twice a second (a thread that falls idle keeps what
-    it noted since, until its next transfer): one locked prometheus child a
-    call cost the service 3.6% of its throughput on the chip's host
-    (PERF.md, PR 24)."""
-    m = _attr_metrics
-    if m is None or nbytes <= 0:
-        return
-    try:
-        pending = _transfer_local.pending
-    except AttributeError:
-        pending = _transfer_local.pending = [0, 0, 0.0]
-    pending[direction == "from_device"] += nbytes
-    now = time.monotonic()
-    if now - pending[2] < _TRANSFER_FLUSH_S:  # lint: ignore[sim-taint] — when a byte counter reaches the registry; nothing reads it back
-        return
-    pending[2] = now
-    for i, name in enumerate(_TRANSFER_DIRECTIONS):
-        if pending[i]:
-            m.mysticeti_device_transfer_bytes_total.labels(name).inc(pending[i])
-            pending[i] = 0
+
+def _note_pack(*columns) -> None:
+    """Count one pack call by the form its columns arrived in: ``array``
+    when every one is rows of a byte array, nothing between the wire and
+    the blob having run once a signature; ``objects`` when any is a
+    sequence of bytes objects.  The verifier service must count ``array``
+    on every launch."""
+    _note(_SLOT["array" if all(_is_rows(c) for c in columns) else "objects"], 1)
 
 
 def _dispatch_packed(*arrays) -> jnp.ndarray:
@@ -1071,7 +1167,7 @@ def dispatch_blob_chunks(blob: np.ndarray):
         padded = _pad_to(blob[start : start + count], b)
         spans.request_stage("service_launch")
         _note_transfer("to_device", padded.nbytes)
-        out.append((count, _dispatch_blob(jnp.asarray(padded))))
+        out.append((count, _dispatch_blob(padded)))
     return out
 
 
@@ -1132,8 +1228,7 @@ def dispatch_batch(
     if n == 0:
         return VerifyDispatch([])
     spans.request_stage("service_pack")
-    fused = all(len(m) == 32 for m in messages)
-    if fused:
+    if _all_digests(messages):
         blob = pack_blob(public_keys, messages, signatures)
         # Dispatch every chunk asynchronously (one transfer each); the
         # handle forces all results with a single combined fetch, so device
@@ -1168,7 +1263,11 @@ def verify_batch(
 
 
 def _pad_to(x: np.ndarray, size: int) -> np.ndarray:
+    """``x`` itself where it already fills the bucket, else a fresh array of
+    the bucket's height with zero rows below it: either way a buffer nobody
+    writes again, so the device may read it for as long as it likes."""
     if x.shape[0] == size:
         return np.ascontiguousarray(x)
-    widths = [(0, size - x.shape[0])] + [(0, 0)] * (x.ndim - 1)
-    return np.pad(x, widths)
+    out = np.zeros((size,) + x.shape[1:], x.dtype)
+    out[: x.shape[0]] = x
+    return out

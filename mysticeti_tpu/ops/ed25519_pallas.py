@@ -701,12 +701,15 @@ def verify_keyed_blob(
     b = grouped.shape[0]
     if b % tile != 0:
         raise ValueError(f"batch {b} not a multiple of tile {tile}")
+    # The arrays are the jitted call's own arguments, numpy or device: that
+    # one call moves a host blob to the chip itself.  A ``jnp.asarray``
+    # before it is a second dispatch, through JAX's Python transfer path.
     return _verify_keyed_blob_jit(
-        jnp.asarray(grouped),
-        jnp.asarray(table_words),
-        jnp.asarray(acomb),
-        jnp.asarray(tile_keys),
-        None if positions is None else jnp.asarray(positions),
+        grouped,
+        table_words,
+        acomb,
+        tile_keys,
+        positions,
         tile=tile,
         interpret=interpret,
     )
@@ -748,9 +751,8 @@ def verify_fused_blob_pallas(
     b = blob.shape[0]
     if b % tile != 0:
         raise ValueError(f"batch {b} not a multiple of tile {tile}")
-    return _verify_fused_blob_pallas_jit(
-        jnp.asarray(blob), tile=tile, interpret=interpret
-    )
+    # ``blob`` goes in as it is (see ``verify_keyed_blob``).
+    return _verify_fused_blob_pallas_jit(blob, tile=tile, interpret=interpret)
 
 
 def verify_fused_indexed_blob_pallas(
@@ -765,8 +767,9 @@ def verify_fused_indexed_blob_pallas(
     b = blob.shape[0]
     if b % tile != 0:
         raise ValueError(f"batch {b} not a multiple of tile {tile}")
+    # ``blob`` and ``table`` go in as they are (see ``verify_keyed_blob``).
     return _verify_fused_indexed_pallas_jit(
-        jnp.asarray(blob), jnp.asarray(table), tile=tile, interpret=interpret
+        blob, table, tile=tile, interpret=interpret
     )
 
 

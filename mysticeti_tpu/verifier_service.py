@@ -277,6 +277,8 @@ class VerifierServer:
         # None until it is warm; a request that arrives before that is
         # launched alone and waits for the warm-up in ``_ensure_backend``.
         self._launch_cap: Optional[int] = None
+        # (the committee's key list, its rows as an array): ``_key_rows``.
+        self._key_rows_cache: Optional[tuple] = None
         # HELLO and warm-up have a thread of their own: a warm-up takes
         # minutes, and HELLOs behind it wait for it anyway.
         self._hello_pool = ThreadPoolExecutor(
@@ -385,18 +387,17 @@ class VerifierServer:
         keys = self._keys or []
         if not keys:
             return
-        pk = keys[0]
-        digest = bytes(32)
-        sig = bytes(64)
-        t0 = time.monotonic()
-        self._backend.verify_signatures([pk], [digest], [sig])
-        fixed = time.monotonic() - t0
+        import numpy as np
+
+        # As a launch hands them over (``_wire_rows``): rows of arrays.
         n = 256
+        pks = self._key_rows(keys)[np.arange(n) % len(keys)]
+        digests, sigs = np.zeros((n, 32), np.uint8), np.zeros((n, 64), np.uint8)
         t0 = time.monotonic()
-        self._backend.verify_signatures(
-            [keys[i % len(keys)] for i in range(n)],
-            [digest] * n, [sig] * n,
-        )
+        self._backend.verify_signatures(pks[:1], digests[:1], sigs[:1])
+        fixed = time.monotonic() - t0
+        t0 = time.monotonic()
+        self._backend.verify_signatures(pks, digests, sigs)
         batch_t = time.monotonic() - t0
         self._calibration = (fixed, max(0.0, (batch_t - fixed) / n))
         log.info(
@@ -913,30 +914,64 @@ class VerifierServer:
                     built if item.handed is not None else None,
                 ))
 
+    def _key_rows(self, keys: List[bytes]):
+        """(K + 1, 32) uint8: the committee's keys by wire index, and below
+        them the all-zero key that an out-of-range index gets (it cannot
+        verify: that slot is rejected, not the request or the launch)."""
+        import numpy as np  # the service's side only: validators stay off it
+
+        rows = self._key_rows_cache
+        if rows is None or rows[0] is not keys:
+            table = np.zeros((len(keys) + 1, 32), np.uint8)
+            if keys:
+                table[:-1] = np.frombuffer(
+                    b"".join(keys), np.uint8).reshape(len(keys), 32)
+            rows = self._key_rows_cache = (keys, table)
+        return rows[1]
+
+    def _wire_rows(self, batch: List[_Pending]):
+        """The public keys, digests and signatures of every request of
+        ``batch``, in order, as three uint8 arrays of one row a signature:
+        column slices of the wire records, which nothing walks.  Requests
+        of one frame type that follow each other share one record array; a
+        VERIFY record's 2-byte index becomes its key's row with one
+        gather."""
+        import numpy as np
+
+        columns: Tuple[list, list, list] = ([], [], [])
+        at = 0
+        while at < len(batch):
+            type_ = batch[at].type_
+            end = at + 1
+            while end < len(batch) and batch[end].type_ == type_:
+                end += 1
+            body = (batch[at].body if end == at + 1
+                    else b"".join([item.body for item in batch[at:end]]))
+            at = end
+            if type_ == T_VERIFY:
+                rows = np.frombuffer(body, np.uint8).reshape(-1, _IDX_REC)
+                table = self._key_rows(self._keys or [])
+                index = rows[:, :2].view("<u2")[:, 0]
+                pks = table[np.minimum(index, len(table) - 1)]
+                digests, sigs = rows[:, 2:34], rows[:, 34:]
+            else:
+                rows = np.frombuffer(body, np.uint8).reshape(-1, _RAW_REC)
+                pks, digests, sigs = rows[:, :32], rows[:, 32:64], rows[:, 64:]
+            for column, part in zip(columns, (pks, digests, sigs)):
+                column.append(part)
+        return [c[0] if len(c) == 1 else np.concatenate(c) for c in columns]
+
     def _verify_batch(self, batch: List[_Pending]) -> List[tuple]:
         """Verify every signature of every request of ``batch`` with one
         backend call and return each request's reply parts, ``(req_id
         bytes, verdict bytes)`` — the writer scatter-gathers them behind a
-        fresh header, so the verdicts are copied exactly once (list ->
-        bytes) on their way out."""
+        fresh header.  The signatures travel as arrays from the wire
+        records to the backend (``_wire_rows``) and the verdicts back into
+        bytes with one conversion: nothing here runs once a signature."""
+        import numpy as np
+
         backend = self._ensure_backend(self._keys or [])
-        keys = self._keys or []
-        pks, digests, sigs = [], [], []
-        for item in batch:
-            body = item.body
-            if item.type_ == T_VERIFY:
-                for off in range(0, item.n * _IDX_REC, _IDX_REC):
-                    (idx,) = struct.unpack_from("<H", body, off)
-                    # An out-of-range index cannot verify; reject that slot
-                    # rather than the whole request (or launch).
-                    pks.append(keys[idx] if idx < len(keys) else bytes(32))
-                    digests.append(body[off + 2: off + 34])
-                    sigs.append(body[off + 34: off + 98])
-            else:
-                for off in range(0, item.n * _RAW_REC, _RAW_REC):
-                    pks.append(body[off: off + 32])
-                    digests.append(body[off + 32: off + 64])
-                    sigs.append(body[off + 64: off + 128])
+        pks, digests, sigs = self._wire_rows(batch)
         # The backend's time is the fetch's (device run + transfer + getting
         # the GIL back; a host oracle's whole work), but for the stages it
         # names itself: the JAX backend packs and launches first
@@ -960,7 +995,7 @@ class VerifierServer:
                 self.metrics.verify_padding_wasted_total.labels(
                     "service"
                 ).inc(max(0, padder(total) - total))
-        verdicts = bytes([1 if ok else 0 for ok in oks])
+        verdicts = np.asarray(oks, bool).view(np.uint8).tobytes()
         replies, at = [], 0
         for item in batch:
             replies.append((struct.pack("<I", item.req_id),

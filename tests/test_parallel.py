@@ -108,3 +108,14 @@ def test_sharded_indexed_verify_matches_oracle():
     ok_gen, total_gen = sharded_verify_batch_fused(mesh, pks, msgs, sigs)
     assert (ok_idx == ok_gen).all()
     assert total_idx == total_gen == int(ok_gen.sum())
+    # The verifier service hands the same batch over as rows of uint8 arrays
+    # (column slices of its wire records): the same verdicts.
+    import numpy as np
+
+    records = np.frombuffer(
+        b"".join(pk + m + s for pk, m, s in zip(pks, msgs, sigs)), np.uint8
+    ).reshape(len(sigs), 128)
+    ok_rows, total_rows = sharded_verify_batch_indexed(
+        mesh, table, records[:, :32], records[:, 32:64], records[:, 64:]
+    )
+    assert (ok_rows == ok_gen).all() and total_rows == total_gen
