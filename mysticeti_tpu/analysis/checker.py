@@ -98,20 +98,12 @@ _WAITER_SUFFIXES = ("wait", "wait_for", "gather", "shield")
 # ``__init__`` must sit lexically inside ``with self.<lock>:``.
 GUARDED_FIELDS: Dict[str, str] = {
     "_dispatch_ema_s": "_lock",
-    "cpu_per_sig_s": "_ema_lock",
-    "tpu_dispatch_s": "_ema_lock",
-    "tpu_per_sig_s": "_ema_lock",
-    # Hybrid verifier circuit breaker: tripped/probed/closed from concurrent
-    # dispatch threads; shares the EMA lock (same writers, same cadence).
-    "_breaker_backoff_s": "_ema_lock",
-    "_breaker_gen": "_ema_lock",
-    "_breaker_open_until": "_ema_lock",
-    "_breaker_probing": "_ema_lock",
-    # Backend pin (zero-tax short-circuit routing): pinned/probed/unpinned
-    # from concurrent dispatch threads; shares the EMA lock like the breaker.
-    "_pinned_backend": "_ema_lock",
-    "_pin_backoff_s": "_ema_lock",
-    "_pin_next_probe_t": "_ema_lock",
+    # FallbackSignatureVerifier's circuit breaker: tripped/probed/closed
+    # from concurrent dispatch threads.
+    "_breaker_backoff_s": "_breaker_lock",
+    "_breaker_gen": "_breaker_lock",
+    "_breaker_open_until": "_breaker_lock",
+    "_breaker_probing": "_breaker_lock",
     # Batching collector arrival-rate EMA: read-modify-written under the
     # pending-queue lock alongside the dispatch EMA it modulates.
     "_arrival_gap_ema_s": "_lock",

@@ -118,7 +118,7 @@ class SignatureVerifier:
         """The platform this verifier's dispatches ACTUALLY land on.  Host
         oracles are "cpu"; accelerator backends override with the live
         runtime's answer so the verifier service can advertise it over
-        HELLO_OK (and clients can short-circuit a service with no chip
+        HELLO_OK (a launcher refuses a fleet whose service has no chip
         behind it)."""
         return "cpu"
 
@@ -399,106 +399,66 @@ class TpuSignatureVerifier(SignatureVerifier):
 
 
 def _update_ema(current: float, sample: float, outlier_s: float) -> float:
-    """EMA with outlier rejection, shared by the batching collector's window
-    and the hybrid router's calibration: samples past ``outlier_s`` (one-time
-    JAX compiles) never enter; the first sample seeds."""
+    """EMA with outlier rejection for the batching collector's window:
+    samples past ``outlier_s`` (one-time JAX compiles) never enter; the
+    first sample seeds."""
     if sample >= outlier_s:
         return current
     return sample if current == 0.0 else 0.8 * current + 0.2 * sample
 
 
-class HybridSignatureVerifier(SignatureVerifier):
-    """Route each batch to the CPU oracle or the TPU backend by MEASURED
-    cost (SURVEY §7 hard part #2: "CPU fallback for stragglers").
+class FallbackSignatureVerifier(SignatureVerifier):
+    """The accelerator backend behind a circuit breaker (the ``tpu``
+    flavor): every batch goes to ``tpu``; the only thing that sends one to
+    the ``cpu`` oracle instead is an open breaker.
 
-    The accelerator's cost model has TWO measured parameters, not one:
-
-    * ``tpu_dispatch_s`` — the fixed per-dispatch cost (µs co-located,
-      ~100 ms over a slow link), seeded by a 1-signature probe after warmup;
-    * ``tpu_per_sig_s`` — the marginal per-signature cost, learned from
-      live TPU-routed dispatches (``max(0, (t - fixed) / n)``).
-
-    A fixed-only model routed saturation batches to "accelerators" that are
-    actually slower per signature than the oracle — on a host whose JAX
-    backend degraded to CPU, a 256-batch "offload" cost 1.5 s where the
-    oracle takes 32 ms, and light-load fleet latency collapsed to ~2 s
-    (round-5 NODE_BENCH draft).  Routing per batch of size n:
-
-    1. ``tpu_time(n) <= cpu_time(n)``       -> TPU (genuinely faster);
-    2. ``cpu_time(n) > MAX_CPU_BUDGET_S``   -> TPU **iff**
-       ``tpu_time(n) <= MAX_OFFLOAD_LATENCY_S`` — offloading frees the
-       host core for the engine (worth paying bounded extra latency on an
-       engine-bound fleet), but never to a backend whose turnaround would
-       itself stall consensus;
-    3. otherwise                            -> CPU.
+    A dead backend (verifier service restart, link outage) degrades the node
+    to the oracle instead of crashing the dispatch thread, and the batch
+    that met the failure — at submit or at fetch — is answered by the
+    oracle, so no future is lost.  While open, one dispatch at a time is
+    admitted as a probe once the backoff deadline passes; the backoff
+    doubles 1 -> 30 s and is jittered so a fleet that lost ONE shared
+    service never re-probes it in lockstep.  Only transport/timeout
+    failures trip it: a :class:`VerifierProtocolError` is a misconfiguration
+    and propagates.  ``tpu-only`` is the same backend without this class:
+    failures surface, which is what a measurement wants.
     """
 
-    DEFAULT_THRESHOLD = 32  # n-based routing until both sides are seeded
-    MAX_CPU_BUDGET_S = 0.010  # max host time one CPU-routed batch may take
-    # Offload-to-free-the-core is only sane when the accelerator turnaround
-    # is itself consensus-compatible: a ~150 ms remote chip qualifies, a
-    # jax-CPU backend (seconds per dispatch) must not.
-    MAX_OFFLOAD_LATENCY_S = 0.5
-    EMA_OUTLIER_S = 5.0  # ignore one-time compile stalls
-    # Circuit breaker over the accelerator route: a dead backend (verifier
-    # service restart, link outage) degrades to the CPU oracle instead of
-    # crashing the dispatch thread; re-probes use jittered exponential
-    # backoff so a fleet that lost ONE shared service never re-probes it in
-    # lockstep.  Only transport/timeout failures trip it — a
-    # VerificationError-shaped rejection is a verdict, not an outage.
     BREAKER_EXCEPTIONS = (ConnectionError, TimeoutError, OSError)
     BREAKER_BASE_BACKOFF_S = 1.0
     BREAKER_MAX_BACKOFF_S = 30.0
-    # Advertised backends with no accelerator behind them (HELLO_OK suffix,
-    # verifier_service.py): a service running on one of these has nothing to
-    # offload TO — routing pins to the in-process oracle and the socket goes
-    # silent (zero frames per batch) until a re-HELLO probe sees an upgrade.
-    CPU_ONLY_BACKENDS = frozenset({"cpu"})
 
     def __init__(
         self,
         tpu: Optional[SignatureVerifier] = None,
         cpu: Optional[SignatureVerifier] = None,
-        threshold: Optional[int] = None,
         metrics=None,
     ) -> None:
         self.tpu = tpu or TpuSignatureVerifier()
         self.cpu = cpu or CpuSignatureVerifier()
-        self._fixed_threshold = threshold
         self.metrics = metrics
-        self.cpu_per_sig_s = 0.0
-        self.tpu_dispatch_s = 0.0  # fixed component
-        self.tpu_per_sig_s = 0.0  # marginal component
-        # EMA read-modify-writes happen from executor threads; serialize them.
-        self._ema_lock = threading.Lock()
-        # Breaker state shares _ema_lock (same writer threads, same cadence).
-        # backoff == 0.0 means closed; while open, dispatches fall back to
-        # the CPU oracle until the probe deadline passes.  _breaker_probing
-        # keeps the probe EXCLUSIVE even when it outlives the backoff
-        # interval (a hung service blocks the probe thread for the whole
-        # dispatch timeout; new windows must not admit more victims).
+        # Breaker state is tripped/probed/closed from concurrent executor
+        # threads.  backoff == 0.0 means closed; while open, dispatches fall
+        # back to the oracle until the probe deadline passes.
+        # _breaker_probing keeps the probe EXCLUSIVE even when it outlives
+        # the backoff interval (a hung service blocks the probe thread for
+        # the whole dispatch timeout; new windows must not admit more
+        # victims).
+        self._breaker_lock = threading.Lock()
         self._breaker_backoff_s = 0.0
         self._breaker_open_until = 0.0
         self._breaker_probing = False
         # Trip generation: with several dispatches in flight, a PRE-outage
         # success can surface at fetch AFTER a newer failure tripped the
-        # circuit — it must not re-close it (see result()).
+        # circuit — it must not re-close it (see _FallbackDispatch.result).
         self._breaker_gen = 0
         self._breaker_rng = random.Random(0x0B7EA6E5)
         self._breaker_clock = time.monotonic  # injectable for tests
-        # Backend pin (shares _ema_lock and the breaker's probe-exclusivity
-        # flag): while the remote side advertises a CPU-only backend, every
-        # batch short-circuits to the in-process oracle and a low-frequency
-        # re-HELLO probe (jittered exponential backoff, same schedule
-        # constants as the breaker) watches for an accelerator upgrade.
-        self._pinned_backend: Optional[str] = None
-        self._pin_backoff_s = 0.0
-        self._pin_next_probe_t = 0.0
-        # Routing label of the dispatch that ran in THIS thread: the batching
-        # collector reads it right after verify_signatures returns, in the
-        # same executor thread, so thread-local storage is exactly the
-        # lifetime needed — a concurrent flush routed the other way cannot
-        # overwrite it (it writes its own thread's slot).
+        # Label of the dispatch that ran in THIS thread: the batching
+        # collector reads it right after result(), in the same executor
+        # thread, so thread-local storage is exactly the lifetime needed — a
+        # concurrent flush that ended on the other backend cannot overwrite
+        # it (it writes its own thread's slot).
         self._tls = threading.local()
 
     @property
@@ -508,63 +468,21 @@ class HybridSignatureVerifier(SignatureVerifier):
     @property
     def dispatch_padded(self) -> Optional[int]:
         """Padded lane count of the dispatch that ran in THIS thread (same
-        thread-local lifetime as ``backend_label``).  Recorded at dispatch
-        time because re-deriving the route afterwards can disagree: the
-        dispatch itself updates the EMA cost model, so near the routing
-        crossover ``padded_batch`` would attribute the waste to the wrong
-        route — exactly the drift regime this telemetry exists to debug."""
+        thread-local lifetime as ``backend_label``): the accelerator's
+        bucket, or n where the oracle answered."""
         return getattr(self._tls, "padded", None)
-
-    def _tpu_time(self, n: int) -> float:
-        return self.tpu_dispatch_s + n * self.tpu_per_sig_s
-
-    def _route_to_tpu(self, n: int) -> bool:
-        if self._fixed_threshold is not None:
-            return n >= self._fixed_threshold
-        if not (self.cpu_per_sig_s > 0.0 and self.tpu_dispatch_s > 0.0):
-            return n >= self.DEFAULT_THRESHOLD
-        cpu_t = n * self.cpu_per_sig_s
-        tpu_t = self._tpu_time(n)
-        if tpu_t <= cpu_t:
-            return True
-        return (
-            cpu_t > self.MAX_CPU_BUDGET_S
-            and tpu_t <= self.MAX_OFFLOAD_LATENCY_S
-        )
-
-    # threshold() sentinel: no batch size is currently routed to the
-    # accelerator (degraded backend).
-    NEVER = 1 << 32
-
-    def threshold(self) -> int:
-        """Smallest batch size currently routed to the accelerator
-        (introspection/logging; routing itself is per-batch).  Closed form
-        over the two linear cost models — routes agree with
-        ``_route_to_tpu`` by construction."""
-        import math
-
-        if self._pinned_backend is not None:
-            return self.NEVER  # CPU-only backend: nothing to offload to
-        if self._fixed_threshold is not None:
-            return self._fixed_threshold
-        if not (self.cpu_per_sig_s > 0.0 and self.tpu_dispatch_s > 0.0):
-            return self.DEFAULT_THRESHOLD
-        best = self.NEVER
-        # Rule 1: tpu genuinely faster from the speed crossover on.
-        denom = self.cpu_per_sig_s - self.tpu_per_sig_s
-        if denom > 0.0:
-            best = max(1, math.ceil(self.tpu_dispatch_s / denom))
-        # Rule 2: smallest over-budget batch, if the offload is sane there.
-        n_budget = int(self.MAX_CPU_BUDGET_S / self.cpu_per_sig_s) + 1
-        if self._tpu_time(n_budget) <= self.MAX_OFFLOAD_LATENCY_S:
-            best = min(best, n_budget)
-        return best
-
-    # -- circuit breaker --
 
     @property
     def breaker_open(self) -> bool:
         return self._breaker_backoff_s > 0.0
+
+    def _is_outage(self, exc: BaseException) -> bool:
+        """Transport/timeout failures are outages and trip the breaker; a
+        :class:`VerifierProtocolError` (though a ``ConnectionError``) is a
+        misconfiguration and propagates, like anything else."""
+        return isinstance(exc, self.BREAKER_EXCEPTIONS) and not isinstance(
+            exc, VerifierProtocolError
+        )
 
     def _admit_accelerator(self) -> Tuple[bool, bool]:
         """(blocked, is_probe).  Blocked while the breaker holds the route
@@ -575,7 +493,7 @@ class HybridSignatureVerifier(SignatureVerifier):
         that flag: only the owner may release it on a non-verdict exit
         (abandon, propagating non-breaker exception) — an unconditional
         clear could release a DIFFERENT in-flight probe's exclusivity."""
-        with self._ema_lock:
+        with self._breaker_lock:
             if self._breaker_backoff_s == 0.0:
                 return False, False
             now = self._breaker_clock()
@@ -592,7 +510,7 @@ class HybridSignatureVerifier(SignatureVerifier):
         straggler failing at fetch while a probe hangs must not readmit
         victims behind the hung probe's back."""
         now = self._breaker_clock()
-        with self._ema_lock:
+        with self._breaker_lock:
             self._breaker_gen += 1
             if owns_probe:
                 self._breaker_probing = False
@@ -611,14 +529,13 @@ class HybridSignatureVerifier(SignatureVerifier):
             "the CPU oracle; next probe in ~%.1f s", exc, backoff,
         )
 
-    def _close_breaker(self, expected_gen: Optional[int] = None) -> bool:
-        """Close the circuit.  With ``expected_gen``, close only while the
-        breaker generation still matches — compared under the lock, so a
-        success surfacing at fetch can never erase a trip that raced it
-        between the caller's generation read and the close."""
-        with self._ema_lock:
-            if (expected_gen is not None
-                    and expected_gen != self._breaker_gen):
+    def _close_breaker(self, expected_gen: int) -> bool:
+        """Close the circuit, but only while the breaker generation still
+        matches — compared under the lock, so a success surfacing at fetch
+        can never erase a trip that raced it between the caller's generation
+        read and the close."""
+        with self._breaker_lock:
+            if expected_gen != self._breaker_gen:
                 return False
             was_open = self._breaker_backoff_s > 0.0
             self._breaker_backoff_s = 0.0
@@ -631,397 +548,113 @@ class HybridSignatureVerifier(SignatureVerifier):
         """Release probe exclusivity when the dispatch neither succeeded nor
         counted as an outage (a propagating non-breaker exception) — a stuck
         flag would otherwise hold the breaker open forever."""
-        with self._ema_lock:
+        with self._breaker_lock:
             self._breaker_probing = False
-
-    # -- backend pin (short-circuit routing) --
-
-    @property
-    def pinned_backend(self) -> Optional[str]:
-        """The CPU-only backend routing is currently pinned against, or
-        None when offload is open (introspection/tests)."""
-        return self._pinned_backend
-
-    def _sync_pin_with_advertisement(self) -> None:
-        """Cheap per-batch attr read: a mid-run reconnect (service restart)
-        can change the remote client's advertised backend between probes —
-        a CPU-only advertisement pins routing the moment any thread sees
-        it, not a probe interval later."""
-        adv = getattr(self.tpu, "advertised_backend", None)
-        if adv in self.CPU_ONLY_BACKENDS and self._pinned_backend is None:
-            self._pin_routing(adv)
-
-    def _pin_routing(self, backend: str) -> None:
-        now = self._breaker_clock()
-        with self._ema_lock:
-            if self._pinned_backend is not None:
-                return
-            self._pinned_backend = backend
-            self._pin_backoff_s = self.BREAKER_BASE_BACKOFF_S
-            self._pin_next_probe_t = now + jittered_backoff(
-                self._pin_backoff_s, self._breaker_rng
-            )
-        log.info(
-            "verifier backend %r has no accelerator: routing pinned to the "
-            "in-process oracle (re-HELLO upgrade probe in ~%.1f s)",
-            backend, self.BREAKER_BASE_BACKOFF_S,
-        )
-
-    def _admit_pin_probe(self) -> bool:
-        """At most one re-HELLO upgrade probe at a time, past the backoff
-        deadline — the ``_breaker_probing`` flag is shared with
-        ``_admit_accelerator`` so a hung HELLO admits no further probes and
-        never races a breaker probe for the same exclusivity."""
-        with self._ema_lock:
-            if self._pinned_backend is None:
-                return False
-            now = self._breaker_clock()
-            if self._breaker_probing or now < self._pin_next_probe_t:
-                return False
-            self._breaker_probing = True
-            return True
-
-    def _finish_pin_probe(self, backend: Optional[str], calibration,
-                          probed: bool = False) -> None:
-        """Probe outcome.  With ``probed`` (the re-HELLO round-trip actually
-        completed): any answer that is not a CPU-only advertisement unpins —
-        including NO advertisement (a pre-r6 service replaced the one that
-        pinned us; its platform is unknown, and unknown must never stay
-        pinned — the same conservative default that refuses to pin in the
-        first place), and a fresh calibration reseeds the cost model.
-        Without ``probed`` (unreachable service, no rehello support, or an
-        abandoned probe) the pin stands and the backoff doubles, decaying
-        the steady-state probe cost to one HELLO per
-        ``BREAKER_MAX_BACKOFF_S``."""
-        now = self._breaker_clock()
-        upgraded = probed and backend not in self.CPU_ONLY_BACKENDS
-        with self._ema_lock:
-            self._breaker_probing = False
-            if upgraded:
-                self._pinned_backend = None
-                self._pin_backoff_s = 0.0
-                if calibration is not None:
-                    self.tpu_dispatch_s, self.tpu_per_sig_s = calibration
-            else:
-                self._pin_backoff_s = min(
-                    self._pin_backoff_s * 2.0, self.BREAKER_MAX_BACKOFF_S
-                )
-                self._pin_next_probe_t = now + jittered_backoff(
-                    self._pin_backoff_s, self._breaker_rng
-                )
-        if upgraded:
-            log.info(
-                "verifier service re-advertised backend %r: offload "
-                "re-opened", backend,
-            )
-
-    def _reprobe_pin_and_verify(self, public_keys, digests, signatures, n):
-        """Fetch-stage body of the probe-carrying batch: ONE re-HELLO round
-        trip (never a verify frame), then the batch verifies on the oracle
-        exactly as its window-mates did.  A service outage here is not an
-        outage of the route in use — the pin already avoids the socket — so
-        it only pushes the next probe out, never trips the breaker."""
-        backend = calibration = None
-        probed = False
-        try:
-            rehello = getattr(self.tpu, "rehello", None)
-            if rehello is not None:
-                backend, calibration = rehello()
-                probed = True
-        except VerifierProtocolError as exc:
-            log.warning(
-                "pin re-probe HELLO rejected (%r): staying on the oracle",
-                exc,
-            )
-        except self.BREAKER_EXCEPTIONS as exc:
-            log.debug(
-                "pin re-probe HELLO failed (%r): staying on the oracle", exc
-            )
-        finally:
-            self._finish_pin_probe(backend, calibration, probed=probed)
-        return self._verify_cpu(public_keys, digests, signatures, n)
 
     def warmup(self) -> None:
-        from . import crypto
-
-        signer = crypto.Signer.dummy()
-        digest = crypto.blake2b_256(b"hybrid-warmup")
-        sig = signer.sign(digest)
-        pk = signer.public_key.bytes
-        # Accelerator cost model: prefer the BACKEND's own calibration (the
-        # verifier service measures its warmed dispatch once and shares it
-        # with every client over HELLO_OK) — N co-located validators each
-        # probing a shared service would serialize N dispatches behind boot
-        # contention.  A local backend without one gets the probe dispatch.
-        # An unreachable backend (service not yet up, link down) must not
-        # kill the warmup thread: trip the breaker and boot on the oracle.
-        provided = None
+        """Warm the accelerator backend (trace/compile, or HELLO and wait).
+        An unreachable backend (service not yet up, link down) must not kill
+        the warmup thread: trip the breaker and boot on the oracle."""
         try:
-            self.tpu.warmup()  # trace/compile (or persistent-cache load)
-            calibrate = getattr(self.tpu, "dispatch_calibration", None)
-            provided = calibrate() if calibrate is not None else None
-            if provided is None:
-                started = time.monotonic()
-                self.tpu.verify_signatures([pk], [digest], [sig])
-                # Real-backend boot calibration only: sims construct oracle
-                # verifiers (chaos.py), so these EMAs keep their
-                # deterministic __init__ defaults in virtual time.
-                provided = (time.monotonic() - started, 0.0)  # lint: ignore[sim-taint]
+            self.tpu.warmup()
         except self.BREAKER_EXCEPTIONS as exc:
-            if isinstance(exc, VerifierProtocolError):
-                raise  # misconfiguration, not an outage: fail fast
+            if not self._is_outage(exc):
+                raise
             self._trip_breaker(exc)
-        # The warmup HELLO told us what actually answers behind the socket:
-        # a CPU-only backend pins routing before the first real batch, so
-        # even boot traffic never pays the socket round-trip for nothing.
-        self._sync_pin_with_advertisement()
-        started = time.monotonic()
-        reps = 32
-        self.cpu.verify_signatures([pk] * reps, [digest] * reps, [sig] * reps)
-        # Same boot-calibration exemption as the TPU probe above.
-        cpu_probe = (time.monotonic() - started) / reps  # lint: ignore[sim-taint]
-        # Warmup runs on a background thread while live dispatches may
-        # already be updating the EMAs from executor threads — the
-        # calibration writes must join the same lock or a concurrent RMW
-        # that read the pre-warmup value could land after and discard them.
-        with self._ema_lock:
-            if provided is not None:
-                self.tpu_dispatch_s, self.tpu_per_sig_s = provided
-            self.cpu_per_sig_s = cpu_probe
-        log.info(
-            "hybrid verifier calibrated: tpu %.1f ms fixed + %.1f µs/sig, "
-            "cpu %.0f µs/sig -> tpu from batch %d",
-            1e3 * self.tpu_dispatch_s,
-            1e6 * self.tpu_per_sig_s,
-            1e6 * self.cpu_per_sig_s,
-            self.threshold(),
-        )
-
-    def _note_route(self, route: str, estimated_s: float, actual_s: float) -> None:
-        """Router decision telemetry: which way the batch went, and how far
-        the cost model's estimate was from the measured dispatch (a drifting
-        estimate is exactly the misroute precursor round 5 debugged blind)."""
-        if self.metrics is None:
-            return
-        self.metrics.verify_route_total.labels(route).inc()
-        if estimated_s > 0.0:
-            self.metrics.verify_route_estimate_error_s.observe(
-                abs(actual_s - estimated_s)
-            )
 
     def verify_signatures_async(self, public_keys, digests, signatures):
-        """Staged routing: a TPU-routed batch submits through the backend's
-        own async queue (JAX dispatch, the service socket) and returns an
-        in-flight handle; a breaker failure AT FETCH degrades that one batch
-        to the oracle inside ``result()`` — zero lost futures.  CPU-routed
-        (and breaker-blocked) batches defer the oracle to the fetch stage
-        unchanged."""
-        n = len(signatures)
-        if n == 0:
+        """Submit through the backend's own async queue (JAX dispatch, the
+        service socket) and return an in-flight handle; a breaker failure AT
+        FETCH degrades that one batch to the oracle inside ``result()``.  A
+        batch the open breaker blocks, or whose submit fails, defers the
+        oracle to the fetch stage."""
+        if not len(signatures):
             return CompletedDispatch([])
-        self._sync_pin_with_advertisement()
-        if self._pinned_backend is not None:
-            # Short-circuit: the service advertised a CPU-only backend, so
-            # the batch completes wholly in-process — zero socket frames,
-            # zero collector serialization toward the wire.  At most one
-            # batch per backoff interval carries the re-HELLO upgrade probe
-            # into its fetch stage (a HELLO frame, never a verify).
-            if self.metrics is not None:
-                self.metrics.verify_shortcircuit_total.labels(
-                    "backend-cpu"
-                ).inc()
-            if self._admit_pin_probe():
-                return _PinProbeDispatch(
-                    self, public_keys, digests, signatures, n
+        blocked, is_probe = self._admit_accelerator()
+        if not blocked:
+            # Captured BEFORE the submit: a trip racing the submission means
+            # this dispatch's eventual success is ambiguous evidence and
+            # must not close the circuit.
+            gen = self._breaker_gen
+            try:
+                handle = self.tpu.verify_signatures_async(
+                    public_keys, digests, signatures
                 )
-            return DeferredDispatch(
-                self._verify_cpu, public_keys, digests, signatures, n
-            )
-        degraded = False
-        breaker_blocked = False
-        if self._route_to_tpu(n):
-            blocked, is_probe = self._admit_accelerator()
-            if blocked:
-                # Circuit open: the route is held closed and the batch
-                # never touches the socket (unlike a mid-dispatch failure
-                # below, which may have sent frames before raising).
-                degraded = True
-                breaker_blocked = True
-            else:
-                # Captured BEFORE the submit: a trip racing the submission
-                # means this dispatch's eventual success is ambiguous
-                # evidence and must not close the circuit.
-                gen = self._breaker_gen
-                try:
-                    handle = self.tpu.verify_signatures_async(
-                        public_keys, digests, signatures
-                    )
-                except self.BREAKER_EXCEPTIONS as exc:
-                    if isinstance(exc, VerifierProtocolError):
-                        if is_probe:
-                            self._clear_probe()
-                        raise
-                    self._trip_breaker(exc, owns_probe=is_probe)
-                    degraded = True
-                except BaseException:
+            except BaseException as exc:
+                if not self._is_outage(exc):
                     if is_probe:
                         self._clear_probe()
                     raise
-                else:
-                    return _HybridTpuDispatch(
-                        self, handle, public_keys, digests, signatures, n,
-                        is_probe, gen,
-                    )
-        if self.metrics is not None:
-            if degraded:
-                self.metrics.verifier_fallback_total.inc()
-                if breaker_blocked:
-                    self.metrics.verify_shortcircuit_total.labels(
-                        "breaker"
-                    ).inc()
+                self._trip_breaker(exc, owns_probe=is_probe)
             else:
-                # The cost-model router decided against offloading: the
-                # batch must never touch the socket — and doesn't (the
-                # oracle runs in-process at the fetch stage).
-                self.metrics.verify_shortcircuit_total.labels("router").inc()
+                return _FallbackDispatch(
+                    self, handle, public_keys, digests, signatures,
+                    is_probe, gen,
+                )
+        if self.metrics is not None:
+            self.metrics.verifier_fallback_total.inc()
         return DeferredDispatch(
-            self._verify_cpu, public_keys, digests, signatures, n
+            self._verify_oracle, public_keys, digests, signatures
         )
 
     def verify_signatures(self, public_keys, digests, signatures):
-        """One routing/breaker implementation for both call shapes: the
-        sync path is the async path fetched immediately (submit-time breaker
-        handling in ``verify_signatures_async``, fetch-time in
-        ``_HybridTpuDispatch.result`` — keeping a second copy in lockstep is
+        """One breaker implementation for both call shapes: the sync path is
+        the async path fetched immediately (submit-time handling in
+        ``verify_signatures_async``, fetch-time in
+        ``_FallbackDispatch.result`` — keeping a second copy in lockstep is
         how probe-ownership bugs breed)."""
         return self.verify_signatures_async(
             public_keys, digests, signatures
         ).result()
 
-    def _verify_cpu(self, public_keys, digests, signatures, n):
-        estimated = n * self.cpu_per_sig_s
-        started = time.monotonic()
+    def _verify_oracle(self, public_keys, digests, signatures):
         out = self.cpu.verify_signatures(public_keys, digests, signatures)
-        elapsed = time.monotonic() - started
-        sample = elapsed / n
-        with self._ema_lock:
-            self.cpu_per_sig_s = _update_ema(
-                self.cpu_per_sig_s, sample, self.EMA_OUTLIER_S
-            )
-        self._note_route("cpu", estimated, elapsed)
         self._tls.label = "hybrid-cpu"
-        self._tls.padded = n  # host oracle: no padding lanes
+        self._tls.padded = len(signatures)  # host oracle: no padding lanes
         return out
 
-    def _absorb_tpu_sample(self, sample: float, n: int) -> None:
-        """Fold one measured TPU dispatch into the two-parameter cost model.
 
-        The residual against the CURRENT model is split 50/50 between the
-        fixed and marginal components (ADVICE r5): attributing the FULL
-        residual to both in the same update — each computed against the
-        other's pre-update value — let one slow dispatch inflate the summed
-        model by ~double the residual and wrongly veto the rule-2 saturation
-        offload until the EMAs decayed.  With the split, the summed model
-        moves by exactly the residual; observations at varied batch sizes
-        still disambiguate fixed from marginal over time, and the fixed
-        component can still rise (a link settling slower than its warmup
-        probe is not misattributed wholesale to per-signature cost).
-        """
-        if sample >= self.EMA_OUTLIER_S:
-            return
-        with self._ema_lock:
-            residual = sample - (self.tpu_dispatch_s + n * self.tpu_per_sig_s)
-            implied_fixed = max(0.0, self.tpu_dispatch_s + 0.5 * residual)
-            implied_marginal = max(
-                0.0, self.tpu_per_sig_s + 0.5 * residual / n
-            )
-            self.tpu_dispatch_s = _update_ema(
-                self.tpu_dispatch_s, implied_fixed, self.EMA_OUTLIER_S
-            )
-            self.tpu_per_sig_s = _update_ema(
-                self.tpu_per_sig_s, implied_marginal, self.EMA_OUTLIER_S
-            )
-
-class _PinProbeDispatch:
-    """The pinned route's probe-carrying batch: ``result()`` runs the
-    re-HELLO + oracle verify on the fetch stage's executor thread.  The
-    handle OWNS the shared probe-exclusivity flag from admission, so a
-    flush cancelled between submit and fetch must release it via
-    ``abandon()`` — a bare DeferredDispatch here would strand the flag
-    forever (no further pin probes, and the breaker's own probes blocked),
-    the exact leak PR 4's abandon protocol exists to prevent."""
-
-    __slots__ = ("_hybrid", "_args")
-
-    def __init__(self, hybrid, public_keys, digests, signatures, n) -> None:
-        self._hybrid = hybrid
-        self._args = (public_keys, digests, signatures, n)
-
-    def result(self) -> List[bool]:
-        return self._hybrid._reprobe_pin_and_verify(*self._args)
-
-    def abandon(self) -> None:
-        """Released without fetching: not a completed probe (``probed``
-        stays False), so the pin stands and only the backoff advances."""
-        self._hybrid._finish_pin_probe(None, None)
-
-
-class _HybridTpuDispatch:
-    """An in-flight TPU-routed batch of the hybrid verifier.
+class _FallbackDispatch:
+    """An in-flight accelerator batch of :class:`FallbackSignatureVerifier`.
 
     ``result()`` runs on the fetch stage's executor thread, so the breaker
-    bookkeeping, cost-model update, and the thread-local backend label all
-    land exactly where the sync path put them — the collector reads
-    ``backend_label``/``dispatch_padded`` right after ``result()`` in the
-    same thread.  A transport/timeout failure surfacing at fetch trips the
-    breaker and verifies THIS batch on the oracle: a backend dying
-    mid-pipeline loses zero futures."""
+    bookkeeping and the thread-local backend label land where the collector
+    reads them right after ``result()`` in the same thread.  A
+    transport/timeout failure surfacing at fetch trips the breaker and
+    verifies THIS batch on the oracle: a backend dying mid-pipeline loses
+    zero futures."""
 
-    __slots__ = ("_hybrid", "_handle", "_args", "_n", "_estimated",
-                 "_padded", "_started", "_is_probe", "_gen")
+    __slots__ = ("_owner", "_handle", "_args", "_padded", "_is_probe", "_gen")
 
-    def __init__(self, hybrid, handle, public_keys, digests, signatures,
-                 n, is_probe: bool = False, gen: int = 0) -> None:
-        self._hybrid = hybrid
+    def __init__(self, owner, handle, public_keys, digests, signatures,
+                 is_probe: bool, gen: int) -> None:
+        self._owner = owner
         self._handle = handle
         self._args = (public_keys, digests, signatures)
-        self._n = n
-        self._estimated = hybrid._tpu_time(n)
-        self._padded = hybrid.tpu.padded_batch(n)
-        self._started = time.monotonic()
+        self._padded = owner.tpu.padded_batch(len(signatures))
         self._is_probe = is_probe
         self._gen = gen
 
     def result(self) -> List[bool]:
-        h = self._hybrid
+        owner = self._owner
         try:
             out = self._handle.result()
-        except h.BREAKER_EXCEPTIONS as exc:
-            if isinstance(exc, VerifierProtocolError):
+        except BaseException as exc:
+            if not owner._is_outage(exc):
                 if self._is_probe:
-                    h._clear_probe()
+                    owner._clear_probe()
                 raise
-            h._trip_breaker(exc, owns_probe=self._is_probe)
-            if h.metrics is not None:
-                h.metrics.verifier_fallback_total.inc()
-            return h._verify_cpu(*self._args, self._n)
-        except BaseException:
-            if self._is_probe:
-                h._clear_probe()
-            raise
-        # Submit-to-fetch wall time: under pipelining this is the batch's
-        # actual turnaround (what the router's model predicts), queueing
-        # included; the EMA's outlier gate still drops compile stalls.
-        sample = time.monotonic() - self._started
-        if not h._close_breaker(expected_gen=self._gen) and self._is_probe:
+            owner._trip_breaker(exc, owns_probe=self._is_probe)
+            if owner.metrics is not None:
+                owner.metrics.verifier_fallback_total.inc()
+            return owner._verify_oracle(*self._args)
+        if not owner._close_breaker(self._gen) and self._is_probe:
             # A newer trip owns the circuit: this probe's success is stale
             # evidence — its only remaining obligation is releasing the
             # exclusive probe slot it still holds.
-            h._clear_probe()
-        h._note_route("tpu", self._estimated, sample)
-        h._absorb_tpu_sample(sample, self._n)
-        h._tls.label = "hybrid-tpu"
-        h._tls.padded = self._padded
+            owner._clear_probe()
+        owner._tls.label = "hybrid-tpu"
+        owner._tls.padded = self._padded
         return list(out)
 
     def abandon(self) -> None:
@@ -1032,7 +665,7 @@ class _HybridTpuDispatch:
         A non-probe dispatch touches nothing (clearing unconditionally
         could release a concurrent probe's exclusivity)."""
         if self._is_probe:
-            self._hybrid._clear_probe()
+            self._owner._clear_probe()
         inner = getattr(self._handle, "abandon", None)
         if inner is not None:
             inner()
@@ -1247,8 +880,8 @@ def _abandon_dispatch(fut) -> None:
 
     Handles that hold releasable state expose ``abandon()``; plain handles
     (completed/deferred/JAX device arrays) need nothing.  A submit that
-    RAISED already cleaned up after itself (the hybrid clears its probe, the
-    remote client discards its connection)."""
+    RAISED already cleaned up after itself (the breaker clears its probe,
+    the remote client discards its connection)."""
     if fut.cancelled() or fut.exception() is not None:
         return
     abandon = getattr(fut.result(), "abandon", None)
@@ -1267,9 +900,9 @@ class BatchedSignatureVerifier(BlockVerifier):
     Policy: a block's verification completes when either (a) ``max_batch``
     items have accumulated, or (b) the collection window elapsed since the
     first pending item — whichever comes first (SURVEY §7 hard part #2).
-    The window is ``max_delay_s`` on a co-located device and widens to 20%
-    of the observed dispatch latency (capped at ``MAX_ADAPTIVE_DELAY_S``)
-    when the accelerator is remote — see ``_effective_delay_s``.
+    The window is ``max_delay_s`` until a dispatch has been measured, then
+    20% of the observed dispatch latency, clamped, and shorter still when
+    arrivals are sparse — see ``_effective_delay_s``.
 
     Usable from any number of asyncio tasks (one per peer connection); the
     device dispatch runs in a worker thread so the event loop never blocks on
@@ -1294,7 +927,7 @@ class BatchedSignatureVerifier(BlockVerifier):
         # Staged dispatch window: several flushes may be in flight at once
         # (pack N+1 while N computes and N-1's results ride back), bounded
         # so a flooding peer cannot queue unbounded device work.  Depth
-        # adapts to the router's measured fixed dispatch cost unless pinned.
+        # adapts to the measured dispatch latency unless pinned.
         self.pipeline = VerifyPipeline(
             depth=pipeline_depth,
             metrics=metrics,
@@ -1327,14 +960,12 @@ class BatchedSignatureVerifier(BlockVerifier):
         self._pending: List[Tuple[StatementBlock, asyncio.Future]] = []
         self._lock = threading.Lock()
         self._flush_task: Optional[asyncio.TimerHandle] = None
-        # EMA of observed dispatch latency: when the accelerator is far away
-        # (remote chip, ~100 ms+ per dispatch), a 5 ms collection
-        # window dispatches tiny batches back-to-back and the queue of
-        # round-trips becomes the latency — waiting a fraction of the
-        # measured latency instead coalesces them at a bounded cost on a
-        # latency already dominated by the round-trip.  The window is clamped
-        # to MAX_ADAPTIVE_DELAY_S (a compile stall or compute-heavy batch
-        # must never push consensus turnaround past ~0.1 s), and dispatches
+        # EMA of observed dispatch latency (submit to verdicts): the window
+        # waits a fifth of it, so batches that would queue behind each
+        # other's round trips coalesce at a bounded cost on a latency the
+        # round trip already dominates.  The window is clamped to
+        # MAX_ADAPTIVE_DELAY_S (a compile stall or compute-heavy batch must
+        # never push consensus turnaround past ~0.1 s), and dispatches
         # slower than EMA_OUTLIER_S (one-time JAX compiles) are not fed into
         # the EMA at all.
         self._dispatch_ema_s = 0.0
@@ -1406,34 +1037,19 @@ class BatchedSignatureVerifier(BlockVerifier):
             sigs.append(signed.signature)
 
     def _pipeline_fixed_cost(self) -> float:
-        """Fixed dispatch cost estimate for the adaptive pipeline depth: the
-        hybrid router's measured fixed component when available, else the
-        collector's own dispatch-latency EMA (reads are unlocked snapshots —
-        depth adaptation tolerates a stale value)."""
-        fixed = getattr(self.verifier, "tpu_dispatch_s", 0.0)
-        return fixed if fixed > 0.0 else self._dispatch_ema_s
+        """Dispatch cost estimate for the adaptive pipeline depth: the
+        collector's own dispatch-latency EMA (an unlocked snapshot — depth
+        adaptation tolerates a stale value)."""
+        return self._dispatch_ema_s
 
     def _effective_delay_s(self) -> float:
-        """Collection window, adaptive in BOTH directions around the
-        ``max_delay_s`` default:
-
-        * expensive dispatches (remote accelerator, ~100 ms round-trips)
-          widen it to 20% of the dispatch-latency EMA (capped) — coalescing
-          is nearly free on a latency already dominated by the round-trip;
-        * cheap dispatches (the hybrid's CPU route at light load, µs-ms)
-          SHRINK it toward the dispatch cost — the window exists to amortize
-          an expensive dispatch, and holding blocks 5 ms to amortize a
-          0.5 ms verify is pure added latency (round-4 weak #5: hybrid
-          light-load latency trailed cpu by exactly this window).
-
-        Saturation is unaffected either way: ``max_batch`` arrivals flush
-        immediately without waiting for any timer.
-
-        One continuous curve covers both: 20% of the EMA, clamped to
-        [MIN, MAX]; ``max_delay_s`` is the pre-calibration default (no
-        dispatch measured yet).  Remote chip (~100 ms dispatch) -> 20 ms
-        window; saturated CPU batch (~30 ms) -> 6 ms; light-load CPU route
-        (~0.5 ms) -> the 0.5 ms floor.
+        """Collection window: 20% of the dispatch-latency EMA, clamped to
+        [MIN, MAX]; ``max_delay_s`` is the default until a dispatch has been
+        measured.  The window exists to amortize a dispatch, so it follows
+        what one costs: a 15 ms round trip to the verifier service gives
+        3 ms, a 30 ms oracle batch 6 ms, a sub-millisecond verify the 0.5 ms
+        floor — holding blocks 5 ms to amortize a 0.5 ms verify is pure
+        added latency.
 
         On top of that dispatch-cost CEILING, the window is arrival-rate-
         adaptive: waiting is only worth it when more blocks are coming.
@@ -1548,9 +1164,9 @@ class BatchedSignatureVerifier(BlockVerifier):
     def _fetch_dispatch(self, handle, n):
         """Fetch stage (executor thread): block until the verdicts are
         ready.  The backend label AND the padded lane count must be read in
-        THIS thread, right after ``result()`` — the hybrid verifier records
-        them thread-locally at fetch, so reading after the await would race
-        with concurrent flushes that routed the other way."""
+        THIS thread, right after ``result()`` — FallbackSignatureVerifier
+        records them thread-locally at fetch, so reading after the await
+        would race with concurrent flushes the other backend answered."""
         timer = (
             self.metrics.utilization_timer("verify:dispatch")
             if self.metrics is not None
@@ -1906,12 +1522,11 @@ class BatchedSignatureVerifier(BlockVerifier):
 
     def health_state(self) -> dict:
         """Verifier-path state for the fleet health plane (health.py):
-        breaker, routing pin, and staged-pipeline occupancy in one cheap
-        read (unlocked snapshots — the probe tolerates a torn read)."""
+        breaker and staged-pipeline occupancy in one cheap read (unlocked
+        snapshots — the probe tolerates a torn read)."""
         backend = self.verifier
         return {
             "breaker_open": bool(getattr(backend, "breaker_open", False)),
-            "pinned_backend": getattr(backend, "pinned_backend", None),
             "backend": getattr(
                 backend, "backend_label", type(backend).__name__
             ),

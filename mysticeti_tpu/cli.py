@@ -10,10 +10,10 @@ Capability parity with ``mysticeti/src/main.rs``:
 * ``testbed`` (:68-73,187-227) — N in-process validators on localhost.
 
 Plus this framework's switch: ``--verifier {accept,cpu,tpu,tpu-only}``
-selects the signature backend: ``tpu`` is the hybrid policy (batched JAX
-kernel for large batches, CPU oracle for small ones — SURVEY §7 hard part
-#2), ``tpu-only`` pins every batch to the kernel (saturation benchmarks),
-``cpu`` is the serial OpenSSL oracle (reference behavior).
+selects the signature backend: ``tpu`` sends every batch to the batched JAX
+kernel behind a circuit breaker (a dead verifier service degrades the node
+to the CPU oracle), ``tpu-only`` is the same kernel with failures surfacing
+(measurements), ``cpu`` is the serial OpenSSL oracle (reference behavior).
 """
 from __future__ import annotations
 

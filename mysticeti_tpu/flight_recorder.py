@@ -8,7 +8,7 @@ module is the bounded black box that does:
 
 * :class:`FlightRecorder` — a fixed-capacity in-memory ring of structured
   events, one per node, recorded from the consensus hot paths at edge
-  granularity (block lifecycle edges, breaker/pin transitions, SLO alerts,
+  granularity (block lifecycle edges, breaker transitions, SLO alerts,
   GC/checkpoint actions, sync decisions, connection churn, and the host
   attribution plane's ``blocking-call`` detections — hostattr.py flags a
   synchronous hold of the core owner past the threshold — never per

@@ -7,7 +7,7 @@ turns both into a diagnosis:
 * :class:`HealthProbe` — per-node consensus health derived from state the
   node already has: round-advance rate and commit-rate EMAs, DAG frontier
   skew (own round vs max peer round), per-authority frontier lag, verifier
-  state (circuit breaker, routing pin, pipeline in-flight), WAL append
+  state (circuit breaker, pipeline in-flight), WAL append
   backlog.  Exported as ``mysticeti_health_*`` gauges and as a
   readiness/diagnosis JSON document served next to ``/healthz``.
 * :class:`SLOThresholds` + the probe's watchdog — declarative thresholds
@@ -263,12 +263,11 @@ class HealthProbe:
         self.slo = slo or SLOThresholds()
         self.clock = clock
         # Flight recorder (flight_recorder.py): alert edges and verifier
-        # breaker/pin transitions land in the node's event ring; an alert
+        # breaker transitions land in the node's event ring; an alert
         # additionally triggers a debounced on-disk dump when the recorder
         # has a path.
         self.recorder = recorder
         self._last_breaker_open: Optional[bool] = None
-        self._last_pinned: Optional[bool] = None
         self.alerts: List[Alert] = []
         self.critical_path: Optional[CriticalPathAnalyzer] = None
         self._core = None
@@ -404,20 +403,13 @@ class HealthProbe:
             verifier_state = state_fn()
         breaker_open = bool(verifier_state and verifier_state["breaker_open"])
         if self.recorder is not None and verifier_state is not None:
-            pinned = bool(verifier_state.get("pinned_backend"))
             if self._last_breaker_open is not None and (
                 breaker_open != self._last_breaker_open
             ):
                 self.recorder.record(
                     "breaker", open=breaker_open
                 )
-            if self._last_pinned is not None and pinned != self._last_pinned:
-                self.recorder.record(
-                    "pin", pinned=pinned,
-                    backend=verifier_state.get("pinned_backend"),
-                )
             self._last_breaker_open = breaker_open
-            self._last_pinned = pinned
         self._breaker_samples.append(1 if breaker_open else 0)
         if len(self._breaker_samples) > self.BREAKER_WINDOW:
             self._breaker_samples.pop(0)
@@ -501,9 +493,6 @@ class HealthProbe:
         verifier = snapshot.get("verifier")
         m.mysticeti_health_verifier_breaker_open.set(
             1 if (verifier and verifier["breaker_open"]) else 0
-        )
-        m.mysticeti_health_verifier_pinned.set(
-            1 if (verifier and verifier.get("pinned_backend")) else 0
         )
         m.mysticeti_health_wal_backlog.set(1 if snapshot["wal_backlog"] else 0)
         m.mysticeti_health_status.set(1 if not self._firing else 0)

@@ -418,15 +418,6 @@ class Metrics:
             "padding lanes dispatched (padded bucket size minus actual "
             "signatures)", labels=("backend",),
         )
-        self.verify_route_total = counter(
-            "verify_route_total", "hybrid router decisions", labels=("route",)
-        )
-        self.verify_route_estimate_error_s = histogram(
-            "verify_route_estimate_error_s",
-            "|estimated - actual| dispatch time of routed batches",
-            buckets=[0.0001, 0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5,
-                     1.0, 5.0],
-        )
         self.verifier_service_queue_depth = gauge(
             "verifier_service_queue_depth",
             "verify requests queued or dispatching in the verifier service",
@@ -517,17 +508,8 @@ class Metrics:
             buckets=[0.0001, 0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.25,
                      0.5, 1.0, 5.0],
         )
-        # Zero-tax data plane (the no-chip flavor parity work): which
-        # batches never touched the socket, what the wire actually carried,
-        # and the window the adaptive collector chose.
-        self.verify_shortcircuit_total = counter(
-            "verify_shortcircuit_total",
-            "signature batches completed without touching the verifier "
-            "service socket (reason: backend-cpu = service advertised a "
-            "CPU-only backend, router = cost model chose the in-process "
-            "oracle, breaker = circuit open)",
-            labels=("reason",),
-        )
+        # What the verifier-service wire carried, and the window the
+        # adaptive collector chose.
         self.verify_wire_bytes_total = counter(
             "verify_wire_bytes_total",
             "bytes moved over the verifier-service socket by this client",
@@ -581,13 +563,8 @@ class Metrics:
         )
         self.mysticeti_health_verifier_breaker_open = gauge(
             "mysticeti_health_verifier_breaker_open",
-            "1 while the hybrid verifier circuit breaker is open (degraded "
-            "to the CPU oracle)",
-        )
-        self.mysticeti_health_verifier_pinned = gauge(
-            "mysticeti_health_verifier_pinned",
-            "1 while short-circuit routing is pinned to the in-process "
-            "oracle (service advertised a CPU-only backend)",
+            "1 while the verifier circuit breaker is open (degraded to the "
+            "CPU oracle)",
         )
         self.mysticeti_health_wal_backlog = gauge(
             "mysticeti_health_wal_backlog",

@@ -27,7 +27,7 @@ from .block_validator import (
     AcceptAllBlockVerifier,
     BatchedSignatureVerifier,
     CpuSignatureVerifier,
-    HybridSignatureVerifier,
+    FallbackSignatureVerifier,
     TpuSignatureVerifier,
 )
 from .commit_observer import SimpleCommitObserver, TestCommitObserver
@@ -88,7 +88,7 @@ def _make_verifier(kind: str, committee: Committee, metrics=None):
     # binds, at zero steady-state cost.
     window_ms = float(os.environ.get("MYSTICETI_VERIFY_WINDOW_MS", "5"))
     # Staged dispatch pipeline depth (verify_pipeline.py): default adapts to
-    # the router's measured fixed dispatch cost; pin it for experiments.
+    # the collector's measured dispatch latency; pin it for experiments.
     depth_env = os.environ.get("MYSTICETI_VERIFY_PIPELINE_DEPTH")
     collector_opts = dict(
         metrics=metrics,
@@ -122,14 +122,15 @@ def _make_verifier(kind: str, committee: Committee, metrics=None):
                 from .ops import ed25519 as _ed25519
 
                 _ed25519.install_device_attribution(metrics)
-        # "tpu" deploys the hybrid dispatch policy (small batches take the
-        # CPU oracle, sparing them the accelerator round-trip — SURVEY §7
-        # hard part #2); "tpu-only" pins every batch to the kernel, which is
-        # what a saturation benchmark wants to measure.
+        # Both flavors send every batch to the accelerator.  "tpu" puts a
+        # circuit breaker in front of it: a fleet that must stay live when
+        # its service dies degrades to the CPU oracle and loses no future.
+        # Under "tpu-only" failures surface: a measurement must not have a
+        # fault hidden from it.
         backend = (
             tpu_backend
             if kind == "tpu-only"
-            else HybridSignatureVerifier(tpu=tpu_backend, metrics=metrics)
+            else FallbackSignatureVerifier(tpu=tpu_backend, metrics=metrics)
         )
 
         verifier = BatchedSignatureVerifier(committee, backend, **collector_opts)

@@ -44,14 +44,11 @@ operator who asked for it by name): ``run_service`` refuses to start when JAX
 found no accelerator and fell back to the host on its own, and a backend
 whose warm-up or calibration fails takes the service down with it — a fleet
 launched on a chip never ends up verifying on the host under the chip's
-name.  The hybrid router pins routing to its in-process oracle when the
-advertised backend is CPU-only, so the socket hop disappears when there is
-nothing behind it to pay for.
-Version skew is safe in both directions: an old client sees a >16-byte
-HELLO_OK, fails its ``len == 16`` calibration check, and falls back to its
-own probe dispatch (it never parses the suffix); a new client against an old
-service sees exactly 16 bytes and simply leaves the backend unknown (no
-pinning — the conservative default).
+name.  A client of a service that resolved to "cpu" still sends every batch
+over the socket and gets correct verdicts; the launchers that measure
+(``chip_smoke.py``, ``benchmark/``) read the suffix and refuse such a fleet.
+A client against a pre-suffix service sees exactly 16 bytes and leaves the
+backend unknown.
 
 HELLO doubles as the warmup gate: the reply is sent only after the backend's
 one-time trace/compile finished, so a client's ``warmup()`` is "send HELLO,
@@ -59,12 +56,9 @@ wait" — seconds against a warm service, never minutes.  All clients must
 present the same committee (one table per service); a mismatch is an ERR.
 
 HELLO_OK carries the service's OWN dispatch calibration (a timed 1-signature
-and batch dispatch after warmup): the hybrid router needs (fixed, per-sig)
-cost estimates, and N validators each probing a shared-host service would
-serialize N probe dispatches behind fleet boot contention — measured on a
-1-core host, 5 of 7 validators were still waiting for their probe a minute
-in.  One server-side measurement, taken once on an idle backend, is both
-cheaper and more accurate.
+and batch dispatch after warmup).  No client reads it: it stays on the wire
+because the backend suffix rides behind its 16 bytes (ROADMAP queue 3: it
+goes with a wire-version bump that keeps the suffix).
 """
 from __future__ import annotations
 
@@ -113,7 +107,7 @@ ENV_SOCKET = "MYSTICETI_VERIFIER_SOCKET"
 
 # VerifierProtocolError (re-exported above from block_validator): the service
 # answered but REJECTED the request.  Excluded from the client's retry loop
-# AND from the hybrid circuit breaker — a misconfigured validator fails fast
+# AND from the circuit breaker — a misconfigured validator fails fast
 # instead of hammering the service or silently degrading to the oracle.
 
 
@@ -740,9 +734,9 @@ class VerifierServer:
 
     def _resolved_backend(self) -> str:
         """The platform the warmed backend ACTUALLY dispatches on —
-        advertised to every client via HELLO_OK so their hybrid routers can
-        short-circuit a service with no accelerator behind it.  Backends
-        without the introspection hook are host oracles: "cpu"."""
+        advertised to every client via HELLO_OK so a launcher can refuse a
+        fleet whose service has no accelerator behind it.  Backends without
+        the introspection hook are host oracles: "cpu"."""
         resolve = getattr(self._backend, "resolved_backend", None)
         return "cpu" if resolve is None else str(resolve())
 
@@ -751,9 +745,8 @@ class VerifierServer:
         backend and frame the reply — HELLO_OK with the calibration + resolved-backend
         advertisement, or ERR on a committee mismatch (which also severs the
         connection client-side).  The backend suffix rides only behind a
-        calibration: old clients check ``len == 16`` and fall back to their
-        own probe, and an UNcalibrated reply stays the old empty payload so
-        it is never mistaken for a 16-byte calibration."""
+        calibration, and an UNcalibrated reply stays the old empty payload
+        so it is never mistaken for a 16-byte calibration."""
         try:
             self._ensure_backend(keys)
         except ValueError as exc:
@@ -1114,8 +1107,8 @@ class RemoteSignatureVerifier(SignatureVerifier):
 
     # Reconnect-retry budget per request: a service restart mid-burst is
     # routine (seconds of downtime), a fleet boot race is routine — neither
-    # is an outage.  Only exhausting the budget propagates, and the hybrid
-    # circuit breaker takes it from there.
+    # is an outage.  Only exhausting the budget propagates, and the circuit
+    # breaker (``tpu`` flavor) takes it from there.
     MAX_ATTEMPTS = 4
     RETRY_BASE_BACKOFF_S = 0.05
     RETRY_MAX_BACKOFF_S = 1.0
@@ -1151,8 +1144,7 @@ class RemoteSignatureVerifier(SignatureVerifier):
         self.calibration: Optional[Tuple[float, float]] = None
         # The service's resolved platform from the HELLO_OK backend suffix
         # ("cpu" | "tpu" | ...); None against a pre-r6 service or before the
-        # first connect.  The hybrid router reads this to pin routing to its
-        # in-process oracle when there is no accelerator behind the socket.
+        # first connect.
         self.advertised_backend: Optional[str] = None
 
     # -- socket plumbing --
@@ -1175,39 +1167,13 @@ class RemoteSignatureVerifier(SignatureVerifier):
         if len(reply) >= 16:
             self.calibration = struct.unpack_from("<dd", reply)
         # No suffix (pre-r6 service, or uncalibrated) = backend UNKNOWN —
-        # overwrite, don't keep: a stale "cpu" from a replaced service
-        # would otherwise hold the hybrid pinned against hardware whose
-        # platform nobody actually advertised.
+        # overwrite, don't keep a replaced service's answer.
         self.advertised_backend = (
             bytes(reply[16:]).decode("ascii", errors="replace")
             if len(reply) > 16
             else None
         )
         return conn
-
-    def dispatch_calibration(self) -> Optional[Tuple[float, float]]:
-        """Server-measured (fixed_s, per_sig_s) — the hybrid router's cost
-        model, without every client paying its own probe dispatch."""
-        return self.calibration
-
-    def rehello(self) -> Tuple[Optional[str], Optional[Tuple[float, float]]]:
-        """Fresh HELLO round-trip on this thread's connection; returns the
-        service's CURRENT (advertised_backend, calibration).
-
-        This is the backend-pinned hybrid router's low-frequency upgrade
-        probe: one HELLO frame over the wire, never a batch — a service that
-        gained an accelerator (restarted on real hardware) re-opens offload
-        without a validator restart.  Transport failures propagate for the
-        caller's backoff."""
-        stale = getattr(self._tls, "conn", None)
-        self._tls.conn = None
-        if stale is not None:
-            try:
-                stale.close()
-            except OSError:
-                pass
-        self._conn()
-        return self.advertised_backend, self.calibration
 
     def _conn(self) -> socket.socket:
         conn = getattr(self._tls, "conn", None)
@@ -1269,7 +1235,7 @@ class RemoteSignatureVerifier(SignatureVerifier):
         service in lockstep; each torn-down connection counts on
         ``verifier_reconnect_total``.  Protocol rejections
         (:class:`VerifierProtocolError`) are never retried, and exhausting
-        the budget propagates — the hybrid circuit breaker takes it from
+        the budget propagates — the circuit breaker takes it from
         there."""
         backoff = self.RETRY_BASE_BACKOFF_S
         for attempt in range(self.max_attempts):

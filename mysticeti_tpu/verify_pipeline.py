@@ -15,9 +15,9 @@ This module is the engine for that shape:
   batching collector (``block_validator.BatchedSignatureVerifier``) may open
   a new flush window while prior dispatches are still in flight; the window
   bounds how many, so a flooding peer cannot queue unbounded device work.
-  Depth adapts to the measured fixed dispatch cost (the hybrid router's
-  ``tpu_dispatch_s``): a co-located chip has little latency to hide (depth
-  2), one behind a slow link wants more overlap (up to 4).
+  Depth adapts to the measured dispatch latency (the collector's
+  ``_dispatch_ema_s``): a fast backend has little latency to hide (depth
+  2), a slow one wants more overlap (up to 4).
 * :class:`DeferredDispatch` / :class:`CompletedDispatch` — future-like
   handles for backends without a native async queue, so every
   ``SignatureVerifier`` presents the same submit-now/fetch-later surface

@@ -139,78 +139,29 @@ def test_validators_with_cpu_signature_verification(tmp_path):
     asyncio.run(main())
 
 
-def test_hybrid_verifier_routes_by_batch_size():
-    """Small batches take the CPU oracle, large ones the TPU backend; the
-    threshold is the measured crossover, capped by the CPU time budget."""
-    from mysticeti_tpu.block_validator import (
-        HybridSignatureVerifier,
-        SignatureVerifier,
-    )
-
-    class Recorder(SignatureVerifier):
-        def __init__(self):
-            self.calls = []
-
-        def verify_signatures(self, pks, digests, sigs):
-            self.calls.append(len(sigs))
-            return [True] * len(sigs)
-
-    tpu, cpu = Recorder(), Recorder()
-    hybrid = HybridSignatureVerifier(tpu=tpu, cpu=cpu)
-    # Pretend calibration: 100 ms accelerator round-trip, 100 µs/sig CPU.
-    hybrid.tpu_dispatch_s = 0.100
-    hybrid.cpu_per_sig_s = 100e-6
-    # Pure-speed crossover would be 1000, but past the CPU budget (10 ms,
-    # i.e. >100 sigs) batches offload to free the host core — the
-    # accelerator's 100 ms turnaround is within MAX_OFFLOAD_LATENCY_S.
-    assert hybrid.threshold() == 101
-
-    args = lambda n: ([b"\0" * 32] * n, [b"\1" * 32] * n, [b"\2" * 64] * n)
-    hybrid.verify_signatures(*args(5))
-    assert cpu.calls == [5] and tpu.calls == []
-    assert hybrid.backend_label == "hybrid-cpu"
-    hybrid.verify_signatures(*args(256))
-    assert tpu.calls == [256]
-    assert hybrid.backend_label == "hybrid-tpu"
-    # EMAs update from routed dispatches (values sane, not outliers)
-    assert 0 < hybrid.tpu_dispatch_s < 0.2
-    assert hybrid.verify_signatures([], [], []) == []
-
-
-def test_hybrid_verifier_fixed_threshold_and_default():
-    from mysticeti_tpu.block_validator import HybridSignatureVerifier
-
-    h = HybridSignatureVerifier(threshold=7)
-    assert h.threshold() == 7
-    h2 = HybridSignatureVerifier()
-    assert h2.threshold() == h2.DEFAULT_THRESHOLD  # uncalibrated
-
-
-def test_hybrid_verifier_end_to_end_cpu_backends(committee_and_signers):
-    """Hybrid with two CPU oracles behind it is behaviorally identical to the
-    plain CPU path: good blocks pass, forged blocks fail, either route."""
+def test_fallback_verifier_end_to_end_cpu_backends(committee_and_signers):
+    """The breaker class with two CPU oracles behind it is behaviorally
+    identical to the plain CPU path: good blocks pass, forged blocks fail."""
     committee, signers = committee_and_signers
-    from mysticeti_tpu.block_validator import HybridSignatureVerifier
+    from mysticeti_tpu.block_validator import FallbackSignatureVerifier
 
     async def main():
-        for threshold in (0, 100):  # force tpu-route and cpu-route
-            hybrid = HybridSignatureVerifier(
-                tpu=CpuSignatureVerifier(),
-                cpu=CpuSignatureVerifier(),
-                threshold=threshold,
-            )
-            verifier = BatchedSignatureVerifier(
-                committee, hybrid, max_batch=10, max_delay_s=0.01
-            )
-            good = StatementBlock.build(0, 1, [], (), signer=signers[0])
-            forged = StatementBlock.build(1, 1, [], (), signer=signers[0])
-            results = await asyncio.gather(
-                verifier.verify(good),
-                verifier.verify(forged),
-                return_exceptions=True,
-            )
-            assert results[0] is None
-            assert isinstance(results[1], VerificationError)
+        fallback = FallbackSignatureVerifier(
+            tpu=CpuSignatureVerifier(),
+            cpu=CpuSignatureVerifier(),
+        )
+        verifier = BatchedSignatureVerifier(
+            committee, fallback, max_batch=10, max_delay_s=0.01
+        )
+        good = StatementBlock.build(0, 1, [], (), signer=signers[0])
+        forged = StatementBlock.build(1, 1, [], (), signer=signers[0])
+        results = await asyncio.gather(
+            verifier.verify(good),
+            verifier.verify(forged),
+            return_exceptions=True,
+        )
+        assert results[0] is None
+        assert isinstance(results[1], VerificationError)
 
     asyncio.run(main())
 
@@ -258,8 +209,8 @@ def test_adaptive_batching_window_tracks_dispatch_latency():
 
 def test_collection_window_adapts_both_directions():
     """Round-4 weak #5: the fixed 5 ms window added pure latency at light
-    load when dispatches are sub-ms (hybrid CPU route).  The window is now
-    20% of the dispatch EMA, clamped — wide for remote accelerators, sub-ms
+    load when dispatches are sub-ms (a host oracle).  The window is now
+    20% of the dispatch EMA, clamped — wide for slow dispatches, sub-ms
     for cheap local dispatch, max_delay_s only before calibration."""
     from mysticeti_tpu.block_validator import BatchedSignatureVerifier
     from mysticeti_tpu.committee import Committee
@@ -348,142 +299,49 @@ def test_collector_tracks_arrival_gaps_and_publishes_window(
     asyncio.run(main())
 
 
-def test_router_shortcircuit_counter(committee_and_signers):
-    """Batches the cost-model router keeps on the oracle never touch the
-    accelerator backend, and each one counts on
-    verify_shortcircuit_total{reason="router"}."""
-    from mysticeti_tpu.block_validator import (
-        HybridSignatureVerifier,
-        SignatureVerifier,
-    )
-    from mysticeti_tpu.crypto import blake2b_256
-    from mysticeti_tpu.metrics import Metrics
+class _NeverOracle(CpuSignatureVerifier):
+    def verify_signatures(self, *args):
+        raise AssertionError("a batch reached the oracle, breaker closed")
 
-    class NeverBackend(SignatureVerifier):
-        def verify_signatures(self, *args):
-            raise AssertionError("router-rejected batch reached the backend")
+
+def _signed_batch(signers, n, corrupt=()):
+    from mysticeti_tpu.crypto import blake2b_256
+
+    pks, digests, sigs = [], [], []
+    for i in range(n):
+        signer = signers[i % len(signers)]
+        digest = blake2b_256(b"fallback-batch-%d" % i)
+        sig = signer.sign(digest)
+        if i in corrupt:
+            sig = bytes([sig[0] ^ 1]) + sig[1:]
+        pks.append(signer.public_key.bytes)
+        digests.append(digest)
+        sigs.append(sig)
+    return pks, digests, sigs
+
+
+@pytest.mark.parametrize("n", [1, 8, 31, 32, 256])
+def test_tpu_flavor_sends_every_batch_size_to_the_accelerator(
+    committee_and_signers, n
+):
+    """With the breaker closed nothing but the accelerator backend verifies
+    a batch, whatever its size (at the parent a cost model kept batches
+    under 32 signatures on the oracle)."""
+    from mysticeti_tpu.block_validator import FallbackSignatureVerifier
+    from mysticeti_tpu.metrics import Metrics
 
     _, signers = committee_and_signers
     metrics = Metrics()
-    h = HybridSignatureVerifier(
-        tpu=NeverBackend(), cpu=CountingVerifier(), metrics=metrics
+    tpu = CountingVerifier()  # the "accelerator": answers like the oracle
+    fallback = FallbackSignatureVerifier(
+        tpu=tpu, cpu=_NeverOracle(), metrics=metrics
     )
-    digest = blake2b_256(b"router-test")
-    sig = signers[0].sign(digest)
-    pk = signers[0].public_key.bytes
-    # Below DEFAULT_THRESHOLD: the router keeps it in-process.
-    assert h.verify_signatures([pk] * 2, [digest] * 2, [sig] * 2) == [
-        True, True,
-    ]
-    count = metrics.verify_shortcircuit_total.labels("router")._value.get()
-    assert count == 1
-
-
-def test_pin_probe_abandon_releases_exclusivity(committee_and_signers):
-    """A flush cancelled between submit and fetch abandons its probe-
-    carrying handle: the shared probe-exclusivity flag is released (no
-    permanently blocked probes), the pin stands, and a completed probe
-    whose re-HELLO reports an UNKNOWN backend (pre-r6 service) unpins —
-    unknown must never stay pinned."""
-    from mysticeti_tpu.block_validator import (
-        HybridSignatureVerifier,
-        SignatureVerifier,
-        _PinProbeDispatch,
-    )
-    from mysticeti_tpu.crypto import blake2b_256
-
-    class StubRemote(SignatureVerifier):
-        advertised_backend = "cpu"
-        rehello_result = ("cpu", None)
-
-        def rehello(self):
-            return self.rehello_result
-
-        def verify_signatures(self, *args):
-            raise AssertionError("pinned batch reached the remote backend")
-
-    _, signers = committee_and_signers
-    digest = blake2b_256(b"pin-abandon")
-    pks = [signers[0].public_key.bytes] * 2
-    digests, sigs = [digest] * 2, [signers[0].sign(digest)] * 2
-    remote = StubRemote()
-    clock = {"t": 0.0}
-    h = HybridSignatureVerifier(tpu=remote, cpu=CountingVerifier())
-    h._breaker_clock = lambda: clock["t"]
-    h._sync_pin_with_advertisement()
-    assert h.pinned_backend == "cpu"
-    clock["t"] = 100.0  # past the probe deadline
-    handle = h.verify_signatures_async(pks, digests, sigs)
-    assert isinstance(handle, _PinProbeDispatch)
-    assert h._breaker_probing  # the handle owns the exclusive slot
-    handle.abandon()
-    assert not h._breaker_probing, "abandon leaked the probe flag"
-    assert h.pinned_backend == "cpu"  # an abandoned probe is no evidence
-    # The next window's probe still runs — and an unknown-backend answer
-    # (old server replaced the advertiser) unpins.
-    remote.rehello_result = (None, None)
-    clock["t"] = 10_000.0
-    handle = h.verify_signatures_async(pks, digests, sigs)
-    assert isinstance(handle, _PinProbeDispatch)
-    assert handle.result() == [True, True]
-    assert h.pinned_backend is None
-    assert not h._breaker_probing
-
-
-def test_hybrid_never_offloads_to_a_degraded_backend():
-    """Round-5 NODE_BENCH finding: a host whose JAX backend degraded to CPU
-    measures seconds per dispatch — the budget-relief offload must refuse it
-    (light-load latency collapsed ~25x when it didn't)."""
-    from mysticeti_tpu.block_validator import HybridSignatureVerifier
-
-    h = HybridSignatureVerifier()
-    h.cpu_per_sig_s = 125e-6
-    h.tpu_dispatch_s = 1.5  # degraded: pad-to-bucket on jax-CPU
-    # 256 sigs: 32 ms of CPU is over budget, but 1.5 s of "accelerator"
-    # would stall consensus -> stay on the oracle.
-    assert not h._route_to_tpu(256)
-    assert not h._route_to_tpu(4096)
-    # A real accelerator (remote, ~150 ms fixed) takes the same batch.
-    h.tpu_dispatch_s = 0.150
-    assert h._route_to_tpu(256)
-    # ...unless its LEARNED marginal cost makes the turnaround stall-grade.
-    h.tpu_per_sig_s = 0.005
-    assert not h._route_to_tpu(256)
-    # Light load always stays local either way.
-    h.tpu_per_sig_s = 0.0
-    assert not h._route_to_tpu(3)
-
-
-def test_hybrid_ema_splits_residual_between_fixed_and_marginal():
-    """ADVICE r5: one slow dispatch used to feed its FULL residual to both
-    cost parameters in the same update (each against the other's pre-update
-    value), inflating the summed model by ~double the residual.  With the
-    50/50 split the summed model moves by exactly one EMA step of the
-    residual — a transient can no longer wrongly veto the saturation
-    offload."""
-    from mysticeti_tpu.block_validator import (
-        HybridSignatureVerifier,
-        SignatureVerifier,
-    )
-
-    class Stub(SignatureVerifier):
-        def verify_signatures(self, pks, digests, sigs):
-            return [True] * len(sigs)
-
-    h = HybridSignatureVerifier(tpu=Stub(), cpu=Stub())
-    h.tpu_dispatch_s = 0.1
-    h.tpu_per_sig_s = 0.0005
-    n = 100
-    before = h._tpu_time(n)
-    residual = 0.2
-    h._absorb_tpu_sample(before + residual, n)
-    after = h._tpu_time(n)
-    assert after > before  # the model does track the slow sample...
-    # ...but by ONE EMA step (alpha=0.2) of the residual, not two.
-    assert after - before == pytest.approx(0.2 * residual, rel=1e-6)
-    # Symmetric on the way down, and outliers never enter.
-    h._absorb_tpu_sample(h._tpu_time(n) - 0.1, n)
-    assert h._tpu_time(n) < after
-    frozen = (h.tpu_dispatch_s, h.tpu_per_sig_s)
-    h._absorb_tpu_sample(h.EMA_OUTLIER_S + 1.0, n)
-    assert (h.tpu_dispatch_s, h.tpu_per_sig_s) == frozen
+    batch = _signed_batch(signers, n, corrupt={n - 1})
+    assert fallback.verify_signatures(*batch) == [True] * (n - 1) + [False]
+    assert tpu.calls == [n]
+    assert fallback.backend_label == "hybrid-tpu"
+    assert fallback.dispatch_padded == n
+    assert not fallback.breaker_open
+    assert metrics.verifier_fallback_total._value.get() == 0.0
+    assert fallback.verify_signatures([], [], []) == []
+    assert tpu.calls == [n]

@@ -16,7 +16,7 @@ import pytest
 
 from mysticeti_tpu.block_validator import (
     BatchedSignatureVerifier,
-    HybridSignatureVerifier,
+    FallbackSignatureVerifier,
     SignatureVerifier,
 )
 from mysticeti_tpu.chaos import (
@@ -228,8 +228,8 @@ def test_verifier_outage_degrades_to_cpu_with_zero_failed_blocks(tmp_path):
     def factory(authority, committee, metrics):
         tpu, cpu = ScriptedTpuBackend(), StubCpuBackend()
         backends[authority] = (tpu, cpu)
-        hybrid = HybridSignatureVerifier(
-            tpu=tpu, cpu=cpu, threshold=1, metrics=metrics
+        hybrid = FallbackSignatureVerifier(
+            tpu=tpu, cpu=cpu, metrics=metrics
         )
         return BatchedSignatureVerifier(committee, hybrid, metrics=metrics)
 
@@ -268,8 +268,7 @@ def test_breaker_opens_falls_back_and_reprobes():
     clock = {"t": 0.0}
     tpu, cpu = ScriptedTpuBackend(), StubCpuBackend()
     metrics = Metrics()
-    hybrid = HybridSignatureVerifier(tpu=tpu, cpu=cpu, threshold=1,
-                                     metrics=metrics)
+    hybrid = FallbackSignatureVerifier(tpu=tpu, cpu=cpu, metrics=metrics)
     hybrid._breaker_clock = lambda: clock["t"]
     batch = ([b"k" * 32], [b"d" * 32], [b"s" * 64])
 
@@ -297,7 +296,7 @@ def test_breaker_backoff_doubles_with_bounded_jitter():
     clock = {"t": 0.0}
     tpu, cpu = ScriptedTpuBackend(), StubCpuBackend()
     tpu.dead = True
-    hybrid = HybridSignatureVerifier(tpu=tpu, cpu=cpu, threshold=1)
+    hybrid = FallbackSignatureVerifier(tpu=tpu, cpu=cpu)
     hybrid._breaker_clock = lambda: clock["t"]
     batch = ([b"k" * 32], [b"d" * 32], [b"s" * 64])
 
@@ -326,8 +325,8 @@ def test_breaker_protocol_error_fails_fast():
             raise VerifierProtocolError("committee mismatch")
 
     metrics = Metrics()
-    hybrid = HybridSignatureVerifier(
-        tpu=RejectingTpu(), cpu=StubCpuBackend(), threshold=1, metrics=metrics
+    hybrid = FallbackSignatureVerifier(
+        tpu=RejectingTpu(), cpu=StubCpuBackend(), metrics=metrics
     )
     with pytest.raises(VerifierProtocolError):
         hybrid.verify_signatures([b"k" * 32], [b"d" * 32], [b"s" * 64])
@@ -342,8 +341,7 @@ def test_breaker_admits_exactly_one_probe():
     clock = {"t": 0.0}
     tpu = ScriptedTpuBackend()
     tpu.dead = True
-    hybrid = HybridSignatureVerifier(tpu=tpu, cpu=StubCpuBackend(),
-                                     threshold=1)
+    hybrid = FallbackSignatureVerifier(tpu=tpu, cpu=StubCpuBackend())
     hybrid._breaker_clock = lambda: clock["t"]
 
     def blocks() -> bool:
@@ -368,8 +366,7 @@ def test_stale_success_after_trip_does_not_close_breaker():
     re-close the breaker or reset the backoff escalation — the route would
     otherwise flap between dead-backend timeouts all outage long."""
     tpu = ScriptedTpuBackend()
-    hybrid = HybridSignatureVerifier(tpu=tpu, cpu=StubCpuBackend(),
-                                     threshold=1)
+    hybrid = FallbackSignatureVerifier(tpu=tpu, cpu=StubCpuBackend())
     batch = ([b"k" * 32], [b"d" * 32], [b"s" * 64])
     d0 = hybrid.verify_signatures_async(*batch)  # submitted while healthy
     d1 = hybrid.verify_signatures_async(*batch)  # submitted while healthy
@@ -390,8 +387,7 @@ def test_non_probe_fetch_failure_keeps_probe_exclusivity():
     concurrent non-probe failures."""
     clock = {"t": 0.0}
     tpu = ScriptedTpuBackend()
-    hybrid = HybridSignatureVerifier(tpu=tpu, cpu=StubCpuBackend(),
-                                     threshold=1)
+    hybrid = FallbackSignatureVerifier(tpu=tpu, cpu=StubCpuBackend())
     hybrid._breaker_clock = lambda: clock["t"]
     batch = ([b"k" * 32], [b"d" * 32], [b"s" * 64])
     straggler = hybrid.verify_signatures_async(*batch)  # healthy submit
@@ -412,8 +408,8 @@ def test_breaker_counts_degraded_batches_not_trips():
     tpu = ScriptedTpuBackend()
     tpu.dead = True
     metrics = Metrics()
-    hybrid = HybridSignatureVerifier(tpu=tpu, cpu=StubCpuBackend(),
-                                     threshold=1, metrics=metrics)
+    hybrid = FallbackSignatureVerifier(tpu=tpu, cpu=StubCpuBackend(),
+                                       metrics=metrics)
     hybrid._breaker_clock = lambda: 0.0  # frozen clock: never re-probes
     batch = ([b"k" * 32], [b"d" * 32], [b"s" * 64])
     for _ in range(5):
@@ -424,10 +420,10 @@ def test_breaker_counts_degraded_batches_not_trips():
 
 def test_breaker_survives_warmup_outage():
     """An unreachable backend at boot must not kill the warmup thread: the
-    hybrid calibrates the oracle, trips the breaker, and serves on CPU."""
+    breaker trips and the node serves on the oracle."""
     tpu, cpu = ScriptedTpuBackend(), StubCpuBackend()
     tpu.dead = True
-    hybrid = HybridSignatureVerifier(tpu=tpu, cpu=cpu, metrics=Metrics())
+    hybrid = FallbackSignatureVerifier(tpu=tpu, cpu=cpu, metrics=Metrics())
 
     def failing_warmup():
         raise ConnectionError("service not up yet")
@@ -435,7 +431,6 @@ def test_breaker_survives_warmup_outage():
     tpu.warmup = failing_warmup
     hybrid.warmup()  # must not raise
     assert hybrid.breaker_open
-    assert hybrid.cpu_per_sig_s > 0.0  # oracle still calibrated
 
 
 def test_own_block_reproposal_wins_dissemination_index(tmp_path):
@@ -493,8 +488,8 @@ def test_breaker_catches_exhausted_remote_retries(tmp_path):
         max_attempts=2,
     )
     remote.RETRY_BASE_BACKOFF_S = 0.001
-    hybrid = HybridSignatureVerifier(
-        tpu=remote, cpu=StubCpuBackend(), threshold=1, metrics=metrics
+    hybrid = FallbackSignatureVerifier(
+        tpu=remote, cpu=StubCpuBackend(), metrics=metrics
     )
     out = hybrid.verify_signatures([b"\x01" * 32], [bytes(32)], [bytes(64)])
     assert out == [True]

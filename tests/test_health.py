@@ -220,6 +220,81 @@ def test_diagnosis_and_health_route():
     assert json.loads(body)["status"] == "degraded"
 
 
+def _tripping_collector(metrics=None):
+    """A collector over the ``tpu`` flavor's breaker whose accelerator is
+    down: the first batch trips it and the oracle answers."""
+    from mysticeti_tpu.block_validator import (
+        BatchedSignatureVerifier,
+        FallbackSignatureVerifier,
+        SignatureVerifier,
+    )
+    from mysticeti_tpu.committee import Committee
+
+    class Down(SignatureVerifier):
+        def verify_signatures(self, public_keys, digests, signatures):
+            raise ConnectionError("verifier service is down")
+
+    class Accepts(SignatureVerifier):
+        def verify_signatures(self, public_keys, digests, signatures):
+            return [True] * len(signatures)
+
+    fallback = FallbackSignatureVerifier(
+        tpu=Down(), cpu=Accepts(), metrics=metrics
+    )
+    fallback._breaker_clock = lambda: 0.0  # frozen: never re-probes
+    collector = BatchedSignatureVerifier(
+        Committee.new_for_benchmarks(4), fallback, metrics=metrics
+    )
+    batch = ([b"k" * 32], [b"d" * 32], [b"s" * 64])
+    return collector, lambda: fallback.verify_signatures(*batch)
+
+
+def test_health_state_carries_the_breaker_and_no_pin():
+    collector, trip = _tripping_collector()
+    state = collector.health_state()
+    assert sorted(state) == [
+        "backend", "breaker_open", "pipeline_depth", "pipeline_inflight",
+    ]
+    assert state["breaker_open"] is False
+    assert trip() == [True]  # the oracle answered the batch that tripped it
+    state = collector.health_state()
+    assert state["breaker_open"] is True
+    assert state["backend"] == "hybrid-cpu"
+
+
+def test_health_document_and_gauge_follow_the_breaker():
+    from mysticeti_tpu.flight_recorder import FlightRecorder
+
+    metrics = Metrics()
+    collector, trip = _tripping_collector(metrics)
+    recorder = FlightRecorder(0)
+    clock = {"t": 0.0}
+    probe = HealthProbe(
+        0, 4, metrics=metrics, clock=lambda: clock["t"], recorder=recorder
+    )
+    probe.attach(
+        core=_FakeCore(0, 4), commit_observer=_FakeObserver(),
+        block_verifier=collector,
+    )
+    probe.sample()
+    verifier = probe.diagnosis()["signals"]["verifier"]
+    assert verifier["breaker_open"] is False
+    assert not [key for key in verifier if "pin" in key]
+    assert "mysticeti_health_verifier_breaker_open 0.0" in (
+        metrics.expose().decode()
+    )
+    trip()
+    clock["t"] = 1.0
+    probe.sample()
+    assert probe.diagnosis()["signals"]["verifier"]["breaker_open"] is True
+    text = metrics.expose().decode()
+    assert "mysticeti_health_verifier_breaker_open 1.0" in text
+    assert "verifier_fallback_total 1.0" in text
+    assert "pinned" not in text
+    kinds = [event["kind"] for event in recorder.events()]
+    assert kinds.count("breaker") == 1 and "pin" not in kinds
+
+
 # -- commit critical-path attribution ----------------------------------------
 
 

@@ -147,17 +147,17 @@ def test_scrape_contains_full_reference_inventory():
     # The precise channels ride histogram_pct{name=...}: check each label.
     for name in sorted(m._precise):
         assert f'name="{name}"' in scrape, name
-    # The verifier hot-path inventory (batch shape, padding, routing,
-    # service queue) — labeled series need one touched child to appear.
+    # The verifier hot-path inventory (batch shape, padding, fallback,
+    # wire, service queue) — labeled series need one touched child to appear.
     m.verify_padding_wasted_total.labels("cpu")
-    m.verify_route_total.labels("cpu")
+    m.verify_wire_bytes_total.labels("sent")
     m.verifier_service_inflight.labels("c0")
     scrape = m.expose().decode()
     for series in (
         "verify_dispatch_batch_size",
         "verify_padding_wasted_total",
-        "verify_route_total",
-        "verify_route_estimate_error_s",
+        "verifier_fallback_total",
+        "verify_wire_bytes_total",
         "verifier_service_queue_depth",
         "verifier_service_inflight",
     ):

@@ -288,22 +288,20 @@ def test_ten_node_sim_trace_report_and_verifier_metrics(tmp_path, monkeypatch, c
     assert "p50_ms" in out and "p99_ms" in out
 
     # Verifier-path telemetry reaches the /metrics endpoint (real asyncio:
-    # the collector + hybrid router + HTTP server need threads/sockets the
+    # the collector + breaker + HTTP server need threads/sockets the
     # simulator forbids).
     scrape = asyncio.run(_verifier_metrics_scrape())
     assert "verify_dispatch_batch_size" in scrape
     assert 'verify_padding_wasted_total{backend="hybrid-tpu"}' in scrape
-    assert 'verify_route_total{route="tpu"}' in scrape
-    assert 'verify_route_total{route="cpu"}' in scrape
+    assert "verifier_fallback_total 0.0" in scrape
     assert "verify_batch_size" in scrape
 
 
 async def _verifier_metrics_scrape() -> str:
-    from mysticeti_tpu import crypto
     from mysticeti_tpu.block_validator import (
         BatchedSignatureVerifier,
         CpuSignatureVerifier,
-        HybridSignatureVerifier,
+        FallbackSignatureVerifier,
     )
     from mysticeti_tpu.metrics import Metrics, serve_metrics
     from mysticeti_tpu.types import Share, StatementBlock
@@ -318,10 +316,9 @@ async def _verifier_metrics_scrape() -> str:
         def padded_batch(self, n):
             return 256 if n <= 256 else n
 
-    # Route 1 (tpu): threshold=1 sends the block batch to the "accelerator".
-    hybrid = HybridSignatureVerifier(
-        tpu=FakeTpu(), cpu=CpuSignatureVerifier(), threshold=1,
-        metrics=metrics,
+    # Breaker closed: the block batch goes to the "accelerator".
+    hybrid = FallbackSignatureVerifier(
+        tpu=FakeTpu(), cpu=CpuSignatureVerifier(), metrics=metrics,
     )
     collector = BatchedSignatureVerifier(committee, hybrid, metrics=metrics)
     genesis = [StatementBlock.new_genesis(i) for i in range(4)]
@@ -332,17 +329,6 @@ async def _verifier_metrics_scrape() -> str:
     ]
     oks = await collector.verify_blocks(blocks)
     assert all(oks)
-    # Route 2 (cpu): a sky-high threshold keeps the batch on the oracle.
-    hybrid_cpu = HybridSignatureVerifier(
-        tpu=FakeTpu(), cpu=CpuSignatureVerifier(), threshold=1 << 30,
-        metrics=metrics,
-    )
-    signer = crypto.Signer.from_seed(bytes(32))
-    digest = crypto.blake2b_256(b"route-probe")
-    hybrid_cpu.verify_signatures(
-        [signer.public_key.bytes], [digest], [signer.sign(digest)]
-    )
-
     server = await serve_metrics(metrics, "127.0.0.1", 0)
     port = server.sockets[0].getsockname()[1]
     reader, writer = await asyncio.open_connection("127.0.0.1", port)

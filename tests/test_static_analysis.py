@@ -160,17 +160,17 @@ def test_lock_discipline_positive_guarded_field():
         """
         import threading
 
-        class Hybrid:
+        class Breaker:
             def __init__(self):
-                self._ema_lock = threading.Lock()
-                self.cpu_per_sig_s = 0.0  # __init__ is exempt
+                self._breaker_lock = threading.Lock()
+                self._breaker_backoff_s = 0.0  # __init__ is exempt
 
-            def observe(self, sample):
-                self.cpu_per_sig_s = 0.8 * self.cpu_per_sig_s + 0.2 * sample
+            def trip(self):
+                self._breaker_backoff_s = 2.0 * self._breaker_backoff_s + 1.0
         """
     )
     assert rules_of(findings) == ["lock-discipline"]
-    assert "cpu_per_sig_s" in findings[0].message
+    assert "_breaker_backoff_s" in findings[0].message
 
 
 def test_lock_discipline_negative():
@@ -179,15 +179,17 @@ def test_lock_discipline_negative():
         import asyncio
         import threading
 
-        class Hybrid:
+        class Breaker:
             def __init__(self):
-                self._ema_lock = threading.Lock()
+                self._breaker_lock = threading.Lock()
                 self._alock = asyncio.Lock()
-                self.cpu_per_sig_s = 0.0
+                self._breaker_backoff_s = 0.0
 
-            def observe(self, sample):
-                with self._ema_lock:
-                    self.cpu_per_sig_s = 0.8 * self.cpu_per_sig_s + 0.2 * sample
+            def trip(self):
+                with self._breaker_lock:
+                    self._breaker_backoff_s = (
+                        2.0 * self._breaker_backoff_s + 1.0
+                    )
 
             async def async_section(self):
                 async with self._alock:

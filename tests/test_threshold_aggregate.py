@@ -158,26 +158,6 @@ def test_singletons_bypass_aggregation(setup):
     asyncio.run(main())
 
 
-def test_make_verifier_agg_kinds(monkeypatch):
-    """"-agg" kinds enable COLLECTOR-level aggregation: the flush window
-    pools blocks from every peer connection, which is where multi-author
-    (quorum-capable) batches actually form — a frame-level wrapper only ever
-    sees one peer's own single-author frames at steady state."""
-    from mysticeti_tpu import block_validator as bv
-    from mysticeti_tpu.validator import _make_verifier
-
-    monkeypatch.setattr(bv.HybridSignatureVerifier, "warmup", lambda self: None)
-    committee = Committee.new_for_benchmarks(4)
-    v = _make_verifier("cpu-agg", committee)
-    assert isinstance(v, bv.BatchedSignatureVerifier) and v.aggregate
-    assert isinstance(v.verifier, bv.CpuSignatureVerifier)
-    v = _make_verifier("tpu-agg", committee)
-    assert isinstance(v, bv.BatchedSignatureVerifier) and v.aggregate
-    assert isinstance(v.verifier, bv.HybridSignatureVerifier)
-    v = _make_verifier("cpu", committee)
-    assert isinstance(v, bv.BatchedSignatureVerifier) and not v.aggregate
-
-
 class CountingSigVerifier(CpuSignatureVerifier):
     def __init__(self):
         self.dispatched = 0

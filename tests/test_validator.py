@@ -70,33 +70,114 @@ async def _wait_commits(validators, minimum, timeout_s):
     await asyncio.wait_for(poll(), timeout=timeout_s)
 
 
-def test_make_verifier_kinds(monkeypatch):
-    """Every --verifier choice constructs (regression: the hybrid wiring
-    once referenced an unimported class and only failed at node boot)."""
+@pytest.mark.parametrize(
+    "kind, backend, aggregate",
+    [
+        ("accept", None, False),
+        ("cpu", "CpuSignatureVerifier", False),
+        ("tpu", "FallbackSignatureVerifier", False),
+        ("tpu-only", "TpuSignatureVerifier", False),
+        ("cpu-agg", "CpuSignatureVerifier", True),
+        ("tpu-agg", "FallbackSignatureVerifier", True),
+    ],
+)
+def test_make_verifier_kinds(monkeypatch, kind, backend, aggregate):
+    """Every --verifier choice constructs and comes ready (regression: the
+    wiring once referenced an unimported class and only failed at node
+    boot).  ``tpu`` puts the circuit breaker in front of the accelerator,
+    ``tpu-only`` is the accelerator alone; the "-agg" kinds turn on
+    COLLECTOR-level aggregation (the flush window pools blocks from every
+    peer connection, which is where quorum-capable batches form)."""
     from mysticeti_tpu import block_validator as bv
     from mysticeti_tpu.validator import _make_verifier
 
     # Warmup threads would trace/compile the kernel; wiring is what's tested.
-    monkeypatch.setattr(bv.HybridSignatureVerifier, "warmup", lambda self: None)
     monkeypatch.setattr(bv.TpuSignatureVerifier, "warmup", lambda self: None)
+    monkeypatch.delenv("MYSTICETI_VERIFIER_SOCKET", raising=False)
     committee = Committee.new_for_benchmarks(4)
 
-    v = _make_verifier("tpu", committee)
+    v = _make_verifier(kind, committee)
+    assert v.ready.wait(10) and v.warmup_error is None
+    if backend is None:
+        assert isinstance(v, bv.AcceptAllBlockVerifier)
+        return
     assert isinstance(v, bv.BatchedSignatureVerifier)
-    assert isinstance(v.verifier, bv.HybridSignatureVerifier)
-    assert isinstance(v.verifier.tpu, bv.TpuSignatureVerifier)
+    assert v.aggregate is aggregate
+    assert type(v.verifier) is getattr(bv, backend)
+    wraps_breaker = kind.startswith("tpu") and kind != "tpu-only"
+    assert hasattr(v.verifier, "breaker_open") is wraps_breaker
+    if wraps_breaker:
+        assert isinstance(v.verifier.tpu, bv.TpuSignatureVerifier)
+        assert isinstance(v.verifier.cpu, bv.CpuSignatureVerifier)
+        assert not v.verifier.breaker_open
+        assert v.health_state()["breaker_open"] is False
 
-    v = _make_verifier("tpu-only", committee)
-    assert isinstance(v.verifier, bv.TpuSignatureVerifier)
 
-    v = _make_verifier("cpu", committee)
-    assert isinstance(v.verifier, bv.CpuSignatureVerifier)
+def test_make_verifier_refuses_unknown_kind():
+    from mysticeti_tpu.validator import _make_verifier
 
-    assert isinstance(
-        _make_verifier("accept", committee), bv.AcceptAllBlockVerifier
-    )
     with pytest.raises(ValueError):
-        _make_verifier("gpu", committee)
+        _make_verifier("gpu", Committee.new_for_benchmarks(4))
+
+
+def test_tpu_flavor_accelerator_then_oracle_behind_the_breaker(monkeypatch):
+    """What ``_make_verifier("tpu")`` deploys: a 1-signature batch goes to
+    the accelerator; when the accelerator raises a transport error the
+    oracle answers that batch, ``verifier_fallback_total`` moves by one and
+    the breaker is open; a protocol error propagates and trips nothing."""
+    from mysticeti_tpu import block_validator as bv
+    from mysticeti_tpu.crypto import blake2b_256
+    from mysticeti_tpu.metrics import Metrics
+    from mysticeti_tpu.validator import _make_verifier
+    from mysticeti_tpu.verify_pipeline import DeferredDispatch
+
+    outcome = {"raises": None}
+    accelerator_calls = []
+
+    def accelerator(self, public_keys, digests, signatures):
+        accelerator_calls.append(len(signatures))
+        if outcome["raises"] is not None:
+            raise outcome["raises"]
+        return DeferredDispatch(
+            bv.CpuSignatureVerifier().verify_signatures,
+            public_keys, digests, signatures,
+        )
+
+    monkeypatch.setattr(bv.TpuSignatureVerifier, "warmup", lambda self: None)
+    monkeypatch.setattr(
+        bv.TpuSignatureVerifier, "verify_signatures_async", accelerator
+    )
+    monkeypatch.setattr(
+        bv.TpuSignatureVerifier, "padded_batch", lambda self, n: 256
+    )
+    monkeypatch.delenv("MYSTICETI_VERIFIER_SOCKET", raising=False)
+    metrics = Metrics()
+    collector = _make_verifier(
+        "tpu", Committee.new_for_benchmarks(4), metrics=metrics
+    )
+    assert collector.ready.wait(10)
+    backend = collector.verifier
+    signer = Committee.benchmark_signers(4)[0]
+    digest = blake2b_256(b"one signature")
+    batch = ([signer.public_key.bytes], [digest], [signer.sign(digest)])
+    fallbacks = metrics.verifier_fallback_total._value.get
+
+    assert backend.verify_signatures(*batch) == [True]
+    assert accelerator_calls == [1] and fallbacks() == 0.0
+    assert backend.backend_label == "hybrid-tpu"
+    assert backend.dispatch_padded == 256
+
+    outcome["raises"] = bv.VerifierProtocolError("committee mismatch")
+    with pytest.raises(bv.VerifierProtocolError):
+        backend.verify_signatures(*batch)
+    assert not backend.breaker_open and fallbacks() == 0.0
+
+    outcome["raises"] = ConnectionError("verifier service is down")
+    assert backend.verify_signatures(*batch) == [True]  # the oracle's answer
+    assert accelerator_calls == [1, 1, 1]
+    assert backend.breaker_open and fallbacks() == 1.0
+    assert backend.backend_label == "hybrid-cpu"
+    assert collector.health_state()["breaker_open"] is True
 
 
 def test_validator_commit(tmp_path):

@@ -78,7 +78,7 @@ def test_roundtrip_indexed_and_raw(tmp_path, signers):
         await asyncio.to_thread(client.warmup)
         base = backend.calls  # warmup + server-side calibration dispatches
         # The service measured its own dispatch costs and shared them.
-        assert client.dispatch_calibration() is not None
+        assert client.calibration is not None
         pks, digests, sigs = _sigs(8, signers)
         # Corrupt one signature: result order must be preserved.
         sigs[3] = bytes(64)
@@ -257,9 +257,9 @@ def test_make_verifier_uses_service_when_env_set(tmp_path, signers, monkeypatch)
         # ready is set by a warmup thread whose HELLO needs THIS event loop
         # (the server runs on it) — wait off-loop.
         assert await asyncio.to_thread(verifier.ready.wait, 30)
-        hybrid = verifier.verifier
-        assert isinstance(hybrid.tpu, RemoteSignatureVerifier)
-        # Hybrid calibration probed the service once (1-sig dispatch).
+        fallback = verifier.verifier
+        assert isinstance(fallback.tpu, RemoteSignatureVerifier)
+        # The service calibrated its backend before it answered the HELLO.
         assert backend.calls >= 1
         only = _make_verifier("tpu-only", committee)
         assert await asyncio.to_thread(only.ready.wait, 30)
@@ -439,11 +439,13 @@ def test_client_async_dispatch_overlaps_and_survives_restart(tmp_path, signers):
     asyncio.run(main())
 
 
-def test_cpu_advertising_service_short_circuits(tmp_path, signers):
-    """Acceptance (a): against a service advertising a CPU-only backend, the
-    hybrid verifier pins routing to the in-process oracle — batches complete
-    with ZERO socket frames and verify_shortcircuit_total flips."""
-    from mysticeti_tpu.block_validator import HybridSignatureVerifier
+def test_cpu_advertising_service_still_serves_every_batch(tmp_path, signers):
+    """A service that resolved to "cpu" says so over HELLO_OK, and that
+    changes nothing about where a batch goes: the ``tpu`` flavor sends every
+    one over the socket, small or large, and gets the oracle's verdicts
+    back.  (Deployments that measure refuse to start on such a service;
+    the client does not route around it.)"""
+    from mysticeti_tpu.block_validator import FallbackSignatureVerifier
     from mysticeti_tpu.metrics import Metrics
 
     keys = [s.public_key.bytes for s in signers]
@@ -452,95 +454,32 @@ def test_cpu_advertising_service_short_circuits(tmp_path, signers):
 
     async def scenario(server):
         remote = RemoteSignatureVerifier(
-            socket_path=server.socket_path, committee_keys=keys
+            socket_path=server.socket_path, committee_keys=keys,
+            metrics=metrics,
         )
-        hybrid = HybridSignatureVerifier(tpu=remote, metrics=metrics)
-        # Frozen clock: the re-HELLO upgrade probe's deadline never passes,
-        # so the steady state under test is PURE short-circuit.
-        hybrid._breaker_clock = lambda: 0.0
-        await asyncio.to_thread(hybrid.warmup)
+        fallback = FallbackSignatureVerifier(tpu=remote, metrics=metrics)
+        await asyncio.to_thread(fallback.warmup)
         assert remote.advertised_backend == "cpu"
-        assert hybrid.pinned_backend == "cpu"
-        assert hybrid.threshold() == hybrid.NEVER
-        base_calls = backend.calls
+        assert not fallback.breaker_open
 
-        def socket_is_lava(*_a, **_k):
-            raise AssertionError("pinned batch touched the service socket")
+        def run_batch(pks, digests, sigs):
+            ok = fallback.verify_signatures(pks, digests, sigs)
+            return ok, fallback.backend_label
 
-        remote.verify_signatures = socket_is_lava
-        remote.verify_signatures_async = socket_is_lava
-        # Well above DEFAULT_THRESHOLD: unpinned, this WOULD offload.
-        pks, digests, sigs = _sigs(40, signers)
-        sigs[5] = bytes(64)
-        expected = [True] * 5 + [False] + [True] * 34
-
-        def run_batch():
-            ok = hybrid.verify_signatures(pks, digests, sigs)
-            return ok, hybrid.backend_label
-
-        for _ in range(2):
-            ok, label = await asyncio.to_thread(run_batch)
-            assert ok == expected
-            assert label == "hybrid-cpu"
-        assert backend.calls == base_calls  # zero frames reached the service
-        flips = metrics.verify_shortcircuit_total.labels(
-            "backend-cpu"
-        )._value.get()
-        assert flips == 2
-
-    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
-
-
-def test_backend_upgrade_reopens_offload_without_restart(tmp_path, signers):
-    """Acceptance (b): when the service re-advertises an accelerator
-    backend (chip window opened / service restarted on real hardware), the
-    pinned hybrid's re-HELLO probe unpins routing and offload resumes — no
-    validator restart."""
-    from mysticeti_tpu.block_validator import HybridSignatureVerifier
-
-    keys = [s.public_key.bytes for s in signers]
-
-    class SwitchableBackend(CountingBackend):
-        platform = "cpu"
-
-        def resolved_backend(self):
-            return self.platform
-
-    backend = SwitchableBackend()
-
-    async def scenario(server):
-        remote = RemoteSignatureVerifier(
-            socket_path=server.socket_path, committee_keys=keys
-        )
-        clock = {"t": 0.0}
-        hybrid = HybridSignatureVerifier(tpu=remote, threshold=1)
-        hybrid._breaker_clock = lambda: clock["t"]
-        await asyncio.to_thread(hybrid.warmup)
-        assert hybrid.pinned_backend == "cpu"
-        base = backend.calls
-        pks, digests, sigs = _sigs(4, signers)
-        # Pinned: the batch stays on the oracle (no service dispatch).
-        ok = await asyncio.to_thread(
-            hybrid.verify_signatures, pks, digests, sigs
-        )
-        assert ok == [True] * 4 and backend.calls == base
-        # The chip arrives: service now resolves to an accelerator.
-        backend.platform = "tpu"
-        clock["t"] = 60.0  # past any jittered probe deadline
-        # This batch carries the re-HELLO probe (still verified on the
-        # oracle — the probe is a HELLO frame, never a verify).
-        ok = await asyncio.to_thread(
-            hybrid.verify_signatures, pks, digests, sigs
-        )
-        assert ok == [True] * 4 and backend.calls == base
-        assert remote.advertised_backend == "tpu"
-        assert hybrid.pinned_backend is None
-        # Offload is open again: the next batch rides the socket.
-        ok = await asyncio.to_thread(
-            hybrid.verify_signatures, pks, digests, sigs
-        )
-        assert ok == [True] * 4
-        assert backend.calls == base + 1
+        for n in (2, 40):
+            pks, digests, sigs = _sigs(n, signers)
+            sigs[1] = bytes(64)
+            calls = backend.calls
+            sent = metrics.verify_wire_bytes_total.labels("sent")._value.get()
+            ok, label = await asyncio.to_thread(run_batch, pks, digests, sigs)
+            assert ok == [True, False] + [True] * (n - 2)
+            assert label == "hybrid-tpu"
+            assert backend.calls == calls + 1  # the service verified it
+            assert metrics.verify_wire_bytes_total.labels(
+                "sent"
+            )._value.get() > sent
+        assert metrics.verifier_fallback_total._value.get() == 0.0
+        assert not fallback.breaker_open
 
     asyncio.run(_with_server(tmp_path, keys, backend, scenario))
 
@@ -704,8 +643,7 @@ def test_hello_ok_version_skew_old_client(tmp_path, signers):
 
 def test_hello_ok_version_skew_old_server(tmp_path, signers, monkeypatch):
     """A new client against an old server (16-byte HELLO_OK, no backend
-    suffix): calibration still seeds, the backend stays UNKNOWN, and the
-    hybrid therefore never pins (conservative default)."""
+    suffix): the calibration is parsed and the backend stays UNKNOWN."""
     from mysticeti_tpu.verifier_service import VerifierServer as VS
 
     keys = [s.public_key.bytes for s in signers]
@@ -718,7 +656,7 @@ def test_hello_ok_version_skew_old_server(tmp_path, signers, monkeypatch):
             socket_path=server.socket_path, committee_keys=keys
         )
         await asyncio.to_thread(client.warmup)
-        assert client.dispatch_calibration() is not None
+        assert client.calibration is not None
         assert client.advertised_backend is None
         pks, digests, sigs = _sigs(4, signers)
         ok = await asyncio.to_thread(

@@ -340,7 +340,7 @@ def test_mid_pipeline_backend_failure_degrades_with_zero_lost_futures():
     """A backend dying while dispatches are in flight trips the existing
     circuit breaker at FETCH time; every affected batch re-verifies on the
     oracle — zero futures lost, zero spurious rejections."""
-    from mysticeti_tpu.block_validator import HybridSignatureVerifier
+    from mysticeti_tpu.block_validator import FallbackSignatureVerifier
     from mysticeti_tpu.metrics import Metrics
 
     class DyingBackend(FixedLatencyVerifier):
@@ -365,8 +365,7 @@ def test_mid_pipeline_backend_failure_degrades_with_zero_lost_futures():
     # just tripped.)
     tpu = DyingBackend(0.03, die_after=0)
     cpu = FixedLatencyVerifier(0.0)
-    hybrid = HybridSignatureVerifier(tpu=tpu, cpu=cpu, threshold=1,
-                                     metrics=metrics)
+    hybrid = FallbackSignatureVerifier(tpu=tpu, cpu=cpu, metrics=metrics)
     _, results, collector = _run_windows(
         committee, blocks, hybrid, depth=4, metrics=metrics,
     )

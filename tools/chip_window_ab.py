@@ -14,9 +14,8 @@ weather window, and records:
     (inherited from tools/maxload_bench.py), so the A/B is self-contained;
   * the headline ratio `tpu_peak / cpu_peak`.
 
-Under JAX_PLATFORMS=cpu (no chip) this doubles as the zero-tax acceptance
-artifact: with backend-aware short-circuit routing the tpu flavor must
-price at >= 0.9x cpu (ISSUE 6).
+Under JAX_PLATFORMS=cpu (no chip) the tpu flavor verifies on the service's
+XLA ladder over the socket: the ratio then prices that path, not a chip.
 
 Usage:
   python tools/chip_window_ab.py --out MAXLOAD_TAX_r06.json

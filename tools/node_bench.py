@@ -33,9 +33,8 @@ async def run_one(verifier: str, nodes: int, load: int, duration: float,
     fleet = os.path.join(workdir, f"fleet-{verifier}")
     results = os.path.join(workdir, f"results-{verifier}")
     # The shared verifier service removed the tpu warmup asymmetry: the
-    # runner blocks until the service is warm before booting nodes, and
-    # nodes seed their routers from the service's HELLO_OK calibration
-    # instead of probing.  Identical delays keep the rows comparable.
+    # runner blocks until the service is warm before booting nodes.
+    # Identical delays keep the rows comparable.
     os.environ["INITIAL_DELAY"] = "1"
     runner = LocalProcessRunner(fleet, verifier=verifier)
     generator = ParametersGenerator(
@@ -84,9 +83,9 @@ def saturation(verifier: str, batch: int = 4096, iters: int = 5) -> dict:
         msgs.append(m)
         sigs.append(k.sign(m))
     # Deployed semantics: the signer set is the committee, keys ride as
-    # indices into a device-resident table (validator._make_verifier).  The
-    # hybrid ("tpu") routes a saturation-sized batch to the kernel, so the
-    # pure TPU backend measures both flavors.
+    # indices into a device-resident table (validator._make_verifier).
+    # "tpu" and "tpu-only" both send every batch to the kernel, so the pure
+    # TPU backend measures both flavors.
     backend = (
         CpuSignatureVerifier()
         if verifier == "cpu"
