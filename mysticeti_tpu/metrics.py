@@ -136,16 +136,19 @@ class PreciseHistogram:
 
 
 class StageSeries:
-    """A ``<name>{stage}`` histogram (and a ``<cpu_name>{stage}`` counter)
-    rendered at scrape time from the stage clocks attached to it
-    (``spans.StageClock``), summed where there are several: the clock is on
-    the verifier service's per-request path and keeps plain arrays there,
-    not one locked prometheus child a sample."""
+    """A ``<name>{stage}`` histogram (and a ``<cpu_name>{stage}`` counter,
+    and an ``<io_name>{direction}`` counter of the clock's ``reads`` and
+    ``writes``) rendered at scrape time from the stage clocks attached to
+    it (``spans.StageClock``), summed where there are several: the clock is
+    on the verifier service's per-request path and keeps plain arrays
+    there, not one locked prometheus child a sample."""
 
     def __init__(self, name: str, doc: str, cpu_name: Optional[str] = None,
-                 cpu_doc: str = "") -> None:
+                 cpu_doc: str = "", io_name: Optional[str] = None,
+                 io_doc: str = "") -> None:
         self.name, self.doc = name, doc
         self.cpu_name, self.cpu_doc = cpu_name, cpu_doc
+        self.io_name, self.io_doc = io_name, io_doc
         self._clocks: list = []
 
     def attach(self, clock) -> None:
@@ -196,6 +199,13 @@ class StageSeries:
         yield seconds
         if cpu is not None:
             yield cpu
+        if self.io_name:
+            io = CounterMetricFamily(
+                self.io_name, self.io_doc, labels=["direction"]
+            )
+            io.add_metric(["read"], sum(c.reads for c in self._clocks))
+            io.add_metric(["write"], sum(c.writes for c in self._clocks))
+            yield io
 
 
 class Metrics:
@@ -444,6 +454,12 @@ class Metrics:
             "minus its CPU is time blocked or waiting for the GIL (where "
             "the kernel moves that clock in ticks, only long sums mean "
             "anything)",
+            "verifier_service_io_calls_total",
+            "socket reads that held at least one verify request and writes "
+            "that held at least one reply: a read hands over every frame it "
+            "holds, a write carries every reply a launch finished for a "
+            "connection, so requests / reads and requests / writes say how "
+            "many a call carried",
         )
         r.register(self.verifier_service_stages)
         # The same clock on a validator's verification path (net_sync.py):

@@ -670,8 +670,8 @@ class StageClock:
     # What ``stamp`` reads once a second, cumulative; the ring's seconds
     # hold the growth from one stamp to the next (whole numbers, but for
     # the seconds, ``*_s``).
-    STAMPS = ("requests", "signatures", "launches", "process_cpu_s",
-              "threads_cpu_s", "loop_cpu_s")
+    STAMPS = ("requests", "signatures", "launches", "reads", "writes",
+              "process_cpu_s", "threads_cpu_s", "loop_cpu_s")
 
     def __init__(self, stages: Sequence[str], ring_seconds: int = 0,
                  tracer: Optional[SpanTracer] = None,
@@ -680,11 +680,16 @@ class StageClock:
         self.tracer = tracer
         self.sample_one_in = sample_one_in
         # Replies written, the signatures in them and the launches that
-        # answered them, clocked or not: plain sums of the one thread that
-        # writes replies (which also stamps).
+        # answered them, clocked or not; the socket reads that held at
+        # least one request and the writes that held at least one reply
+        # (``requests / reads`` and ``requests / writes``: how many frames a
+        # read and replies a write carried): plain sums of the one thread
+        # that reads requests and writes replies (which also stamps).
         self.requests = 0
         self.signatures = 0
         self.launches = 0
+        self.reads = 0
+        self.writes = 0
         self._slot = {name: i for i, name in enumerate(self.stages)}
         self._request_slots = [
             self._slot[name] for name in REQUEST_STAGES if name in self._slot
@@ -793,8 +798,8 @@ class StageClock:
             except OSError:
                 pass
             threads += entry[2] - entry[1]
-        return (self.requests, self.signatures, self.launches,
-                time.process_time(), threads, time.thread_time())
+        return (self.requests, self.signatures, self.launches, self.reads,
+                self.writes, time.process_time(), threads, time.thread_time())
 
     def stamp(self, now: float) -> None:
         """In the first call of a whole second of ``now``, read STAMPS into
@@ -931,7 +936,10 @@ class StageClock:
         second that was stamped — STAMPS: ``requests`` and ``signatures``
         answered and the ``launches`` that answered them (one backend call
         carries every request that was pending when a dispatcher thread
-        came free), and the CPU seconds the process (``process_cpu_s``),
+        came free), the socket ``reads`` that held a request and the
+        ``writes`` that held a reply (one read hands over every frame it
+        holds, one write carries every reply a launch finished for a
+        connection), and the CPU seconds the process (``process_cpu_s``),
         the threads that book here (``threads_cpu_s``; left out where a
         thread's CPU clock cannot be read from outside it) and the stamping
         thread itself (``loop_cpu_s``) used, each from that second's stamp
@@ -998,16 +1006,21 @@ class stage:  # noqa: N801 - reads as a statement: ``with stage(...):``
     """One occurrence of a stage that stands alone (not one of a dispatcher
     thread's launch) and books itself into ``clock``::
 
-        with spans.stage("service_decode", clock, since=t_header) as decode:
+        with spans.stage("service_decode", clock, since=t_read) as decode:
             ...
             decode.ref = (connection, req_id)
 
     ``since`` moves the stage's start back (it began in a wait); ``ref``,
     set inside the block, names the span for the clock's tracer; ``end`` is
-    the instant the block was left."""
+    the instant the block was left.  An occurrence that served several
+    requests at once (one socket read of the verifier service) lists the
+    clocked ones in ``refs`` and counts all it served in ``riders``: each
+    of ``refs`` books a sample of its own, the wall whole and the CPU
+    divided by ``riders``, as the requests of a launch do
+    (``StageClock.end_launch``)."""
 
-    __slots__ = ("name", "clock", "since", "ref", "end", "_state", "_c0",
-                 "_g0", "_annotation")
+    __slots__ = ("name", "clock", "since", "ref", "refs", "riders", "end",
+                 "_state", "_c0", "_g0", "_annotation")
 
     def __init__(self, name: str, clock: StageClock,
                  since: Optional[float] = None) -> None:
@@ -1015,6 +1028,8 @@ class stage:  # noqa: N801 - reads as a statement: ``with stage(...):``
         self.clock = clock
         self.since = time.monotonic() if since is None else since
         self.ref = None
+        self.refs: Optional[list] = None
+        self.riders = 1
 
     def __enter__(self) -> "stage":
         state = self._state = _state()
@@ -1036,9 +1051,10 @@ class stage:  # noqa: N801 - reads as a statement: ``with stage(...):``
             state.annotated = False
         self.end = t1 = time.monotonic()
         clock = self.clock
-        clock.book(self.name, t1, t1 - self.since, cpu)
-        if clock.tracer is not None and self.ref is not None:
-            clock.tracer.record_span(self.name, self.ref, self.since, t1)
+        for ref in self.refs or (self.ref,):
+            clock.book(self.name, t1, t1 - self.since, cpu / self.riders)
+            if clock.tracer is not None and ref is not None:
+                clock.tracer.record_span(self.name, ref, self.since, t1)
         return False
 
 

@@ -64,7 +64,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
-import contextlib
+import functools
 import gc
 import itertools
 import json
@@ -87,7 +87,6 @@ from .hostattr import LoopLagProbe
 from .network import jittered_backoff
 from .verify_pipeline import CompletedDispatch, DeferredDispatch
 from .tracing import logger
-from .utils.tasks import spawn_logged
 
 log = logger(__name__)
 
@@ -100,8 +99,8 @@ T_ERR = 255
 
 _IDX_REC = 2 + 32 + 64  # u16 idx | digest | sig
 _RAW_REC = 32 + 32 + 64
-# Stands where spans.stage would, for a request that is not clocked.
-_NOT_CLOCKED = contextlib.nullcontext()
+_HEADER = struct.Struct("<IB")  # u32 payload_len | u8 type
+_REQUEST = struct.Struct("<II")  # u32 req_id | u32 n
 
 ENV_SOCKET = "MYSTICETI_VERIFIER_SOCKET"
 
@@ -118,11 +117,10 @@ def report_path(socket_path: str) -> str:
 
 
 def _frame(type_: int, payload: bytes) -> bytes:
-    """Small-frame builder (HELLO, HELLO_OK, ERR).  The hot paths — VERIFY
-    requests client-side, RESULT replies service-side — do NOT come through
-    here: they pack into reusable buffers / scatter-gather parts so payload
-    bytes are copied at most once per direction (see ``_WireBuffer`` and
-    ``VerifierServer._reply_writer``)."""
+    """Small-frame builder (HELLO, HELLO_OK, ERR).  The hot path — VERIFY
+    requests client-side — does NOT come through here: it packs into a
+    reusable buffer so payload bytes are copied at most once (see
+    ``_WireBuffer``)."""
     return struct.pack("<IB", len(payload), type_) + payload
 
 
@@ -165,32 +163,40 @@ def _peer_uid(sock) -> Optional[int]:
         return None
 
 
-def _abandoned_reply(fut: asyncio.Future, cleanup) -> None:
-    """Completion hook for a dispatch whose connection died before its reply
-    could be written: retrieve the exception (so asyncio never logs it as
-    never-retrieved at GC) and only then release the service gauges."""
-    if not fut.cancelled() and fut.exception() is not None:
-        log.error(
-            "verifier service dispatch failed after client disconnect",
-            exc_info=fut.exception(),
-        )
-    if cleanup is not None:
-        cleanup()
+class _Slot:
+    """One reply a connection owes, in the order its frames came in
+    (``_Connection.slots``).  ``frame``: the wire frame to write, None
+    until it is known; ``req_id``: None for a HELLO_OK or an
+    ERR; ``waiting``: the launches that still carry a piece of the request
+    (one, but for a request wider than a launch, whose pieces' verdicts
+    gather in ``parts``); ``built``: when its launch was done with it, for
+    a request that is clocked; ``conn``: None once the slot was dropped
+    behind a HELLO that was refused."""
+
+    __slots__ = ("conn", "req_id", "frame", "waiting", "parts", "built")
+
+    def __init__(self, conn, req_id, frame=None) -> None:
+        self.conn = conn
+        self.req_id = req_id
+        self.frame = frame
+        self.waiting = 0
+        self.parts = None
+        self.built = None
 
 
 class _Pending:
-    """One decoded VERIFY/RAW request between its connection's reader and
-    the launch that answers it.  ``future`` is what the connection's reply
-    queue awaits; ``handed`` is when it was handed over, None for a request
-    that is not clocked; ``alone``: it found a launch slot asleep while the
-    service held at most one request more than it has slots, so it is
-    launched by itself."""
+    """One decoded VERIFY/RAW request — or the ``piece``-th piece of one
+    wider than a launch — between its connection's read and the launch
+    that answers it into ``slot``.  ``handed``: when it was handed over,
+    None for a request that is not clocked; ``alone``: it found a launch
+    slot asleep while the service held at most one request more than it
+    has slots, so it is launched by itself."""
 
     __slots__ = ("type_", "req_id", "n", "body", "conn_label", "handed",
-                 "future", "alone")
+                 "slot", "piece", "alone")
 
     def __init__(self, type_, req_id, n, body, conn_label, handed,
-                 future) -> None:
+                 slot, piece=0) -> None:
         self.alone = False
         self.type_ = type_
         self.req_id = req_id
@@ -198,7 +204,398 @@ class _Pending:
         self.body = body
         self.conn_label = conn_label
         self.handed = handed
-        self.future = future
+        self.slot = slot
+        self.piece = piece
+
+
+class _Connection(asyncio.Protocol):
+    """One client connection: all of it on the loop, none of it a task.
+
+    The loop works once a socket read and once a launch, never once a
+    request.  A read decodes every complete frame it holds, gives each a
+    slot in ``slots`` — the replies owed, in request order — and hands all
+    its requests over at once (``VerifierServer._hand_over``: they join the
+    pending list with the rest of that turn of the loop, under one
+    acquisition of its condition).  A finished launch fills its requests'
+    slots (``VerifierServer._resolve``) and each connection it touched
+    writes the run of finished slots at the head of its deque with one
+    ``write`` (``_flush``): replies leave strictly in request order,
+    whichever launches the requests rode.
+
+    Memory is bounded both ways: owed ``PIPELINE_DEPTH`` replies the
+    connection stops reading (what it had read waits in ``held``) until one
+    is written, and a client that does not read its replies
+    (``pause_writing``) is not read either.  A HELLO runs on the server's
+    HELLO thread and owns a slot like any reply, so HELLO_OK never
+    overtakes a RESULT; a verify must not be LAUNCHED before the HELLO that
+    establishes the committee finished (it would see no keys and report
+    every slot invalid), so requests behind an unresolved HELLO wait in
+    ``gated`` until ``_hello_done``."""
+
+    __slots__ = ("server", "label", "transport", "slots", "partial", "need",
+                 "held", "gate", "gated", "inflight", "counted",
+                 "write_paused", "finishing", "lost")
+
+    def __init__(self, server: "VerifierServer") -> None:
+        self.server = server
+        self.label = f"c{next(server._conn_ids)}"
+        self.transport = None
+        self.slots: collections.deque = collections.deque()
+        # A frame that spans reads gathers in a bytearray of its own
+        # (``need`` bytes when whole; 5 while its header is short).
+        self.partial: Optional[bytearray] = None
+        self.need = 0
+        self.held: Optional[memoryview] = None
+        # The slot of the last HELLO that has not completed.
+        self.gate: Optional[_Slot] = None
+        self.gated: List[_Pending] = []
+        # The connection's child of verifier_service_inflight (made at its
+        # first request) and how many requests it holds in the gauges.
+        self.inflight = None
+        self.counted = 0
+        self.write_paused = False
+        # Nothing more is decoded (a malformed frame, a HELLO refused, the
+        # client's EOF): the connection closes once ``slots`` is empty.
+        self.finishing = False
+        self.lost = False
+
+    # -- asyncio.Protocol --
+
+    def connection_made(self, transport) -> None:
+        self.transport = transport
+        # Trust gate first: the socket lives in a 0700 dir, but an
+        # unrelated local user who still reached it (shared parent mount,
+        # pre-hardening dir) must not get to submit RAW batches to the
+        # warmed backend.  Same-uid and root peers only.
+        uid = _peer_uid(transport.get_extra_info("socket"))
+        if uid is not None and uid not in (os.getuid(), 0):
+            log.warning(
+                "verifier service refusing foreign-uid peer (uid %d)", uid
+            )
+            self._close()
+            return
+        self.server._conns.add(self)
+
+    def data_received(self, data: bytes) -> None:
+        # A frame that lies whole in this read is a memoryview of it: the
+        # bytes the transport produced are the LAST host copy before the
+        # backend packs them device-ward.  One that spans reads is copied
+        # once, into ``partial``.  (Never called at PIPELINE_DEPTH: the
+        # connection stops reading the moment it gets there.)
+        view = memoryview(data)
+        partial = self.partial
+        if partial is None:
+            self._receive(view)
+        elif len(partial) < 5:
+            self.partial = None
+            self._receive(memoryview(bytes(partial) + data))
+        else:
+            take = self.need - len(partial)
+            partial += view[:take]
+            if len(partial) == self.need:
+                self.partial = None
+                self._receive(memoryview(partial), view[take:])
+
+    def eof_received(self) -> bool:
+        # The client will send no more; what it is owed is still written.
+        self.finishing = True
+        if not self.slots:
+            self._close()
+        return True
+
+    def pause_writing(self) -> None:
+        self.write_paused = True
+        self.transport.pause_reading()
+
+    def resume_writing(self) -> None:
+        self.write_paused = False
+        self._read_on()
+
+    def connection_lost(self, exc) -> None:
+        self._close()
+
+    # -- the read side --
+
+    def _receive(self, *views: memoryview) -> None:
+        """Decode every complete frame of ``views`` (one read; two views
+        where it began with the end of a frame that spans reads), give each
+        a slot, and hand every request among them over at once.
+
+        ``service_decode`` is the read in hand -> handed over, for the
+        requests of the read that are clocked (one in
+        ``spans.SAMPLE_ONE_IN``): its CPU clock and the profiler's
+        annotation start at the first of them, and its CPU is shared by
+        the requests decoded from there on."""
+        read_at = time.monotonic()
+        server = self.server
+        slots = self.slots
+        depth = server.PIPELINE_DEPTH
+        label = self.label
+        sampled = server.stages.sampled
+        items: List[_Pending] = []
+        requests = 0
+        decode = None  # the spans.stage of this read's clocked requests
+        for view in views:
+            at, end = 0, len(view)
+            while at < end and not self.finishing:
+                if len(slots) >= depth:
+                    self.held = view[at:]
+                    break
+                if end - at < 5:
+                    self.partial, self.need = bytearray(view[at:]), 5
+                    break
+                length, type_ = _HEADER.unpack_from(view, at)
+                if end - at - 5 < length:
+                    self.partial = bytearray(view[at:])
+                    self.need = 5 + length
+                    break
+                payload = view[at + 5: at + 5 + length]
+                at += 5 + length
+                if type_ == T_VERIFY or type_ == T_RAW:
+                    rec = _IDX_REC if type_ == T_VERIFY else _RAW_REC
+                    if length >= 8:
+                        req_id, n = _REQUEST.unpack_from(payload)
+                    if length < 8 or length - 8 != n * rec:
+                        self._refuse(b"malformed verify frame")
+                        break
+                    slot = _Slot(self, req_id)
+                    slots.append(slot)
+                    requests += 1
+                    handed = None
+                    if sampled():
+                        if decode is None:
+                            decode = spans.stage(
+                                "service_decode", server.stages, since=read_at)
+                            decode.refs = []
+                            clocked_from = requests
+                            decode.__enter__()
+                        decode.refs.append((label, req_id))
+                        handed = read_at  # clocked; the instant comes below
+                    # A request wider than what the backend warmed is cut
+                    # into pieces of at most that width, each pending like
+                    # a request of its own (the last, short one rides with
+                    # whatever else is pending), and its reply is their
+                    # verdicts joined when the last lands: no launch is
+                    # ever wider than what boot compiled, so a wide request
+                    # — a collector window of blocks full of signed
+                    # transactions — compiles nothing.
+                    cap = server._launch_cap
+                    body = payload[8:]
+                    if cap is None or n <= cap:
+                        slot.waiting = 1
+                        items.append(_Pending(
+                            type_, req_id, n, body, label, handed, slot))
+                        continue
+                    slot.waiting = -(-n // cap)
+                    slot.parts = [None] * slot.waiting
+                    for piece in range(slot.waiting):
+                        items.append(_Pending(
+                            type_, req_id, min(cap, n - piece * cap),
+                            body[piece * cap * rec: (piece + 1) * cap * rec],
+                            label, handed if piece == 0 else None, slot,
+                            piece))
+                elif type_ == T_HELLO:
+                    n_keys = (struct.unpack_from("<H", payload)[0]
+                              if length >= 2 else -1)
+                    if n_keys < 0 or length != 2 + 32 * n_keys:
+                        self._refuse(b"malformed hello frame")
+                        break
+                    keys = [bytes(payload[2 + 32 * i: 2 + 32 * (i + 1)])
+                            for i in range(n_keys)]
+                    if items and self.gate is None:
+                        # What this read held before the HELLO does not
+                        # wait for it (nor fall with it).
+                        server._hand_over(items)
+                        items = []
+                    self.gate = slot = _Slot(self, None)
+                    slots.append(slot)
+                    server._loop.run_in_executor(
+                        server._hello_pool, server._hello_reply, keys
+                    ).add_done_callback(
+                        functools.partial(self._hello_done, slot))
+                else:
+                    self._refuse(b"unknown frame type")
+                    break
+        if requests:
+            server.stages.reads += 1
+            # The gauges move once a read: depth = requests handed over
+            # and not yet answered (pending, or riding a launch); inflight
+            # splits it per client connection so one flooding validator is
+            # attributable.  They come back when the replies are written
+            # (``_flush``) or dropped (``_close``, ``_resolve``).
+            self.counted += requests
+            metrics = server.metrics
+            if metrics is not None:
+                if self.inflight is None:
+                    self.inflight = metrics.verifier_service_inflight.labels(
+                        label)
+                metrics.verifier_service_queue_depth.inc(requests)
+                self.inflight.inc(requests)
+            if decode is not None:
+                decode.riders = requests - clocked_from + 1
+                decode.__exit__(None, None, None)
+                for item in items:
+                    if item.handed is not None:
+                        item.handed = decode.end
+            if self.gate is not None:
+                self.gated += items
+            elif items:
+                server._hand_over(items)
+        if len(slots) >= depth:
+            self.transport.pause_reading()
+        if self.finishing:
+            self._flush()
+
+    def _refuse(self, message: bytes) -> None:
+        """A frame that is none of the protocol's: an ERR in its slot —
+        the replies before it are written, then the ERR, then the
+        connection is closed — and nothing behind it is looked at."""
+        self.slots.append(_Slot(self, None, _frame(T_ERR, message)))
+        self.finishing = True
+        self.transport.pause_reading()
+
+    def _hello_done(self, slot: _Slot, future) -> None:
+        """On the loop: the HELLO thread is done with the HELLO of
+        ``slot``."""
+        frame = None
+        if not future.cancelled():
+            if future.exception() is None:
+                frame = future.result()
+            else:
+                log.error("verifier service hello failed",
+                          exc_info=future.exception())
+        if self.lost or slot.conn is None:
+            return
+        if frame is None:
+            self._close()
+            return
+        slot.frame = frame
+        if frame[4] == T_ERR:
+            # Rejected (committee mismatch): the connection is severed
+            # after the ERR, so what was pipelined behind the HELLO is
+            # dropped and must NOT burn a backend dispatch (a
+            # reconnect-looping misconfigured client would otherwise cost
+            # a device round-trip per queued frame).  Nothing behind an
+            # unresolved HELLO has been handed over.
+            dropped = 0
+            while self.slots[-1] is not slot:
+                behind = self.slots.pop()
+                behind.conn = None
+                dropped += behind.req_id is not None
+            self.gated.clear()
+            self.gate = self.held = None
+            self.finishing = True
+            self.transport.pause_reading()
+            self._release(dropped)
+        elif slot is self.gate:
+            self.gate = None
+            gated, self.gated = self.gated, []
+            if gated:
+                now = time.monotonic()
+                for item in gated:
+                    if item.handed is not None:
+                        item.handed = now
+                self.server._hand_over(gated)
+        self._flush()
+
+    def _read_on(self) -> None:
+        """A reply was written, or the client reads again: decode what
+        waited in ``held``, and read on if that leaves room."""
+        if self.write_paused or self.finishing or self.lost:
+            return
+        depth = self.server.PIPELINE_DEPTH
+        if self.held is not None and len(self.slots) < depth:
+            view, self.held = self.held, None
+            self._receive(view)
+        if (self.held is None and len(self.slots) < depth
+                and not self.finishing):
+            self.transport.resume_reading()
+
+    # -- the write side --
+
+    def _flush(self) -> None:
+        """Write the run of finished slots at the head of ``slots`` with
+        one ``write``: every reply a launch finished for this connection,
+        and whatever waited behind them for their turn.  (One bytes object
+        a reply and one a run: a reply is a dozen bytes, and the clients
+        of today keep one request a connection, so a run is mostly one
+        reply — ``send`` of one buffer, where ``writelines`` of a header,
+        an id and the verdicts cost the loop a tenth more a request.)"""
+        slots = self.slots
+        if self.lost or not slots or slots[0].frame is None:
+            return
+        out: list = []
+        answered = signatures = 0
+        clocked = []
+        while slots and slots[0].frame is not None:
+            slot = slots.popleft()
+            out.append(slot.frame)
+            if slot.req_id is not None:
+                answered += 1
+                signatures += len(slot.frame) - 9
+                if slot.built is not None:
+                    clocked.append(slot)
+        self.transport.write(out[0] if len(out) == 1 else b"".join(out))
+        if answered:
+            # Counted for every request: sums of this thread's, which the
+            # clock's stamp reads once a second.
+            stages = self.server.stages
+            stages.writes += 1
+            stages.requests += answered
+            stages.signatures += signatures
+            if clocked:
+                # service_reply_wait: reply built -> written, i.e. the
+                # loop's wake-up, the earlier replies of this connection,
+                # then the write.
+                written = time.monotonic()
+                for slot in clocked:
+                    stages.book("service_reply_wait", written,
+                                written - slot.built)
+                    if stages.tracer is not None:
+                        stages.tracer.record_span(
+                            "service_reply_wait", (self.label, slot.req_id),
+                            slot.built, written)
+            self._release(answered)
+        if self.finishing and not slots:
+            self._close()
+        else:
+            self._read_on()
+
+    def _release(self, requests: int) -> None:
+        """``requests`` of this connection's leave the gauges: answered,
+        or dropped.  Labels are minted per connection from an unbounded
+        counter, so a reconnecting fleet would grow dead
+        {connection="cN"} series forever: the label goes when the
+        connection is lost — after its last request came back, since a
+        dec() after remove() would re-mint the dead series at -1."""
+        self.counted -= requests
+        if self.inflight is None:
+            return
+        self.server.metrics.verifier_service_queue_depth.dec(requests)
+        self.inflight.dec(requests)
+        if self.lost and not self.counted:
+            self.server.metrics.verifier_service_inflight.remove(self.label)
+            self.inflight = None
+
+    def _close(self) -> None:
+        """The connection is over (its client went, a launch of its
+        requests raised, an ERR was its last reply, ``stop()``): nothing
+        more is read or written.  A request that was handed over stays in
+        the gauges until its launch ends (``_resolve``) — releasing it now
+        would show an idle service during real device work — and every
+        other one leaves them here."""
+        if self.lost:
+            return
+        self.lost = True
+        self.server._conns.discard(self)
+        self.transport.close()
+        for item in self.gated:
+            item.slot.waiting = 0  # never handed over
+        riding = sum(1 for slot in self.slots if slot.waiting)
+        self.slots.clear()
+        self.gated.clear()
+        self.held = self.partial = self.gate = None
+        self._release(self.counted - riding)
 
 
 # ---------------------------------------------------------------------------
@@ -208,9 +605,10 @@ class _Pending:
 class VerifierServer:
     """One accelerator runtime serving every validator on the host."""
 
-    # Per-connection staged request window: the reader decodes request N+1
-    # while N waits for or rides a launch; replies are written strictly in
-    # request order by a dedicated writer task.  The bound backpressures a
+    # Per-connection staged request window: request N+1 is decoded and
+    # handed over while N waits for or rides a launch; replies are written
+    # strictly in request order.  A connection that is owed this many
+    # replies is not read (``_Connection``): the bound backpressures a
     # client pipelining faster than the backend drains.
     PIPELINE_DEPTH = 8
     # Launch slots: each is a thread that takes everything pending when it
@@ -257,11 +655,12 @@ class VerifierServer:
         self._warmed = threading.Event()
         self._warm_lock = threading.Lock()
         # Decoded requests that no launch has taken yet, in arrival order.
-        # The loop appends (``_submit``), the dispatcher threads take
+        # The loop appends (``_deliver``), the dispatcher threads take
         # (``_take``), both under the condition, on which a dispatcher
         # with nothing to take sleeps.
         self._pending: collections.deque = collections.deque()
         self._pending_cond = threading.Condition()
+        self._arrived: List[_Pending] = []  # read this turn of the loop
         self._idle = 0  # dispatchers asleep on the condition
         self._promised = 0  # pending requests that each woke one of them
         self._in_service = 0  # handed over and not yet resolved (the loop's)
@@ -279,7 +678,7 @@ class VerifierServer:
             max_workers=1, thread_name_prefix="verify-hello",
         )
         self._server: Optional[asyncio.AbstractServer] = None
-        self._writers: set = set()
+        self._conns: set = set()  # every live _Connection
         self._calibration: Optional[Tuple[float, float]] = None
         # A backend that cannot warm is fatal to the service: the failing
         # thread records the cause and wakes serve_forever, which raises
@@ -405,332 +804,7 @@ class VerifierServer:
             raise ValueError("prewarm requires committee keys")
         self._ensure_backend(self._keys)
 
-    # -- connection handling --
-
-    async def _handle(self, reader: asyncio.StreamReader,
-                      writer: asyncio.StreamWriter) -> None:
-        # Trust gate first: the socket lives in a 0700 dir,
-        # but an unrelated local user who still reached it (shared parent
-        # mount, pre-hardening dir) must not get to submit RAW batches to
-        # the warmed backend.  Same-uid and root peers only.
-        uid = _peer_uid(writer.get_extra_info("socket"))
-        if uid is not None and uid not in (os.getuid(), 0):
-            log.warning(
-                "verifier service refusing foreign-uid peer (uid %d)", uid
-            )
-            writer.close()
-            return
-        # Staged per-connection request pipeline: the reader decodes and
-        # hands over request N+1 while request N waits for or rides a
-        # launch; a dedicated writer task emits replies strictly in request
-        # order (the protocol contract clients rely on, whichever launches
-        # the requests rode), so the service is no stop-and-wait RPC for a
-        # client that pipelines its frames.
-        loop = asyncio.get_running_loop()
-        self._writers.add(writer)
-        conn_label = f"c{next(self._conn_ids)}"
-        replies: asyncio.Queue = asyncio.Queue(maxsize=self.PIPELINE_DEPTH)
-        reply_task = spawn_logged(
-            self._reply_writer(replies, writer, conn_label), log,
-            name="verifier-replies",
-        )
-
-        def _accounted():
-            metrics = self.metrics
-            if metrics is None:
-                return None
-            # Depth = requests handed over and not yet answered (pending,
-            # or riding a launch); inflight splits it per client connection
-            # so one flooding validator is attributable.  Decremented by
-            # the writer once the reply is built (cleanup runs even when
-            # the launch raised).
-            metrics.verifier_service_queue_depth.inc()
-            metrics.verifier_service_inflight.labels(conn_label).inc()
-
-            def _done():
-                metrics.verifier_service_queue_depth.dec()
-                metrics.verifier_service_inflight.labels(conn_label).dec()
-
-            return _done
-
-        # A pipelined client may send VERIFY frames behind a HELLO without
-        # waiting for HELLO_OK; the HELLO runs on a thread of its own, so a
-        # verify must not be LAUNCHED before the HELLO that establishes the
-        # committee finished (it would see no keys and report every slot
-        # invalid).  Replies stay ordered by the queue; the hand-over is
-        # gated on the connection's last unresolved HELLO only.
-        last_hello: Optional[asyncio.Future] = None
-
-        async def _after_hello(gate, type_, req_id, n, body, clocked):
-            try:
-                hello_frame = await asyncio.shield(gate)
-            except Exception:  # noqa: BLE001 - HELLO's own reply carries it
-                hello_frame = None
-            if hello_frame is None or hello_frame[4] == T_ERR:
-                # The HELLO was rejected (committee mismatch) or crashed:
-                # the connection is being severed and this reply would be
-                # discarded in drain mode — do NOT burn a backend dispatch
-                # for it (a reconnect-looping misconfigured client would
-                # otherwise cost a device round-trip per queued frame).
-                return None
-            return await self._submit(
-                loop, type_, req_id, n, body, conn_label,
-                time.monotonic() if clocked else None,
-            )
-
-        try:
-            while True:
-                try:
-                    header = await reader.readexactly(5)
-                except asyncio.IncompleteReadError:
-                    return
-                t_header = time.monotonic()
-                if reply_task.done():
-                    return  # writer died (client gone, backend crash)
-                length, type_ = struct.unpack("<IB", header)
-                payload = await reader.readexactly(length) if length else b""
-                if type_ == T_HELLO:
-                    n_keys = (
-                        struct.unpack_from("<H", payload)[0]
-                        if length >= 2 else -1
-                    )
-                    if n_keys < 0 or length != 2 + 32 * n_keys:
-                        await replies.put(
-                            (_frame(T_ERR, b"malformed hello frame"),
-                             None, True)
-                        )
-                        return
-                    keys = [
-                        bytes(payload[2 + 32 * i: 2 + 32 * (i + 1)])
-                        for i in range(n_keys)
-                    ]
-                    # HELLO replies ride the same in-order queue as results:
-                    # a client that pipelines frames must never see HELLO_OK
-                    # overtake an earlier RESULT.
-                    fut = loop.run_in_executor(
-                        self._hello_pool, self._hello_reply, keys
-                    )
-                    last_hello = fut
-                    await replies.put((fut, None, False))
-                elif type_ in (T_VERIFY, T_RAW):
-                    # service_decode: header read -> frame checked and
-                    # handed over (the CPU clock and the profiler's
-                    # annotation start here, with the payload in hand: no
-                    # await lies between this line and the hand-over).
-                    # One request in spans.SAMPLE_ONE_IN is clocked, whole:
-                    # ``decode`` is None for the others.
-                    malformed = False
-                    with (spans.stage("service_decode", self.stages,
-                                      since=t_header)
-                          if self.stages.sampled()
-                          else _NOT_CLOCKED) as decode:
-                        if length >= 8:
-                            req_id, n = struct.unpack_from("<II", payload)
-                            if decode is not None:
-                                decode.ref = (conn_label, req_id)
-                            # memoryview, not a bytes slice: the request
-                            # body is the bulk of every frame, and the
-                            # per-record digest/sig slices below stay views
-                            # too — the payload bytes the reader produced
-                            # are the LAST host copy before the backend
-                            # packs them device-ward.
-                            body = memoryview(payload)[8:]
-                            rec = _IDX_REC if type_ == T_VERIFY else _RAW_REC
-                        if length < 8 or len(body) != n * rec:
-                            malformed = True
-                        elif last_hello is not None and last_hello.done():
-                            rejected = last_hello.cancelled() or (
-                                last_hello.exception() is not None
-                                or last_hello.result()[4] == T_ERR
-                            )
-                            if rejected:
-                                # The writer is severing after the HELLO's
-                                # ERR: frames pipelined behind it must not
-                                # burn backend dispatches for replies that
-                                # will be discarded in drain mode.
-                                return
-                            last_hello = None  # accepted: no more gating
-                        if not malformed:
-                            done = _accounted()
-                    if malformed:
-                        await replies.put(
-                            (_frame(T_ERR, b"malformed verify frame"),
-                             None, True)
-                        )
-                        return
-                    if last_hello is not None:
-                        # Awaited by the reply writer in order, which
-                        # observes its exception.
-                        fut = asyncio.ensure_future(_after_hello(
-                            last_hello, type_, req_id, n, body,
-                            decode is not None,
-                        ))
-                    else:
-                        fut = self._submit(
-                            loop, type_, req_id, n, body, conn_label,
-                            None if decode is None else decode.end,
-                        )
-                    await replies.put((fut, done, False))
-                else:
-                    await replies.put(
-                        (_frame(T_ERR, b"unknown frame type"), None, True)
-                    )
-                    return
-        except (ConnectionResetError, BrokenPipeError, OSError):
-            return
-        finally:
-            # Let the writer drain everything already submitted, then stop.
-            try:
-                replies.put_nowait(None)
-            except asyncio.QueueFull:
-                reply_task.cancel()
-            try:
-                await reply_task
-            except asyncio.CancelledError:
-                reply_task.cancel()
-            except Exception:  # noqa: BLE001 - writer logged its own failure
-                pass
-            # Anything left unqueued-for-write still owes its cleanup, but
-            # its launch may still be running on a dispatcher: releasing
-            # the gauges now would show an idle service during real device
-            # work, and abandoning the future would leave its exception
-            # unretrieved.  Defer both to the dispatch's own completion.
-            abandoned = []
-            while True:
-                try:
-                    item = replies.get_nowait()
-                except asyncio.QueueEmpty:
-                    break
-                if item is None:
-                    continue
-                frame, cleanup, _close_after = item
-                if asyncio.isfuture(frame):
-                    abandoned.append((frame, cleanup))
-                elif cleanup is not None:
-                    cleanup()
-
-            def _remove_label() -> None:
-                # Labels are minted per connection from an unbounded counter;
-                # a reconnecting fleet would otherwise grow dead
-                # {connection="cN"} series in the registry forever.
-                if self.metrics is not None:
-                    try:
-                        self.metrics.verifier_service_inflight.remove(
-                            conn_label
-                        )
-                    except KeyError:
-                        pass  # connection closed before its first verify
-
-            if abandoned:
-                # The label must outlive every deferred cleanup: a dec()
-                # after remove() would re-mint the dead series at -1 and
-                # leak it forever.  The LAST abandoned dispatch to complete
-                # removes it (done-callbacks run on the loop thread, so the
-                # countdown needs no lock).
-                remaining = {"n": len(abandoned)}
-
-                def _finish(fut, cleanup) -> None:
-                    _abandoned_reply(fut, cleanup)
-                    remaining["n"] -= 1
-                    if remaining["n"] == 0:
-                        _remove_label()
-
-                for fut, cleanup in abandoned:
-                    fut.add_done_callback(
-                        lambda f, cleanup=cleanup: _finish(f, cleanup)
-                    )
-            else:
-                _remove_label()
-            self._writers.discard(writer)
-            writer.close()
-
-    async def _reply_writer(self, replies: asyncio.Queue,
-                            writer: asyncio.StreamWriter,
-                            conn_label: str = "") -> None:
-        """Emit queued replies in request order; ``None`` ends the stream.
-        Queue items are ``(frame_or_future, cleanup, close_after)``.  A
-        dispatch failure or a dead client socket flips to drain mode —
-        remaining cleanups still run (gauge hygiene) but nothing is written,
-        and the transport is closed so the reader unblocks.
-
-        A reply is either a prebuilt ``bytes`` frame (HELLO_OK, ERR) or a
-        ``(type, parts, built)`` tuple from the verify path (``built``: when
-        its launch was done with it, None for a request that is not
-        clocked): a fresh 5-byte header
-        rides ``writer.writelines`` with the parts as-is — scatter-gather,
-        no header+payload concatenation per reply.  The header must be a
-        fresh immutable object per reply: since 3.12 the selector transport
-        may hold a zero-copy view of writelines' buffers under
-        backpressure, so a reused mutable scratch could be rewritten while
-        frame N still sits unsent in the transport buffer."""
-        dead = False
-        stages = self.stages
-        while True:
-            item = await replies.get()
-            if item is None:
-                return
-            frame, cleanup, close_after = item
-            try:
-                if asyncio.isfuture(frame):
-                    try:
-                        frame = await frame
-                    except Exception:  # noqa: BLE001 - logged, conn severed
-                        log.exception("verifier service dispatch failed")
-                        frame = None
-                if dead or frame is None or writer.is_closing():
-                    # (closing: stop() severed the connection under a
-                    # launch; a write to its transport would raise.)
-                    dead = True
-                    writer.close()
-                    continue
-                if isinstance(frame, tuple):
-                    type_, parts, built = frame
-                else:
-                    type_, parts = frame[4], None
-                if type_ == T_ERR:
-                    # Protocol errors sever the connection after the reply
-                    # (the pre-pipeline contract), wherever they were built.
-                    close_after = True
-                try:
-                    if parts is not None:
-                        header = struct.pack(
-                            "<IB", sum(len(p) for p in parts), type_
-                        )
-                        writer.writelines((header, *parts))
-                    else:
-                        writer.write(frame)
-                    await writer.drain()
-                    if parts is not None:
-                        # Counted for every request: two sums of this
-                        # thread's, which the clock's stamp reads once a
-                        # second.
-                        stages.requests += 1
-                        stages.signatures += len(parts[1])
-                    if parts is not None and built is not None:
-                        # service_reply_wait: reply built -> written, i.e.
-                        # the loop's wake-up, the earlier replies of this
-                        # connection, then the write.
-                        written = time.monotonic()
-                        stages.book(
-                            "service_reply_wait", written, written - built
-                        )
-                        tracer = stages.tracer
-                        if tracer is not None:
-                            tracer.record_span(
-                                "service_reply_wait",
-                                (conn_label,
-                                 struct.unpack("<I", parts[0])[0]),
-                                built, written,
-                            )
-                except (ConnectionResetError, BrokenPipeError, OSError):
-                    dead = True
-                    continue
-                if close_after:
-                    dead = True
-                    writer.close()
-            finally:
-                if cleanup is not None:
-                    cleanup()
+    # -- HELLO --
 
     def _resolved_backend(self) -> str:
         """The platform the warmed backend ACTUALLY dispatches on —
@@ -759,53 +833,24 @@ class VerifierServer:
 
     # -- the coalescer: pending requests -> launches --
 
-    def _submit(self, loop, type_: int, req_id: int, n: int, body,
-                conn_label: str, handed: Optional[float]) -> asyncio.Future:
-        """Hand a decoded request over (on the loop): it joins the pending
-        list, and the future resolves to its reply, ``(T_RESULT, parts,
-        built)``, once the launch that took it is done.  ``handed``: the
-        instant, for a request that is clocked.
+    def _hand_over(self, items: List[_Pending]) -> None:
+        """The requests of one read, in arrival order (on the loop).  They
+        join the pending list with everything else this turn of the loop
+        reads, once the turn is over (``_deliver``): a dispatcher that is
+        woken for the first must not get the GIL at the next socket's
+        ``recv`` and leave with a launch of one."""
+        if not self._arrived:
+            self._loop.call_soon(self._deliver)
+        self._arrived += items
 
-        A request wider than what the backend warmed is cut here into
-        pieces of at most that width, each pending like a request of its
-        own (the last, short one rides with whatever else is pending), and
-        its reply is their verdicts rejoined: no launch is ever wider than
-        what boot compiled, so a wide request — a collector window of
-        blocks full of signed transactions — compiles nothing."""
-        cap = self._launch_cap
-        if cap is None or n <= cap:
-            return self._enqueue(
-                loop, type_, req_id, n, body, conn_label, handed)
-        rec = _IDX_REC if type_ == T_VERIFY else _RAW_REC
-        pieces = [
-            self._enqueue(
-                loop, type_, req_id, min(cap, n - at),
-                body[at * rec: (at + cap) * rec], conn_label,
-                handed if at == 0 else None,
-            )
-            for at in range(0, n, cap)
-        ]
-        return asyncio.ensure_future(self._rejoin(pieces))
-
-    @staticmethod
-    async def _rejoin(pieces: List[asyncio.Future]) -> tuple:
-        """The reply of a request that was cut into ``pieces``: verdicts in
-        order; a clocked request's ``built`` is its first piece's (the
-        others are not clocked)."""
-        results = await asyncio.gather(*pieces)
-        _, (req_id, _), built = results[0]
-        return (T_RESULT,
-                (req_id, b"".join(parts[1] for _, parts, _ in results)),
-                built)
-
-    def _enqueue(self, loop, type_: int, req_id: int, n: int, body,
-                 conn_label: str, handed: Optional[float]) -> asyncio.Future:
-        future = loop.create_future()
-        item = _Pending(type_, req_id, n, body, conn_label, handed, future)
+    def _deliver(self) -> None:
+        """Everything the connections read in one turn of the loop joins
+        the pending list under one acquisition of the condition."""
+        items, self._arrived = self._arrived, []
         with self._pending_cond:
             if self._stopping:
-                future.cancel()
-            else:
+                return  # never launched: ``stop()`` has closed its connection
+            for item in items:
                 self._in_service += 1
                 self._pending.append(item)
                 if self._idle > self._promised:
@@ -822,16 +867,15 @@ class VerifierServer:
                         item.alone = True
                         self._promised += 1
                     self._pending_cond.notify()
-        return future
 
     def _take(self) -> List[_Pending]:
         """Everything pending, in arrival order, while the signatures sum
         to at most what the backend warmed: whole requests only, and the
         first whatever it holds (only a request that arrived before the
-        backend was warm can be over the cap: ``_submit`` cuts the others).
-        A request
-        marked ``alone`` (``_submit``) goes alone, whichever slot gets to
-        it first.  Called with the condition held and something pending."""
+        backend was warm can be over the cap: ``_Connection._receive`` cuts
+        the others).  A request marked ``alone`` (``_deliver``) goes
+        alone, whichever slot gets to it first.  Called with the condition
+        held and something pending."""
         pending = self._pending
         first = pending.popleft()
         batch = [first]
@@ -850,7 +894,7 @@ class VerifierServer:
         """A launch slot: take what is pending, launch it, again; sleep
         only when nothing is pending.  No timer anywhere: a launch holds
         what queued while every slot was busy, and a request that finds a
-        slot asleep wakes it (``_submit``)."""
+        slot asleep wakes it (``_deliver``)."""
         self.stages.adopt_thread()
         cond, pending = self._pending_cond, self._pending
         while True:
@@ -866,7 +910,7 @@ class VerifierServer:
 
     def _launch(self, batch: List[_Pending]) -> None:
         """One backend call for every request of ``batch``, then each
-        request's reply to its future, with one wake-up of the loop.  The
+        request's reply to its slot, with one wake-up of the loop.  The
         thread works for the launch from here to ``built``: every
         ``spans.request_stage`` below, in the backend too, names the stage
         it is in, for the clocked requests that ride it.  A launch that
@@ -880,7 +924,7 @@ class VerifierServer:
         replies = error = built = None
         try:
             replies = self._verify_batch(batch)
-        except Exception as exc:  # noqa: BLE001 - the reply writers log it
+        except Exception as exc:  # noqa: BLE001 - ``_resolve`` logs it
             error = exc
         finally:
             if clocked:
@@ -892,20 +936,39 @@ class VerifierServer:
             pass  # the loop closed under a launch that stop() did not await
 
     def _resolve(self, batch: List[_Pending], replies, error, built) -> None:
-        """On the loop: a launch is done."""
+        """On the loop: a launch is done.  Its requests' slots are filled
+        and every connection it touched writes what it now can, once.  A
+        launch that raised closes exactly the connections whose requests
+        rode it; a request whose connection is lost is dropped here."""
         self.stages.launches += 1
         self._in_service -= len(batch)
+        if error is not None:
+            log.error("verifier service dispatch failed", exc_info=error)
+        touched = set()
         for i, item in enumerate(batch):
-            future = item.future
-            if future.done():
-                continue  # cancelled: its connection's writer was, or stop()
+            slot = item.slot
+            conn = slot.conn
+            slot.waiting -= 1
+            if conn.lost:
+                if not slot.waiting:
+                    conn._release(1)
+                continue
             if error is not None:
-                future.set_exception(error)
-            else:
-                future.set_result((
-                    T_RESULT, replies[i],
-                    built if item.handed is not None else None,
-                ))
+                conn._close()
+                continue
+            req_id, verdicts = replies[i]
+            if item.handed is not None:
+                slot.built = built
+            if slot.parts is not None:
+                slot.parts[item.piece] = verdicts
+                if slot.waiting:
+                    continue
+                verdicts = b"".join(slot.parts)
+            slot.frame = (
+                _HEADER.pack(4 + len(verdicts), T_RESULT) + req_id + verdicts)
+            touched.add(conn)
+        for conn in touched:
+            conn._flush()
 
     def _key_rows(self, keys: List[bytes]):
         """(K + 1, 32) uint8: the committee's keys by wire index, and below
@@ -957,8 +1020,7 @@ class VerifierServer:
     def _verify_batch(self, batch: List[_Pending]) -> List[tuple]:
         """Verify every signature of every request of ``batch`` with one
         backend call and return each request's reply parts, ``(req_id
-        bytes, verdict bytes)`` — the writer scatter-gathers them behind a
-        fresh header.  The signatures travel as arrays from the wire
+        bytes, verdict bytes)`` — ``_resolve`` frames them.  The signatures travel as arrays from the wire
         records to the backend (``_wire_rows``) and the verdicts back into
         bytes with one conversion: nothing here runs once a signature."""
         import numpy as np
@@ -1032,8 +1094,8 @@ class VerifierServer:
         ]
         for thread in self._dispatchers:
             thread.start()
-        self._server = await asyncio.start_unix_server(
-            self._handle, path=self.socket_path
+        self._server = await self._loop.create_unix_server(
+            lambda: _Connection(self), path=self.socket_path
         )
         # Belt to the dir's braces: same-uid-or-root only, and the peercred
         # gate enforces it even where a path somehow stays reachable.
@@ -1065,24 +1127,27 @@ class VerifierServer:
             ) from self._fatal
 
     async def stop(self) -> None:
-        # Nothing pending is launched from here on: its future is cancelled
-        # (the connection's writer ends with it), and the dispatchers end
+        # Nothing pending is launched from here on, and the dispatchers end
         # once the launch they are in returns.
         with self._pending_cond:
             self._stopping = True
-            abandoned = list(self._pending)
+            abandoned = list(self._pending) + self._arrived
             self._pending.clear()
             self._pending_cond.notify_all()
-        for item in abandoned:
-            item.future.cancel()
         if self._server is not None:
             self._server.close()
-            # Sever live client connections first: since 3.12,
-            # ``wait_closed`` waits for every connection HANDLER to finish,
-            # and handlers block in readexactly on idle-but-open clients.
-            for writer in list(self._writers):
-                writer.close()
+            # Sever live client connections first: ``wait_closed`` waits
+            # for every one of them.  (A client that does not read its
+            # replies would never let ``close`` flush them.)
+            for conn in list(self._conns):
+                if conn.write_paused:
+                    conn.transport.abort()
+                conn._close()
             await self._server.wait_closed()
+        for item in abandoned:  # as a launch's end would (``_resolve``)
+            item.slot.waiting -= 1
+            if not item.slot.waiting:
+                item.slot.conn._release(1)
         self._hello_pool.shutdown(wait=False)
         self._write_report()
         if os.path.exists(self.socket_path):

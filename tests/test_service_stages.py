@@ -367,6 +367,12 @@ def test_the_report_ring_sums_to_the_scraped_series(tmp_path):
     assert not [s for s in series
                 if s[0] == "verifier_service_stage_cpu_seconds_total"
                 and s[1].get("stage") == "service_fetch"]
+    # The reads that held a request and the writes that held a reply.
+    for direction, stamp in (("read", "reads"), ("write", "writes")):
+        calls = harness.series_sum(
+            series, "verifier_service_io_calls_total", direction=direction)
+        assert calls == sum(second[stamp] for second in stamped)
+        assert 1 <= calls <= 8
 
 
 def test_a_host_oracle_leaves_no_report(tmp_path):
@@ -711,6 +717,51 @@ def test_a_seconds_stamp_counts_the_launches():
     assert seconds[str(base)]["launches"] == 5
     assert seconds[str(base + 1)]["launches"] == 1
     assert isinstance(seconds[str(base)]["launches"], int)
+
+
+def test_a_seconds_stamp_counts_the_reads_and_the_writes():
+    """``reads`` and ``writes`` ride the ring's stamp too: with
+    ``requests`` they say how many frames a socket read and how many
+    replies a write carried in that second."""
+    clock = spans.StageClock(spans.SERVICE_STAGES, ring_seconds=600)
+    assert {"reads", "writes"} < set(clock.STAMPS)
+    base = int(time.monotonic()) + 10
+    clock.requests, clock.reads, clock.writes = 40, 30, 10
+    clock.stamp(base + 0.0)
+    clock.requests, clock.reads, clock.writes = 100, 50, 22
+    clock.stamp(base + 1.0)
+    clock.reads = 51
+    seconds = clock.export()["seconds"]
+    first, second = seconds[str(base)], seconds[str(base + 1)]
+    assert (first["requests"], first["reads"], first["writes"]) == (60, 20, 12)
+    assert (second["reads"], second["writes"]) == (1, 0)
+    assert isinstance(first["reads"], int) and isinstance(first["writes"], int)
+
+
+def test_one_launch_that_answers_a_connection_writes_to_it_once(tmp_path):
+    """Over the socket: ten requests of one connection, pipelined; each of
+    the first three wakes a launch slot and goes alone, the seven behind
+    them ride one launch and their replies one write - so the ring reads
+    ``requests / writes`` > 1, and ``requests / reads`` > 1 where a read
+    held several frames.  Every request still books ``service_decode`` and
+    ``service_reply_wait`` once."""
+    backend = SleepingBackend(default=0.1)
+
+    async def scenario(server):
+        server.PIPELINE_DEPTH = 16  # (of this server alone)
+        frames = [_verify_frame(i + 1, 2, _records(2, i)) for i in range(10)]
+        await asyncio.to_thread(_pipelined, server.socket_path, frames)
+        return server.stages.export(), server.stages.launches
+
+    report, launches = asyncio.run(_serve(tmp_path, backend, scenario))
+    sums, requests, _ = _ring_sums(report)
+    seconds = report["seconds"].values()
+    reads = sum(entry.get("reads", 0) for entry in seconds)
+    writes = sum(entry.get("writes", 0) for entry in seconds)
+    assert requests == 10 and launches == backend.calls
+    assert 1 <= writes <= launches < requests
+    assert 1 <= reads < requests
+    assert sums["service_decode"][0] == sums["service_reply_wait"][0] == 10
 
 
 def test_the_service_counts_launches_and_shares_cpu(tmp_path):

@@ -871,6 +871,57 @@ class _RawConn:
         self.sock.close()
 
 
+class _Wire:
+    """A transport that only records, for a ``_Connection`` that a test
+    drives by hand, on the loop: what ``data_received`` is given is one
+    socket read."""
+
+    def __init__(self) -> None:
+        self.writes = []  # one entry a write call
+        self.reading = True
+        self.closed = False
+
+    def get_extra_info(self, name):
+        return None
+
+    def write(self, data) -> None:
+        self.writes.append(bytes(data))
+
+    def pause_reading(self) -> None:
+        self.reading = False
+
+    def resume_reading(self) -> None:
+        self.reading = True
+
+    def close(self) -> None:
+        self.closed = True
+
+    def frames(self):
+        """[(type, payload)] of everything written so far."""
+        blob, out, at = b"".join(self.writes), [], 0
+        while at < len(blob):
+            length, type_ = struct.unpack_from("<IB", blob, at)
+            out.append((type_, blob[at + 5: at + 5 + length]))
+            at += 5 + length
+        return out
+
+    def results(self):
+        """[(req_id, verdict bits)] of the RESULT frames written so far."""
+        from mysticeti_tpu.verifier_service import T_RESULT
+
+        return [(struct.unpack_from("<I", payload)[0], list(payload[4:]))
+                for type_, payload in self.frames() if type_ == T_RESULT]
+
+
+def _by_hand(server):
+    """A connection of ``server`` on a ``_Wire``: the hand-over seam."""
+    from mysticeti_tpu.verifier_service import _Connection
+
+    conn, wire = _Connection(server), _Wire()
+    conn.connection_made(wire)
+    return conn, wire
+
+
 async def _until(condition, what, timeout=20.0):
     deadline = time.monotonic() + timeout
     while not condition():
@@ -1104,8 +1155,6 @@ def test_a_request_that_finds_a_slot_asleep_is_launched_alone(
     not ride together on the first slot to wake: each wakes a slot of its
     own and goes alone; only the one that finds them all promised waits,
     and then rides with nothing either."""
-    from mysticeti_tpu.verifier_service import T_VERIFY
-
     keys = [s.public_key.bytes for s in signers]
     backend = GatedBackend()
     slots = VerifierServer.DISPATCHERS
@@ -1115,18 +1164,16 @@ def test_a_request_that_finds_a_slot_asleep_is_launched_alone(
         warm.close()
         await _until(lambda: server._idle == slots, "every slot asleep")
         backend.close_gate()
-        loop = asyncio.get_running_loop()
         sizes = [2 + i for i in range(slots + 1)]
-        futures = []
-        for i, n in enumerate(sizes):  # no await: the loop keeps the GIL
-            frame = _verify_frame(i, keys, _indexed(n, signers, b"w%d" % i))
-            futures.append(server._submit(
-                loop, T_VERIFY, i, n, memoryview(frame)[13:], "test", None))
+        conn, wire = _by_hand(server)
+        conn.data_received(b"".join(  # one read: one hand-over
+            _verify_frame(i, keys, _indexed(n, signers, b"w%d" % i))
+            for i, n in enumerate(sizes)))
         await _until(lambda: backend.waiting == slots, "a slot each")
         assert len(server._pending) == 1 and not server._pending[0].alone
         backend.gate.set()
-        replies = await asyncio.gather(*futures)
-        assert [len(parts[1]) for _, parts, _ in replies] == sizes
+        await _until(lambda: len(wire.results()) == len(sizes), "answered")
+        assert wire.results() == [(i, [1] * n) for i, n in enumerate(sizes)]
         assert sorted(backend.sizes) == sizes
         assert server._promised == 0
 
@@ -1139,8 +1186,6 @@ def test_with_a_queue_in_the_service_a_woken_slot_takes_all_that_is_pending(
     than it has slots.  While one launch carries five requests, two more
     handed over back to back wake a sleeping slot and ride together: a
     queue is draining, and sharing launches is how."""
-    from mysticeti_tpu.verifier_service import T_VERIFY
-
     keys = [s.public_key.bytes for s in signers]
     backend = GatedBackend()
 
@@ -1158,15 +1203,15 @@ def test_with_a_queue_in_the_service_a_woken_slot_takes_all_that_is_pending(
         await _until(lambda: 10 in backend.sizes, "the five ride one launch")
         await _until(lambda: server._idle == len(plugs) - 1, "others asleep")
         assert server._in_service == 5
-        loop = asyncio.get_running_loop()
-        futures = []
-        for i, n in enumerate((3, 4)):  # no await: the loop keeps the GIL
-            frame = _verify_frame(i, keys, _indexed(n, signers, b"l%d" % i))
-            futures.append(server._submit(
-                loop, T_VERIFY, i, n, memoryview(frame)[13:], "test", None))
-        assert not any(item.alone for item in server._pending)
-        replies = await asyncio.gather(*futures)
-        assert [len(parts[1]) for _, parts, _ in replies] == [3, 4]
+        conn, wire = _by_hand(server)
+        conn.data_received(b"".join(  # one read: one hand-over
+            _verify_frame(i, keys, _indexed(n, signers, b"l%d" % i))
+            for i, n in enumerate((3, 4))))
+        await asyncio.sleep(0)  # handed over when the loop's turn is over
+        assert [item.alone for item in server._pending] == [False, False]
+        await _until(lambda: len(wire.results()) == 2, "answered")
+        assert wire.results() == [(0, [1] * 3), (1, [1] * 4)]
+        assert len(wire.writes) == 1  # and their replies in one write
         assert backend.sizes.count(7) == 1  # together, on one woken slot
         for conn in plugs + [queue]:
             conn.close()
@@ -1308,3 +1353,401 @@ def test_the_pending_list_loses_and_doubles_nothing_under_contention(
         asyncio.run(_with_server(tmp_path, keys, backend, scenario))
     finally:
         sys.setswitchinterval(interval)
+
+
+# ---------------------------------------------------------------------------
+# The loop works a read and a launch, never a request.
+
+
+def _connection_of(server, before):
+    """The one ``_Connection`` that joined ``server`` since ``before``."""
+    (conn,) = server._conns - before
+    return conn
+
+
+def test_eight_frames_in_one_sendall_are_handed_over_from_fewer_reads(
+        tmp_path, signers):
+    """A read hands over every frame it holds: eight frames that left the
+    client in one ``sendall`` cost the loop fewer than eight socket reads,
+    and their replies come back in request order."""
+    keys = [s.public_key.bytes for s in signers]
+
+    async def scenario(server):
+        conn = await asyncio.to_thread(_RawConn, server, keys)
+        reads = server.stages.reads
+        conn.send(*(_verify_frame(i, keys, _indexed(1 + i, signers, b"e%d" % i))
+                    for i in range(8)))
+        replies = [await asyncio.to_thread(conn.read) for _ in range(8)]
+        conn.close()
+        assert replies == [(i, [1] * (1 + i)) for i in range(8)]
+        assert 1 <= server.stages.reads - reads < 8
+        assert server.stages.requests == 8
+
+    asyncio.run(_with_server(tmp_path, keys, CountingBackend(), scenario))
+
+
+def test_a_frame_fed_a_byte_at_a_time_decodes_the_same(tmp_path, signers):
+    """Where a read ends is nothing to the decoder: three frames (a VERIFY,
+    a RAW, a VERIFY with a corrupted signature) fed whole, a byte a read,
+    and cut in the middle of a header and of a body give the same replies,
+    and a read that completes no frame hands nothing over."""
+    keys = [s.public_key.bytes for s in signers]
+    items = _indexed(3, signers, b"bytewise")
+    bad = list(items)
+    bad[1] = (bad[1][0], bad[1][1], bytes([bad[1][2][0] ^ 1]) + bad[1][2][1:])
+    blob = (_verify_frame(1, keys, items)
+            + _raw_frame(2, [(keys[idx], d, s) for idx, d, s in items])
+            + _verify_frame(3, keys, bad))
+    expected = [(1, [1, 1, 1]), (2, [1, 1, 1]), (3, [1, 0, 1])]
+    first = len(_verify_frame(1, keys, items))
+
+    async def scenario(server):
+        feeds = [
+            [blob],
+            [blob[i: i + 1] for i in range(len(blob))],
+            [blob[:first + 2], blob[first + 2: first + 40], blob[first + 40:]],
+        ]
+        for feed, counted in zip(feeds, (1, 3, 2)):
+            reads = server.stages.reads
+            conn, wire = _by_hand(server)
+            for data in feed:
+                conn.data_received(data)
+            await _until(lambda: len(wire.results()) == 3, "three replies")
+            assert wire.results() == expected, len(feed)
+            # Only a read that held the end of a request counts.
+            assert server.stages.reads - reads == counted
+            assert conn.partial is None and not conn.slots
+
+    asyncio.run(_with_server(tmp_path, keys, CountingBackend(), scenario))
+
+
+def test_the_replies_of_one_launch_to_one_connection_leave_in_one_write(
+        tmp_path, signers):
+    """Three requests of one connection and two of another wait behind the
+    plugs and ride one launch: each connection gets its replies in one
+    write, and ``writes`` counts one a connection."""
+    keys = [s.public_key.bytes for s in signers]
+    backend = GatedBackend()
+
+    async def scenario(server):
+        warm = await asyncio.to_thread(_RawConn, server, keys)
+        warm.close()
+        plugs = await _plug_the_slots(server, backend, keys, signers)
+        (one, wire_one), (two, wire_two) = _by_hand(server), _by_hand(server)
+        one.data_received(b"".join(
+            _verify_frame(i, keys, _indexed(2, signers, b"one%d" % i))
+            for i in range(3)))
+        two.data_received(b"".join(
+            _verify_frame(i, keys, _indexed(1, signers, b"two%d" % i))
+            for i in range(2)))
+        assert not server._pending  # the turn of the loop is not over
+        await asyncio.sleep(0)
+        assert len(server._pending) == 5  # both reads, handed over at once
+        writes, requests = server.stages.writes, server.stages.requests
+        backend.gate.set()
+        await _until(lambda: len(wire_two.results()) == 2, "answered")
+        assert wire_one.results() == [(i, [1, 1]) for i in range(3)]
+        assert wire_two.results() == [(i, [1]) for i in range(2)]
+        assert len(wire_one.writes) == len(wire_two.writes) == 1
+        for conn in plugs:
+            assert (await asyncio.to_thread(conn.read))[1] == [1]
+            conn.close()
+        assert backend.sizes.count(8) == 1
+        assert server.stages.writes - writes == len(plugs) + 2
+        assert server.stages.requests - requests == len(plugs) + 5
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
+
+
+def test_a_connection_owed_pipeline_depth_replies_is_not_read(
+        tmp_path, signers):
+    """With the backend's gate shut a client sends fifty frames: the
+    service takes PIPELINE_DEPTH of them and reads no further (the rest
+    wait in the connection and the socket); once the gate opens all fifty
+    are answered, in order."""
+    from mysticeti_tpu.metrics import Metrics
+
+    keys = [s.public_key.bytes for s in signers]
+    backend = GatedBackend()
+    metrics = Metrics()
+    depth = VerifierServer.PIPELINE_DEPTH
+
+    async def scenario():
+        server = VerifierServer(
+            str(tmp_path / "verifier.sock"), committee_keys=keys,
+            backend=backend, metrics=metrics,
+        )
+        await server.start()
+        try:
+            conn = await asyncio.to_thread(_RawConn, server, keys)
+            backend.close_gate()
+            conn.send(*(
+                _verify_frame(i, keys, _indexed(1 + i % 3, signers, b"d%d" % i))
+                for i in range(50)))
+            gauge = metrics.verifier_service_queue_depth._value.get
+            await _until(lambda: gauge() == depth, "a window of requests")
+            (served,) = server._conns
+            for _ in range(20):  # and it stays there
+                await asyncio.sleep(0.005)
+                assert server._in_service == depth == len(served.slots)
+                assert not served.transport.is_reading()
+            backend.gate.set()
+            replies = [await asyncio.to_thread(conn.read) for _ in range(50)]
+            assert replies == [(i, [1] * (1 + i % 3)) for i in range(50)]
+            await _until(served.transport.is_reading, "read on")
+            assert gauge() == 0 and served.held is None
+            conn.close()
+        finally:
+            await server.stop()
+
+    asyncio.run(scenario())
+
+
+def test_a_client_that_never_reads_stops_being_read_while_another_is_served(
+        tmp_path, signers):
+    """A client that sends and does not read its replies fills its socket:
+    the service stops reading it (its memory stays bounded) and goes on
+    serving another connection; when the client reads at last, every
+    request it sent is answered, in order, none dropped."""
+    import socket as _socket
+
+    keys = [s.public_key.bytes for s in signers]
+
+    class Echo(SignatureVerifier):
+        def verify_signatures(self, public_keys, digests, signatures):
+            return [s[0] % 2 == 0 for s in signatures]
+
+    n, frames = 200, 150
+    requests = [
+        _verify_frame(i, keys, [(0, bytes(32), bytes([(i + k) % 2]) + bytes(63))
+                                for k in range(n)])
+        for i in range(frames)]
+
+    async def scenario(server):
+        other = await asyncio.to_thread(_RawConn, server, keys)
+        before = set(server._conns)
+        silent = await asyncio.to_thread(_RawConn, server, keys)
+        served = _connection_of(server, before)
+        # A small pipe from the service to this client, so that a few
+        # dozen replies fill it.
+        served.transport.get_extra_info("socket").setsockopt(
+            _socket.SOL_SOCKET, _socket.SO_SNDBUF, 4096)
+        served.transport.set_write_buffer_limits(high=2048)
+        sender = threading.Thread(
+            target=silent.sock.sendall, args=(b"".join(requests),))
+        sender.start()
+        await _until(lambda: served.write_paused, "the client's pipe is full")
+        await _until(lambda: not served.transport.is_reading(), "and it is not read")
+        answered = server.stages.requests
+        assert answered < frames
+        for i in range(3):  # the other connection is served meanwhile
+            other.send(_verify_frame(
+                i, keys, [(0, bytes(32), bytes([k]) + bytes(63))
+                          for k in range(3)]))
+            assert await asyncio.to_thread(other.read) == (i, [1, 0, 1])
+        assert len(served.slots) <= server.PIPELINE_DEPTH
+        assert server.stages.requests <= answered + 3 + server.PIPELINE_DEPTH
+        for i in range(frames):  # the client reads at last
+            assert await asyncio.to_thread(silent.read) == (
+                i, [(i + k + 1) % 2 for k in range(n)]), i
+        await asyncio.to_thread(sender.join, 10)
+        assert not sender.is_alive()
+        assert not served.write_paused
+        other.close()
+        silent.close()
+
+    asyncio.run(_with_server(tmp_path, keys, Echo(), scenario))
+
+
+def _malformed_frames():
+    from mysticeti_tpu.verifier_service import T_HELLO, T_VERIFY, _frame
+
+    return {
+        "verify": (_frame(T_VERIFY, struct.pack("<II", 9, 2) + bytes(98)),
+                   b"malformed verify frame"),
+        "short": (_frame(T_VERIFY, b"\x01\x02\x03"),
+                  b"malformed verify frame"),
+        "hello": (_frame(T_HELLO, struct.pack("<H", 3) + bytes(64)),
+                  b"malformed hello frame"),
+        "unknown": (_frame(77, b"what"), b"unknown frame type"),
+    }
+
+
+@pytest.mark.parametrize("kind", ["verify", "short", "hello", "unknown"])
+def test_valid_frames_then_a_malformed_one(tmp_path, signers, kind):
+    """Two valid frames, a frame that is none of the protocol's and a valid
+    one behind it, in one read: nothing is written while the two ride
+    their launch; then their replies, T_ERR, and the connection is closed.
+    What came behind the malformed frame is never launched."""
+    from mysticeti_tpu.verifier_service import T_ERR, T_RESULT
+
+    keys = [s.public_key.bytes for s in signers]
+    backend = GatedBackend()
+    malformed, message = _malformed_frames()[kind]
+
+    async def scenario(server):
+        warm = await asyncio.to_thread(_RawConn, server, keys)
+        warm.close()
+        plugs = await _plug_the_slots(server, backend, keys, signers)
+        conn, wire = _by_hand(server)
+        conn.data_received(
+            _verify_frame(1, keys, _indexed(2, signers, b"v1"))
+            + _verify_frame(2, keys, _indexed(3, signers, b"v2"))
+            + malformed
+            + _verify_frame(3, keys, _indexed(1, signers, b"never")))
+        await asyncio.sleep(0)
+        assert len(server._pending) == 2 and not wire.reading
+        assert wire.writes == [] and not wire.closed
+        backend.gate.set()
+        await _until(lambda: wire.closed, "closed")
+        assert wire.frames() == [
+            (T_RESULT, struct.pack("<I", 1) + b"\x01\x01"),
+            (T_RESULT, struct.pack("<I", 2) + b"\x01\x01\x01"),
+            (T_ERR, message),
+        ]
+        assert conn not in server._conns and not server._pending
+        for plug in plugs:
+            assert (await asyncio.to_thread(plug.read))[1] == [1]
+            plug.close()
+        assert sorted(backend.sizes) == [1] * len(plugs) + [5]
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
+
+
+@pytest.mark.parametrize("accepted", [True, False])
+def test_a_hello_between_requests_keeps_its_place(tmp_path, signers, accepted):
+    """VERIFY, HELLO, VERIFY in one read.  The request before the HELLO
+    does not wait for it; HELLO_OK leaves after that request's reply and
+    before the next one's.  A HELLO that is refused (another committee)
+    answers what came before it, then T_ERR, and closes: what came behind
+    it is dropped unlaunched and leaves the gauges."""
+    from mysticeti_tpu.metrics import Metrics
+    from mysticeti_tpu.verifier_service import (
+        T_ERR, T_HELLO, T_HELLO_OK, T_RESULT, _frame)
+
+    keys = [s.public_key.bytes for s in signers]
+    hello_keys = keys if accepted else keys[::-1]
+    hello = _frame(T_HELLO, struct.pack("<H", len(keys)) + b"".join(hello_keys))
+    backend = CountingBackend()
+    metrics = Metrics()
+
+    async def scenario():
+        server = VerifierServer(
+            str(tmp_path / "verifier.sock"), committee_keys=keys,
+            backend=backend, metrics=metrics,
+        )
+        await server.start()
+        try:
+            warm = await asyncio.to_thread(_RawConn, server, keys)
+            warm.close()
+            calls = backend.calls
+            conn, wire = _by_hand(server)
+            conn.data_received(
+                _verify_frame(1, keys, _indexed(2, signers, b"h1")) + hello
+                + _verify_frame(2, keys, _indexed(1, signers, b"h2")))
+            if accepted:
+                await _until(lambda: len(wire.frames()) == 3, "three replies")
+                assert [t for t, _ in wire.frames()] == [
+                    T_RESULT, T_HELLO_OK, T_RESULT]
+                assert wire.results() == [(1, [1, 1]), (2, [1])]
+                assert not wire.closed and backend.calls == calls + 2
+            else:
+                await _until(lambda: wire.closed, "closed")
+                assert [t for t, _ in wire.frames()] == [T_RESULT, T_ERR]
+                assert wire.results() == [(1, [1, 1])]
+                assert backend.calls == calls + 1
+            assert metrics.verifier_service_queue_depth._value.get() == 0
+        finally:
+            await server.stop()
+
+    asyncio.run(scenario())
+
+
+def test_a_request_wider_than_a_launch_comes_back_whole(tmp_path, signers):
+    """A request of 3 x cap + r signatures is cut into four pieces that
+    ride launches of their own widths; its one reply holds every verdict in
+    order, with the one corrupted signature of each piece rejected, and is
+    written once, when the last piece lands."""
+    keys = [s.public_key.bytes for s in signers]
+    backend = GatedBackend()
+    oracle = CpuSignatureVerifier()
+
+    async def scenario(server):
+        warm = await asyncio.to_thread(_RawConn, server, keys)
+        warm.close()
+        cap = server._launch_cap
+        n = 3 * cap + 17
+        items = _indexed(n, signers, b"wide")
+        for at in (5, cap + 7, 2 * cap + 9, 3 * cap + 3):  # one a piece
+            idx, d, s = items[at]
+            items[at] = (idx, d, s[:10] + bytes([s[10] ^ 4]) + s[11:])
+        expected = [int(ok) for ok in oracle.verify_signatures(
+            [keys[idx] for idx, _, _ in items],
+            [d for _, d, _ in items], [s for _, _, s in items])]
+        assert expected.count(0) == 4
+        backend.close_gate()
+        conn, wire = _by_hand(server)
+        conn.data_received(
+            _verify_frame(7, keys, items)
+            + _verify_frame(8, keys, _indexed(2, signers, b"behind")))
+        assert len(conn.slots) == 2 and conn.slots[0].waiting == 4
+        writes = server.stages.writes
+        backend.gate.set()
+        await _until(lambda: len(wire.results()) == 2, "both answered")
+        assert wire.results() == [(7, expected), (8, [1, 1])]
+        assert sum(backend.sizes) == n + 2 and max(backend.sizes) <= cap
+        assert backend.sizes.count(cap) == 3
+        assert 1 <= server.stages.writes - writes <= 2
+        assert server.stages.requests == 2 and server._in_service == 0
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
+
+
+def test_a_connection_lost_under_a_launch_gives_its_gauges_back_after_it(
+        tmp_path, signers):
+    """A connection is reset with three requests pending behind the plugs:
+    the gauges keep showing the three until the launch that carries them
+    ends (the device still works for them), then they come back, and the
+    connection's label goes with the last."""
+    from mysticeti_tpu.metrics import Metrics
+
+    keys = [s.public_key.bytes for s in signers]
+    backend = GatedBackend()
+    metrics = Metrics()
+
+    async def scenario():
+        server = VerifierServer(
+            str(tmp_path / "verifier.sock"), committee_keys=keys,
+            backend=backend, metrics=metrics,
+        )
+        await server.start()
+        try:
+            warm = await asyncio.to_thread(_RawConn, server, keys)
+            warm.close()
+            plugs = await _plug_the_slots(server, backend, keys, signers)
+            before = set(server._conns)
+            conn = await asyncio.to_thread(_RawConn, server, keys)
+            served = _connection_of(server, before)
+            conn.send(*(
+                _verify_frame(i, keys, _indexed(2, signers, b"g%d" % i))
+                for i in range(3)))
+            gauge = metrics.verifier_service_queue_depth._value.get
+            await _until(lambda: gauge() == len(plugs) + 3, "three more")
+            served.transport.abort()
+            await _until(lambda: served.lost, "the service saw it go")
+            assert await asyncio.to_thread(conn.read) is None
+            conn.close()
+            label = 'verifier_service_inflight{connection="%s"} ' % served.label
+            assert gauge() == len(plugs) + 3
+            assert label + "3.0" in metrics.expose().decode()
+            backend.gate.set()
+            await _until(lambda: gauge() == 0, "the launches ended")
+            assert label not in metrics.expose().decode()
+            assert served.counted == 0 and server._in_service == 0
+            for plug in plugs:
+                assert (await asyncio.to_thread(plug.read))[1] == [1]
+                plug.close()
+        finally:
+            await server.stop()
+
+    asyncio.run(scenario())
