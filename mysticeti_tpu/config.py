@@ -231,8 +231,16 @@ class Parameters:
     synchronizer: SynchronizerParameters = field(default_factory=SynchronizerParameters)
     ingress: IngressParameters = field(default_factory=IngressParameters)
     network_connection_max_latency_s: float = 5.0
+    # Injected link delay (network.py: DelayLine; docs/fault-injection.md):
+    # the N x N table of one-way delays in milliseconds, row = sender,
+    # column = receiver.  Every frame validator ``a`` sends validator ``b``
+    # over the real-socket mesh is held for ``link_delay_ms[a][b]`` before it
+    # reaches the socket.  Empty (the default): nothing is held and the mesh
+    # runs the loop it always ran.
+    link_delay_ms: List[List[float]] = field(default_factory=list)
 
     def __post_init__(self) -> None:
+        self._check_link_delays()
         if self.enable_cleanup is not None:
             self.storage.enable_cleanup = bool(self.enable_cleanup)
         if self.store_retain_rounds is not None:
@@ -266,6 +274,27 @@ class Parameters:
         if timeout > 0:
             overrides["leader_timeout_s"] = timeout
         return cls(identifiers=identifiers, **overrides)
+
+    def _check_link_delays(self) -> None:
+        table = self.link_delay_ms
+        if not table:
+            return
+        n = len(self.identifiers) or len(table)
+        if len(table) != n or any(len(row) != n for row in table):
+            raise ValueError(
+                f"link_delay_ms must be {n} x {n}, one row and one column a "
+                "validator"
+            )
+        if any(not delay >= 0 for row in table for delay in row):
+            raise ValueError("link_delay_ms holds a negative delay")
+
+    def link_delays_s(self, authority: int) -> Optional[List[float]]:
+        """The one-way delays, in seconds, from ``authority`` to each
+        validator: its row of ``link_delay_ms``; None where no table is
+        configured."""
+        if not self.link_delay_ms:
+            return None
+        return [ms / 1e3 for ms in self.link_delay_ms[authority]]
 
     def address(self, authority: int) -> Tuple[str, int]:
         ident = self.identifiers[authority]

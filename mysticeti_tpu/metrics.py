@@ -382,6 +382,22 @@ class Metrics:
             "send queue was full (backpressure; previously silent)",
             labels=("peer",),
         )
+        # Injected link delay (network.py: DelayLine), where
+        # ``Parameters.link_delay_ms`` holds a table; absent without one.
+        self.mesh_delayed_frames_total = counter(
+            "mesh_delayed_frames_total",
+            "mesh frames (Ping and Pong among them) that left for this "
+            "peer through the link's delay line: held from their hand-over "
+            "to the connection for the configured one-way delay",
+            labels=("peer",),
+        )
+        self.mesh_link_delay_seconds = gauge(
+            "mesh_link_delay_seconds",
+            "the configured one-way delay of the link to this peer "
+            "(Parameters.link_delay_ms, this validator's row); no series "
+            "where no table is configured",
+            labels=("peer",),
+        )
 
         # TPU verifier.
         self.verified_signatures_total = counter(
@@ -469,9 +485,11 @@ class Metrics:
             "wall seconds of a received batch of blocks in receive (decode "
             "+ dedup + structure), verify (collector window + the round "
             "trip to the verifier) and dag_add (core-task queue + "
-            "insertion); and of a gateway submission in admit_verify (its "
+            "insertion); of a gateway submission in admit_verify (its "
             "signatures' round trip to the verifier, where signatures are "
-            "required)",
+            "required); and of a mesh frame in mesh_hold (handed to the "
+            "connection -> written to the socket, where a link delay is "
+            "injected)",
         )
         r.register(self.block_stages)
         # Staged dispatch pipeline (verify_pipeline.py): the collector may

@@ -56,6 +56,7 @@ import sys
 import time
 from typing import Dict, List, Optional, Tuple
 
+from .runtime import now as runtime_now
 from .serde import Reader, SerdeError, Writer
 from .tracing import logger
 from .utils.tasks import spawn_logged
@@ -598,22 +599,77 @@ def _is_urgent(msg: NetworkMessage) -> bool:
     return type(msg) is Ping or type(msg) is Pong
 
 
+class DelayLine:
+    """The injected one-way delay of one directed link
+    (``Parameters.link_delay_ms``; docs/fault-injection.md, "Injected link
+    delay").
+
+    A frame is stamped when the protocol hands it to the connection
+    (``Connection.send`` / ``try_send``) and reaches the socket no earlier
+    than ``due(stamp)`` = stamp + delay: a write loop that is late does not
+    add the delay a second time.  The connection's send queue holds the
+    stamped frames in hand-over order and Ping/Pong take their turn in it
+    (no urgent lane round the line), so order on a link is kept.
+    ``written`` books what left: one ``mesh_hold`` sample a frame
+    (``block_stage_seconds``, handed over -> written) and
+    ``mesh_delayed_frames_total{peer}``."""
+
+    __slots__ = ("delay_s", "clock", "_stages", "_frames")
+
+    def __init__(self, delay_s: float, clock=runtime_now, stages=None,
+                 frames=None) -> None:
+        self.delay_s = float(delay_s)
+        self.clock = clock
+        self._stages = stages  # spans.StageClock(("mesh_hold",)) or None
+        self._frames = frames  # mesh_delayed_frames_total{peer} or None
+
+    def due(self, stamp: float) -> float:
+        return stamp + self.delay_s
+
+    async def wait(self, stamp: float) -> None:
+        """Until the frame stamped ``stamp`` is due: one timer, at the due
+        time itself (a loop may fire a timer a clock resolution early, so
+        the clock is read again)."""
+        due = self.due(stamp)
+        while self.clock() < due:
+            loop = asyncio.get_running_loop()
+            woken = loop.create_future()
+            timer = loop.call_at(due, woken.set_result, None)
+            try:
+                await woken
+            finally:
+                timer.cancel()
+
+    def written(self, stamps: List[float]) -> None:
+        if self._frames is not None:
+            self._frames.inc(len(stamps))
+        if self._stages is not None:
+            end = self.clock()
+            book = self._stages.book
+            for stamp in stamps:
+                book("mesh_hold", end, end - stamp)
+
+
 class Connection:
     """One live peer link: outgoing via ``send``, incoming via ``receiver``.
 
     The transport (TCP worker or simulated link) feeds ``receiver`` and drains
     the internal send queue; when either side drops, the connection closes and
     the owning worker establishes a fresh Connection object (network.rs:195-242
-    Worker semantics).
+    Worker semantics).  With a ``delay_line`` (a real-socket link under an
+    injected delay) every message is queued as ``(hand-over stamp, message)``
+    and none jumps the queue.
     """
 
-    def __init__(self, peer: int, latency_getter=None, metrics=None) -> None:
+    def __init__(self, peer: int, latency_getter=None, metrics=None,
+                 delay_line: Optional[DelayLine] = None) -> None:
         self.peer = peer
         self.sender: asyncio.Queue = _SendQueue(maxsize=1024)
         self.receiver: asyncio.Queue = asyncio.Queue(maxsize=1024)
         self._closed = asyncio.Event()
         self._latency_getter = latency_getter
         self.metrics = metrics
+        self.delay_line = delay_line
 
     def try_send(self, msg: NetworkMessage) -> bool:
         """Non-blocking send; drops (returns False) when the peer is slow —
@@ -623,7 +679,9 @@ class Connection:
         one that never sent them)."""
         if self.is_closed():
             return False
-        if _is_urgent(msg):
+        if self.delay_line is not None:
+            msg = (self.delay_line.clock(), msg)
+        elif _is_urgent(msg):
             if self.sender.put_front_nowait(msg):
                 return True
             self._count_drop()
@@ -643,6 +701,11 @@ class Connection:
 
     async def send(self, msg: NetworkMessage) -> None:
         if self.is_closed():
+            return
+        if self.delay_line is not None:
+            # Stamped now, not when a full queue lets it in: the hold runs
+            # from the hand-over.
+            await self.sender.put((self.delay_line.clock(), msg))
             return
         if _is_urgent(msg):
             # Ping/Pong jump the queue AND never block behind a full one —
@@ -979,6 +1042,51 @@ class _FrameReceiver(asyncio.BufferedProtocol):
                     pass
 
 
+async def _held_write_loop(conn: Connection, writer, encode_timer,
+                           sent_bytes=None, coalesced=None) -> None:
+    """The write loop of a link under an injected delay: the coalescing
+    loop of ``TcpNetwork._run_peer``, but a frame leaves only when its
+    ``DelayLine`` says it is due.  One timer a link, set for the head of the
+    queue; every frame that is due when it fires leaves in the same
+    ``writelines`` (byte-capped as ever), so a burst handed over together
+    waits one delay, not one each.  The frames that are not yet due stay in
+    the connection's bounded send queue, which therefore holds a delay's
+    worth of traffic."""
+    line, sender = conn.delay_line, conn.sender
+    stamp, msg = await sender.get()
+    while True:
+        await line.wait(stamp)
+        now = line.clock()
+        parts: List[bytes] = []
+        stamps: List[float] = []
+        total = 0
+        held = None
+        with encode_timer("net:mesh_encode"):
+            while True:
+                payload = frame_payload(msg)
+                parts.append(len(payload).to_bytes(4, "little"))
+                parts.append(payload)
+                stamps.append(stamp)
+                total += 4 + len(payload)
+                if total >= MAX_COALESCE_BYTES:
+                    break
+                try:
+                    stamp, msg = sender.get_nowait()
+                except asyncio.QueueEmpty:
+                    break
+                if line.due(stamp) > now:
+                    held = (stamp, msg)
+                    break
+        writer.writelines(parts)
+        line.written(stamps)
+        if sent_bytes is not None:
+            sent_bytes.inc(total)
+        if coalesced is not None and len(stamps) > 1:
+            coalesced.inc(len(stamps) - 1)
+        await writer.drain()
+        stamp, msg = held if held is not None else await sender.get()
+
+
 class TcpNetwork:
     """Full-mesh TCP among the committee (network.rs:48-292).
 
@@ -992,12 +1100,26 @@ class TcpNetwork:
         addresses: List[Tuple[str, int]],
         metrics=None,
         max_latency_s: float = 5.0,
+        link_delays_s: Optional[List[float]] = None,
     ) -> None:
         self.authority = authority
         self.addresses = addresses
         self.connections: asyncio.Queue = asyncio.Queue()
         self.metrics = metrics
         self.max_latency_s = max_latency_s
+        # This validator's row of ``Parameters.link_delay_ms``, in seconds:
+        # the one-way delay to each peer.  None: no frame is held.
+        self.link_delays_s = link_delays_s
+        self._hold_stages = None
+        if link_delays_s is not None and metrics is not None:
+            from . import spans
+
+            self._hold_stages = spans.StageClock(("mesh_hold",))
+            metrics.block_stages.attach(self._hold_stages)
+            for peer, delay_s in enumerate(link_delays_s):
+                if peer != authority:
+                    metrics.mesh_link_delay_seconds.labels(str(peer)).set(
+                        delay_s)
         self._latency: Dict[int, float] = {}
         self._server: Optional[asyncio.AbstractServer] = None
         self._tasks: List[asyncio.Task] = []
@@ -1075,11 +1197,21 @@ class TcpNetwork:
 
     # -- shared read/write/ping loops --
 
+    def _delay_line(self, peer: int) -> Optional[DelayLine]:
+        if self.link_delays_s is None:
+            return None
+        frames = None
+        if self.metrics is not None:
+            frames = self.metrics.mesh_delayed_frames_total.labels(str(peer))
+        return DelayLine(self.link_delays_s[peer], stages=self._hold_stages,
+                         frames=frames)
+
     async def _run_peer(self, peer: int, reader, writer) -> None:
         conn = Connection(
             peer,
             latency_getter=lambda p=peer: self._latency.get(p, float("inf")),
             metrics=self.metrics,
+            delay_line=self._delay_line(peer),
         )
         await self.connections.put(conn)
         legacy = mesh_legacy()
@@ -1163,6 +1295,9 @@ class TcpNetwork:
                 if metrics is not None
                 else (lambda _name: contextlib.nullcontext())
             )
+            if conn.delay_line is not None:
+                await _held_write_loop(conn, writer, encode_timer,
+                                       sent_bytes, coalesced)
             if legacy:
                 # Pre-r10 path: one encode + one concat + one drain PER
                 # frame.  The encode timer runs here too so the A/B

@@ -68,15 +68,23 @@ WAN_INTER_RANGE = (0.080, 0.160)
 
 def wan_latency_ranges(
     regions: List[int],
+    link_delay_ms: Optional[List[List[float]]] = None,
 ) -> Dict[Tuple[int, int], Tuple[float, float]]:
     """Per-directed-link latency ranges from a region assignment (node ->
     region index): intra-region links draw from WAN_INTRA_RANGE, cross-
-    region from WAN_INTER_RANGE."""
+    region from WAN_INTER_RANGE.  Where ``link_delay_ms`` is given — the
+    table of ``Parameters.link_delay_ms``, one row and one column a node —
+    every link has that constant delay instead, so "WAN" is the same thing
+    in the simulator and on the real-socket mesh (network.py: DelayLine)."""
     n = len(regions)
     out: Dict[Tuple[int, int], Tuple[float, float]] = {}
     for a in range(n):
         for b in range(n):
             if a == b:
+                continue
+            if link_delay_ms:
+                delay_s = link_delay_ms[a][b] / 1e3
+                out[(a, b)] = (delay_s, delay_s)
                 continue
             out[(a, b)] = (
                 WAN_INTRA_RANGE if regions[a] == regions[b] else WAN_INTER_RANGE
@@ -142,6 +150,9 @@ class Scenario:
     leader_timeout_s: float = 0.5
     # Geo profile: region index per node (() = uniform sim default).
     regions: Tuple[int, ...] = ()
+    # With ``regions``: the table of ``Parameters.link_delay_ms`` (rows of
+    # one-way milliseconds), in place of the two ranges.
+    link_delay_ms: Tuple[Tuple[float, ...], ...] = ()
     # Uniform link profile: one-way latency range for EVERY directed link
     # (None = the sim default 50-100 ms).  The default's ±33% jitter is far
     # above real WAN links; stable-link scenarios pin e.g. (0.08, 0.10) so
@@ -245,7 +256,8 @@ class Scenario:
 
     def latency_ranges(self):
         if self.regions:
-            return wan_latency_ranges(list(self.regions))
+            return wan_latency_ranges(
+                list(self.regions), [list(r) for r in self.link_delay_ms])
         if self.latency is not None:
             return {
                 (a, b): tuple(self.latency)
@@ -272,6 +284,8 @@ class Scenario:
             "new_version_nodes": list(self.new_version_nodes),
             "plan": self.plan().to_dict(),
         }
+        if self.link_delay_ms:
+            out["link_delay_ms"] = [list(r) for r in self.link_delay_ms]
         if self.reconfig:
             # Emitted only for reconfig scenarios so frozen-committee
             # verdict documents stay byte-identical.

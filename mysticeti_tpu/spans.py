@@ -87,6 +87,11 @@ STAGES = (
     # The gateway's check of a submission's signatures (ingress.py; always
     # on through a StageClock of its own, where signatures are required).
     "admit_verify",
+    # A mesh frame under an injected link delay (network.py: DelayLine;
+    # always on through a StageClock of its own, where
+    # ``Parameters.link_delay_ms`` holds a table): handed to the connection
+    # -> written to the socket, one sample a frame.
+    "mesh_hold",
     # The verifier service's stages of one VERIFY/RAW request
     # (verifier_service.py, ops/ed25519.py; SERVICE_STAGES below): always on
     # through StageClock, and spans keyed by (connection, req_id) when a

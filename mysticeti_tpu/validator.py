@@ -403,6 +403,7 @@ class Validator:
                 parameters.all_network_addresses(),
                 metrics=v.metrics,
                 max_latency_s=parameters.network_connection_max_latency_s,
+                link_delays_s=parameters.link_delays_s(authority),
             )
         v.network_syncer = NetworkSyncer(
             core,
@@ -531,6 +532,7 @@ class Validator:
                 parameters.all_network_addresses(),
                 metrics=v.metrics,
                 max_latency_s=parameters.network_connection_max_latency_s,
+                link_delays_s=parameters.link_delays_s(authority),
             )
         recorder = v._make_recorder(authority, lifecycle, observer)
         core.block_store.recorder = recorder
