@@ -112,6 +112,9 @@ GUARDED_FIELDS: Dict[str, str] = {
     # out/in from any executor thread; the live-connection count must move
     # with the deque under one lock or the bound drifts.
     "_pool_size": "_pool_lock",
+    # ... and the connection its VERIFY frames share: of two threads that
+    # connected at once, one connection is kept.
+    "_shared": "_pool_lock",
     # Flight-recorder event ring (flight_recorder.py): appended from the
     # loop thread while the metrics endpoint / a signal path snapshots it —
     # any reassignment (resize, swap) must happen under the ring lock.

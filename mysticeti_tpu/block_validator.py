@@ -1275,9 +1275,10 @@ class BatchedSignatureVerifier(BlockVerifier):
                         # Flush task cancelled mid-submit (node shutdown):
                         # the shielded executor job still runs and its
                         # handle may hold per-dispatch backend state (a
-                        # pooled service connection, the breaker's exclusive
-                        # probe flag) that only the fetch normally releases
-                        # — dispose it the moment it lands.
+                        # pooled service connection or its place on the
+                        # shared one, the breaker's exclusive probe flag)
+                        # that only the fetch normally releases — dispose
+                        # it the moment it lands.
                         submit_fut.add_done_callback(_abandon_dispatch)
                         raise
                     device_done = time.monotonic()
@@ -1298,10 +1299,10 @@ class BatchedSignatureVerifier(BlockVerifier):
                     # The fetch hop is shielded for the same reason the
                     # submit hop is: an unshielded cancel can cancel a
                     # QUEUED executor job before it starts, and then nothing
-                    # ever consumes the handle (pooled connection, probe
-                    # flag).  Shielded, the job always runs; result() does
-                    # its own cleanup, so cancellation here needs only to
-                    # observe the orphaned outcome.
+                    # ever consumes the handle (its service connection,
+                    # probe flag).  Shielded, the job always runs; result()
+                    # does its own cleanup, so cancellation here needs only
+                    # to observe the orphaned outcome.
                     fetch_fut = loop.run_in_executor(
                         None, self._fetch_dispatch, handle, len(sigs)
                     )

@@ -564,6 +564,14 @@ class Metrics:
             "verifier_reconnect_total",
             "verifier-service client connections torn down and retried",
         )
+        self.verifier_client_requests_total = counter(
+            "verifier_client_requests_total",
+            "requests this client sent the verifier service, by the road "
+            "they took: the connection its committee-signature requests "
+            "share, a pooled connection of the request's own, or the "
+            "calling thread's (the blocking path and every re-run)",
+            labels=("path",),
+        )
 
         # Fleet health plane (health.py): consensus-level health signals
         # derived from state the node already has, refreshed by the

@@ -85,7 +85,11 @@ from .block_validator import (
 )
 from .hostattr import LoopLagProbe
 from .network import jittered_backoff
-from .verify_pipeline import CompletedDispatch, DeferredDispatch
+from .verify_pipeline import (
+    CompletedDispatch,
+    DeferredDispatch,
+    VerifyPipeline,
+)
 from .tracing import logger
 
 log = logger(__name__)
@@ -517,10 +521,10 @@ class _Connection(asyncio.Protocol):
         """Write the run of finished slots at the head of ``slots`` with
         one ``write``: every reply a launch finished for this connection,
         and whatever waited behind them for their turn.  (One bytes object
-        a reply and one a run: a reply is a dozen bytes, and the clients
-        of today keep one request a connection, so a run is mostly one
-        reply — ``send`` of one buffer, where ``writelines`` of a header,
-        an id and the verdicts cost the loop a tenth more a request.)"""
+        a reply and one a run: a reply is a dozen bytes and a run at most
+        the few a client keeps in flight on its shared connection —
+        ``send`` of one buffer, where ``writelines`` of a header, an id and
+        the verdicts cost the loop a tenth more a request.)"""
         slots = self.slots
         if self.lost or not slots or slots[0].frame is None:
             return
@@ -1162,10 +1166,24 @@ class RemoteSignatureVerifier(SignatureVerifier):
     """Validator-side stub: forwards batches to the host's verifier service.
 
     jax-free by design — the validator process stays import-light and leans
-    on the service's single warmed runtime.  Called from the batching
-    collector's executor threads: each thread keeps its own connection
-    (``threading.local``) so concurrent flushes pipeline through the service
-    rather than serializing on one socket.
+    on the service's single warmed runtime.  Three roads to it, and the
+    request itself chooses (no option):
+
+    * ``verify_signatures`` (the gateway's transfer check, every deferred
+      fallback) keeps one connection a calling thread (``threading.local``),
+      one request on it at a time, and owns the reconnect-retry budget.
+    * ``verify_signatures_async`` sends a request whose signers are all in
+      the committee table (a VERIFY frame: short, never cut, answered by
+      the launch that takes it) down the ONE connection every such request
+      of this client shares (:class:`_SharedConnection`), without waiting
+      for the replies owed on it: the service finds several frames in a
+      read and writes several replies at once.
+    * A request with any other signer (a RAW frame: a collector window of
+      blocks full of signed transfers, hundreds of signatures wide and cut
+      across launches by the service) keeps a pooled connection of its own.
+      The service answers a connection strictly in request order, so down
+      the shared socket a handful of committee signatures would wait behind
+      every piece of the wide request sent before it.
     """
 
     backend_label = "tpu-remote"
@@ -1178,8 +1196,11 @@ class RemoteSignatureVerifier(SignatureVerifier):
     RETRY_BASE_BACKOFF_S = 0.05
     RETRY_MAX_BACKOFF_S = 1.0
 
-    # Bound on idle pooled connections for the async dispatch path; matches
-    # the deepest pipeline window the collector runs (verify_pipeline.py).
+    # Bound on the pooled connections of the async dispatch path: one a
+    # request in flight that may not ride the shared connection (a RAW
+    # frame, or a VERIFY frame beyond the shared connection's depth);
+    # matches the deepest pipeline window the collector runs
+    # (verify_pipeline.py).
     MAX_POOLED_CONNS = 4
 
     def __init__(self, socket_path: Optional[str] = None,
@@ -1203,6 +1224,9 @@ class RemoteSignatureVerifier(SignatureVerifier):
         self._pool_conns: List[socket.socket] = []
         self._pool_lock = threading.Lock()
         self._pool_size = 0
+        # The connection the VERIFY frames of the staged path share; made
+        # at the first of them and again after it was lost.
+        self._shared: Optional[_SharedConnection] = None
         self._async_req_ids = itertools.count(1)
         # (fixed_dispatch_s, per_sig_s) as measured by the SERVICE on its
         # own warmed backend (HELLO_OK payload); None until first connect.
@@ -1251,6 +1275,13 @@ class RemoteSignatureVerifier(SignatureVerifier):
     def _count_wire(self, direction: str, nbytes: int) -> None:
         if self.metrics is not None:
             self.metrics.verify_wire_bytes_total.labels(direction).inc(nbytes)
+
+    def _count_request(self, path: str) -> None:
+        """One request sent down ``path`` (``shared`` / ``pooled`` /
+        ``sync``); one that is re-run or deferred counts again, as
+        ``sync``."""
+        if self.metrics is not None:
+            self.metrics.verifier_client_requests_total.labels(path).inc()
 
     def _wire(self, attr: str) -> _WireBuffer:
         """Per-thread reusable buffer, one per direction: ``pack`` must stay
@@ -1374,6 +1405,32 @@ class RemoteSignatureVerifier(SignatureVerifier):
         except OSError:
             pass
 
+    # -- the shared connection (async dispatch path, VERIFY frames) --
+
+    def _send_shared(self, frame, req_id, n, args):
+        """``frame`` down the shared connection and its handle, or None
+        where that connection already owes as many replies as a validator's
+        verify pipeline goes deep (the caller takes a pooled connection:
+        nothing here waits for the service to read).  The connect, and its
+        wait for HELLO_OK, runs outside every lock — threads that find the
+        service silent wait side by side, as the sync path's do — and of
+        two that raced the first connection is kept."""
+        shared = self._shared
+        if shared is None or shared.lost:
+            fresh = _SharedConnection(self._connect(), self.metrics)
+            with self._pool_lock:
+                shared = self._shared
+                if shared is None or shared.lost:
+                    shared = self._shared = fresh
+            if shared is not fresh:
+                fresh.sock.close()
+        handle = _SharedDispatch(self, shared, req_id, n, args)
+        if not shared.send(handle, frame):
+            return None
+        self._count_wire("sent", len(frame))
+        self._count_request("shared")
+        return handle
+
     # -- frame building --
 
     def _pack_request(self, public_keys, digests, signatures, req_id, n):
@@ -1423,12 +1480,16 @@ class RemoteSignatureVerifier(SignatureVerifier):
         self._conn()
 
     def verify_signatures_async(self, public_keys, digests, signatures):
-        """Staged dispatch: send the request now (on a pooled connection the
-        handle carries — submit and fetch may run on different executor
-        threads) and read the reply at ``result()``.  With the service's own
-        per-connection request pipeline, several of these overlap through
-        ONE warmed backend.  A send failure here falls back to the deferred
-        sync path, which owns the full reconnect-retry budget."""
+        """Staged dispatch: send the request now and read the reply at
+        ``result()`` — submit and fetch may run on different executor
+        threads, so the handle carries its connection.  A VERIFY frame
+        (every signer in the committee table) goes down the connection all
+        such requests of this client share, up to ``VerifyPipeline.
+        MAX_DEPTH`` owed on it; a RAW frame, and a VERIFY frame beyond that
+        depth, takes a pooled connection of its own.  Either way several
+        overlap through ONE warmed backend.  A connect or send that fails
+        falls back to the deferred sync path, which owns the full
+        reconnect-retry budget."""
         n = len(signatures)
         if n == 0:
             return CompletedDispatch([])
@@ -1442,12 +1503,19 @@ class RemoteSignatureVerifier(SignatureVerifier):
                 public_keys, digests, signatures,
             )
         try:
+            if frame[4] == T_VERIFY:
+                handle = self._send_shared(
+                    frame, req_id, n, (public_keys, digests, signatures))
+                if handle is not None:
+                    return handle
             conn = self._pool_checkout()
         except VerifierProtocolError:
             raise
         except (ConnectionError, OSError, socket.timeout):
-            # No reconnect count here: the deferred sync fallback runs the
-            # full retry loop and accounts each torn-down attempt itself.
+            # No reconnect count for a connect that failed: the deferred
+            # sync fallback runs the full retry loop and accounts each
+            # torn-down attempt itself.  (A send that failed on the shared
+            # connection took it down, once, with everything owed on it.)
             conn = None
         if conn is None:
             # Pool exhausted or unreachable: the sync path (thread-local
@@ -1465,6 +1533,7 @@ class RemoteSignatureVerifier(SignatureVerifier):
             return DeferredDispatch(
                 self.verify_signatures, public_keys, digests, signatures
             )
+        self._count_request("pooled")
         return _RemoteDispatch(
             self, conn, req_id, n, public_keys, digests, signatures
         )
@@ -1481,13 +1550,16 @@ class RemoteSignatureVerifier(SignatureVerifier):
             return CpuSignatureVerifier().verify_signatures(
                 public_keys, digests, signatures
             )
+        self._count_request("sync")
         oks = self._roundtrip(frame, req_id)
         assert len(oks) == n
         return [bool(b) for b in oks]
 
 
 class _RemoteDispatch:
-    """An in-flight request to the verifier service.
+    """An in-flight request to the verifier service on a pooled connection
+    of its own (a RAW frame, or a VERIFY frame the shared connection had no
+    room for).
 
     ``result()`` reads the reply off the handle's own connection and returns
     it to the pool.  A connection failure at fetch time is NOT fatal to the
@@ -1542,6 +1614,217 @@ class _RemoteDispatch:
         request on it would read a stale frame — so it is discarded, which
         also keeps the pool's live-connection count honest."""
         self._client._pool_discard(self._conn)
+
+
+_RERUN = object()  # the connection was lost under the request
+_DROPPED = object()  # abandoned: its reply is read in its turn and dropped
+
+
+class _SharedConnection:
+    """The one connection a client's VERIFY frames share: requests go down
+    it as they are submitted, ``owed`` holds their handles in the wire's
+    order, and the service answers in that order.
+
+    A handle's ``result()`` may run on any thread and in any order, so the
+    reads are serialized: ONE thread at a time is the reader (``reading``),
+    takes whatever the socket holds with one ``recv_into`` and gives every
+    complete frame of it, copied out of ``buf``, to the handle at the head
+    of ``owed`` — a client answered with one write reads its replies with
+    one ``recv``, and the next fetches find theirs filled — until its own
+    handle is answered.  A handle that finds another thread reading waits
+    on ``cond`` and returns the moment its own reply is filled, whoever
+    read it.  A connection that fails — send, read, timeout — or answers
+    ERR is torn down once (``lose``): every handle still owed a reply
+    re-runs through the sync path's bounded reconnect-retry at its own
+    ``result()``, and the client connects anew at its next request.
+
+    It holds no reference to its client, so a client that is dropped with
+    nothing in flight takes its socket with it."""
+
+    __slots__ = ("sock", "metrics", "cond", "owed", "reading", "lost",
+                 "buf", "end")
+
+    def __init__(self, sock: socket.socket, metrics) -> None:
+        self.sock = sock
+        self.metrics = metrics
+        self.cond = threading.Condition()
+        self.owed: collections.deque = collections.deque()
+        self.reading = False
+        self.lost = False
+        # The reader's alone: buf[:end] is received and not yet a whole
+        # frame.
+        self.buf = bytearray(4096)
+        self.end = 0
+
+    def send(self, handle: "_SharedDispatch", frame) -> bool:
+        """``frame`` onto the wire and ``handle`` to the tail of ``owed``,
+        under one lock: a handle's place in line is its frame's place on
+        the wire.  False, and nothing sent, where the connection owes the
+        deepest window a validator runs (under what the service reads of
+        one connection before it answers, ``VerifierServer.
+        PIPELINE_DEPTH``: the service is still reading and ``sendall`` does
+        not wait for it) or was just lost."""
+        with self.cond:
+            if self.lost or len(self.owed) >= VerifyPipeline.MAX_DEPTH:
+                return False
+            self.owed.append(handle)
+            try:
+                self.sock.sendall(frame)
+            except (ConnectionError, OSError, socket.timeout):
+                self.owed.pop()
+                self.lose()
+                raise
+        return True
+
+    def lose(self, count: bool = True) -> None:
+        """Tear the connection down, once: whatever is owed re-runs on the
+        sync path, and the teardown counts as one reconnect."""
+        with self.cond:
+            if self.lost:
+                return
+            self.lost = True
+            for handle in self.owed:
+                handle._fill(_RERUN)
+            self.owed.clear()
+            self.cond.notify_all()
+        try:
+            # A thread that still waits in ``recv_into`` (a send failed
+            # first) is woken by the shutdown, not by the close.
+            self.sock.shutdown(socket.SHUT_RDWR)
+        except OSError:
+            pass
+        try:
+            self.sock.close()
+        except OSError:
+            pass
+        if count and self.metrics is not None:
+            self.metrics.verifier_reconnect_total.inc()
+
+    def fetch(self, handle: "_SharedDispatch") -> None:
+        """Returns with ``handle`` filled, by this thread or by another."""
+        cond = self.cond
+        with cond:
+            while handle._reply is None and self.reading:
+                cond.wait()
+            if handle._reply is not None:
+                return
+            self.reading = True
+        try:
+            while handle._reply is None:
+                self._read()
+        except (ConnectionError, OSError, socket.timeout):
+            self.lose()
+        except BaseException:
+            self.lose()  # it may stand in the middle of a frame
+            raise
+        finally:
+            with cond:
+                self.reading = False
+                cond.notify_all()
+
+    def _read(self) -> None:
+        """One ``recv_into`` of whatever the socket holds, and every
+        complete frame of it to the handle it answers."""
+        buf, end = self.buf, self.end
+        if end == len(buf):  # one frame, wider than the buffer
+            self.buf = buf = buf + bytearray(len(buf))
+        got = self.sock.recv_into(memoryview(buf)[end:])
+        if got == 0:
+            raise ConnectionError("verifier service closed the connection")
+        if self.metrics is not None:
+            self.metrics.verify_wire_bytes_total.labels("recv").inc(got)
+        end += got
+        at = 0
+        with self.cond:
+            while end - at >= 5 and not self.lost:
+                length, type_ = _HEADER.unpack_from(buf, at)
+                if end - at - 5 < length:
+                    break
+                self._answer(type_, bytes(buf[at + 5: at + 5 + length]))
+                at += 5 + length
+        if 0 < at < end:  # a frame that spans reads: its head to the front
+            buf[: end - at] = buf[at:end]
+        self.end = end - at
+
+    def _answer(self, type_: int, payload: bytes) -> None:
+        """One frame to the handle it answers: the head of ``owed`` (with
+        ``cond`` held).  ``payload`` is a copy: ``buf`` is overwritten by
+        the next read, and a handle may fetch long after.  The service
+        closes behind an ERR, and a frame that is not the head's reply
+        leaves nothing to trust: either way the request at the head fails
+        alone and the rest re-run."""
+        if not self.owed:
+            raise ConnectionError(
+                "verifier service sent a reply nobody waits for")
+        head = self.owed.popleft()
+        if (type_ == T_RESULT and len(payload) == 4 + head._n
+                and struct.unpack_from("<I", payload)[0] == head._req_id):
+            head._fill(payload[4:])
+            self.cond.notify_all()
+            return
+        head._fill(
+            VerifierProtocolError(
+                "verifier service error: "
+                f"{payload.decode(errors='replace')}")
+            if type_ == T_ERR else
+            AssertionError("verifier service response out of order"))
+        self.lose(count=False)
+
+
+class _SharedDispatch:
+    """An in-flight request on the client's shared connection.
+
+    ``result()`` takes the reply from the connection (``_SharedConnection.
+    fetch``), whichever thread read it.  A connection failure is NOT fatal
+    to the batch: the whole request re-runs through the sync path's bounded
+    reconnect-retry budget (the service may have restarted mid-flight;
+    re-verifying is idempotent).  ``_reply``: None while owed; the verdict
+    bytes; the exception ``result()`` raises; ``_RERUN``; ``_DROPPED``."""
+
+    __slots__ = ("_client", "_conn", "_req_id", "_n", "_args", "_reply")
+
+    def __init__(self, client, conn, req_id, n, args) -> None:
+        self._client = client
+        self._conn = conn
+        self._req_id = req_id
+        self._n = n
+        self._args = args
+        self._reply = None
+
+    @property
+    def req_id(self) -> int:
+        """What the service's spans of this request carry too."""
+        return self._req_id
+
+    def _fill(self, reply) -> None:
+        """With the connection's condition held; the first to fill wins."""
+        if self._reply is None:
+            self._reply = reply
+
+    def result(self) -> List[bool]:
+        if self._reply is None:
+            self._conn.fetch(self)
+        reply = self._reply
+        if reply is _RERUN:
+            return self._client.verify_signatures(*self._args)
+        if isinstance(reply, BaseException):
+            raise reply
+        return [bool(b) for b in reply]
+
+    def abandon(self) -> None:
+        """Release without fetching (the flush was cancelled).  The
+        connection carries other requests and stays: this reply is read in
+        its turn, by whoever reads then, and dropped.  Where nobody is owed
+        anything else on it, nobody would read: it is discarded, as a
+        pooled connection with an unread reply is."""
+        conn = self._conn
+        with conn.cond:
+            if self._reply is not None:
+                return  # answered already, or lost with its connection
+            self._reply = _DROPPED
+            orphaned = all(h._reply is _DROPPED for h in conn.owed)
+        if orphaned:
+            conn.lose(count=False)
 
 
 def require_accelerator() -> str:

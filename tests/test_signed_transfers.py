@@ -357,6 +357,64 @@ def test_receipt_skips_what_the_own_gateway_verified(allocation):
     assert calls == [3, 3]
 
 
+def test_through_the_service_a_flush_with_transfers_keeps_a_connection_of_its_own(
+        allocation, tmp_path):
+    """What a validator of this deployment sends the verifier service, by
+    the road the request chooses (``verifier_client_requests_total``): a
+    collector flush that holds a transfer to check has a signer the
+    committee table lacks — a RAW frame, on a pooled connection of its
+    own; the gateway's check blocks on the calling thread's; only a flush
+    of committee signatures alone (a block without transfers, or whose
+    transfers the own gateway verified) goes down the shared pipelined
+    connection."""
+    from mysticeti_tpu.verifier_service import (
+        RemoteSignatureVerifier, VerifierServer)
+
+    metrics = Metrics()
+    plane, collector, _ = _plane(allocation, metrics=metrics)
+    committee = Committee.new_for_benchmarks(4)
+    keys = [committee.get_public_key(i).bytes for i in range(4)]
+    signers = Committee.benchmark_signers(4)
+    genesis = [StatementBlock.new_genesis(i).reference for i in range(4)]
+    txs = [_transfer(i, i + 1) for i in range(8)]
+
+    def roads():
+        series = metrics.verifier_client_requests_total
+        return [series.labels(path)._value.get()
+                for path in ("shared", "pooled", "sync")]
+
+    async def scenario():
+        server = VerifierServer(
+            str(tmp_path / "v.sock"), committee_keys=keys,
+            backend=CpuSignatureVerifier())
+        await server.start()
+        try:
+            remote = RemoteSignatureVerifier(
+                socket_path=server.socket_path, committee_keys=keys,
+                metrics=metrics)
+            collector.verifier = plane._tx_verifier = remote
+            full = StatementBlock.build(
+                1, 1, genesis, [Share(tx) for tx in txs[:5]],
+                signer=signers[1])
+            assert await collector.verify_blocks([full]) == [True]
+            assert roads() == [0, 1, 0]  # 1 + 5 signatures, one RAW frame
+            empty = StatementBlock.build(2, 1, genesis, [], signer=signers[2])
+            assert await collector.verify_blocks([empty]) == [True]
+            assert roads() == [1, 1, 0]
+            reply = await asyncio.to_thread(plane.submit, "c", txs[5:])
+            assert reply.accepted == 3
+            assert roads() == [1, 1, 1]  # the gateway's blocking check
+            own = StatementBlock.build(
+                3, 1, genesis, [Share(tx) for tx in txs[5:]],
+                signer=signers[3])
+            assert await collector.verify_blocks([own]) == [True]
+            assert roads() == [2, 1, 1]  # nothing left to check but its own
+        finally:
+            await server.stop()
+
+    asyncio.run(scenario())
+
+
 def test_without_an_ingress_plane_a_forged_transfer_is_rejected_on_receipt(
         allocation, tmp_path):
     """``signed_transactions`` on and no ingress plane anywhere (the

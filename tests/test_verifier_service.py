@@ -1751,3 +1751,478 @@ def test_a_connection_lost_under_a_launch_gives_its_gauges_back_after_it(
             await server.stop()
 
     asyncio.run(scenario())
+
+
+# ---------------------------------------------------------------------------
+# The client's shared connection: the staged requests whose signers are all
+# in the committee table (VERIFY frames) go down ONE pipelined connection;
+# RAW frames and the blocking path keep a connection each.
+
+
+def _marked(n, signers, tag, bad):
+    """``n`` signatures by committee signers, digests unique to ``tag``,
+    the ``bad``-th one zeroed: (public keys, digests, signatures) and the
+    verdicts they must get."""
+    pks, digests, sigs = [], [], []
+    for i in range(n):
+        signer = signers[i % len(signers)]
+        digest = crypto.blake2b_256(b"%s-%d" % (tag, i))
+        pks.append(signer.public_key.bytes)
+        digests.append(digest)
+        sigs.append(bytes(64) if i == bad else signer.sign(digest))
+    return (pks, digests, sigs), [i != bad for i in range(n)]
+
+
+def _outsiders(n, tag):
+    """``n`` valid signatures by signers no committee table holds."""
+    pks, digests, sigs = [], [], []
+    for i in range(n):
+        signer = crypto.Signer.from_seed((1000 + i).to_bytes(32, "little"))
+        digest = crypto.blake2b_256(b"%s-%d" % (tag, i))
+        pks.append(signer.public_key.bytes)
+        digests.append(digest)
+        sigs.append(signer.sign(digest))
+    return pks, digests, sigs
+
+
+def _path_counts(metrics):
+    series = metrics.verifier_client_requests_total
+    return {path: series.labels(path)._value.get()
+            for path in ("shared", "pooled", "sync")}
+
+
+async def _shared_client(server, keys, signers, metrics=None):
+    """A client of ``server`` whose shared connection is up (one request
+    answered on it), and that connection on the service's side."""
+    client = RemoteSignatureVerifier(
+        socket_path=server.socket_path, committee_keys=keys, metrics=metrics)
+    before = set(server._conns)
+    args, want = _marked(2, signers, b"warm", 1)
+    handle = await asyncio.to_thread(client.verify_signatures_async, *args)
+    assert await asyncio.to_thread(handle.result) == want
+    return client, _connection_of(server, before)
+
+
+def test_four_requests_in_flight_share_one_connection_and_one_read(
+        tmp_path, signers):
+    """Four VERIFY requests in flight from one client reach the service on
+    ONE connection, in one read where they were sent together, and the
+    ring reads more than one request a read; every byte either way is
+    counted."""
+    from mysticeti_tpu.metrics import Metrics
+    from mysticeti_tpu.verifier_service import _SharedDispatch
+
+    keys = [s.public_key.bytes for s in signers]
+    metrics = Metrics()
+
+    async def scenario(server):
+        client, served = await _shared_client(server, keys, signers, metrics)
+        stages = server.stages
+        reads, requests = stages.reads, stages.requests
+        batches = [_marked(2 + i, signers, b"four%d" % i, i) for i in range(4)]
+        # Sent from the loop's own thread: the service cannot read before
+        # all four are on the wire.
+        handles = [client.verify_signatures_async(*args)
+                   for args, _ in batches]
+        assert all(type(h) is _SharedDispatch for h in handles)
+        for handle, (_, want) in zip(handles, batches):
+            assert await asyncio.to_thread(handle.result) == want
+        assert server._conns == {served}
+        assert stages.reads == reads + 1
+        assert stages.requests == requests + 4
+        assert stages.requests / stages.reads > 1
+        assert _path_counts(metrics) == {
+            "shared": 5.0, "pooled": 0.0, "sync": 0.0}
+        wire = metrics.verify_wire_bytes_total
+        sent = sum(5 + 8 + 98 * (2 + i) for i in range(4)) + 5 + 8 + 98 * 2
+        hello = 5 + 2 + 32 * len(keys)
+        assert wire.labels("sent")._value.get() == sent + hello
+        replies = sum(5 + 4 + 2 + i for i in range(4)) + 5 + 4 + 2
+        assert wire.labels("recv")._value.get() >= replies + 5
+
+    asyncio.run(_with_server(tmp_path, keys, CountingBackend(), scenario))
+
+
+def test_results_fetched_newest_first_and_from_two_threads_are_each_their_own(
+        tmp_path, signers):
+    """``result()`` in any order and from any thread: a reply read on
+    behalf of another handle is kept for it — as a copy, so later reads
+    on the connection do not overwrite it — and the response-out-of-order
+    check never fires."""
+    keys = [s.public_key.bytes for s in signers]
+
+    async def scenario(server):
+        client, served = await _shared_client(server, keys, signers)
+
+        def newest_first():
+            batches = [_marked(3 + i, signers, b"nf%d" % i, i)
+                       for i in range(4)]
+            handles = [client.verify_signatures_async(*args)
+                       for args, _ in batches]
+            out = {3: handles[3].result()}  # reads the other three's too
+            later = [_marked(6, signers, b"later%d" % i, 5 - i)
+                     for i in range(2)]
+            for args, want in later:  # the read buffer is used again
+                assert client.verify_signatures_async(*args).result() == want
+            for i in (2, 1, 0):
+                out[i] = handles[i].result()
+            return [(out[i], batches[i][1]) for i in range(4)]
+
+        for got, want in await asyncio.to_thread(newest_first):
+            assert got == want
+
+        def two_threads():
+            batches = [_marked(2 + i, signers, b"tt%d" % i, i)
+                       for i in range(4)]
+            handles = [client.verify_signatures_async(*args)
+                       for args, _ in batches]
+            out = [None] * 4
+
+            def fetch(order):
+                for i in order:
+                    out[i] = handles[i].result()
+
+            threads = [threading.Thread(target=fetch, args=(order,))
+                       for order in ((3, 0), (1, 2))]
+            for thread in threads:
+                thread.start()
+            for thread in threads:
+                thread.join(30)
+                assert not thread.is_alive()
+            return [(out[i], batches[i][1]) for i in range(4)]
+
+        for _ in range(10):
+            for got, want in await asyncio.to_thread(two_threads):
+                assert got == want
+        assert server._conns == {served}
+
+    asyncio.run(_with_server(tmp_path, keys, CountingBackend(), scenario))
+
+
+def test_a_wide_raw_request_and_the_blocking_path_keep_connections_of_their_own(
+        tmp_path, signers):
+    """A RAW request (a signer the committee table lacks) and a blocking
+    ``verify_signatures`` issued meanwhile each use a connection of their
+    own: the short VERIFY request sent after the wide one, and the
+    blocking one, are answered while the wide one is still in its
+    launch."""
+    from mysticeti_tpu.metrics import Metrics
+    from mysticeti_tpu.verifier_service import _RemoteDispatch, _SharedDispatch
+
+    keys = [s.public_key.bytes for s in signers]
+    backend = GatedBackend()
+    metrics = Metrics()
+    slow = 1.5
+
+    async def scenario(server):
+        client, served = await _shared_client(server, keys, signers, metrics)
+        wide = _outsiders(40, b"wide")
+        backend.slow[wide[1][0]] = slow
+        short, short_want = _marked(3, signers, b"short", 1)
+        blocking, blocking_want = _marked(5, signers, b"blocking", 4)
+
+        def run():
+            started = time.monotonic()
+            h_wide = client.verify_signatures_async(*wide)
+            h_short = client.verify_signatures_async(*short)
+            assert type(h_wide) is _RemoteDispatch
+            assert type(h_short) is _SharedDispatch
+            got_short = h_short.result()
+            got_blocking = client.verify_signatures(*blocking)
+            ahead = time.monotonic() - started
+            return got_short, got_blocking, ahead, h_wide.result()
+
+        got_short, got_blocking, ahead, got_wide = await asyncio.to_thread(run)
+        assert got_short == short_want and got_blocking == blocking_want
+        assert got_wide == [True] * 40
+        assert ahead < slow * 0.8, ahead
+        assert len(server._conns) == 3 and served in server._conns
+        assert _path_counts(metrics) == {
+            "shared": 2.0, "pooled": 1.0, "sync": 1.0}
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
+
+
+def test_the_shared_connection_killed_with_three_owed_reruns_each_and_counts_once(
+        tmp_path, signers):
+    """The shared connection is reset with three requests owed on it: each
+    is answered through the blocking path's bounded retries, the teardown
+    counts one reconnect, and the next staged request finds a new shared
+    connection."""
+    from mysticeti_tpu.metrics import Metrics
+
+    keys = [s.public_key.bytes for s in signers]
+    backend = GatedBackend()
+    metrics = Metrics()
+
+    async def scenario(server):
+        client, served = await _shared_client(server, keys, signers, metrics)
+        backend.close_gate()
+        batches = [_marked(2 + i, signers, b"kill%d" % i, i) for i in range(3)]
+        handles = [client.verify_signatures_async(*args)
+                   for args, _ in batches]
+        await _until(lambda: backend.waiting == 3, "all three in launches")
+        served.transport.abort()
+        await _until(lambda: served.lost, "the service saw it go")
+        backend.gate.set()
+        # Newest first: the first to fetch finds the connection gone for
+        # all three.
+        for handle, (_, want) in reversed(list(zip(handles, batches))):
+            assert await asyncio.to_thread(handle.result) == want
+        assert metrics.verifier_reconnect_total._value.get() == 1.0
+        assert _path_counts(metrics) == {
+            "shared": 4.0, "pooled": 0.0, "sync": 3.0}
+        before = set(server._conns)
+        args, want = _marked(2, signers, b"after", 0)
+        handle = await asyncio.to_thread(client.verify_signatures_async, *args)
+        assert await asyncio.to_thread(handle.result) == want
+        assert _connection_of(server, before) is not served
+        assert _path_counts(metrics)["shared"] == 5.0
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
+
+
+def test_abandoning_the_middle_of_three_leaves_the_others_and_the_connection(
+        tmp_path, signers):
+    """``abandon()`` of the middle one of three owed: its reply is read in
+    its turn and dropped, the other two get their own verdicts, and the
+    connection stays for the requests after them; with nobody else owed
+    anything, an abandoned request's connection is discarded."""
+    keys = [s.public_key.bytes for s in signers]
+    backend = GatedBackend()
+
+    async def scenario(server):
+        client, served = await _shared_client(server, keys, signers)
+        shared = client._shared
+        backend.close_gate()
+        batches = [_marked(3 + i, signers, b"ab%d" % i, i) for i in range(3)]
+        handles = [client.verify_signatures_async(*args)
+                   for args, _ in batches]
+        handles[1].abandon()
+        backend.gate.set()
+        assert await asyncio.to_thread(handles[2].result) == batches[2][1]
+        assert await asyncio.to_thread(handles[0].result) == batches[0][1]
+        assert not shared.lost and not shared.owed
+        args, want = _marked(4, signers, b"next", 2)
+        handle = await asyncio.to_thread(client.verify_signatures_async, *args)
+        assert await asyncio.to_thread(handle.result) == want
+        assert client._shared is shared and server._conns == {served}
+        handle.abandon()  # answered already: nothing to release
+        assert not shared.lost
+        # Alone on the connection and abandoned: nobody would read the
+        # reply, so the connection goes, as a pooled one would.
+        backend.close_gate()
+        lonely = client.verify_signatures_async(*args)
+        lonely.abandon()
+        assert shared.lost
+        backend.gate.set()
+        await _until(lambda: served.lost, "the service saw it go")
+        handle = await asyncio.to_thread(client.verify_signatures_async, *args)
+        assert await asyncio.to_thread(handle.result) == want
+        assert client._shared is not shared
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
+
+
+def test_a_fifth_request_at_depth_four_takes_a_pooled_connection(
+        tmp_path, signers):
+    """With as many replies owed on the shared connection as a validator's
+    verify pipeline goes deep, the next request does not wait in
+    ``sendall`` for the service to read: it takes a pooled connection."""
+    from mysticeti_tpu.verifier_service import _RemoteDispatch, _SharedDispatch
+    from mysticeti_tpu.verify_pipeline import VerifyPipeline
+
+    keys = [s.public_key.bytes for s in signers]
+    backend = GatedBackend()
+    depth = VerifyPipeline.MAX_DEPTH
+    assert depth <= RemoteSignatureVerifier.MAX_POOLED_CONNS
+    assert depth < VerifierServer.PIPELINE_DEPTH
+
+    async def scenario(server):
+        client, served = await _shared_client(server, keys, signers)
+        backend.close_gate()
+        batches = [_marked(2 + i, signers, b"fifth%d" % i, i)
+                   for i in range(depth + 1)]
+        handles = [client.verify_signatures_async(*args)
+                   for args, _ in batches[:depth]]
+        assert all(type(h) is _SharedDispatch for h in handles)
+        assert len(client._shared.owed) == depth
+        started = time.monotonic()
+        handles.append(await asyncio.to_thread(
+            client.verify_signatures_async, *batches[depth][0]))
+        assert time.monotonic() - started < 5.0
+        assert type(handles[depth]) is _RemoteDispatch
+        assert len(server._conns) == 2 and served in server._conns
+        backend.gate.set()
+        for handle, (_, want) in zip(handles, batches):
+            assert await asyncio.to_thread(handle.result) == want
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
+
+
+def test_sixteen_threads_on_one_client_each_get_their_own_verdicts(
+        tmp_path, signers):
+    """More threads than cores submit and fetch through one client at
+    once, the interpreter switching threads every few microseconds: every
+    request — on the shared connection, on a pooled one where that is four
+    deep, deferred to the blocking path where the pool is out too — gets
+    its own verdicts, no reply is lost or given twice, and the shared
+    connection ends owing nothing."""
+    import sys
+
+    from mysticeti_tpu.metrics import Metrics
+
+    keys = [s.public_key.bytes for s in signers]
+    metrics = Metrics()
+    workers, rounds = 16, 25
+    batches = [_marked(2 + i % 5, signers, b"stress%d" % i, i % 2)
+               for i in range(10)]
+
+    async def scenario(server):
+        client, _served = await _shared_client(server, keys, signers, metrics)
+        wrong = []
+
+        def work(seed):
+            for i in range(rounds):
+                first = batches[(seed + i) % len(batches)]
+                second = batches[(seed + 3 * i + 1) % len(batches)]
+                h1 = client.verify_signatures_async(*first[0])
+                h2 = client.verify_signatures_async(*second[0])
+                # Newest first on odd rounds: replies are kept for others.
+                pairs = [(h1, first), (h2, second)]
+                for handle, (_, want) in (pairs if i % 2 else pairs[::-1]):
+                    if handle.result() != want:
+                        wrong.append((seed, i))
+
+        def run():
+            interval = sys.getswitchinterval()
+            sys.setswitchinterval(1e-5)
+            try:
+                threads = [threading.Thread(target=work, args=(seed,))
+                           for seed in range(workers)]
+                for thread in threads:
+                    thread.start()
+                for thread in threads:
+                    thread.join(120)
+                    assert not thread.is_alive()
+            finally:
+                sys.setswitchinterval(interval)
+
+        await asyncio.to_thread(run)
+        assert wrong == []
+        shared = client._shared
+        assert not shared.lost and not shared.owed and not shared.reading
+        assert client._pool_size <= client.MAX_POOLED_CONNS
+        counts = _path_counts(metrics)
+        assert sum(counts.values()) == 1 + workers * rounds * 2
+        assert counts["shared"] > 1
+        assert metrics.verifier_reconnect_total._value.get() == 0.0
+
+    asyncio.run(_with_server(tmp_path, keys, CountingBackend(), scenario))
+
+
+class _ScriptedService:
+    """A service of threads that answers HELLO with HELLO_OK and every
+    VERIFY with all-valid verdicts — but the first request that holds the
+    ``marked`` digest as ``how`` says: ``err`` (an ERR, then the connection
+    closed, as the service closes behind one) or ``swapped`` (a RESULT
+    under another request's id).  It answers no request before ``go`` is
+    set: the test has every frame on the wire by then, so the ERR cannot
+    close the connection under a send."""
+
+    def __init__(self, path, marked, how) -> None:
+        import socket as _socket
+
+        self.marked, self.how, self.fired = marked, how, False
+        self.go = threading.Event()
+        self.connections = 0
+        self.listener = _socket.socket(_socket.AF_UNIX, _socket.SOCK_STREAM)
+        self.listener.bind(path)
+        self.listener.listen(8)
+        threading.Thread(target=self._accept, daemon=True).start()
+
+    def _accept(self) -> None:
+        while True:
+            try:
+                conn, _ = self.listener.accept()
+            except OSError:
+                return
+            self.connections += 1
+            threading.Thread(
+                target=self._serve, args=(conn,), daemon=True).start()
+
+    @staticmethod
+    def _exactly(conn, n):
+        out = b""
+        while len(out) < n:
+            chunk = conn.recv(n - len(out))
+            if not chunk:
+                raise ConnectionError
+            out += chunk
+        return out
+
+    def _serve(self, conn) -> None:
+        from mysticeti_tpu.verifier_service import (
+            T_ERR, T_HELLO, T_HELLO_OK, T_RESULT, _frame)
+
+        try:
+            while True:
+                length, type_ = struct.unpack("<IB", self._exactly(conn, 5))
+                payload = self._exactly(conn, length)
+                if type_ == T_HELLO:
+                    conn.sendall(_frame(T_HELLO_OK, b""))
+                    continue
+                req_id, n = struct.unpack_from("<II", payload)
+                assert self.go.wait(30), "the test never set go"
+                if self.marked in payload and not self.fired:
+                    self.fired = True
+                    if self.how == "err":
+                        conn.sendall(_frame(T_ERR, b"malformed verify frame"))
+                        conn.close()
+                        return
+                    req_id += 1000
+                conn.sendall(_frame(
+                    T_RESULT, struct.pack("<I", req_id) + b"\x01" * n))
+        except (ConnectionError, OSError):
+            pass
+
+    def close(self) -> None:
+        self.listener.close()
+
+
+@pytest.mark.parametrize("how,raised", [
+    ("err", "VerifierProtocolError"), ("swapped", "AssertionError")])
+def test_an_err_or_a_reply_out_of_order_fails_one_request_of_three_alone(
+        tmp_path, signers, how, raised):
+    """ERR in place of the second of three replies on the shared
+    connection — or a RESULT that is not the second's — fails that request
+    and no other: the first has its verdicts, the third re-runs on the
+    blocking path.  Neither counts as a reconnect."""
+    from mysticeti_tpu.metrics import Metrics
+
+    keys = [s.public_key.bytes for s in signers]
+    metrics = Metrics()
+    batches = [_marked(2 + i, signers, b"err%d" % i, None) for i in range(3)]
+    service = _ScriptedService(
+        str(tmp_path / "scripted.sock"), batches[1][0][1][0], how)
+    try:
+        client = RemoteSignatureVerifier(
+            socket_path=str(tmp_path / "scripted.sock"), committee_keys=keys,
+            metrics=metrics, timeout_s=10.0)
+        handles = [client.verify_signatures_async(*args)
+                   for args, _ in batches]
+        service.go.set()
+        assert handles[2].result() == [True] * 4  # re-run, blocking path
+        assert handles[0].result() == [True] * 2
+        with pytest.raises(Exception) as caught:
+            handles[1].result()
+        assert type(caught.value).__name__ == raised
+        if how == "swapped":
+            assert "response out of order" in str(caught.value)
+        assert client._shared.lost
+        assert service.connections == 2  # the shared one, the re-run's
+        assert metrics.verifier_reconnect_total._value.get() == 0.0
+        assert _path_counts(metrics) == {
+            "shared": 3.0, "pooled": 0.0, "sync": 1.0}
+    finally:
+        service.close()

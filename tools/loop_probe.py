@@ -4,8 +4,10 @@
 The service as it runs (``VerifierServer`` on a unix socket, its dispatcher
 threads, stage clock and gauges) in front of a stub backend, under the
 traffic shape of ``service10-catchup``: ten client processes, four requests
-in flight each — every one on a pooled connection of its own, as
-``RemoteSignatureVerifier`` sends them — eight signatures a request.  A
+in flight each — all four down the client's one shared connection, as
+``RemoteSignatureVerifier`` sends requests by committee signers since PR 34
+(before it: every one on a pooled connection of its own, which
+``PYTHONPATH=<an older tree>`` still shows) — eight signatures a request.  A
 launch of the stub is ``--launch-cpu-ms`` of Python that holds the GIL (what
 packing and the jitted call cost the interpreter; 0.5 shows how many
 requests a launch gets when launches compete with the loop) and then
@@ -41,9 +43,11 @@ KEYS = [bytes([i + 1]) * 32 for i in range(10)]
 def client(path: str, depth: int, signatures: int, seconds: float) -> None:
     """A closed loop of ``depth`` requests in flight, sent as a validator
     and the benchmark's client send them: the program's own
-    ``RemoteSignatureVerifier.verify_signatures_async``, so each request in
-    flight has a pooled connection to itself, and the oldest is awaited
-    before the next is sent."""
+    ``RemoteSignatureVerifier.verify_signatures_async``, so the requests
+    in flight — by committee signers, as a validator's block signatures
+    are — share the client's one connection (the service may find several
+    frames in a read and write several replies at once), and the oldest is
+    awaited before the next is sent."""
     import collections
 
     from mysticeti_tpu.verifier_service import RemoteSignatureVerifier
