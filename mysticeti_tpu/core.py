@@ -483,7 +483,12 @@ class Core:
 
     def ready_new_block(self, period: int, connected_authorities: AuthoritySet) -> bool:
         """Leader-aware proposal gating (core.rs:401-450): propose when the previous
-        round's (connected) leaders have been received, or there are none."""
+        round's connected leaders have been received, or there are none.
+        ``connected_authorities`` is the set as it is now: this validator
+        and every peer whose connection has not closed (net_sync.py takes a
+        peer out when its connection task ends), so a dead leader's slot is
+        not waited for and a connected, silent one is, until the leader
+        timeout forces the proposal."""
         quorum_round = self.threshold_clock.get_round()
         if quorum_round <= max(self.last_decided_leader.round, period - 1):
             return False

@@ -199,6 +199,13 @@ class CoreTaskDispatcher:
             internal=True,
         )
 
+    async def try_new_block(self, connected: AuthoritySet) -> None:
+        # internal: a peer's connection closed (net_sync.py), so the set the
+        # proposal gate reads has shrunk; no remote peer's frame drives it.
+        return await self._call(
+            self.syncer.try_new_block, connected, internal=True
+        )
+
     async def cleanup(self) -> None:
         # internal: driven by the node's periodic task.  Routed through the
         # syncer so the observer's settled floor moves in the same owner

@@ -125,12 +125,22 @@ class NetworkSyncer:
     ) -> None:
         self.parameters = parameters or Parameters()
         self.signals = AsyncSignals()
+        # block_stage_seconds{stage}: what part of a round the verification
+        # path is — receive / verify / dag_add, one sample a batch — and
+        # what the proposal gate cost it — leader_wait, one sample a
+        # proposal (syncer.py); always on (the per-block spans of the first
+        # three names stay opt-in).
+        self._block_stages = None
+        if metrics is not None:
+            self._block_stages = spans.StageClock(spans.NODE_STAGES)
+            metrics.block_stages.attach(self._block_stages)
         self.syncer = Syncer(
             core,
             self.parameters.wave_length,
             self.signals,
             commit_observer,
             metrics,
+            stages=self._block_stages,
         )
         self.core = core
         self.network = network
@@ -155,13 +165,6 @@ class NetworkSyncer:
         # inert (inline path) under sims, without the extension, or for
         # small frames — see DataPlaneOffload.should_offload.
         self.dataplane_offload = DataPlaneOffload(metrics=metrics)
-        # block_stage_seconds{stage}: what part of a round the verification
-        # path is — receive / verify / dag_add, one sample a batch, always
-        # on (the per-block spans of the same names stay opt-in).
-        self._block_stages = None
-        if metrics is not None:
-            self._block_stages = spans.StageClock(spans.BLOCK_PATH_STAGES)
-            metrics.block_stages.attach(self._block_stages)
         # Bound once: _decode_fresh is per-incoming-frame hot.
         self._utilization_timer = (
             metrics.utilization_timer
@@ -218,6 +221,10 @@ class NetworkSyncer:
     def _record(self, kind: str, **fields) -> None:
         if self.recorder is not None:
             self.recorder.record(kind, **fields)
+
+    def _note_connections(self) -> None:
+        if self.metrics is not None:
+            self.metrics.connected_nodes.set(len(self.connections))
 
     # -- lifecycle --
 
@@ -313,12 +320,17 @@ class NetworkSyncer:
     VERIFY_PIPELINE_DEPTH = 32
 
     async def _connection_task(self, connection: Connection) -> None:
-        """net_sync.rs:237-312."""
+        """net_sync.rs:237-312.  A peer counts as connected, for the
+        proposal gate (``connected_authorities``) as for ``connections``,
+        from here until the connection that holds its slot in
+        ``connections`` ends: a second connection to the peer takes the
+        slot over, and the first one closing then takes nothing out."""
         peer = connection.peer
         log.debug("connection established with authority %d", peer)
         self._record("peer-connect", peer=peer)
         self.connections[peer] = connection
         self.connected_authorities.insert(peer)
+        self._note_connections()
         disseminator = BlockDisseminator(
             connection,
             self.core.block_store,
@@ -540,8 +552,14 @@ class NetworkSyncer:
             self.snapshot_blocks_served += disseminator.snapshot_blocks_sent
             self.snapshot_bytes_served += disseminator.snapshot_bytes_sent
             self._disseminators.pop(peer, None)
-            if self.connections.get(peer) is connection:
+            gone = self.connections.get(peer) is connection
+            if gone:
+                # Not where a reconnect that raced this teardown already
+                # holds the slot: that peer is connected.
                 del self.connections[peer]
+                if peer != self.core.authority:  # its own index stays in
+                    self.connected_authorities.remove(peer)
+                self._note_connections()
             connection.close()
             # Helper-stream hygiene: relays this peer ran for us died with
             # the connection, and the peer's own blocks now need a relay —
@@ -557,6 +575,14 @@ class NetworkSyncer:
                     live = self.connections.get(authority)
                     if live is None or live.is_closed():
                         self._ask_relays_for(authority)
+            if gone and not self._stopped.is_set():
+                # A proposal held for this peer's leader slot
+                # (core.ready_new_block waits for connected leaders only)
+                # goes out now, not at the leader timeout — which stays the
+                # backstop for a leader that is connected and silent.
+                await self.dispatcher.try_new_block(
+                    self.connected_authorities.copy()
+                )
 
     async def _handle_snapshot_response(
         self, connection: Connection, msg: SnapshotResponse

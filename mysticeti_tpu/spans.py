@@ -92,6 +92,12 @@ STAGES = (
     # ``Parameters.link_delay_ms`` holds a table): handed to the connection
     # -> written to the socket, one sample a frame.
     "mesh_hold",
+    # What the proposal gate cost a round (syncer.py; always on through the
+    # node's StageClock): the threshold clock reached round r + 1 (a quorum
+    # of round r is in the DAG) -> this validator's own proposal for it,
+    # one sample a proposal.  The previous round's leader arriving, its
+    # connection closing or the leader timeout ends it.
+    "leader_wait",
     # The verifier service's stages of one VERIFY/RAW request
     # (verifier_service.py, ops/ed25519.py; SERVICE_STAGES below): always on
     # through StageClock, and spans keyed by (connection, req_id) when a
@@ -487,6 +493,9 @@ SAMPLED_STAGES = SERVICE_STAGES[:8]
 # A validator's verification path, one sample a received batch of blocks
 # (net_sync.py): the always-on twins of the per-block spans of those names.
 BLOCK_PATH_STAGES = ("receive", "verify", "dag_add")
+# What the node's clock books (net_sync.py): that path, and the proposal
+# gate's wait, one sample a proposal (syncer.py).
+NODE_STAGES = BLOCK_PATH_STAGES + ("leader_wait",)
 # Stages in which a request waits (for a launch, the device, the loop, the
 # GIL): wall time only, no CPU clock and no profiler annotation —
 # the runtime's own events mark them in a trace already.

@@ -41,6 +41,7 @@ class Syncer:
         signals: SyncerSignals,
         commit_observer: CommitObserver,
         metrics=None,
+        stages=None,
     ) -> None:
         self.core = core
         self.force_new_block_flag = False
@@ -48,6 +49,11 @@ class Syncer:
         self.signals = signals
         self.commit_observer = commit_observer
         self.metrics = metrics
+        # block_stage_seconds{stage="leader_wait"}: when the threshold clock
+        # last advanced, until the proposal for that round goes out (the
+        # node's spans.StageClock; None = not clocked).
+        self.stages = stages
+        self._round_reached_at = None
 
     def add_blocks(
         self, blocks: Sequence[StatementBlock], connected_authorities: AuthoritySet
@@ -56,6 +62,8 @@ class Syncer:
         missing_references = self.core.add_blocks(blocks)
         new_round = self.core.current_round()
         if new_round > previous_round:
+            if self.stages is not None:
+                self._round_reached_at = spans.runtime_now()
             self.signals.new_round(new_round)
             if self.metrics is not None:
                 self.metrics.threshold_clock_round.set(new_round)
@@ -111,6 +119,9 @@ class Syncer:
         ):
             if self.core.try_new_block() is None:
                 return
+            if self._round_reached_at is not None:
+                self.stages.book_since("leader_wait", self._round_reached_at)
+                self._round_reached_at = None
             self.signals.new_block_ready()
             self.force_new_block_flag = False
 

@@ -258,6 +258,14 @@ class AuthoritySet:
         self.bits |= mask
         return True
 
+    def remove(self, authority: AuthorityIndex) -> bool:
+        """Returns False if it was not present."""
+        mask = 1 << authority
+        if not self.bits & mask:
+            return False
+        self.bits &= ~mask
+        return True
+
     def contains(self, authority: AuthorityIndex) -> bool:
         return bool(self.bits >> authority & 1)
 

@@ -100,6 +100,7 @@ def test_the_service_stages_are_registered_stage_names():
     assert set(spans.REQUEST_STAGES) < set(spans.SERVICE_STAGES)
     assert spans.WAITING_STAGES < set(spans.SERVICE_STAGES)
     assert set(spans.BLOCK_PATH_STAGES) <= set(PIPELINE_STAGES)
+    assert set(spans.NODE_STAGES) <= set(spans.STAGES)
     # A service request's span is labelled by (connection, req_id).
     assert format_ref(("c3", 17)) == "c3#17"
 
@@ -124,7 +125,8 @@ def test_a_stage_that_stands_alone_books_itself_and_its_span():
 
 def test_block_path_stages_are_always_on_and_virtual_under_the_sim(tmp_path):
     """Without any tracer a validator books receive / verify / dag_add per
-    received batch into ``block_stage_seconds``; under the simulator the
+    received batch, and leader_wait per proposal, into
+    ``block_stage_seconds``; under the simulator the
     clock is virtual, so two same-seed runs scrape identical text."""
     from prometheus_client import generate_latest
 
@@ -145,7 +147,7 @@ def test_block_path_stages_are_always_on_and_virtual_under_the_sim(tmp_path):
     assert first == second
     counts = {line.split('stage="')[1].split('"')[0]: float(line.split()[-1])
               for line in first if line.startswith("block_stage_seconds_count")}
-    assert set(counts) == set(spans.BLOCK_PATH_STAGES)
+    assert set(counts) == set(spans.NODE_STAGES)
     assert all(count > 10 for count in counts.values()), counts
     assert spans.active() is None
 

@@ -1982,6 +1982,71 @@ def test_the_shared_connection_killed_with_three_owed_reruns_each_and_counts_onc
     asyncio.run(_with_server(tmp_path, keys, backend, scenario))
 
 
+def test_three_clients_killed_with_replies_owed_leave_the_fourth_served(
+        tmp_path, signers):
+    """Three validators are SIGKILLed with requests owed on their shared
+    connections (the sockets close under the service's feet, nobody reads
+    the replies): the service lets the three connections go when the
+    launches that carry them end, gives their gauges back, and goes on
+    answering the client that is left, on the connection it had."""
+    import socket
+
+    from mysticeti_tpu.metrics import Metrics
+
+    keys = [s.public_key.bytes for s in signers]
+    backend = GatedBackend()
+    metrics = Metrics()
+
+    async def scenario():
+        server = VerifierServer(
+            str(tmp_path / "verifier.sock"), committee_keys=keys,
+            backend=backend, metrics=metrics,
+        )
+        await server.start()
+        try:
+            live, live_served = await _shared_client(server, keys, signers)
+            doomed = [await _shared_client(server, keys, signers)
+                      for _ in range(3)]
+            backend.close_gate()
+            owed = []
+            for i, (client, _) in enumerate(doomed):
+                for j in range(3):
+                    args, _ = _marked(2 + j, signers, b"d%d%d" % (i, j), j)
+                    owed.append(client.verify_signatures_async(*args))
+            args, want = _marked(5, signers, b"live", 3)
+            handle = live.verify_signatures_async(*args)
+            gauge = metrics.verifier_service_queue_depth._value.get
+            await _until(lambda: gauge() == 10, "ten requests handed over")
+            # What a SIGKILL does to a process's sockets: closed at once,
+            # whatever they were owed.
+            for client, _ in doomed:
+                sock = client._shared.sock
+                sock.shutdown(socket.SHUT_RDWR)
+                sock.close()
+            await _until(lambda: all(served.finishing or served.lost
+                                     for _, served in doomed),
+                         "the service saw the three go")
+            backend.gate.set()
+            assert await asyncio.to_thread(handle.result) == want
+            await _until(lambda: server._conns == {live_served},
+                         "the three connections are let go")
+            await _until(lambda: gauge() == 0, "the gauges came back")
+            assert server._in_service == 0
+            assert all(served.counted == 0 for _, served in doomed)
+            for i in range(4):
+                args, want = _marked(3 + i, signers, b"on%d" % i, i)
+                handle = await asyncio.to_thread(
+                    live.verify_signatures_async, *args)
+                assert await asyncio.to_thread(handle.result) == want
+            assert live._shared.sock.fileno() >= 0 and not live._shared.lost
+            assert server._conns == {live_served}
+            del owed  # never fetched: their clients are dead
+        finally:
+            await server.stop()
+
+    asyncio.run(scenario())
+
+
 def test_abandoning_the_middle_of_three_leaves_the_others_and_the_connection(
         tmp_path, signers):
     """``abandon()`` of the middle one of three owed: its reply is read in
