@@ -137,18 +137,21 @@ class PreciseHistogram:
 
 class StageSeries:
     """A ``<name>{stage}`` histogram (and a ``<cpu_name>{stage}`` counter,
-    and an ``<io_name>{direction}`` counter of the clock's ``reads`` and
-    ``writes``) rendered at scrape time from the stage clocks attached to
+    an ``<io_name>{direction}`` counter of the clock's ``reads`` and
+    ``writes``, and a ``<left_name>{left}`` counter of its launches by why
+    they left) rendered at scrape time from the stage clocks attached to
     it (``spans.StageClock``), summed where there are several: the clock is
     on the verifier service's per-request path and keeps plain arrays
     there, not one locked prometheus child a sample."""
 
     def __init__(self, name: str, doc: str, cpu_name: Optional[str] = None,
                  cpu_doc: str = "", io_name: Optional[str] = None,
-                 io_doc: str = "") -> None:
+                 io_doc: str = "", left_name: Optional[str] = None,
+                 left_doc: str = "") -> None:
         self.name, self.doc = name, doc
         self.cpu_name, self.cpu_doc = cpu_name, cpu_doc
         self.io_name, self.io_doc = io_name, io_doc
+        self.left_name, self.left_doc = left_name, left_doc
         self._clocks: list = []
 
     def attach(self, clock) -> None:
@@ -160,7 +163,12 @@ class StageSeries:
             HistogramMetricFamily,
         )
 
-        from .spans import SAMPLED_STAGES, STAGE_BUCKETS, WAITING_STAGES
+        from .spans import (
+            SAMPLED_STAGES,
+            STAGE_BUCKETS,
+            WAITING_STAGES,
+            StageClock,
+        )
 
         merged: Dict[str, dict] = {}
         for clock in self._clocks:
@@ -206,6 +214,14 @@ class StageSeries:
             io.add_metric(["read"], sum(c.reads for c in self._clocks))
             io.add_metric(["write"], sum(c.writes for c in self._clocks))
             yield io
+        if self.left_name:
+            left = CounterMetricFamily(
+                self.left_name, self.left_doc, labels=["left"]
+            )
+            for i, why in enumerate(StageClock.LEFT):
+                left.add_metric(
+                    [why], sum(c.left[i] for c in self._clocks))
+            yield left
 
 
 class Metrics:
@@ -476,6 +492,12 @@ class Metrics:
             "holds, a write carries every reply a launch finished for a "
             "connection, so requests / reads and requests / writes say how "
             "many a call carried",
+            "verifier_service_launches_total",
+            "verifier-service launches by why the coalescer let them leave: "
+            "alone (a request that found a slot asleep at a lightly loaded "
+            "service), full (what was pending filled the launch), drained "
+            "(no part-full launch was out), expired (one was, for longer "
+            "than twice a calibrated full launch)",
         )
         r.register(self.verifier_service_stages)
         # The same clock on a validator's verification path (net_sync.py):

@@ -684,7 +684,11 @@ class StageClock:
     # What ``stamp`` reads once a second, cumulative; the ring's seconds
     # hold the growth from one stamp to the next (whole numbers, but for
     # the seconds, ``*_s``).
-    STAMPS = ("requests", "signatures", "launches", "reads", "writes",
+    # Why a launch of the verifier service left when it did
+    # (``VerifierServer._take``): ``left`` counts them in this order.
+    LEFT = ("alone", "full", "drained", "expired")
+    STAMPS = ("requests", "signatures", "launches",
+              *("left_" + why for why in LEFT), "reads", "writes",
               "process_cpu_s", "threads_cpu_s", "loop_cpu_s")
 
     def __init__(self, stages: Sequence[str], ring_seconds: int = 0,
@@ -699,9 +703,12 @@ class StageClock:
         # (``requests / reads`` and ``requests / writes``: how many frames a
         # read and replies a write carried): plain sums of the one thread
         # that reads requests and writes replies (which also stamps).
+        # ``left``: the launches that left, by why (LEFT), counted where
+        # the dispatcher threads decide it, under the service's condition.
         self.requests = 0
         self.signatures = 0
         self.launches = 0
+        self.left = [0] * len(self.LEFT)
         self.reads = 0
         self.writes = 0
         self._slot = {name: i for i, name in enumerate(self.stages)}
@@ -812,8 +819,9 @@ class StageClock:
             except OSError:
                 pass
             threads += entry[2] - entry[1]
-        return (self.requests, self.signatures, self.launches, self.reads,
-                self.writes, time.process_time(), threads, time.thread_time())
+        return (self.requests, self.signatures, self.launches, *self.left,
+                self.reads, self.writes, time.process_time(), threads,
+                time.thread_time())
 
     def stamp(self, now: float) -> None:
         """In the first call of a whole second of ``now``, read STAMPS into
@@ -950,10 +958,12 @@ class StageClock:
         second that was stamped — STAMPS: ``requests`` and ``signatures``
         answered and the ``launches`` that answered them (one backend call
         carries every request that was pending when a dispatcher thread
-        came free), the socket ``reads`` that held a request and the
-        ``writes`` that held a reply (one read hands over every frame it
-        holds, one write carries every reply a launch finished for a
-        connection), and the CPU seconds the process (``process_cpu_s``),
+        came free and the coalescer let it go) and, as ``left_<why>``, the
+        launches that left by why they did (LEFT: counted as they leave,
+        ``launches`` as they land), the socket ``reads`` that held a
+        request and the ``writes`` that held a reply (one read hands over
+        every frame it holds, one write carries every reply a launch
+        finished for a connection), and the CPU seconds the process (``process_cpu_s``),
         the threads that book here (``threads_cpu_s``; left out where a
         thread's CPU clock cannot be read from outside it) and the stamping
         thread itself (``loop_cpu_s``) used, each from that second's stamp

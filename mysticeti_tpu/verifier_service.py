@@ -16,9 +16,11 @@ process, shared by every co-located validator.
     (one PJRT client, one compile cache, warmed once), serves signature
     batches over a unix-domain socket.  Requests from different validators
     share launches: a few dispatcher threads each take every request that
-    is pending when they come free, up to the bucket the backend warmed,
-    and verify them with ONE backend call (group commit: no timer, a
-    request that finds the service idle is launched at once, alone).
+    is pending when they are free, up to the bucket the backend warmed,
+    and verify them with ONE backend call (group commit: a request that
+    finds the service idle is launched at once, alone; while a queue
+    drains one part-full launch is out at a time and what arrives behind
+    it rides together when it lands, ``VerifierServer._take``).
   * :class:`RemoteSignatureVerifier` — the validator-side
     :class:`SignatureVerifier` that forwards batches to the service.  It
     never imports jax: a validator process using it boots import-light, and
@@ -107,6 +109,10 @@ _HEADER = struct.Struct("<IB")  # u32 payload_len | u8 type
 _REQUEST = struct.Struct("<II")  # u32 req_id | u32 n
 
 ENV_SOCKET = "MYSTICETI_VERIFIER_SOCKET"
+
+# Why a launch left (``VerifierServer._take``), as ``StageClock.left`` is
+# indexed.
+_ALONE, _FULL, _DRAINED, _EXPIRED = range(len(spans.StageClock.LEFT))
 
 # VerifierProtocolError (re-exported above from block_validator): the service
 # answered but REJECTED the request.  Excluded from the client's retry loop
@@ -615,16 +621,21 @@ class VerifierServer:
     # replies is not read (``_Connection``): the bound backpressures a
     # client pipelining faster than the backend drains.
     PIPELINE_DEPTH = 8
-    # Launch slots: each is a thread that takes everything pending when it
-    # comes free.  The fewer there are, the more requests share a launch
-    # and the fewer threads queue for the GIL with the loop: on the chip
-    # one slot verified a third more signatures a second than two or three
-    # (PERF.md, PR 25, has the pairs).  But with one slot launches run one
-    # after another, so a slow backend call makes the service stop-and-wait
-    # and holds up every connection, and with two a client that keeps four
-    # requests in flight (the deepest a validator's verify pipeline goes)
-    # finds two of them merged; with three each of its requests is launched
-    # at once and alone, as a lightly loaded service should.
+    # Launch slots: each is a thread that launches what is pending when it
+    # is free and the coalescer's rule (``_take``) lets it leave.  The
+    # fewer launches are out, the more requests share one and the fewer
+    # threads queue for the GIL with the loop: on the chip one slot
+    # verified a third more signatures a second than two or three (PERF.md,
+    # PR 25, has the pairs).  The rule collects that without giving up
+    # what the slots are for — at most ONE part-full launch is out at a
+    # time, and the other slots carry what must not wait for it: a request
+    # that goes alone (a client that keeps four requests in flight, the
+    # deepest a validator's verify pipeline goes, finds each launched at
+    # once and by itself at an otherwise idle service: three slots and one
+    # that waits its turn), a launch that is full (a wide request's pieces
+    # take every slot), and the launch after a hold has run out (a slow
+    # backend call holds the others up for two normal launches, not for
+    # its own length).
     DISPATCHERS = 3
 
     def __init__(self, socket_path: str, committee_keys: Optional[Sequence[bytes]] = None,
@@ -666,7 +677,12 @@ class VerifierServer:
         self._pending_cond = threading.Condition()
         self._arrived: List[_Pending] = []  # read this turn of the loop
         self._idle = 0  # dispatchers asleep on the condition
+        self._watching = 0  # of them, asleep until a hold runs out
         self._promised = 0  # pending requests that each woke one of them
+        # When the hold of each part-full launch that is out runs out: what
+        # is pending waits for the newest of them to land, or for that
+        # instant (``_take``).
+        self._part_full: List[float] = []
         self._in_service = 0  # handed over and not yet resolved (the loop's)
         self._stopping = False
         self._dispatchers: List[threading.Thread] = []
@@ -849,76 +865,195 @@ class VerifierServer:
 
     def _deliver(self) -> None:
         """Everything the connections read in one turn of the loop joins
-        the pending list under one acquisition of the condition."""
+        the pending list under one acquisition of the condition, and wakes
+        a slot for what may leave (``_take``)."""
         items, self._arrived = self._arrived, []
         with self._pending_cond:
             if self._stopping:
                 return  # never launched: ``stop()`` has closed its connection
+            shared = False
             for item in items:
                 self._in_service += 1
                 self._pending.append(item)
-                if self._idle > self._promised:
+                if (self._idle > self._promised
+                        and self._in_service <= self.DISPATCHERS + 1):
                     # A slot is asleep and nobody has woken it yet: this
                     # request does.  While the service holds at most one
                     # request more than it has slots, each can have a
                     # launch to itself (one waits its turn) and sharing
                     # gains nothing: it is launched alone, with the kernel
                     # of its own shape (what arrives before that slot is up
-                    # does not ride with it).  With more in the service,
-                    # the slot takes all that is pending when it is up: a
-                    # queue is draining.
-                    if self._in_service <= self.DISPATCHERS + 1:
-                        item.alone = True
-                        self._promised += 1
+                    # does not ride with it).  With more in the service a
+                    # queue is draining, and the request goes with others.
+                    item.alone = True
+                    self._promised += 1
                     self._pending_cond.notify()
+                else:
+                    shared = True
+            if shared:
+                self._wake_one()
 
-    def _take(self) -> List[_Pending]:
-        """Everything pending, in arrival order, while the signatures sum
-        to at most what the backend warmed: whole requests only, and the
-        first whatever it holds (only a request that arrived before the
-        backend was warm can be over the cap: ``_Connection._receive`` cuts
-        the others).  A request marked ``alone`` (``_deliver``) goes
-        alone, whichever slot gets to it first.  Called with the condition
-        held and something pending."""
+    def _wake_one(self) -> None:
+        """Wake a sleeping slot for pending requests that go with others:
+        where they may leave now, or where no slot is awake at the instant
+        their hold runs out (the one woken will be).  What is held and
+        watched wakes nobody: a thread that wakes to find nothing to launch
+        only meets the loop at the GIL."""
+        if self._idle > self._promised and (
+                not self._watching or self._may_leave()):
+            self._pending_cond.notify()
+
+    def _may_leave(self) -> bool:
+        """Whether ``_take`` could let pending requests that go with others
+        leave: no hold is in force, or they fill a launch (where one of
+        them goes alone the sum says so too soon, and a slot wakes in
+        vain)."""
+        if self._held_for(time.monotonic()) <= 0.0:
+            return True
+        return sum(item.n for item in self._pending) >= self._launch_cap
+
+    def _held_for(self, now: float) -> float:
+        """Seconds for which the newest part-full launch that is out still
+        holds what is pending; none out, or zero and less: nothing is
+        held."""
+        return max(self._part_full, default=now) - now
+
+    def _hold_s(self) -> float:
+        """How long a part-full launch holds what arrives behind it, if it
+        does not land before: twice what the service timed a full launch to
+        last when it calibrated (``_calibrate``: one thread at an idle
+        service).  Twice, because under load the same launch lasts up to a
+        half longer than that (it queues for the GIL with the loop), and a
+        bound as tight as the calibration itself would run out in most
+        cycles and put two part-full launches out again; not more, so that
+        a launch ten times slower than it should be costs the others two
+        normal launches, not its own length.  An uncalibrated service (no
+        committee keys) holds nothing."""
+        if self._calibration is None or self._launch_cap is None:
+            return 0.0
+        fixed, per_signature = self._calibration
+        return 2.0 * (fixed + self._launch_cap * per_signature)
+
+    def _take(self, now: float) -> Optional[Tuple[List[_Pending], Optional[float]]]:
+        """The coalescer's rule (Nagle's, for launches).  What would ride
+        the next launch is everything pending, in arrival order, while the
+        signatures sum to at most what the backend warmed: whole requests
+        only, and the first whatever it holds (only a request that arrived
+        before the backend was warm can be over the cap:
+        ``_Connection._receive`` cuts the others).  It leaves now if
+
+        * its first request is marked ``alone`` (``_deliver``): that one
+          goes by itself, whatever else is out;
+        * it is *full*: it ends because the next request does not fit (or
+          the sum is the cap), not because the list ran out.  A full
+          launch shares its fixed cost as widely as a launch can, so it
+          never waits;
+        * *drained*: no part-full launch — one that left under this clause
+          or the next — is out.  So one part-full launch is out at a time,
+          and what arrives while it is rides together when it lands, or
+          when it fills a launch;
+        * *expired*: the newest part-full launch has been out for longer
+          than ``_hold_s``.
+
+        Otherwise it stays pending, but for a request in it that goes
+        alone.  Returns the launch and when its hold runs out (None: it
+        holds nothing), or None.  Called with the condition held and
+        something pending."""
         pending = self._pending
-        first = pending.popleft()
-        batch = [first]
-        cap = self._launch_cap
+        first = pending[0]
         if first.alone:
+            pending.popleft()
+            return self._leave([first], _ALONE, now)
+        cap = self._launch_cap
+        riders, total, full = 1, first.n, False
+        if cap is not None:
+            for item in itertools.islice(pending, 1, None):
+                if item.alone:
+                    break
+                if total + item.n > cap:
+                    full = True
+                    break
+                total += item.n
+                riders += 1
+            full = full or total >= cap
+        if full:
+            why = _FULL
+        elif not self._part_full:
+            why = _DRAINED
+        elif self._held_for(now) <= 0.0:
+            why = _EXPIRED
+        else:
+            for item in pending:
+                if item.alone:
+                    pending.remove(item)
+                    return self._leave([item], _ALONE, now)
+            return None
+        return self._leave(
+            [pending.popleft() for _ in range(riders)], why, now)
+
+    def _leave(self, batch: List[_Pending], why: int, now: float):
+        """``batch`` leaves because ``why`` (an index of
+        ``spans.StageClock.LEFT``): counted, its hold begun where it is
+        part-full, and another slot woken for what it left pending."""
+        self.stages.left[why] += 1
+        hold = None
+        if why == _ALONE:
             self._promised -= 1
-        elif cap is not None:
-            total = first.n
-            while (pending and not pending[0].alone
-                   and total + pending[0].n <= cap):
-                total += pending[0].n
-                batch.append(pending.popleft())
-        return batch
+        elif why != _FULL:
+            hold = now + self._hold_s()
+            self._part_full.append(hold)
+        if self._pending:
+            self._wake_one()
+        return batch, hold
 
     def _dispatch_loop(self) -> None:
-        """A launch slot: take what is pending, launch it, again; sleep
-        only when nothing is pending.  No timer anywhere: a launch holds
-        what queued while every slot was busy, and a request that finds a
-        slot asleep wakes it (``_deliver``)."""
+        """A launch slot: launch what the rule lets leave (``_take``), and
+        where that lands what it lets leave then; sleep while nothing is
+        pending, and while what is pending is held — then for no longer
+        than the hold has left, which is the one timer here.  A request
+        that finds a slot asleep wakes it if it may leave (``_deliver``)."""
         self.stages.adopt_thread()
-        cond, pending = self._pending_cond, self._pending
+        taken = None
         while True:
-            with cond:
-                while not pending:
-                    if self._stopping:
-                        return
-                    self._idle += 1
-                    cond.wait()
-                    self._idle -= 1
-                batch = self._take()
-            self._launch(batch)
+            if taken is None:
+                with self._pending_cond:
+                    taken = self._sleep_until_taken()
+                if taken is None:
+                    return  # ``stop()``
+            taken = self._launch(*taken)
 
-    def _launch(self, batch: List[_Pending]) -> None:
+    def _sleep_until_taken(self):
+        """Called with the condition held: wait on it until ``_take`` lets
+        a launch leave, and return that; None once the service stops."""
+        while not self._stopping:
+            left = None
+            if self._pending:
+                now = time.monotonic()
+                taken = self._take(now)
+                if taken is not None:
+                    return taken
+                left = self._held_for(now)
+            watching = left is not None
+            self._idle += 1
+            self._watching += watching
+            self._pending_cond.wait(left)
+            self._watching -= watching
+            self._idle -= 1
+        return None
+
+    def _launch(self, batch: List[_Pending], hold: Optional[float]):
         """One backend call for every request of ``batch``, then each
         request's reply to its slot, with one wake-up of the loop.  The
         thread works for the launch from here to ``built``: every
         ``spans.request_stage`` below, in the backend too, names the stage
         it is in, for the clocked requests that ride it.  A launch that
-        raises fails each of its requests and nothing else."""
+        raises fails each of its requests and nothing else.
+
+        Where the launch lands its ``hold`` ends, on this thread and not at
+        the loop's next turn (what a launch carries is decided by who gets
+        the GIL next: the loop's turn would add its lag to every hold), and
+        this slot, which is free and awake, takes at once what the rule now
+        lets leave: returned, for ``_dispatch_loop`` to launch."""
         stages = self.stages
         clocked = [((item.conn_label, item.req_id), item.handed)
                    for item in batch if item.handed is not None]
@@ -933,11 +1068,18 @@ class VerifierServer:
         finally:
             if clocked:
                 built = stages.end_launch(len(batch))
+        taken = None
+        with self._pending_cond:
+            if hold is not None:
+                self._part_full.remove(hold)
+            if self._pending and not self._stopping:
+                taken = self._take(time.monotonic())
         try:
             self._loop.call_soon_threadsafe(
                 self._resolve, batch, replies, error, built)
         except RuntimeError:
             pass  # the loop closed under a launch that stop() did not await
+        return taken
 
     def _resolve(self, batch: List[_Pending], replies, error, built) -> None:
         """On the loop: a launch is done.  Its requests' slots are filled

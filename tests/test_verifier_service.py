@@ -54,11 +54,12 @@ def signers():
     return [crypto.Signer.from_seed(i.to_bytes(32, "little")) for i in range(4)]
 
 
-async def _with_server(tmp_path, committee_keys, backend, fn):
+async def _with_server(tmp_path, committee_keys, backend, fn, metrics=None):
     server = VerifierServer(
         str(tmp_path / "verifier.sock"),
         committee_keys=committee_keys,
         backend=backend,
+        metrics=metrics,
     )
     await server.start()
     try:
@@ -1353,6 +1354,312 @@ def test_the_pending_list_loses_and_doubles_nothing_under_contention(
         asyncio.run(_with_server(tmp_path, keys, backend, scenario))
     finally:
         sys.setswitchinterval(interval)
+
+
+# ---------------------------------------------------------------------------
+# The coalescer's rule: one part-full launch out at a time.
+
+
+def _left(server):
+    """Launches so far by why they left, ``{why: count}``."""
+    return dict(zip(server.stages.LEFT, server.stages.left))
+
+
+def _grown(server, before):
+    """The whys that grew since ``before`` (a ``_left``), by how much."""
+    return {why: n - before[why] for why, n in _left(server).items()
+            if n != before[why]}
+
+
+async def _a_part_full_launch_out(server, backend, keys, signers, lasts,
+                                  riders=5):
+    """Plug the slots, queue ``riders`` requests of two signatures behind
+    them and open the gate: the plugs land and the queued leave together,
+    no part-full launch being out, on a backend call that lasts ``lasts``
+    seconds.  Returns once that launch is out and the other slots are
+    asleep, with the connections to close and when it left (roughly: when
+    it was seen to be out)."""
+    plugs = await _plug_the_slots(server, backend, keys, signers)
+    queue = await asyncio.to_thread(_RawConn, server, keys)
+    queued = [_indexed(2, signers, b"out%d" % i) for i in range(riders)]
+    backend.slow[queued[0][0][1]] = lasts
+    queue.send(*(_verify_frame(i, keys, items)
+                 for i, items in enumerate(queued)))
+    await _until(lambda: len(server._pending) == riders, "queued")
+    before = _left(server)
+    backend.gate.set()
+    await _until(lambda: 2 * riders in backend.sizes, "the queued are out")
+    left_at = time.monotonic()
+    await _until(lambda: server._idle == len(plugs) - 1, "others asleep")
+    assert _grown(server, before) == {"drained": 1}
+    assert server._in_service == riders and len(server._part_full) == 1
+    return plugs + [queue], left_at
+
+
+def _hand(conn, keys, signers, req_id, n, tag):
+    """One read of ``conn`` (by hand) that holds one request of ``n``."""
+    conn.data_received(_verify_frame(req_id, keys, _indexed(n, signers, tag)))
+
+
+def test_while_a_part_full_launch_is_out_what_arrives_rides_one_launch(
+        tmp_path, signers):
+    """One launch carries five requests and lasts a second, and the service
+    calibrated a full launch to 5 s.  Requests handed over in four turns of
+    the loop, by two connections, stay pending though two slots sleep; when
+    the launch lands they ride ONE launch, and each connection's replies
+    leave in one write."""
+    keys = [s.public_key.bytes for s in signers]
+    backend = GatedBackend()
+
+    async def scenario(server):
+        warm = await asyncio.to_thread(_RawConn, server, keys)
+        warm.close()
+        server._calibration = (5.0, 0.0)
+        others, _ = await _a_part_full_launch_out(
+            server, backend, keys, signers, lasts=1.0)
+        before, launched = _left(server), len(backend.sizes)
+        (one, wire_one), (two, wire_two) = _by_hand(server), _by_hand(server)
+        sizes = [3, 4, 5, 6]
+        for i, n in enumerate(sizes):
+            _hand(one if i % 2 == 0 else two, keys, signers, i, n,
+                  b"held%d" % i)
+            await asyncio.sleep(0.02)  # several turns of the loop
+            assert len(server._pending) == i + 1
+            assert not any(item.alone for item in server._pending)
+        assert len(backend.sizes) == launched and server._watching == 1
+        await _until(lambda: len(wire_one.results()) == 2
+                     and len(wire_two.results()) == 2, "answered")
+        assert wire_one.results() == [(0, [1] * 3), (2, [1] * 5)]
+        assert wire_two.results() == [(1, [1] * 4), (3, [1] * 6)]
+        assert len(wire_one.writes) == len(wire_two.writes) == 1
+        assert backend.sizes[launched:] == [sum(sizes)]
+        assert _grown(server, before) == {"drained": 1}
+        assert server._part_full == [] or not server._pending
+        for conn in others:
+            conn.close()
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
+
+
+def test_what_fills_a_launch_leaves_at_once_past_a_part_full_launch(
+        tmp_path, signers):
+    """A part-full launch is out for 1.5 s and holds for 10 s.  Three
+    requests of 100 signatures handed over in one turn: the first two fill
+    a launch (the third does not fit) and leave at once on a sleeping slot;
+    the third stays pending until the part-full launch lands."""
+    keys = [s.public_key.bytes for s in signers]
+    backend = GatedBackend()
+
+    async def scenario(server):
+        warm = await asyncio.to_thread(_RawConn, server, keys)
+        warm.close()
+        server._calibration = (5.0, 0.0)
+        others, left_at = await _a_part_full_launch_out(
+            server, backend, keys, signers, lasts=1.5)
+        before = _left(server)
+        conn, wire = _by_hand(server)
+        conn.data_received(b"".join(
+            _verify_frame(i, keys, _indexed(100, signers, b"wide%d" % i))
+            for i in range(3)))
+        await _until(lambda: len(wire.results()) == 2, "the full launch")
+        assert time.monotonic() - left_at < 1.3  # the other is still out
+        assert 200 in backend.sizes and len(server._pending) == 1
+        assert _grown(server, before) == {"full": 1}
+        await _until(lambda: len(wire.results()) == 3, "the third")
+        assert time.monotonic() - left_at > 1.4  # (seen out a little late)
+        assert wire.results() == [(i, [1] * 100) for i in range(3)]
+        assert _grown(server, before) == {"full": 1, "drained": 1}
+        for conn in others:
+            conn.close()
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
+
+
+@pytest.mark.parametrize("calibration,held_s", [
+    ((0.1, 0.0), 0.2),  # a full launch calibrated to 0.1 s: held for 0.2 s
+    ((0.0, 0.0005), 0.256),  # the same from the cost a signature (x 256)
+    (None, 0.0),  # an uncalibrated service holds nothing
+])
+def test_a_slow_launch_holds_what_arrives_for_twice_a_calibrated_launch(
+        tmp_path, signers, calibration, held_s):
+    """A part-full launch made slow (2 s) holds two later requests no
+    longer than twice the time the service calibrated a full launch to
+    last, and not at all where it calibrated nothing; then they leave
+    together on a slot that slept, as ``expired``."""
+    keys = [s.public_key.bytes for s in signers]
+    backend = GatedBackend()
+
+    async def scenario(server):
+        warm = await asyncio.to_thread(_RawConn, server, keys)
+        warm.close()
+        server._calibration = calibration
+        assert server._hold_s() == pytest.approx(held_s)
+        others, left_at = await _a_part_full_launch_out(
+            server, backend, keys, signers, lasts=2.0)
+        before = _left(server)
+        conn, wire = _by_hand(server)
+        handed = time.monotonic()
+        conn.data_received(b"".join(  # one read: one hand-over
+            _verify_frame(i, keys, _indexed(n, signers, b"late%d" % i))
+            for i, n in enumerate((3, 4))))
+        if held_s and handed - left_at < held_s / 2:
+            await asyncio.sleep(held_s / 4)
+            assert len(server._pending) == 2 and not wire.writes
+        await _until(lambda: len(wire.results()) == 2, "answered")
+        answered = time.monotonic()
+        assert wire.results() == [(0, [1] * 3), (1, [1] * 4)]
+        assert len(wire.writes) == 1 and backend.sizes.count(7) == 1
+        assert _grown(server, before) == {"expired": 1}
+        # Not before the hold ran out (the launch was seen to be out a
+        # little after it left), soon after it, and long before the slow
+        # launch lands.
+        assert answered - left_at >= held_s - 0.05
+        assert answered - max(handed, left_at + held_s) < 0.5
+        assert answered - left_at < 1.5
+        for conn in others:
+            conn.close()
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
+
+
+def test_a_request_that_goes_alone_does_not_wait_for_a_part_full_launch(
+        tmp_path, signers):
+    """A part-full launch of two requests is out and holds for 10 s.  The
+    service is lightly loaded — it holds no more than one request more than
+    it has slots — so the next two requests each wake a slot and go alone,
+    at once; a third is one too many and is held; and when the first two
+    have landed a fourth goes alone again, past the one that is held."""
+    keys = [s.public_key.bytes for s in signers]
+    backend = GatedBackend()
+    slots = VerifierServer.DISPATCHERS
+
+    async def scenario(server):
+        warm = await asyncio.to_thread(_RawConn, server, keys)
+        warm.close()
+        server._calibration = (5.0, 0.0)
+        others, _ = await _a_part_full_launch_out(
+            server, backend, keys, signers, lasts=1.0, riders=2)
+        before = _left(server)
+        conn, wire = _by_hand(server)
+        for i, n in enumerate((3, 5)):
+            _hand(conn, keys, signers, i, n, b"light%d" % i)
+            await _until(lambda: len(wire.results()) == i + 1, "at once")
+        assert _grown(server, before) == {"alone": 2}
+        await _until(lambda: server._idle == slots - 1, "asleep again")
+        # Two ride the launch that is out; with three more the service
+        # would hold five.
+        backend.close_gate()
+        for i, n in ((2, 6), (3, 7)):  # each wakes a slot and waits there
+            _hand(conn, keys, signers, i, n, b"light%d" % i)
+        await _until(lambda: backend.waiting == 2, "two alone at the gate")
+        _hand(conn, keys, signers, 4, 8, b"light4")
+        await asyncio.sleep(0.05)
+        assert [item.n for item in server._pending] == [8]
+        assert not server._pending[0].alone  # held: one too many
+        backend.gate.set()
+        await _until(lambda: len(wire.results()) == 4, "the two landed")
+        await _until(lambda: server._idle == slots - 1, "asleep again")
+        _hand(conn, keys, signers, 5, 9, b"light5")  # behind the held one
+        await _until(lambda: 9 in backend.sizes, "alone, past the held one")
+        assert [item.n for item in server._pending] == [8]
+        assert _grown(server, before) == {"alone": 5}
+        assert server._promised == 0
+        await _until(lambda: len(wire.results()) == 6, "all answered")
+        assert wire.results() == [
+            (i, [1] * n) for i, n in enumerate((3, 5, 6, 7, 8, 9))]
+        assert sorted(backend.sizes) == [6, 7, 8, 9]  # since the gate shut
+        for conn in others:
+            conn.close()
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
+
+
+def test_stop_with_requests_held_behind_a_part_full_launch_closes_cleanly(
+        tmp_path, signers):
+    """``stop()`` while a part-full launch is out, two requests are held
+    behind it and a slot sleeps until their hold runs out: the held are let
+    go unlaunched, their gauges come back, every dispatcher ends."""
+    from mysticeti_tpu.metrics import Metrics
+
+    keys = [s.public_key.bytes for s in signers]
+    backend = GatedBackend()
+    metrics = Metrics()
+
+    async def scenario():
+        server = VerifierServer(
+            str(tmp_path / "verifier.sock"), committee_keys=keys,
+            backend=backend, metrics=metrics,
+        )
+        await server.start()
+        warm = await asyncio.to_thread(_RawConn, server, keys)
+        warm.close()
+        server._calibration = (30.0, 0.0)
+        others, _ = await _a_part_full_launch_out(
+            server, backend, keys, signers, lasts=0.5)
+        launched = len(backend.sizes)
+        held = await asyncio.to_thread(_RawConn, server, keys)
+        held.send(*(_verify_frame(i, keys, _indexed(2, signers, b"h%d" % i))
+                    for i in range(2)))
+        await _until(lambda: len(server._pending) == 2, "two held")
+        await _until(lambda: server._watching == 1, "a slot watches the hold")
+        depth = metrics.verifier_service_queue_depth._value.get
+        assert depth() == 5 + 2
+        started = time.monotonic()
+        await asyncio.wait_for(server.stop(), 20)
+        assert not server._pending
+        assert await asyncio.to_thread(held.read) is None
+        for thread in server._dispatchers:  # the one out ends after its launch
+            await asyncio.to_thread(thread.join, 5)
+            assert not thread.is_alive()
+        assert time.monotonic() - started < 5  # nobody slept the hold out
+        assert len(backend.sizes) == launched  # the held: not launched
+        assert depth() == 0
+        for conn in others + [held]:
+            conn.close()
+
+    asyncio.run(scenario())
+
+
+def test_launches_are_counted_by_why_they_left_in_the_ring_and_the_scrape(
+        tmp_path, signers):
+    """A scripted sequence — three plugs alone, five queued behind them
+    once no part-full launch is out, two of three wide requests as a full
+    launch past it, the third when its hold has run out — read back from
+    the stage clock, the ring's stamp as the report carries it, and
+    ``verifier_service_launches_total{left}``."""
+    from mysticeti_tpu.metrics import Metrics
+
+    keys = [s.public_key.bytes for s in signers]
+    backend = GatedBackend()
+    metrics = Metrics()
+    expected = {"alone": 3, "full": 1, "drained": 1, "expired": 1}
+
+    async def scenario(server):
+        warm = await asyncio.to_thread(_RawConn, server, keys)
+        warm.close()
+        assert _left(server) == dict.fromkeys(expected, 0)
+        server._calibration = (0.1, 0.0)
+        others, _ = await _a_part_full_launch_out(
+            server, backend, keys, signers, lasts=1.0)
+        conn, wire = _by_hand(server)
+        conn.data_received(b"".join(
+            _verify_frame(i, keys, _indexed(100, signers, b"c%d" % i))
+            for i in range(3)))
+        await _until(lambda: len(wire.results()) == 3, "answered")
+        assert sorted(backend.sizes)[-3:] == [10, 100, 200]
+        assert _left(server) == expected
+        server.stages.stamp(time.monotonic() + 1.0)
+        seconds = server.stages.export()["seconds"].values()
+        assert {why: sum(s.get("left_" + why, 0) for s in seconds)
+                for why in expected} == expected
+        scrape = metrics.expose().decode()
+        for why, n in expected.items():
+            assert ('verifier_service_launches_total{left="%s"} %.1f'
+                    % (why, n)) in scrape
+        for other in others:
+            other.close()
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario, metrics))
 
 
 # ---------------------------------------------------------------------------

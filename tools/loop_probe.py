@@ -14,7 +14,10 @@ requests a launch gets when launches compete with the loop) and then
 ``--launch-ms`` of sleep (the fetch).  What is left is the plumbing between
 the socket and the launch, both ways: the loop's own CPU and the process's
 CPU a request, the requests a second and a launch, and — where the tree
-counts them — how many frames a read and replies a write carried.
+counts them — the launches by why the coalescer let them leave
+(``VerifierServer._take``: the stub is calibrated like any backend, so a
+part-full launch holds what arrives behind it) and how many frames a read
+and replies a write carried.
 
     python3 tools/loop_probe.py --launch-cpu-ms 0.5        # this tree
     PYTHONPATH=<other tree> python3 tools/loop_probe.py    # another one
@@ -119,11 +122,12 @@ async def serve(args) -> dict:
     def reading():
         return (time.monotonic(), time.thread_time(), time.process_time(),
                 stages.requests, stages.launches,
-                getattr(stages, "reads", None), getattr(stages, "writes", None))
+                getattr(stages, "reads", None), getattr(stages, "writes", None),
+                list(getattr(stages, "left", ())))
 
-    t0, loop0, process0, requests0, launches0, reads0, writes0 = reading()
+    t0, loop0, process0, requests0, launches0, reads0, writes0, left0 = reading()
     await asyncio.sleep(args.seconds)
-    t1, loop1, process1, requests1, launches1, reads1, writes1 = reading()
+    t1, loop1, process1, requests1, launches1, reads1, writes1, left1 = reading()
     for proc in clients:  # off the loop: it still answers them
         await asyncio.get_running_loop().run_in_executor(None, proc.wait)
     await server.stop()
@@ -135,6 +139,10 @@ async def serve(args) -> dict:
             1e6 * (process1 - process0) / requests, 2),
         "requests_per_launch": round(requests / (launches1 - launches0), 2),
     }
+    if left0:  # why the coalescer let them leave (``VerifierServer._take``)
+        out["launches_left"] = {
+            why: after - before
+            for why, before, after in zip(stages.LEFT, left0, left1)}
     if reads0 is not None:
         out["requests_per_read"] = round(requests / (reads1 - reads0), 2)
         out["requests_per_write"] = round(requests / (writes1 - writes0), 2)
