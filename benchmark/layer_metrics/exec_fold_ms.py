@@ -1,0 +1,8 @@
+"""Mean fold of one commit through the execution state (``exec_fold``,
+one sample a commit) over the window, median over validators, in ms
+(execution)."""
+from benchmark import node_readers
+
+
+def read(run):
+    return node_readers.stage_mean_ms(run, "exec_fold")

@@ -944,6 +944,10 @@ class BatchedSignatureVerifier(BlockVerifier):
         self.aggregate = aggregate
         self.aggregated_total = 0
         self.direct_total = 0
+        # The validator's stage clock (spans.StageClock; None = not
+        # clocked): a dispatch's two hops through the loop's default
+        # executor book their wait for a thread as ``executor_wait``.
+        self.stages = None
         # Cross-flush endorsement index: ref -> authors of ACCEPTED blocks
         # that include it.  Catch-up streams from different peers run at
         # different round offsets, so a backlog block's quorum of verified
@@ -1266,8 +1270,9 @@ class BatchedSignatureVerifier(BlockVerifier):
                     # dispatch.
                     self.pipeline.note_stage(STAGE_DEVICE, 0.0)
                 else:
-                    submit_fut = loop.run_in_executor(
-                        None, self._submit_dispatch, pks, digests, sigs
+                    submit_fut = spans.in_default_executor(
+                        loop, self.stages, self._submit_dispatch, pks,
+                        digests, sigs
                     )
                     try:
                         handle = await asyncio.shield(submit_fut)
@@ -1303,8 +1308,9 @@ class BatchedSignatureVerifier(BlockVerifier):
                     # probe flag).  Shielded, the job always runs; result()
                     # does its own cleanup, so cancellation here needs only
                     # to observe the orphaned outcome.
-                    fetch_fut = loop.run_in_executor(
-                        None, self._fetch_dispatch, handle, len(sigs)
+                    fetch_fut = spans.in_default_executor(
+                        loop, self.stages, self._fetch_dispatch, handle,
+                        len(sigs)
                     )
                     try:
                         out, label, padded = await asyncio.shield(fetch_fut)

@@ -40,11 +40,22 @@ class CoreTaskDispatcher:
     # timeouts) also reads as unobserved, but it hammers one command;
     # genuine corruption poisons every mutation type.
     MAX_CONSECUTIVE_FAILURES = 16
+    CPU_ONE_IN = 8
 
     def __init__(self, syncer: Syncer, metrics=None,
-                 fatal_handler=None) -> None:
+                 fatal_handler=None, stages=None) -> None:
         self.syncer = syncer
         self.metrics = metrics
+        # The node's stage clock (spans.StageClock; None = not clocked):
+        # every synchronous command is one ``core_command`` sample of wall,
+        # and the instant it ends is a tick for the ring's stamp.  The loop
+        # thread's CPU is read around one command in CPU_ONE_IN and booked
+        # times that, so the stage's ``cpu_s`` sums to the commands' CPU
+        # without a clock read a command: ``time.thread_time`` is a system
+        # call of 6 us on the chips' sandboxed hosts, and twice a command
+        # it was two thirds of what the clock did there by arithmetic
+        # (PERF.md section 5, PR 39).
+        self.stages = stages
         # Called when the owner dies on a persistent failure.  Merely
         # letting the task die would leave a ZOMBIE: ports held, /metrics
         # stale, every subsequent command awaiting a reply forever.  The
@@ -105,9 +116,10 @@ class CoreTaskDispatcher:
         # simulated schedules, so the detector stays off there (evaluated
         # once — the loop flavor cannot change mid-run).
         from .runtime import is_simulated
-        from time import perf_counter
+        from time import monotonic, thread_time
 
         measure_blocking = not is_simulated()
+        turn = 0  # of the commands: which has its CPU read
         while True:
             command, args, reply, internal = await self._queue.get()
             if dequeued is not None:
@@ -115,20 +127,31 @@ class CoreTaskDispatcher:
             try:
                 label = getattr(command, "__name__", "other")
                 monitor = self.blocking_monitor
-                t0 = (
-                    perf_counter()
-                    if monitor is not None and measure_blocking
-                    else 0.0
-                )
+                stages = self.stages if measure_blocking else None
+                measured = measure_blocking and (
+                    monitor is not None or stages is not None)
+                if measured:
+                    t0 = monotonic()
+                    cpu_read = stages is not None and turn == 0
+                    if cpu_read:
+                        c0 = thread_time()
+                    turn = (turn + 1) % self.CPU_ONE_IN
                 if timers is not None:
                     with timers(f"core:{label}"):
                         result = command(*args)
                 else:
                     result = command(*args)
-                if monitor is not None and measure_blocking:
-                    monitor.note_command(
-                        f"core:{label}", perf_counter() - t0
-                    )
+                if measured:
+                    t1 = monotonic()
+                    if stages is not None:
+                        cpu = (
+                            self.CPU_ONE_IN * (thread_time() - c0)
+                            if cpu_read else 0.0
+                        )
+                        stages.book("core_command", t1, t1 - t0, cpu)
+                        stages.stamp(t1)
+                    if monitor is not None:
+                        monitor.note_command(f"core:{label}", t1 - t0)
                 consecutive_failures = 0
                 failed_kinds.clear()
                 if reply is not None and not reply.done():

@@ -61,6 +61,7 @@ from dataclasses import dataclass
 from typing import Deque, Dict, List, Optional, Tuple
 
 from .serde import Reader, SerdeError, Writer
+from .spans import booked
 from .types import Share, StatementBlock
 
 # Share-payload prefix marking an execution transaction.  Same shape as
@@ -355,6 +356,9 @@ class ExecutionState:
         self.applied_total = 0
         self.rejected_total = 0
         self.metrics = metrics
+        # The validator's stage clock (spans.StageClock; None = not
+        # clocked): ``observe_commit`` books ``exec_fold``.
+        self.stages = None
 
     # -- queries ---------------------------------------------------------
 
@@ -482,9 +486,17 @@ class ExecutionState:
         """Fold one committed sub-dag (linearized block order) into the
         state and advance the root chain.  Returns None when the commit was
         already folded (crash replay re-delivers committed heights —
-        exactly the ``ReconfigState.observe_commit`` skip)."""
+        exactly the ``ReconfigState.observe_commit`` skip).  Where the
+        validator's stage clock is attached (``stages``) a fold is one
+        ``exec_fold`` sample, wall and the thread's CPU."""
         if height <= self.last_height:
             return None
+        with booked(self.stages, "exec_fold", cpu=True):
+            return self._fold(height, blocks)
+
+    def _fold(
+        self, height: int, blocks: List[StatementBlock]
+    ) -> ExecutionResult:
         verdicts: Dict[str, int] = {}
         deltas: Dict[bytes, Tuple[int, int]] = {}
         with self._exec_lock:

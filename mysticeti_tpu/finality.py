@@ -86,8 +86,13 @@ class FinalityTracker:
         pending_cap: int = DEFAULT_PENDING_CAP,
         sample_window: int = DEFAULT_SAMPLE_WINDOW,
         clock=runtime_now,
+        stages=None,
     ) -> None:
         self.metrics = metrics
+        # The validator's stage clock (spans.StageClock; None = not
+        # clocked): every admission / proposal / commit sample also goes
+        # into the second it ended in, as ``phase_<name>``.
+        self.stages = stages
         self.sample_every = max(1, sample_every)
         self.pending_cap = max(16, pending_cap)
         self.clock = clock
@@ -110,17 +115,25 @@ class FinalityTracker:
     def sampled(self, key: bytes) -> bool:
         return key_sampled(key, self.sample_every)
 
-    def _observe(self, phase: str, seconds: float) -> None:
+    _CLOCKED = {"admission": "phase_admission", "proposal": "phase_proposal",
+                "commit": "phase_commit"}
+
+    def _observe(self, phase: str, seconds: float,
+                 end: Optional[float] = None) -> None:
+        """``end``: when the phase ended on ``clock`` (the phases the
+        stage clock books carry it)."""
         if self.metrics is not None:
             self.metrics.mysticeti_e2e_finality_seconds.labels(phase).observe(
                 max(0.0, seconds)
             )
+        if self.stages is not None and end is not None:
+            self.stages.book(self._CLOCKED[phase], end, max(0.0, seconds))
 
     # -- lifecycle stamps (all tolerate unknown/unsampled keys) --
 
     def on_submit(self, key: bytes, t_submit: float, t_admitted: float) -> None:
         """A sampled key was admitted into the mempool."""
-        self._observe("admission", t_admitted - t_submit)
+        self._observe("admission", t_admitted - t_submit, t_admitted)
         with self._finality_lock:
             self._finality_pending[key] = {
                 "submit": t_submit,
@@ -138,7 +151,7 @@ class FinalityTracker:
                 return
             entry["proposal"] = t
             admitted = entry["admitted"]
-        self._observe("proposal", t - admitted)
+        self._observe("proposal", t - admitted, t)
 
     def on_commit(self, key: bytes, t_commit: float, t_finalize: float) -> None:
         """A sampled key's transaction was committed (``t_commit`` = the
@@ -158,7 +171,7 @@ class FinalityTracker:
             if not self.execute_expected:
                 self._finality_samples.append(max(0.0, total))
                 self.completed += 1
-        self._observe("commit", t_commit - upstream)
+        self._observe("commit", t_commit - upstream, t_commit)
         self._observe("finalize", t_finalize - t_commit)
         if not self.execute_expected:
             self._observe("total", total)

@@ -15,10 +15,18 @@ module is the bounded black box that does:
   message).  The ring is lock-disciplined (``_ring_lock``; the lint's
   GUARDED_FIELDS covers the ring field) because dumps may be requested from
   the metrics endpoint or a signal path while the loop records.
+* The document also carries the validator's stage clock
+  (``spans.StageClock``, ``"stages"``): per whole second of the same clock
+  the events are stamped on, every stage's ``[count, wall_s, cpu_s,
+  max_wall_s]`` and what the validator counted in that second
+  (``spans.NODE_STAMPS``) — what the node was doing in the seconds the
+  events fall in.  On a live node only: a simulated one has no ring.
 * Dump triggers, all writing the SAME canonical JSON document atomically
   (tmp + rename):
-  - orderly shutdown / SIGTERM — ``Validator.stop`` dumps to the path from
-    ``MYSTICETI_FLIGHT_RECORDER`` (``%p`` expands to the pid);
+  - orderly shutdown / SIGTERM — ``Validator.stop`` dumps to
+    ``flight-recorder.json`` in the validator's storage directory, or to
+    the path from ``MYSTICETI_FLIGHT_RECORDER`` (``%p`` expands to the
+    pid) where that is set;
   - ``GET /debug/flight-recorder`` on the metrics endpoint returns the
     document live (``metrics.serve_metrics``);
   - SLO alert transitions — the health watchdog calls :meth:`on_alert`,
@@ -55,6 +63,12 @@ ENV_FLIGHT_RECORDER = "MYSTICETI_FLIGHT_RECORDER"
 # holds many minutes of history in ~1 MB — enough to cover any alert's
 # debounce window plus the run-up.
 DEFAULT_CAPACITY = 4096
+# A live validator's ring (validator.py): at the pace of a fleet on the chip
+# every committed leader is a ``commit`` and a ``decision-flip`` event, 40-80
+# a second together, and the document written at shutdown has to reach back
+# over the minutes an operator — or a benchmark's window — asks about: 4096
+# held 52 s of them (PERF.md, PR 39).  ~4 MB in memory.
+LIVE_CAPACITY = 16384
 
 # Minimum seconds between alert-triggered dumps (runtime-clocked).
 ALERT_DEBOUNCE_S = 30.0
@@ -90,8 +104,14 @@ class FlightRecorder:
         dump_path: Optional[str] = None,
         metrics=None,
         alert_debounce_s: float = ALERT_DEBOUNCE_S,
+        stages=None,
     ) -> None:
         self.authority = authority
+        # The validator's stage clock (spans.StageClock): where it has a
+        # ring — a live node; never under the simulator — the document
+        # carries it as ``"stages"``, the last ten minutes by the second
+        # beside the events, both on the runtime clock (time.monotonic).
+        self.stages = stages
         self.capacity = max(1, capacity)
         self.dump_path = dump_path
         self.metrics = metrics
@@ -162,6 +182,8 @@ class FlightRecorder:
             "events": events,
             "dumps": list(self.dumps),
         }
+        if self.stages is not None and self.stages.ring_seconds:
+            doc["stages"] = self.stages.export()
         if not is_simulated():
             import time as _time
 

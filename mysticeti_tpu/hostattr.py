@@ -6,10 +6,11 @@ costs the event loop* —
 
 * :class:`LoopLagProbe` — measures asyncio scheduling lag by the classic
   sleep-overshoot probe: schedule a callback ``interval`` out, measure how
-  late it actually ran.  The delta histogram
-  (``mysticeti_loop_lag_seconds``) is the node's direct "is the core owner
-  responsive" signal; its p99 rides a gauge, the ``/health`` diagnosis, and
-  the ``loop-lag`` SLO watchdog kind.
+  late it actually ran.  Every sample goes to ``on_lag`` — the node's
+  stage clock books it as ``loop_lag`` (``block_stage_seconds``, and the
+  ring's seconds in the flight-recorder document), the node's direct "is
+  the core owner responsive" signal; the p99 rides a gauge, the ``/health``
+  diagnosis, and the ``loop-lag`` SLO watchdog kind.
 * :class:`HostMonitor` — bundles the probe with the blocking-call detector:
   the core task dispatcher (``core_task.py``) reports every synchronous
   command's wall duration here, and any hold beyond the threshold
@@ -52,8 +53,9 @@ class LoopLagProbe:
     One coroutine, one short sleep per interval: the overshoot beyond the
     requested interval is exactly the time the loop spent running other
     callbacks (or a blocking call) instead of this one.  ``on_lag(lag)``
-    hears every sample (the verifier service books them as its
-    ``service_loop_lag`` stage).
+    hears every sample (``spans.StageClock.loop_lag``: the verifier service
+    books them as its ``service_loop_lag`` stage, a validator as
+    ``loop_lag``, and the call is the tick that stamps the ring).
     """
 
     def __init__(
@@ -94,7 +96,6 @@ class LoopLagProbe:
             if self.on_lag is not None:
                 self.on_lag(lag)
             if self.metrics is not None:
-                self.metrics.mysticeti_loop_lag_seconds.observe(lag)
                 self.metrics.mysticeti_loop_lag_p99_seconds.set(
                     self.percentile(99)
                 )
@@ -119,6 +120,7 @@ class HostMonitor:
         metrics=None,
         recorder=None,
         blocking_threshold_ms: Optional[float] = None,
+        stages=None,
     ) -> None:
         if blocking_threshold_ms is None:
             blocking_threshold_ms = float(
@@ -128,7 +130,12 @@ class HostMonitor:
         self.blocking_threshold_ms = blocking_threshold_ms
         self.metrics = metrics
         self.recorder = recorder
-        self.loop_lag = LoopLagProbe(metrics=metrics)
+        # ``stages``: the node's ringed stage clock (None under the
+        # simulator, where the probe never starts either).
+        self.loop_lag = LoopLagProbe(
+            metrics=metrics,
+            on_lag=stages.loop_lag if stages is not None else None,
+        )
         self._blocking_total = 0
         self._worst_since_drain_ms = 0.0
         self._last_blocking: Optional[dict] = None
@@ -158,7 +165,6 @@ class HostMonitor:
         self._last_blocking = {"site": site, "ms": round(ms, 3)}
         if self.metrics is not None:
             self.metrics.mysticeti_blocking_calls_total.labels(site).inc()
-            self.metrics.mysticeti_blocking_call_last_ms.set(round(ms, 3))
         if self.recorder is not None:
             self.recorder.record(
                 "blocking-call",

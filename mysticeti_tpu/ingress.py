@@ -479,12 +479,17 @@ class IngressPlane:
         metrics=None,
         recorder=None,
         clock: Callable[[], float] = runtime_now,
+        stages=None,
     ) -> None:
         self.params = params or IngressParameters()
         self.authority = authority
         self.metrics = metrics
         self.recorder = recorder
         self.clock = clock
+        # The validator's stage clock (spans.StageClock; None = not
+        # clocked): the gateway check's ``admit_verify``, its wait for an
+        # executor thread, and the finality tracker's phases.
+        self._stages = stages
         # Server-side submit→finality phase joiner (finality.py) over
         # count-sampled ingress keys; finality_sample_every=0 disables it.
         self.finality = (
@@ -492,6 +497,7 @@ class IngressPlane:
                 metrics=metrics,
                 sample_every=self.params.finality_sample_every,
                 clock=clock,
+                stages=stages,
             )
             if self.params.finality_sample_every > 0
             else None
@@ -511,6 +517,11 @@ class IngressPlane:
         self.commit_height = 0
         self._commit_sinks: List[Callable[[int, List[bytes]], None]] = []
         self._last_shed_mode = False
+        # ``shed`` events (flight_recorder.py): one a whole second in which
+        # something was refused — the first refusal and the second's count
+        # by reason — written when a later second sheds, at the next tick
+        # or at stop; [second, first refusal's t, client, reason, counts].
+        self._shed_second: Optional[list] = None
         self._task: Optional[asyncio.Task] = None
         # Core signal taps (attach()); all optional.
         self._core = None
@@ -531,10 +542,6 @@ class IngressPlane:
         # block carries them — and the gateway check's stage.
         self._tx_verifier = None
         self._verified: "OrderedDict[bytes, None]" = OrderedDict()
-        self._admit_stages = None
-        if metrics is not None:
-            self._admit_stages = spans.StageClock(("admit_verify",))
-            metrics.block_stages.attach(self._admit_stages)
         self._pending_exec: Deque[Tuple[int, List[bytes], dict]] = deque()
         self.executed_height = 0
         self.executed_root = b""
@@ -656,8 +663,8 @@ class IngressPlane:
                     verified[key] = None
             while len(verified) > self.params.dedup_window:
                 verified.popitem(last=False)
-        if self._admit_stages is not None:
-            self._admit_stages.book_since("admit_verify", started)
+        if self._stages is not None:
+            self._stages.book_since("admit_verify", started)
         if self.metrics is not None:
             label = getattr(self._tx_verifier, "backend_label",
                             type(self._tx_verifier).__name__)
@@ -703,8 +710,9 @@ class IngressPlane:
                 # rule, block_validator.py).
                 oks = self._verify_transactions(found)
             else:
-                oks = await asyncio.get_running_loop().run_in_executor(
-                    None, self._verify_transactions, found)
+                oks = await spans.in_default_executor(
+                    asyncio.get_running_loop(), self._stages,
+                    self._verify_transactions, found)
             transactions, refused = self._settle_signatures(
                 transactions, found, oks, started)
         return self._submit(client, transactions, priority, refused)
@@ -818,6 +826,9 @@ class IngressPlane:
         self, client: str, sheds: Dict[str, int], retry_ms: int
     ) -> None:
         t = round(self.clock(), 6)
+        if (self.recorder is not None and self._stages is not None
+                and self._stages.ring_seconds):
+            self._note_shed_second(t, client, sheds)
         for reason in sorted(sheds):
             count = sheds[reason]
             with self._accounting_lock:
@@ -841,9 +852,32 @@ class IngressPlane:
                     count
                 )
 
+    def _note_shed_second(self, t: float, client: str,
+                          sheds: Dict[str, int]) -> None:
+        held = self._shed_second
+        if held is None or held[0] != int(t):
+            self._flush_shed_second()
+            held = self._shed_second = [
+                int(t), t, client, min(sheds), {}]
+        for reason, count in sheds.items():
+            held[4][reason] = held[4].get(reason, 0) + count
+
+    def _flush_shed_second(self) -> None:
+        held, self._shed_second = self._shed_second, None
+        if held is not None and self.recorder is not None:
+            second, first_t, client, reason, counts = held
+            self.recorder.record(
+                "shed", second=second, first_t=first_t, first_client=client,
+                first_reason=reason, by_reason=dict(sorted(counts.items())),
+            )
+
     def shed_total(self) -> int:
         with self._accounting_lock:
             return sum(self.shed_by_reason.values())
+
+    def shed_for(self, reason: str) -> int:
+        with self._accounting_lock:
+            return self.shed_by_reason.get(reason, 0)
 
     def shed_log_bytes(self) -> bytes:
         """Canonical shed schedule — byte-identical across same-seed sims."""
@@ -887,6 +921,9 @@ class IngressPlane:
     def tick(self) -> dict:
         """One controller step + gauge refresh; returns the signal dict."""
         signals = self._signals()
+        if (self._shed_second is not None
+                and self._shed_second[0] < int(self.clock())):
+            self._flush_shed_second()
         congested = self.controller.tick(signals)
         shed_mode = self.controller.shed_mode
         if shed_mode != self._last_shed_mode:
@@ -1053,6 +1090,7 @@ class IngressPlane:
                 log.exception("ingress tick failed")
 
     def stop(self) -> None:
+        self._flush_shed_second()
         if self._task is not None:
             self._task.cancel()
             self._task = None

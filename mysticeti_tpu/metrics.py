@@ -137,25 +137,32 @@ class PreciseHistogram:
 
 class StageSeries:
     """A ``<name>{stage}`` histogram (and a ``<cpu_name>{stage}`` counter,
-    an ``<io_name>{direction}`` counter of the clock's ``reads`` and
-    ``writes``, and a ``<left_name>{left}`` counter of its launches by why
-    they left) rendered at scrape time from the stage clocks attached to
-    it (``spans.StageClock``), summed where there are several: the clock is
+    an ``<io_name>{direction}`` counter of the ``reads`` and ``writes``,
+    and a ``<left_name>{left}`` counter of the launches by why they left,
+    of the clock's owner's counts: ``verifier_service.ServiceCounts``)
+    rendered at scrape time from the stage clocks attached to it
+    (``spans.StageClock``), summed where there are several: the clock is
     on the verifier service's per-request path and keeps plain arrays
-    there, not one locked prometheus child a sample."""
+    there, not one locked prometheus child a sample.  ``sparse``: a stage
+    that has booked nothing is left out (a validator's one clock names
+    every stage a deployment can reach; most reach some)."""
 
     def __init__(self, name: str, doc: str, cpu_name: Optional[str] = None,
                  cpu_doc: str = "", io_name: Optional[str] = None,
                  io_doc: str = "", left_name: Optional[str] = None,
-                 left_doc: str = "") -> None:
+                 left_doc: str = "", sparse: bool = False) -> None:
         self.name, self.doc = name, doc
         self.cpu_name, self.cpu_doc = cpu_name, cpu_doc
         self.io_name, self.io_doc = io_name, io_doc
         self.left_name, self.left_doc = left_name, left_doc
+        self.sparse = sparse
         self._clocks: list = []
+        self._counts: list = []
 
-    def attach(self, clock) -> None:
+    def attach(self, clock, counts=None) -> None:
         self._clocks.append(clock)
+        if counts is not None:
+            self._counts.append(counts)
 
     def collect(self):
         from prometheus_client.core import (
@@ -167,14 +174,13 @@ class StageSeries:
             SAMPLED_STAGES,
             STAGE_BUCKETS,
             WAITING_STAGES,
-            StageClock,
         )
 
         merged: Dict[str, dict] = {}
         for clock in self._clocks:
-            totals = clock.totals()
-            del totals["answered"]
-            for stage, row in totals.items():
+            for stage, row in clock.totals().items():
+                if self.sparse and not row["count"]:
+                    continue
                 into = merged.setdefault(
                     stage, {"wall_s": 0.0, "cpu_s": 0.0,
                             "buckets": [0] * len(row["buckets"])},
@@ -211,16 +217,16 @@ class StageSeries:
             io = CounterMetricFamily(
                 self.io_name, self.io_doc, labels=["direction"]
             )
-            io.add_metric(["read"], sum(c.reads for c in self._clocks))
-            io.add_metric(["write"], sum(c.writes for c in self._clocks))
+            io.add_metric(["read"], sum(c.reads for c in self._counts))
+            io.add_metric(["write"], sum(c.writes for c in self._counts))
             yield io
-        if self.left_name:
+        if self.left_name and self._counts:
             left = CounterMetricFamily(
                 self.left_name, self.left_doc, labels=["left"]
             )
-            for i, why in enumerate(StageClock.LEFT):
+            for i, why in enumerate(self._counts[0].LEFT):
                 left.add_metric(
-                    [why], sum(c.left[i] for c in self._clocks))
+                    [why], sum(c.left[i] for c in self._counts))
             yield left
 
 
@@ -500,18 +506,27 @@ class Metrics:
             "than twice a calibrated full launch)",
         )
         r.register(self.verifier_service_stages)
-        # The same clock on a validator's verification path (net_sync.py):
-        # one sample a received batch of blocks, always on.
+        # A validator's one clock (validator.py; spans.NODE_STAGES says
+        # where each stage is taken), always on; a stage that has booked
+        # nothing is left out.
         self.block_stages = StageSeries(
             "block_stage_seconds",
             "wall seconds of a received batch of blocks in receive (decode "
             "+ dedup + structure), verify (collector window + the round "
             "trip to the verifier) and dag_add (core-task queue + "
-            "insertion); of a gateway submission in admit_verify (its "
-            "signatures' round trip to the verifier, where signatures are "
-            "required); and of a mesh frame in mesh_hold (handed to the "
-            "connection -> written to the socket, where a link delay is "
-            "injected)",
+            "insertion); of a proposal in leader_wait; of a gateway "
+            "submission in admit_verify (its signatures' round trip to the "
+            "verifier, where signatures are required); of a mesh frame in "
+            "mesh_hold (handed to the connection -> written to the socket, "
+            "where a link delay is injected); and, on a live node, of a "
+            "core-owner command (core_command), the loop probe's lag "
+            "(loop_lag), a collection (gc), a job's wait for the default "
+            "executor (executor_wait), a WAL batch written (wal_write), a "
+            "WAL drain + fsync (wal_sync), a checkpoint, a commit's "
+            "execution fold (exec_fold), a metrics-endpoint request "
+            "(scrape) and the finality tracker's phase_admission / "
+            "phase_proposal / phase_commit samples",
+            sparse=True,
         )
         r.register(self.block_stages)
         # Staged dispatch pipeline (verify_pipeline.py): the collector may
@@ -674,13 +689,6 @@ class Metrics:
             "microseconds per committed leader (the PERF_ATTR budget rows)",
             labels=("subsystem",),
         )
-        self.mysticeti_loop_lag_seconds = histogram(
-            "mysticeti_loop_lag_seconds",
-            "asyncio loop scheduling lag: scheduled-vs-actual callback "
-            "delta of the loop-lag probe (hostattr.LoopLagProbe)",
-            buckets=[0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0,
-                     2.5],
-        )
         self.mysticeti_loop_lag_p99_seconds = gauge(
             "mysticeti_loop_lag_p99_seconds",
             "p99 loop scheduling lag over the probe's bounded window (the "
@@ -697,10 +705,6 @@ class Metrics:
             "MYSTICETI_BLOCKING_CALL_MS (the dynamic twin of the "
             "async-blocking lint rule), by command site",
             labels=("site",),
-        )
-        self.mysticeti_blocking_call_last_ms = gauge(
-            "mysticeti_blocking_call_last_ms",
-            "duration of the most recent detected blocking call, ms",
         )
         self.mysticeti_jax_compiles_total = counter(
             "mysticeti_jax_compiles_total",
@@ -1048,7 +1052,7 @@ class MetricReporter:
 
 async def serve_metrics(metrics: Metrics, host: str, port: int,
                         health_probe=None, flight_recorder=None,
-                        consensus_debug=None):
+                        consensus_debug=None, stages=None):
     """Minimal asyncio HTTP endpoint (prometheus.rs:31-49): ``/metrics`` for
     the scraper, ``/healthz`` (200 + uptime) for liveness probes, and — when
     a :class:`~mysticeti_tpu.health.HealthProbe` is wired — ``/health``, the
@@ -1059,7 +1063,10 @@ async def serve_metrics(metrics: Metrics, host: str, port: int,
     canonical document the SIGTERM/alert dumps write).  ``consensus_debug``
     is a zero-arg callable returning the live consensus-state document (DAG
     frontier, undecided slots, threshold-clock round, last-K decision
-    records) served on ``/debug/consensus``."""
+    records) served on ``/debug/consensus``.  ``stages`` is the node's
+    stage clock (``spans.StageClock``): every request, whichever document
+    it asks for, is one ``scrape`` sample, request read -> body written —
+    the endpoint renders on the node's own event loop."""
     import json as _json
 
     started = time.monotonic()
@@ -1071,6 +1078,7 @@ async def serve_metrics(metrics: Metrics, host: str, port: int,
                 line = await reader.readline()
                 if line in (b"\r\n", b"\n", b""):
                     break
+            read_at = time.monotonic()
             parts = request.split()
             path = parts[1].decode(errors="replace") if len(parts) > 1 else "/"
             status = b"200 OK"
@@ -1111,6 +1119,9 @@ async def serve_metrics(metrics: Metrics, host: str, port: int,
                 + body
             )
             await writer.drain()
+            if stages is not None:
+                written = time.monotonic()
+                stages.book("scrape", written, written - read_at)
         finally:
             writer.close()
 

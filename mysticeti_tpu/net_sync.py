@@ -122,6 +122,7 @@ class NetworkSyncer:
         metrics=None,
         start_wal_sync_thread: bool = False,
         recorder=None,
+        stages=None,
     ) -> None:
         self.parameters = parameters or Parameters()
         self.signals = AsyncSignals()
@@ -129,18 +130,24 @@ class NetworkSyncer:
         # path is — receive / verify / dag_add, one sample a batch — and
         # what the proposal gate cost it — leader_wait, one sample a
         # proposal (syncer.py); always on (the per-block spans of the first
-        # three names stay opt-in).
-        self._block_stages = None
-        if metrics is not None:
-            self._block_stages = spans.StageClock(spans.NODE_STAGES)
-            metrics.block_stages.attach(self._block_stages)
+        # three names stay opt-in).  ``stages`` is the validator's one
+        # clock (validator.py, which attached it); a syncer assembled
+        # without a validator clocks itself, with no ring.
+        if stages is None and metrics is not None:
+            stages = spans.StageClock(spans.NODE_STAGES)
+            metrics.block_stages.attach(stages)
+        self._block_stages = stages
         self.syncer = Syncer(
             core,
             self.parameters.wave_length,
             self.signals,
             commit_observer,
             metrics,
-            stages=self._block_stages,
+            stages=stages,
+            # The ring's events (slow-round) go with the ring: a simulated
+            # run has neither.
+            recorder=(recorder if stages is not None and stages.ring_seconds
+                      else None),
         )
         self.core = core
         self.network = network
@@ -160,7 +167,8 @@ class NetworkSyncer:
                     f"{type(self.block_verifier).__name__} checks none")
             require()
         self.metrics = metrics
-        self.dispatcher = CoreTaskDispatcher(self.syncer, metrics=metrics)
+        self.dispatcher = CoreTaskDispatcher(
+            self.syncer, metrics=metrics, stages=stages)
         # Batched native decode+digest off the event loop (core_task.py):
         # inert (inline path) under sims, without the extension, or for
         # small frames — see DataPlaneOffload.should_offload.
@@ -207,6 +215,9 @@ class NetworkSyncer:
         # and tests read how much bootstrap data this node shipped.
         self.snapshot_blocks_served = 0
         self.snapshot_bytes_served = 0
+        # Blocks received that passed decode, dedup and the structure
+        # checks, for the clock's stamp (spans.NODE_STAMPS).
+        self.blocks_received = 0
         # Flight recorder (flight_recorder.py): connection churn, leader
         # timeouts, and sync decisions are exactly the "seconds before the
         # incident" events its ring exists for.  None = not recording.
@@ -817,6 +828,7 @@ class NetworkSyncer:
                     "receive", block.reference, t_recv,
                     authority=self.core.authority,
                 )
+        self.blocks_received += len(verified)
         if self._block_stages is not None:
             self._block_stages.book_since("receive", t_recv)
         return verified

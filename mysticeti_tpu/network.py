@@ -620,7 +620,7 @@ class DelayLine:
                  frames=None) -> None:
         self.delay_s = float(delay_s)
         self.clock = clock
-        self._stages = stages  # spans.StageClock(("mesh_hold",)) or None
+        self._stages = stages  # the validator's spans.StageClock, or None
         self._frames = frames  # mesh_delayed_frames_total{peer} or None
 
     def due(self, stamp: float) -> float:
@@ -1101,6 +1101,7 @@ class TcpNetwork:
         metrics=None,
         max_latency_s: float = 5.0,
         link_delays_s: Optional[List[float]] = None,
+        stages=None,
     ) -> None:
         self.authority = authority
         self.addresses = addresses
@@ -1110,12 +1111,10 @@ class TcpNetwork:
         # This validator's row of ``Parameters.link_delay_ms``, in seconds:
         # the one-way delay to each peer.  None: no frame is held.
         self.link_delays_s = link_delays_s
-        self._hold_stages = None
+        # The validator's stage clock (spans.StageClock; None = not
+        # clocked): a delay line books ``mesh_hold`` into it.
+        self._hold_stages = stages if link_delays_s is not None else None
         if link_delays_s is not None and metrics is not None:
-            from . import spans
-
-            self._hold_stages = spans.StageClock(("mesh_hold",))
-            metrics.block_stages.attach(self._hold_stages)
             for peer, delay_s in enumerate(link_delays_s):
                 if peer != authority:
                     metrics.mesh_link_delay_seconds.labels(str(peer)).set(

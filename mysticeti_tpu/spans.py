@@ -39,8 +39,10 @@ prints per-stage latency breakdowns from a trace file.
 Besides the opt-in per-block tracer there is one ALWAYS-ON stage clock
 (:class:`StageClock`, :func:`request_stage`, :class:`stage`): the same stage
 names, aggregated instead of recorded — a histogram a stage, CPU seconds a
-working stage, and in the verifier service a ring of whole seconds that the
-service writes into its report.  It is what the benchmark's per-layer
+working stage, and a ring of whole seconds that the verifier service writes
+into its report and a live validator into its flight-recorder document
+(``flight_recorder.py``; one clock a validator, made by ``validator.py``).
+It is what the benchmark's per-layer
 metrics read, so it is cheap: a few clock reads a stage, one request in
 ``SAMPLE_ONE_IN`` clocked in the service, no lock, nothing allocated that
 outlives the call.  With a tracer the same calls also record the spans.
@@ -85,10 +87,10 @@ STAGES = (
     "commit",
     "finalize",
     # The gateway's check of a submission's signatures (ingress.py; always
-    # on through a StageClock of its own, where signatures are required).
+    # on through the validator's StageClock, where signatures are required).
     "admit_verify",
     # A mesh frame under an injected link delay (network.py: DelayLine;
-    # always on through a StageClock of its own, where
+    # always on through the validator's StageClock, where
     # ``Parameters.link_delay_ms`` holds a table): handed to the connection
     # -> written to the socket, one sample a frame.
     "mesh_hold",
@@ -98,6 +100,28 @@ STAGES = (
     # one sample a proposal.  The previous round's leader arriving, its
     # connection closing or the leader timeout ends it.
     "leader_wait",
+    # Where a validator's host time can hide (always on through the node's
+    # StageClock, on a live node; NODE_STAGES below says where each is
+    # taken): a synchronous command on the core owner, the event loop's
+    # lag, a garbage collection, a job's wait for a thread of the loop's
+    # default executor, a batch of WAL frames written, a WAL drain + fsync,
+    # a checkpoint whole, one commit folded through the execution state, a
+    # request to the metrics endpoint.
+    "core_command",
+    "loop_lag",
+    "gc",
+    "executor_wait",
+    "wal_write",
+    "wal_sync",
+    "checkpoint",
+    "exec_fold",
+    "scrape",
+    # The finality tracker's samples (finality.py), as they feed
+    # ``mysticeti_e2e_finality_seconds{phase}``: submit -> admitted,
+    # admitted -> proposed, proposed -> commit decision.
+    "phase_admission",
+    "phase_proposal",
+    "phase_commit",
     # The verifier service's stages of one VERIFY/RAW request
     # (verifier_service.py, ops/ed25519.py; SERVICE_STAGES below): always on
     # through StageClock, and spans keyed by (connection, req_id) when a
@@ -493,9 +517,32 @@ SAMPLED_STAGES = SERVICE_STAGES[:8]
 # A validator's verification path, one sample a received batch of blocks
 # (net_sync.py): the always-on twins of the per-block spans of those names.
 BLOCK_PATH_STAGES = ("receive", "verify", "dag_add")
-# What the node's clock books (net_sync.py): that path, and the proposal
-# gate's wait, one sample a proposal (syncer.py).
-NODE_STAGES = BLOCK_PATH_STAGES + ("leader_wait",)
+# What a validator's one clock books (validator.py makes it and hands it
+# on), and where: that path (net_sync.py, a received batch); the proposal
+# gate's wait (syncer.py, a proposal); the gateway's signature check
+# (ingress.py, a frame); a mesh frame under an injected delay (network.py);
+# a command of the core owner (core_task.py, CPU beside wall); the loop
+# probe's lag and a collection (hostattr.py, ``gc.callbacks``); the wait for
+# a thread of the loop's default executor (``in_default_executor`` below);
+# the WAL's writer and syncer threads (wal.py, storage.py), the checkpoint
+# (storage.py), the execution fold (execution.py, a commit), the metrics
+# endpoint (metrics.py, a request); the finality tracker's phases
+# (finality.py, a sampled transaction).  What measures the host (the
+# middle nine) is off under the simulator; the rest is on the runtime clock.
+NODE_STAGES = BLOCK_PATH_STAGES + (
+    "leader_wait", "admit_verify", "mesh_hold",
+    "core_command", "loop_lag", "gc", "executor_wait",
+    "wal_write", "wal_sync", "checkpoint", "exec_fold", "scrape",
+    "phase_admission", "phase_proposal", "phase_commit",
+)
+# What a validator's clock stamps once a second (``Validator._read_stamps``
+# reads them, cumulative, in this order): the threshold clock's round,
+# leaders committed, own proposals, blocks received, transactions admitted
+# and shed (all, and by ``lane_cap``), leader timeouts, and the requests it
+# sent to the verifier service.
+NODE_STAMPS = ("rounds", "leaders", "proposals", "blocks_received",
+               "tx_admitted", "shed", "shed_lane_cap", "leader_timeouts",
+               "verify_requests")
 # Stages in which a request waits (for a launch, the device, the loop, the
 # GIL): wall time only, no CPU clock and no profiler annotation —
 # the runtime's own events mark them in a trace already.
@@ -670,8 +717,9 @@ class StageClock:
     ``ring_seconds`` — a ring of the last whole seconds of
     ``time.monotonic``, each holding per stage ``[count, wall_s, cpu_s,
     max_wall_s]`` and, from ``stamp``, what was answered and what CPU was
-    used in that second.  A sample is booked to the second its stage ENDED
-    in.  Every thread books into preallocated arrays of its own (made when
+    used in that second (``stamps`` / ``read_stamps``: the owner's counts).
+    A sample is booked to the second its stage ENDED in.  Every thread
+    books into preallocated arrays of its own (made when
     it is adopted, or at its first sample), summed when read: booking takes
     no lock and allocates nothing that outlives the call.  One request in
     ``sample_one_in`` is clocked through its stages (``sampled``).  With a
@@ -681,36 +729,28 @@ class StageClock:
 
     RING_SECONDS = 600
     COLUMNS = ("count", "wall_s", "cpu_s", "max_wall_s")
-    # What ``stamp`` reads once a second, cumulative; the ring's seconds
-    # hold the growth from one stamp to the next (whole numbers, but for
-    # the seconds, ``*_s``).
-    # Why a launch of the verifier service left when it did
-    # (``VerifierServer._take``): ``left`` counts them in this order.
-    LEFT = ("alone", "full", "drained", "expired")
-    STAMPS = ("requests", "signatures", "launches",
-              *("left_" + why for why in LEFT), "reads", "writes",
-              "process_cpu_s", "threads_cpu_s", "loop_cpu_s")
+    # The CPU clocks ``stamp`` reads once a second beside its owner's
+    # counts (``stamps``): the ring's seconds hold the growth from one
+    # stamp to the next (whole numbers, but for the seconds, ``*_s``).
+    CPU_STAMPS = ("process_cpu_s", "threads_cpu_s", "loop_cpu_s")
 
     def __init__(self, stages: Sequence[str], ring_seconds: int = 0,
                  tracer: Optional[SpanTracer] = None,
-                 sample_one_in: int = 1) -> None:
+                 sample_one_in: int = 1, stamps: Sequence[str] = (),
+                 read_stamps=None, lag_stage: Optional[str] = None,
+                 gc_stage: Optional[str] = None) -> None:
+        """``stamps`` names what the clock's owner counts and
+        ``read_stamps()`` returns those counts, cumulative, in that order
+        (plain sums the stamping thread keeps or may read);
+        ``lag_stage`` / ``gc_stage`` name the stages that ``loop_lag`` and
+        ``gc_callback`` book."""
         self.stages = tuple(stages)
         self.tracer = tracer
         self.sample_one_in = sample_one_in
-        # Replies written, the signatures in them and the launches that
-        # answered them, clocked or not; the socket reads that held at
-        # least one request and the writes that held at least one reply
-        # (``requests / reads`` and ``requests / writes``: how many frames a
-        # read and replies a write carried): plain sums of the one thread
-        # that reads requests and writes replies (which also stamps).
-        # ``left``: the launches that left, by why (LEFT), counted where
-        # the dispatcher threads decide it, under the service's condition.
-        self.requests = 0
-        self.signatures = 0
-        self.launches = 0
-        self.left = [0] * len(self.LEFT)
-        self.reads = 0
-        self.writes = 0
+        self.ring_seconds = ring_seconds
+        self.stamp_names = tuple(stamps) + self.CPU_STAMPS
+        self._read_owner = read_stamps or (lambda: ())
+        self.lag_stage, self.gc_stage = lag_stage, gc_stage
         self._slot = {name: i for i, name in enumerate(self.stages)}
         self._request_slots = [
             self._slot[name] for name in REQUEST_STAGES if name in self._slot
@@ -729,7 +769,8 @@ class StageClock:
         self._turn = 0  # of the requests: which are clocked
         self._stamped = -1
         self._stamp_second = array("q", [-1]) * ring_seconds
-        self._stamps = array("d", bytes(8 * ring_seconds * len(self.STAMPS)))
+        self._stamps = array(
+            "d", bytes(8 * ring_seconds * len(self.stamp_names)))
         if ring_seconds:
             self.stamp(time.monotonic())
 
@@ -810,8 +851,8 @@ class StageClock:
     # -- once a second --
 
     def _read_stamps(self) -> tuple:
-        """STAMPS now.  A thread's CPU clock can be read from another
-        thread; one that has ended keeps what it had used."""
+        """``stamp_names`` now.  A thread's CPU clock can be read from
+        another thread; one that has ended keeps what it had used."""
         threads = 0.0
         for entry in self._thread_clocks:
             try:
@@ -819,29 +860,28 @@ class StageClock:
             except OSError:
                 pass
             threads += entry[2] - entry[1]
-        return (self.requests, self.signatures, self.launches, *self.left,
-                self.reads, self.writes, time.process_time(), threads,
+        return (*self._read_owner(), time.process_time(), threads,
                 time.thread_time())
 
     def stamp(self, now: float) -> None:
-        """In the first call of a whole second of ``now``, read STAMPS into
-        the ring (one CPU clock read a thread, a second); any other call
-        returns at once.  Called by the thread that counts ``requests``,
-        several times a second."""
+        """In the first call of a whole second of ``now``, read
+        ``stamp_names`` into the ring (one CPU clock read a thread, a
+        second); any other call returns at once.  Called by the thread that
+        keeps the owner's counts, several times a second."""
         second = int(now)
         if second == self._stamped or not self._rows:
             return
         self._stamped = second
         row = second % self._rows
-        n = len(self.STAMPS)
+        n = len(self.stamp_names)
         self._stamps[row * n:(row + 1) * n] = array("d", self._read_stamps())
         self._stamp_second[row] = second
 
     def loop_lag(self, lag: float) -> None:
-        """For ``hostattr.LoopLagProbe``: one sample of
-        ``service_loop_lag``, and the tick that ``stamp`` needs."""
+        """For ``hostattr.LoopLagProbe``: one sample of ``lag_stage``, and
+        the tick that ``stamp`` needs."""
         now = time.monotonic()
-        self.book("service_loop_lag", now, lag)
+        self.book(self.lag_stage, now, lag)
         self.stamp(now)
 
     # -- a launch on a dispatcher thread --
@@ -897,7 +937,7 @@ class StageClock:
         if phase == "start":
             state.gc_t0 = time.monotonic()
             if not state.annotated:
-                annotation = state.gc_annotation = _annotation("service_gc")
+                annotation = state.gc_annotation = _annotation(self.gc_stage)
                 if annotation is not None:
                     annotation.__enter__()
             return
@@ -918,9 +958,10 @@ class StageClock:
         books = self._books()
         books.gc[2 * generation] += 1.0
         books.gc[2 * generation + 1] += t1 - t0
-        self._book(books, self._slot["service_gc"], t1, t1 - t0, cpu)
+        self._book(books, self._slot[self.gc_stage], t1, t1 - t0, cpu)
         if self.tracer is not None:
-            self.tracer.record_span("service_gc", ("gc", generation), t0, t1)
+            self.tracer.record_span(
+                self.gc_stage, ("gc", generation), t0, t1)
 
     # -- export --
 
@@ -930,8 +971,7 @@ class StageClock:
 
     def totals(self) -> Dict[str, dict]:
         """Cumulative ``{stage: {"count", "wall_s", "cpu_s", "buckets"}}``
-        over every thread, and ``"answered"``: the requests answered,
-        clocked or not.  ``buckets`` holds one count a bound of
+        over every thread.  ``buckets`` holds one count a bound of
         STAGE_BUCKETS plus the overflow (not cumulative over the bounds)."""
         n = self._nbuckets
         totals = [0.0] * (3 * len(self.stages))
@@ -939,7 +979,7 @@ class StageClock:
         for books in self._snapshot():
             totals = [a + b for a, b in zip(totals, books.totals)]
             buckets = [a + b for a, b in zip(buckets, books.buckets)]
-        out: Dict[str, dict] = {
+        return {
             name: {
                 "count": int(totals[3 * slot]),
                 "wall_s": totals[3 * slot + 1],
@@ -948,29 +988,22 @@ class StageClock:
             }
             for slot, name in enumerate(self.stages)
         }
-        out["answered"] = self.requests
-        return out
 
     def export(self) -> dict:
-        """The ring as the service's report carries it: ``seconds`` maps a
-        whole second of ``clock`` to ``{stage: [count, wall_s, cpu_s,
-        max_wall_s]}``, stages that saw nothing left out, and — for a
-        second that was stamped — STAMPS: ``requests`` and ``signatures``
-        answered and the ``launches`` that answered them (one backend call
-        carries every request that was pending when a dispatcher thread
-        came free and the coalescer let it go) and, as ``left_<why>``, the
-        launches that left by why they did (LEFT: counted as they leave,
-        ``launches`` as they land), the socket ``reads`` that held a
-        request and the ``writes`` that held a reply (one read hands over
-        every frame it holds, one write carries every reply a launch
-        finished for a connection), and the CPU seconds the process (``process_cpu_s``),
-        the threads that book here (``threads_cpu_s``; left out where a
-        thread's CPU clock cannot be read from outside it) and the stamping
-        thread itself (``loop_cpu_s``) used, each from that second's stamp
-        to the next one's (the last: to now).  A request stage's ``count``
-        is of the requests that were clocked (one in ``sample_one_in``) and
-        its ``cpu_s`` their share of their launches' (``end_launch``).  Sum
-        the seconds inside a window."""
+        """The ring as the service's report and a validator's
+        flight-recorder document carry it: ``seconds`` maps a whole second
+        of ``clock`` to ``{stage: [count, wall_s, cpu_s, max_wall_s]}``,
+        stages that saw nothing left out, and — for a second that was
+        stamped — ``stamp_names``: what the owner counts
+        (``verifier_service.ServiceCounts.STAMPS`` in the service,
+        ``NODE_STAMPS`` on a validator), and the CPU seconds the process
+        (``process_cpu_s``), the threads that book here (``threads_cpu_s``;
+        left out where a thread's CPU clock cannot be read from outside it)
+        and the stamping thread itself (``loop_cpu_s``) used, each from
+        that second's stamp to the next one's (the last: to now).  A
+        request stage's ``count`` is of the requests that were clocked (one
+        in ``sample_one_in``) and its ``cpu_s`` their share of their
+        launches' (``end_launch``).  Sum the seconds inside a window."""
         width = self._width
         rows: Dict[int, list] = {}  # second -> the threads' rows, summed
         collections = [0.0] * 6
@@ -990,7 +1023,7 @@ class StageClock:
                     for column in (0, 1, 2):
                         into[at + column] += mine[at + column]
                     into[at + 3] = longest
-        n = len(self.STAMPS)
+        n = len(self.stamp_names)
         stamps = sorted(
             (second, self._stamps[row * n:(row + 1) * n])
             for row, second in enumerate(self._stamp_second) if second >= 0
@@ -1009,7 +1042,7 @@ class StageClock:
                     entry[name] = [int(cell[0]), cell[1], cell[2], cell[3]]
         for (second, at), (_, then) in zip(stamps, stamps[1:]):
             entry = out.setdefault(str(second), {})
-            for i, name in enumerate(self.STAMPS):
+            for i, name in enumerate(self.stamp_names):
                 grown = then[i] - at[i]
                 entry[name] = grown if name.endswith("_s") else int(grown)
             if not self._thread_clocks:
@@ -1080,6 +1113,42 @@ class stage:  # noqa: N801 - reads as a statement: ``with stage(...):``
             if clock.tracer is not None and ref is not None:
                 clock.tracer.record_span(self.name, ref, self.since, t1)
         return False
+
+
+@contextmanager
+def booked(clock: Optional[StageClock], name: str, cpu: bool = False):
+    """One sample of stage ``name`` around the block — its wall and, with
+    ``cpu``, the calling thread's CPU — booked as the block is left,
+    however it is left; with no clock, just the block."""
+    if clock is None:
+        yield
+        return
+    t0 = time.monotonic()
+    c0 = time.thread_time() if cpu else 0.0
+    try:
+        yield
+    finally:
+        end = time.monotonic()
+        clock.book(name, end, end - t0,
+                   time.thread_time() - c0 if cpu else 0.0)
+
+
+def in_default_executor(loop, clock: Optional[StageClock], fn, *args):
+    """``loop.run_in_executor(None, fn, *args)`` with the job's wait for a
+    thread of the loop's default pool — handed over -> its first
+    instruction — booked as ``executor_wait``: the pool is shared by the
+    gateway's signature check and the collector's two hops, and a job
+    queued behind the others waits here."""
+    if clock is None:
+        return loop.run_in_executor(None, fn, *args)
+    handed = time.monotonic()
+
+    def job():
+        begun = time.monotonic()
+        clock.book("executor_wait", begun, begun - handed)
+        return fn(*args)
+
+    return loop.run_in_executor(None, job)
 
 
 # ---------------------------------------------------------------------------

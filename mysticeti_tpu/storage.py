@@ -55,6 +55,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from .config import StorageParameters
 from .serde import Reader, SerdeError, Writer
+from .spans import booked
 from .tracing import logger
 from .types import BlockReference
 from .wal import (
@@ -159,8 +160,20 @@ class SegmentedWalWriter:
         self._segments: List[_Segment] = []
         self._next_seq = 0
         self._active_writer: Optional[WalWriter] = None
+        self._stages = None
         os.makedirs(directory, exist_ok=True)
         self._recover_manifest()
+
+    @property
+    def stages(self):
+        """The validator's stage clock (``WalWriter.stages``): every
+        segment's writer books into it, and the syncer."""
+        return self._stages
+
+    @stages.setter
+    def stages(self, clock) -> None:
+        self._stages = clock
+        self._active_writer.stages = clock
 
     # -- recovery --
 
@@ -250,6 +263,7 @@ class SegmentedWalWriter:
         fd = os.open(seg.path, os.O_RDWR | os.O_CREAT, 0o644)
         writer = WalWriter(fd, os.fstat(fd).st_size, seg.path,
                            async_writes=self._async)
+        writer.stages = self._stages
         reader = WalReader(seg.path)
         reader._inflight = writer.inflight_get
         reader._writer_flush = writer.flush
@@ -487,6 +501,12 @@ class SegmentedWalSyncer:
         self._path: Optional[str] = None
 
     def sync(self) -> None:
+        """Drain + fsync; one ``wal_sync`` sample where the writer is
+        clocked."""
+        with booked(self._writer.stages, "wal_sync"):
+            self._sync()
+
+    def _sync(self) -> None:
         try:
             self._writer.flush()
         except (WalError, OSError):
@@ -972,6 +992,9 @@ class StorageLifecycle:
         # the node assembly: GC passes and checkpoint writes are incident-
         # ring events.
         self.recorder = None
+        # The validator's stage clock (spans.StageClock; None = not
+        # clocked): a checkpoint, whole, is one ``checkpoint`` sample.
+        self.stages = None
         # Boot-cost evidence (the acceptance criterion "replay bytes <<
         # lifetime WAL bytes"): how much replay this boot actually paid.
         self.replay_start = recovered.replay_start
@@ -1050,6 +1073,10 @@ class StorageLifecycle:
         """One durable recovery anchor.  The WAL is fsynced FIRST: a
         checkpoint must never reference bytes that could be lost behind it
         (replay starts at its recorded position)."""
+        with booked(self.stages, "checkpoint"):
+            return self._write_checkpoint(core, committed_state)
+
+    def _write_checkpoint(self, core, committed_state: bytes) -> str:
         self.wal_writer.sync()
         ckpt = Checkpoint(
             wal_position=self.wal_writer.position(),

@@ -42,6 +42,7 @@ class Syncer:
         commit_observer: CommitObserver,
         metrics=None,
         stages=None,
+        recorder=None,
     ) -> None:
         self.core = core
         self.force_new_block_flag = False
@@ -54,6 +55,20 @@ class Syncer:
         # node's spans.StageClock; None = not clocked).
         self.stages = stages
         self._round_reached_at = None
+        # Own proposals and leader timeouts, for the clock's stamp
+        # (spans.NODE_STAMPS).
+        self.proposals = 0
+        self.leader_timeouts = 0
+        # ``slow-round`` events (flight_recorder.py; None = not recorded):
+        # the threshold clock took over SLOW_ROUND_S to advance — the
+        # round it sat in, the seconds, this validator's own wait at the
+        # proposal gate in that round and what ended it (the ``leader``
+        # arrived with a batch of blocks, a connection ``closed``, or the
+        # leader ``timeout`` fired).
+        self.recorder = recorder
+        self._advanced_at = None
+        self._wait_s = 0.0
+        self._wait_ended = None
 
     def add_blocks(
         self, blocks: Sequence[StatementBlock], connected_authorities: AuthoritySet
@@ -63,18 +78,32 @@ class Syncer:
         new_round = self.core.current_round()
         if new_round > previous_round:
             if self.stages is not None:
-                self._round_reached_at = spans.runtime_now()
+                now = self._round_reached_at = spans.runtime_now()
+                if self.recorder is not None:
+                    self._note_advance(previous_round, now)
             self.signals.new_round(new_round)
             if self.metrics is not None:
                 self.metrics.threshold_clock_round.set(new_round)
-        self.try_new_block(connected_authorities)
+        self.try_new_block(connected_authorities, ended="leader")
         return missing_references
+
+    SLOW_ROUND_S = 0.5
+
+    def _note_advance(self, previous_round: RoundNumber, now: float) -> None:
+        took = now - (self._advanced_at or now)
+        self._advanced_at = now
+        if took > self.SLOW_ROUND_S:
+            self.recorder.record(
+                "slow-round", round=previous_round, seconds=round(took, 6),
+                wait_s=round(self._wait_s, 6), ended=self._wait_ended,
+            )
 
     def force_new_block(
         self, round_: RoundNumber, connected_authorities: AuthoritySet,
         genesis: bool = False,
     ) -> bool:
         if self.core.last_proposed() < round_:
+            self.leader_timeouts += 1
             if self.metrics is not None:
                 self.metrics.leader_timeout_total.inc()
                 if not genesis:
@@ -89,7 +118,7 @@ class Syncer:
                         )
                         channel.labels(str(leader)).inc()
             self.force_new_block_flag = True
-            self.try_new_block(connected_authorities)
+            self.try_new_block(connected_authorities, ended="timeout")
             return True
         return False
 
@@ -113,14 +142,23 @@ class Syncer:
         self.commit_observer.adopt_snapshot(manifest)
         return True
 
-    def try_new_block(self, connected_authorities: AuthoritySet) -> None:
+    def try_new_block(self, connected_authorities: AuthoritySet,
+                      ended: str = "closed") -> None:
+        """``ended``: what woke the proposal gate — a batch of blocks
+        (``leader``), the leader ``timeout``, or, as the dispatcher calls
+        it, a connection that ``closed``."""
         if self.force_new_block_flag or self.core.ready_new_block(
             self.commit_period, connected_authorities
         ):
             if self.core.try_new_block() is None:
                 return
+            self.proposals += 1
             if self._round_reached_at is not None:
-                self.stages.book_since("leader_wait", self._round_reached_at)
+                end = spans.runtime_now()
+                self._wait_s = end - self._round_reached_at
+                self._wait_ended = (
+                    "timeout" if self.force_new_block_flag else ended)
+                self.stages.book("leader_wait", end, self._wait_s)
                 self._round_reached_at = None
             self.signals.new_block_ready()
             self.force_new_block_flag = False

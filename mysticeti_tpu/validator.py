@@ -19,6 +19,7 @@ AcceptAllBlockVerifier wiring, validator.rs:137).
 from __future__ import annotations
 
 import asyncio
+import gc
 import os
 from typing import List, Optional, Tuple
 
@@ -35,7 +36,12 @@ from .committee import Committee
 from .config import Parameters, PrivateConfig
 from .core import Core, CoreOptions
 from .crypto import Signer
-from .flight_recorder import FlightRecorder, path_from_env
+from .flight_recorder import (
+    DEFAULT_CAPACITY,
+    LIVE_CAPACITY,
+    FlightRecorder,
+    path_from_env,
+)
 from .health import HealthProbe, SLOThresholds
 from .ingress import IngressGateway, IngressPlane
 from .metrics import MetricReporter, Metrics, serve_metrics
@@ -74,6 +80,7 @@ def _make_verifier(kind: str, committee: Committee, metrics=None):
 
     ready = threading.Event()
     warm = None  # background warmup, started once the verifier is assembled
+    tpu_backend = None
     aggregate = kind.endswith("-agg")
     if aggregate:
         kind = kind[: -len("-agg")]
@@ -156,6 +163,10 @@ def _make_verifier(kind: str, committee: Committee, metrics=None):
     else:
         raise ValueError(f"unknown verifier kind {kind!r}")
     verifier.ready = ready
+    # The client of the verifier service, where this validator has one: its
+    # ``requests_sent`` is the ``verify_requests`` the node's clock stamps.
+    verifier.service_client = (
+        tpu_backend if hasattr(tpu_backend, "requests_sent") else None)
     verifier.warmup_error = None
     if warm is not None:
         threading.Thread(target=warm, daemon=True, name="verifier-warmup").start()
@@ -178,6 +189,72 @@ class Validator:
         self.ingress: Optional[IngressPlane] = None
         self.gateway: Optional[IngressGateway] = None
         self.host_monitor = None
+        # The validator's one stage clock (spans.StageClock over
+        # spans.NODE_STAGES), and the flight recorder's file at shutdown.
+        self.stages = None
+        self._plane: Optional[IngressPlane] = None
+        self._recorder_path: Optional[str] = None
+
+    def _make_clock(self, private: PrivateConfig):
+        """One clock a validator: every always-on stage of the node books
+        into it, ``block_stage_seconds{stage}`` renders it, and on a live
+        node it keeps the last ten minutes by the second, which the flight
+        recorder's document carries.  Under the simulator it has no ring
+        and what measures the host is not handed it (``_host_clock``)."""
+        from . import spans
+        from .runtime import is_simulated
+
+        clock = self.stages = spans.StageClock(
+            spans.NODE_STAGES,
+            ring_seconds=(
+                0 if is_simulated() else spans.StageClock.RING_SECONDS),
+            stamps=spans.NODE_STAMPS,
+            read_stamps=self._read_stamps,
+            lag_stage="loop_lag",
+            gc_stage="gc",
+        )
+        self.metrics.block_stages.attach(clock)
+        self._recorder_path = os.path.join(
+            private.storage_path, "flight-recorder.json")
+        if clock.ring_seconds:
+            gc.callbacks.append(clock.gc_callback)
+        return clock
+
+    def _host_clock(self):
+        """The clock for what measures the host (the core owner's
+        commands, the loop, the WAL's threads, the checkpoint, the
+        execution fold, the endpoint): None under the simulator."""
+        clock = self.stages
+        return clock if clock is not None and clock.ring_seconds else None
+
+    def _clock_storage(self, wal_writer, lifecycle) -> None:
+        clock = self._host_clock()
+        if clock is not None:
+            wal_writer.stages = clock
+            if lifecycle is not None:
+                lifecycle.stages = clock
+
+    def _read_stamps(self) -> tuple:
+        """spans.NODE_STAMPS now, cumulative: plain sums the loop thread
+        keeps (zeros until the node is assembled)."""
+        from .ingress import SHED_LANE_CAP
+        from .spans import NODE_STAMPS
+
+        syncer, core, plane = self.network_syncer, self.core, self._plane
+        if syncer is None or core is None:
+            return (0,) * len(NODE_STAMPS)
+        client = getattr(syncer.block_verifier, "service_client", None)
+        return (
+            core.current_round(),
+            core.storage.commit_height if core.storage is not None else 0,
+            syncer.syncer.proposals,
+            syncer.blocks_received,
+            plane.admitted_total if plane is not None else 0,
+            plane.shed_total() if plane is not None else 0,
+            plane.shed_for(SHED_LANE_CAP) if plane is not None else 0,
+            syncer.syncer.leader_timeouts,
+            client.requests_sent if client is not None else 0,
+        )
 
     async def warmup_failure(self) -> None:
         """Completes only by raising: the verifier's background warmup
@@ -194,11 +271,17 @@ class Validator:
 
     def _make_recorder(self, authority: int, lifecycle, observer):
         """The always-on flight recorder: ring in memory unconditionally,
-        on-disk dumps when ``MYSTICETI_FLIGHT_RECORDER`` names a path."""
+        written at shutdown to ``flight-recorder.json`` in the storage
+        directory (``stop``); ``MYSTICETI_FLIGHT_RECORDER`` moves that file
+        and turns on the alert-triggered dumps."""
         recorder = FlightRecorder(
             authority=authority,
+            # A simulated run's document is what it was, capacity and all.
+            capacity=(LIVE_CAPACITY if self._host_clock() is not None
+                      else DEFAULT_CAPACITY),
             dump_path=path_from_env(authority),
             metrics=self.metrics,
+            stages=self.stages,
         )
         if lifecycle is not None:
             lifecycle.recorder = recorder
@@ -239,7 +322,8 @@ class Validator:
             recorder=self.recorder,
         )
         monitor = HostMonitor(
-            metrics=self.metrics, recorder=self.recorder
+            metrics=self.metrics, recorder=self.recorder,
+            stages=self._host_clock(),
         ).start()
         self.host_monitor = monitor
         if self.network_syncer is not None:
@@ -310,15 +394,17 @@ class Validator:
         current_authority.set(authority)
         log.info("starting benchmarking validator %d (verifier=%s)", authority, verifier)
         v.metrics = Metrics()
+        clock = v._make_clock(private)
         (recovered, observer_recovered, wal_writer, lifecycle) = cls.init_storage(
             authority, committee, private, parameters, v.metrics
         )
+        v._clock_storage(wal_writer, lifecycle)
         # Overload-resilient ingress plane (ingress.py): every submission —
         # generator or gateway client — runs through the admission-controlled
         # mempool; proposals drain weighted-round-robin from it.
-        plane = (
+        plane = v._plane = (
             IngressPlane(parameters.ingress, authority=authority,
-                         metrics=v.metrics)
+                         metrics=v.metrics, stages=clock)
             if parameters.ingress.enabled
             else None
         )
@@ -345,6 +431,8 @@ class Validator:
             storage=lifecycle,
         )
         v.core = core
+        if core.execution is not None:
+            core.execution.stages = v._host_clock()
         observer = TestCommitObserver(
             core.block_store,
             committee,
@@ -362,6 +450,7 @@ class Validator:
         core.block_store.recorder = recorder
         core.committer.ledger.recorder = recorder
         block_verifier = _make_verifier(verifier, committee, v.metrics)
+        block_verifier.stages = v._host_clock()
         # Overload modes (tools/overload_bench.py drives these through the
         # environment): an offered-load multiplier schedule and a closed
         # loop that consumes the ingress plane's SHED/retry-after verdicts.
@@ -404,6 +493,7 @@ class Validator:
                 metrics=v.metrics,
                 max_latency_s=parameters.network_connection_max_latency_s,
                 link_delays_s=parameters.link_delays_s(authority),
+                stages=clock,
             )
         v.network_syncer = NetworkSyncer(
             core,
@@ -414,6 +504,7 @@ class Validator:
             metrics=v.metrics,
             start_wal_sync_thread=True,
             recorder=recorder,
+            stages=clock,
         )
         await v.network_syncer.start()
         v.generator.start()
@@ -451,6 +542,7 @@ class Validator:
                 v.metrics, "0.0.0.0", port, health_probe=v.health,
                 flight_recorder=recorder,
                 consensus_debug=v._consensus_debug_doc,
+                stages=v._host_clock(),
             )
         return v
 
@@ -501,9 +593,11 @@ class Validator:
         current_authority.set(authority)
         log.info("starting production validator %d (verifier=%s)", authority, verifier)
         v.metrics = Metrics()
+        clock = v._make_clock(private)
         (recovered, observer_recovered, wal_writer, lifecycle) = cls.init_storage(
             authority, committee, private, parameters, v.metrics
         )
+        v._clock_storage(wal_writer, lifecycle)
         handler = SimpleBlockHandler()
         core = Core(
             block_handler=handler,
@@ -518,6 +612,8 @@ class Validator:
             storage=lifecycle,
         )
         v.core = core
+        if core.execution is not None:
+            core.execution.stages = v._host_clock()
         consumer = commit_consumer or CommitConsumer()
         observer = SimpleCommitObserver(
             core.block_store,
@@ -533,11 +629,13 @@ class Validator:
                 metrics=v.metrics,
                 max_latency_s=parameters.network_connection_max_latency_s,
                 link_delays_s=parameters.link_delays_s(authority),
+                stages=clock,
             )
         recorder = v._make_recorder(authority, lifecycle, observer)
         core.block_store.recorder = recorder
         core.committer.ledger.recorder = recorder
         block_verifier = _make_verifier(verifier, committee, v.metrics)
+        block_verifier.stages = v._host_clock()
         v.network_syncer = NetworkSyncer(
             core,
             observer,
@@ -547,6 +645,7 @@ class Validator:
             metrics=v.metrics,
             start_wal_sync_thread=True,
             recorder=recorder,
+            stages=clock,
         )
         await v.network_syncer.start()
         v.reporter = MetricReporter(v.metrics).start()
@@ -579,9 +678,18 @@ class Validator:
         spans.flush_active()
         # Flight-recorder tail: SIGTERM routes here too (the node CLI's
         # handler), so an operator-stopped node always leaves its incident
-        # ring on disk when MYSTICETI_FLIGHT_RECORDER is set.
-        if self.recorder is not None and self.recorder.dump_path:
-            self.recorder.dump("shutdown")
+        # ring and its last ten minutes by the second on disk — beside its
+        # WAL, or where MYSTICETI_FLIGHT_RECORDER says.
+        if self._host_clock() is not None:
+            try:
+                gc.callbacks.remove(self.stages.gc_callback)
+            except ValueError:
+                pass  # stopped twice
+        if self.recorder is not None:
+            self.recorder.dump(
+                "shutdown",
+                path=self.recorder.dump_path or self._recorder_path,
+            )
         if self.core is not None:
             self.core.wal_writer.close()
             # Release the WAL reader too (fd + whole-file mmap): embeddings

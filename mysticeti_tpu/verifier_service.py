@@ -110,9 +110,42 @@ _REQUEST = struct.Struct("<II")  # u32 req_id | u32 n
 
 ENV_SOCKET = "MYSTICETI_VERIFIER_SOCKET"
 
-# Why a launch left (``VerifierServer._take``), as ``StageClock.left`` is
-# indexed.
-_ALONE, _FULL, _DRAINED, _EXPIRED = range(len(spans.StageClock.LEFT))
+
+
+class ServiceCounts:
+    """What the service's stage clock stamps once a second
+    (``spans.StageClock``: ``stamps`` / ``read_stamps``), cumulative:
+    replies written, the signatures in them and the launches that answered
+    them, clocked or not; the socket reads that held at least one request
+    and the writes that held at least one reply (``requests / reads`` and
+    ``requests / writes``: how many frames a read and replies a write
+    carried) — plain sums of the one thread that reads requests and writes
+    replies, which also stamps; and ``left``: the launches that left, by
+    why (LEFT), counted where the dispatcher threads decide it, under the
+    service's condition."""
+
+    # Why a launch left when it did (``VerifierServer._take``): ``left``
+    # counts them in this order.
+    LEFT = ("alone", "full", "drained", "expired")
+    STAMPS = ("requests", "signatures", "launches",
+              *("left_" + why for why in LEFT), "reads", "writes")
+
+    __slots__ = ("requests", "signatures", "launches", "left", "reads",
+                 "writes")
+
+    def __init__(self) -> None:
+        self.requests = self.signatures = self.launches = 0
+        self.left = [0] * len(self.LEFT)
+        self.reads = self.writes = 0
+
+    def read(self) -> tuple:
+        """STAMPS now."""
+        return (self.requests, self.signatures, self.launches, *self.left,
+                self.reads, self.writes)
+
+
+# Why a launch left, as ``ServiceCounts.left`` is indexed.
+_ALONE, _FULL, _DRAINED, _EXPIRED = range(len(ServiceCounts.LEFT))
 
 # VerifierProtocolError (re-exported above from block_validator): the service
 # answered but REJECTED the request.  Excluded from the client's retry loop
@@ -427,7 +460,7 @@ class _Connection(asyncio.Protocol):
                     self._refuse(b"unknown frame type")
                     break
         if requests:
-            server.stages.reads += 1
+            server.counts.reads += 1
             # The gauges move once a read: depth = requests handed over
             # and not yet answered (pending, or riding a launch); inflight
             # splits it per client connection so one flooding validator is
@@ -549,14 +582,15 @@ class _Connection(asyncio.Protocol):
         if answered:
             # Counted for every request: sums of this thread's, which the
             # clock's stamp reads once a second.
-            stages = self.server.stages
-            stages.writes += 1
-            stages.requests += answered
-            stages.signatures += signatures
+            counts = self.server.counts
+            counts.writes += 1
+            counts.requests += answered
+            counts.signatures += signatures
             if clocked:
                 # service_reply_wait: reply built -> written, i.e. the
                 # loop's wake-up, the earlier replies of this connection,
                 # then the write.
+                stages = self.server.stages
                 written = time.monotonic()
                 for slot in clocked:
                     stages.book("service_reply_wait", written,
@@ -658,14 +692,19 @@ class VerifierServer:
         # spans.SAMPLE_ONE_IN clocked whole, scraped through ``metrics``
         # when there is one, and written as the last 600 whole seconds of
         # time.monotonic into the report at ``stop``.
+        self.counts = ServiceCounts()
         self.stages = spans.StageClock(
             spans.SERVICE_STAGES,
             ring_seconds=spans.StageClock.RING_SECONDS,
             tracer=spans.active(),
             sample_one_in=spans.SAMPLE_ONE_IN,
+            stamps=ServiceCounts.STAMPS,
+            read_stamps=self.counts.read,
+            lag_stage="service_loop_lag",
+            gc_stage="service_gc",
         )
         if metrics is not None:
-            metrics.verifier_service_stages.attach(self.stages)
+            metrics.verifier_service_stages.attach(self.stages, self.counts)
         self._conn_ids = itertools.count()
         self._warmed = threading.Event()
         self._warm_lock = threading.Lock()
@@ -993,9 +1032,9 @@ class VerifierServer:
 
     def _leave(self, batch: List[_Pending], why: int, now: float):
         """``batch`` leaves because ``why`` (an index of
-        ``spans.StageClock.LEFT``): counted, its hold begun where it is
+        ``ServiceCounts.LEFT``): counted, its hold begun where it is
         part-full, and another slot woken for what it left pending."""
-        self.stages.left[why] += 1
+        self.counts.left[why] += 1
         hold = None
         if why == _ALONE:
             self._promised -= 1
@@ -1086,7 +1125,7 @@ class VerifierServer:
         and every connection it touched writes what it now can, once.  A
         launch that raised closes exactly the connections whose requests
         rode it; a request whose connection is lost is dropped here."""
-        self.stages.launches += 1
+        self.counts.launches += 1
         self._in_service -= len(batch)
         if error is not None:
             log.error("verifier service dispatch failed", exc_info=error)
@@ -1355,6 +1394,10 @@ class RemoteSignatureVerifier(SignatureVerifier):
         self._index = {pk: i for i, pk in enumerate(self._keys)}
         self.timeout_s = timeout_s
         self.metrics = metrics
+        # Requests sent, every road (``_count_request``, from whichever
+        # thread sends): the validator's stage clock stamps its growth.
+        self.requests_sent = 0
+        self._sent_lock = threading.Lock()
         self.max_attempts = max_attempts or self.MAX_ATTEMPTS
         self._retry_rng = random.Random(0x5E7C1E27)
         self._tls = threading.local()
@@ -1422,6 +1465,8 @@ class RemoteSignatureVerifier(SignatureVerifier):
         """One request sent down ``path`` (``shared`` / ``pooled`` /
         ``sync``); one that is re-run or deferred counts again, as
         ``sync``."""
+        with self._sent_lock:
+            self.requests_sent += 1
         if self.metrics is not None:
             self.metrics.verifier_client_requests_total.labels(path).inc()
 

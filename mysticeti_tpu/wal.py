@@ -25,10 +25,12 @@ import mmap
 import os
 import struct
 import threading
+import time
 import zlib
 from typing import Iterator, List, Optional, Sequence, Tuple
 
 from .native import native as _native
+from .spans import booked
 
 
 def _close_quietly(m: mmap.mmap) -> None:
@@ -106,7 +108,8 @@ class WalWriter:
     """
 
     __slots__ = ("_fd", "_pos", "_path", "_closed", "_async", "_queue",
-                 "_inflight", "_inflight_lock", "_thread", "_error")
+                 "_inflight", "_inflight_lock", "_thread", "_error",
+                 "stages")
 
     def __init__(self, fd: int, pos: int, path: str,
                  async_writes: Optional[bool] = None) -> None:
@@ -119,6 +122,11 @@ class WalWriter:
             async_writes = os.environ.get("MYSTICETI_SYNC_WAL_WRITES") != "1"
         self._async = async_writes
         self._error: Optional[BaseException] = None
+        # The validator's stage clock (spans.StageClock; None = not
+        # clocked): the writer thread books ``wal_write``, one sample a
+        # run of queued frames — first frame taken -> queue empty — wall
+        # and the thread's CPU; a syncer made from here books ``wal_sync``.
+        self.stages = None
         if async_writes:
             import queue as _queue
 
@@ -186,24 +194,36 @@ class WalWriter:
             written += n
 
     def _drain(self) -> None:
+        t0 = c0 = 0.0
+        timed = False  # a clocked run of frames is under way
         while True:
             item = self._queue.get()
+            frame = None
+            if item is not None and not isinstance(item, threading.Event):
+                with self._inflight_lock:
+                    frame = self._inflight.get(item)
+            if frame is not None:
+                if self.stages is not None and not timed:
+                    timed = True
+                    t0, c0 = time.monotonic(), time.thread_time()
+                try:
+                    self._pwrite_all(frame, item, len(frame))
+                except BaseException as exc:  # noqa: BLE001 - recorded, re-raised
+                    self._error = exc
+                    return
+                with self._inflight_lock:
+                    self._inflight.pop(item, None)
+            if timed and (frame is None or self._queue.empty()):
+                # The run is over: nothing is queued behind its last frame,
+                # or a flush marker (or the end) follows it.
+                timed = False
+                end = time.monotonic()
+                self.stages.book("wal_write", end, end - t0,
+                                 time.thread_time() - c0)
             if item is None:
                 return
             if isinstance(item, threading.Event):
                 item.set()  # flush marker: everything before it has landed
-                continue
-            with self._inflight_lock:
-                frame = self._inflight.get(item)
-            if frame is None:
-                continue
-            try:
-                self._pwrite_all(frame, item, len(frame))
-            except BaseException as exc:  # noqa: BLE001 - recorded, re-raised
-                self._error = exc
-                return
-            with self._inflight_lock:
-                self._inflight.pop(item, None)
 
     def inflight_get(self, position: WalPosition) -> Optional[bytes]:
         """Framed bytes of a queued-but-unwritten entry (reader seam).
@@ -288,7 +308,7 @@ class WalWriter:
         appends, an fsync that does not drain the queue first would not
         cover acknowledged entries and the 1 s loss-window bound would be a
         lie."""
-        return WalSyncer(self._path, flush=self.flush)
+        return WalSyncer(self._path, flush=self.flush, stages=self.stages)
 
     def close(self) -> None:
         if not self._closed:
@@ -307,13 +327,19 @@ class WalSyncer:
     dedicated flusher thread never contends with the appender (wal.rs:199-208,
     used by net_sync.rs:496-560's AsyncWalSyncer)."""
 
-    __slots__ = ("_fd", "_flush")
+    __slots__ = ("_fd", "_flush", "_stages")
 
-    def __init__(self, path: str, flush=None) -> None:
+    def __init__(self, path: str, flush=None, stages=None) -> None:
         self._fd = os.open(path, os.O_RDWR)
         self._flush = flush
+        self._stages = stages  # the writer's stage clock, or None
 
     def sync(self) -> None:
+        """Drain + fsync; one ``wal_sync`` sample where clocked."""
+        with booked(self._stages, "wal_sync"):
+            self._sync()
+
+    def _sync(self) -> None:
         if self._flush is not None:
             try:
                 self._flush()

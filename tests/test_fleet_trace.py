@@ -129,7 +129,7 @@ class _SimNodeNetwork:
 
 
 def _build_node(committee, signers, authority, tmp_dir, sim_net, parameters,
-                recorder=None):
+                recorder=None, stages=None):
     wal_writer, wal_reader = walf(os.path.join(tmp_dir, f"wal-{authority}"))
     recovered, observer_recovered = BlockStore.open(
         authority, wal_reader, wal_writer, committee
@@ -160,18 +160,26 @@ def _build_node(committee, signers, authority, tmp_dir, sim_net, parameters,
         _SimNodeNetwork(sim_net.node_connections[authority]),
         parameters=parameters,
         recorder=recorder,
+        stages=stages,
     )
 
 
-async def _run_traced_fleet(n, tmp_dir, virtual_seconds, recorders):
+async def _run_traced_fleet(n, tmp_dir, virtual_seconds, recorders,
+                            clocks=None):
     committee = Committee.new_test([1] * n)
     signers = Committee.benchmark_signers(n)
     parameters = Parameters(leader_timeout_s=1.0)
     parameters.synchronizer.timestamp_frames = True
     sim_net = SimulatedNetwork(n)
+    if clocks is not None:
+        # Made on the virtual loop, as validator.py makes a validator's.
+        clocks.extend(_validators_clock(tmp_dir, a) for a in range(n))
+        for recorder, clock in zip(recorders, clocks):
+            recorder.stages = clock
     nodes = [
         _build_node(committee, signers, a, tmp_dir, sim_net, parameters,
-                    recorder=recorders[a])
+                    recorder=recorders[a],
+                    stages=clocks[a] if clocks is not None else None)
         for a in range(n)
     ]
     for node in nodes:
@@ -185,6 +193,60 @@ async def _run_traced_fleet(n, tmp_dir, virtual_seconds, recorders):
     # runs where the incident is; and is_simulated() gates the wall stamp).
     dumps = [recorders[a].snapshot_bytes() for a in range(n)]
     return nodes, dumps
+
+
+def _validators_clock(tmp_dir, authority):
+    """The stage clock ``Validator._make_clock`` makes, where it is called."""
+    from mysticeti_tpu.config import PrivateConfig
+    from mysticeti_tpu.metrics import Metrics
+    from mysticeti_tpu.validator import Validator
+
+    validator = Validator()
+    validator.metrics = Metrics()
+    clock = validator._make_clock(PrivateConfig.new_in_dir(
+        authority, os.path.join(tmp_dir, f"v{authority}")))
+    assert validator._host_clock() is None  # nothing that measures the host
+    return clock
+
+
+# sha256 over the four dumps of ``_run_traced_fleet(4, ..., 6.0)`` at seed 23
+# as the tree before the validator's ring (PR 37) wrote them.  A PR that
+# adds an event to simulated runs moves it, and says so here.
+SIM_DUMPS_BEFORE_THE_RING = (
+    "2b816902c58ce1566f5ce6a4a1290690ee74635fc26da61c4b3e50883596a2e8")
+
+
+def test_a_simulated_validator_has_no_ring_and_dumps_what_it_dumped(tmp_path):
+    """Under the simulator the validator's clock has no ring, so the
+    recorder's document has no ``"stages"`` and the ring's events
+    (``slow-round``, ``shed``) are not recorded: a seeded fleet whose nodes
+    were handed that clock dumps, byte for byte, what one that was handed
+    none dumps, and what the tree before the ring dumped."""
+    import hashlib
+
+    def dumps_of(name, clocks):
+        (tmp_path / name).mkdir()
+        recorders = [FlightRecorder(authority=a) for a in range(4)]
+        _, dumps = run_simulation(
+            _run_traced_fleet(4, str(tmp_path / name), 6.0, recorders,
+                              clocks=clocks), seed=23)
+        return dumps
+
+    clocks = []
+    clocked, bare = dumps_of("clocked", clocks), dumps_of("bare", None)
+    assert len(clocks) == 4
+    assert all(clock.ring_seconds == 0 for clock in clocks)
+    assert all(clock.stages == spans.NODE_STAGES for clock in clocks)
+    # The clock still books what runs on the virtual clock.
+    assert clocks[0].totals()["leader_wait"]["count"] > 10
+    assert clocks[0].totals()["core_command"]["count"] == 0
+    assert clocked == bare
+    for dump in clocked:
+        doc = json.loads(dump)
+        assert "stages" not in doc
+        assert not {e["kind"] for e in doc["events"]} & {"slow-round", "shed"}
+    assert (hashlib.sha256(b"\n".join(clocked)).hexdigest()
+            == SIM_DUMPS_BEFORE_THE_RING)
 
 
 def _traced_fleet_run(tmp_dir, seed, n=10):

@@ -25,6 +25,7 @@ from mysticeti_tpu.verifier_service import (
     T_HELLO_OK,
     T_RESULT,
     T_VERIFY,
+    ServiceCounts,
     VerifierServer,
     report_path,
 )
@@ -108,6 +109,22 @@ async def _serve(tmp_path, backend, fn, metrics=None, tracer=None,
         await server.stop()
 
 
+def _totals(server):
+    """The clock's totals, and what the service's own counts say was
+    answered, clocked or not."""
+    return dict(server.stages.totals(), answered=server.counts.requests)
+
+
+def _service_clock(**kwargs):
+    """A clock as the service makes one, and the counts it stamps."""
+    counts = ServiceCounts()
+    clock = spans.StageClock(
+        spans.SERVICE_STAGES, stamps=ServiceCounts.STAMPS,
+        read_stamps=counts.read, lag_stage="service_loop_lag",
+        gc_stage="service_gc", **kwargs)
+    return clock, counts
+
+
 def _ring_sums(report):
     """{stage: [count, wall_s, cpu_s, max_wall_s]} and requests /
     signatures over every second of a report's ring."""
@@ -170,7 +187,7 @@ def test_every_stage_counts_every_request_and_their_walls_tile_it(tmp_path):
         ))
         for conn in replies:
             assert conn == [bytes([1] * 5)] * per_conn
-        return server.stages.export(), server.stages.totals()
+        return server.stages.export(), _totals(server)
 
     report, totals = asyncio.run(
         _serve(tmp_path, CpuSignatureVerifier(), scenario, tracer=tracer))
@@ -302,7 +319,7 @@ def test_a_collection_shows_in_service_gc(tmp_path):
             gc.collect()
         finally:
             gc.callbacks.remove(server.stages.gc_callback)
-        return server.stages.export(), server.stages.totals()
+        return server.stages.export(), _totals(server)
 
     report, totals = asyncio.run(
         _serve(tmp_path, CpuSignatureVerifier(), scenario))
@@ -404,7 +421,6 @@ def test_sixteen_threads_lose_no_sample():
         t.join()
     total = per_thread * n_threads
     totals = clock.totals()
-    del totals["answered"]
     assert sum(row["count"] for row in totals.values()) == total
     assert sum(sum(row["buckets"]) for row in totals.values()) == total
     sums = _ring_sums(clock.export())[0]
@@ -421,7 +437,7 @@ def test_a_seconds_stamp_holds_what_was_answered_and_the_cpu_used():
     thread) and its own: a second's entry holds the growth to the next
     stamp.  A thread that burns shows, the stamping thread that sleeps
     does not, and no reading is held to another."""
-    clock = spans.StageClock(spans.SERVICE_STAGES, ring_seconds=600)
+    clock, counts = _service_clock(ring_seconds=600)
     stop = threading.Event()
     adopted = threading.Event()
 
@@ -435,16 +451,16 @@ def test_a_seconds_stamp_holds_what_was_answered_and_the_cpu_used():
     burner.start()
     adopted.wait()
     base = int(time.monotonic()) + 10  # seconds of its own, later than now
-    clock.requests, clock.signatures = 5, 9
+    counts.requests, counts.signatures = 5, 9
     clock.stamp(base + 0.0)
     time.sleep(0.4)
-    clock.requests, clock.signatures = 12, 30
+    counts.requests, counts.signatures = 12, 30
     clock.stamp(base + 1.2)
-    clock.requests = 99
+    counts.requests = 99
     clock.stamp(base + 1.7)  # the same second: not read again
     stop.set()
     burner.join()
-    clock.requests, clock.signatures = 20, 31
+    counts.requests, counts.signatures = 20, 31
     seconds = clock.export()["seconds"]  # the burner has ended: its last
     first, second = seconds[str(base)], seconds[str(base + 1)]
     assert (first["requests"], first["signatures"]) == (7, 21)
@@ -564,7 +580,7 @@ def test_no_object_outlives_its_request(tmp_path):
         gc.collect()
         after = len(gc.get_objects())
         sock.close()
-        return before, after, server.stages.totals()
+        return before, after, _totals(server)
 
     before, after, totals = asyncio.run(
         _serve(tmp_path, Null(), scenario, every_request=False))
@@ -591,7 +607,7 @@ def test_one_request_in_thirty_two_is_clocked_and_all_are_counted(tmp_path):
         frames = [_verify_frame(i + 1, 2, _records(2, i)) for i in range(8)]
         for _ in range(10):  # 80 requests, in the order they were sent
             await asyncio.to_thread(_pipelined, server.socket_path, frames)
-        return server.stages.export(), server.stages.totals()
+        return server.stages.export(), _totals(server)
 
     report, totals = asyncio.run(_serve(
         tmp_path, CpuSignatureVerifier(), scenario, metrics=metrics,
@@ -705,14 +721,14 @@ def test_a_clocked_request_books_its_launch_wall_whole_and_cpu_shared(riders):
 def test_a_seconds_stamp_counts_the_launches():
     """``launches`` rides the ring's stamp beside ``requests`` and
     ``signatures``: a whole number a second, the growth to the next."""
-    clock = spans.StageClock(spans.SERVICE_STAGES, ring_seconds=600)
-    assert clock.STAMPS[:3] == ("requests", "signatures", "launches")
+    clock, counts = _service_clock(ring_seconds=600)
+    assert clock.stamp_names[:3] == ("requests", "signatures", "launches")
     base = int(time.monotonic()) + 10
-    clock.requests, clock.signatures, clock.launches = 40, 320, 4
+    counts.requests, counts.signatures, counts.launches = 40, 320, 4
     clock.stamp(base + 0.0)
-    clock.requests, clock.signatures, clock.launches = 100, 800, 9
+    counts.requests, counts.signatures, counts.launches = 100, 800, 9
     clock.stamp(base + 1.0)
-    clock.launches = 10
+    counts.launches = 10
     seconds = clock.export()["seconds"]
     assert seconds[str(base)]["launches"] == 5
     assert seconds[str(base + 1)]["launches"] == 1
@@ -723,14 +739,14 @@ def test_a_seconds_stamp_counts_the_reads_and_the_writes():
     """``reads`` and ``writes`` ride the ring's stamp too: with
     ``requests`` they say how many frames a socket read and how many
     replies a write carried in that second."""
-    clock = spans.StageClock(spans.SERVICE_STAGES, ring_seconds=600)
-    assert {"reads", "writes"} < set(clock.STAMPS)
+    clock, counts = _service_clock(ring_seconds=600)
+    assert {"reads", "writes"} < set(clock.stamp_names)
     base = int(time.monotonic()) + 10
-    clock.requests, clock.reads, clock.writes = 40, 30, 10
+    counts.requests, counts.reads, counts.writes = 40, 30, 10
     clock.stamp(base + 0.0)
-    clock.requests, clock.reads, clock.writes = 100, 50, 22
+    counts.requests, counts.reads, counts.writes = 100, 50, 22
     clock.stamp(base + 1.0)
-    clock.reads = 51
+    counts.reads = 51
     seconds = clock.export()["seconds"]
     first, second = seconds[str(base)], seconds[str(base + 1)]
     assert (first["requests"], first["reads"], first["writes"]) == (60, 20, 12)
@@ -751,7 +767,7 @@ def test_one_launch_that_answers_a_connection_writes_to_it_once(tmp_path):
         server.PIPELINE_DEPTH = 16  # (of this server alone)
         frames = [_verify_frame(i + 1, 2, _records(2, i)) for i in range(10)]
         await asyncio.to_thread(_pipelined, server.socket_path, frames)
-        return server.stages.export(), server.stages.launches
+        return server.stages.export(), server.counts.launches
 
     report, launches = asyncio.run(_serve(tmp_path, backend, scenario))
     sums, requests, _ = _ring_sums(report)
@@ -781,7 +797,7 @@ def test_the_service_counts_launches_and_shares_cpu(tmp_path):
         await asyncio.gather(*(
             asyncio.to_thread(_pipelined, server.socket_path, frames)
             for _ in range(10)))
-        return server.stages.export(), server.stages.launches
+        return server.stages.export(), server.counts.launches
 
     report, launches = asyncio.run(
         _serve(tmp_path, backend, scenario, metrics=metrics))

@@ -13,7 +13,7 @@ import pytest
 
 from benchmark.reference import ed25519_oracle as oracle
 from benchmark.reference import transfers as ref
-from mysticeti_tpu import crypto, execution as X
+from mysticeti_tpu import crypto, execution as X, spans
 from mysticeti_tpu.block_validator import (
     BatchedSignatureVerifier,
     CpuSignatureVerifier,
@@ -81,7 +81,13 @@ def _plane(allocation, signed=True, metrics=None):
         # What NetworkSyncer does where Parameters.signed_transactions is
         # set; the plane does not decide it.
         collector.require_transaction_signatures()
-    plane = IngressPlane(IngressParameters(admission=False), metrics=metrics)
+    # The validator hands its one stage clock to the plane (validator.py).
+    stages = None
+    if metrics is not None:
+        stages = spans.StageClock(spans.NODE_STAGES)
+        metrics.block_stages.attach(stages)
+    plane = IngressPlane(IngressParameters(admission=False), metrics=metrics,
+                         stages=stages)
     plane.attach(core=Core(), block_verifier=collector)
     return plane, collector, state
 

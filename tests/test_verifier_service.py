@@ -1011,7 +1011,7 @@ def test_pending_requests_of_many_connections_share_one_launch(
         assert sorted(backend.sizes) == [1] * len(plugs) + [
             sum(len(bits) for bits in expected.values())]
         assert any(0 in bits for bits in expected.values())
-        assert server.stages.launches == len(plugs) + 1
+        assert server.counts.launches == len(plugs) + 1
 
     asyncio.run(_with_server(tmp_path, keys, backend, scenario))
 
@@ -1042,7 +1042,7 @@ def test_pipelined_replies_keep_request_order_across_launches(
         assert all(bits == [1, 1] for _, bits in replies)
         # Three launches, and the first request's was the last to end.
         assert len(backend.sizes) == VerifierServer.DISPATCHERS + 1
-        assert server.stages.launches == len(backend.sizes)
+        assert server.counts.launches == len(backend.sizes)
 
     asyncio.run(_with_server(tmp_path, keys, backend, scenario))
 
@@ -1110,7 +1110,7 @@ def test_an_idle_service_launches_each_request_at_once_and_alone(
 
         elapsed = await asyncio.to_thread(sequential, 50)
         assert backend.calls == base + 50
-        assert server.stages.launches == 50
+        assert server.counts.launches == 50
         # 50 round trips of three host-verified signatures: any window
         # worth the name (a millisecond a request) would double this.
         assert elapsed < 50 * 0.02, elapsed
@@ -1343,8 +1343,8 @@ def test_the_pending_list_loses_and_doubles_nothing_under_contention(
             asyncio.to_thread(lambda c=c: sum(one_connection(server, c)))
             for c in range(n_conns))), 120)
         assert sum(backend.sizes) - calibrated == sum(totals)
-        assert server.stages.requests == rounds * per_round * n_conns
-        assert server.stages.launches == len(backend.sizes) - 2
+        assert server.counts.requests == rounds * per_round * n_conns
+        assert server.counts.launches == len(backend.sizes) - 2
         assert max(backend.sizes) <= 256
         assert not server._pending
 
@@ -1362,7 +1362,7 @@ def test_the_pending_list_loses_and_doubles_nothing_under_contention(
 
 def _left(server):
     """Launches so far by why they left, ``{why: count}``."""
-    return dict(zip(server.stages.LEFT, server.stages.left))
+    return dict(zip(server.counts.LEFT, server.counts.left))
 
 
 def _grown(server, before):
@@ -1681,14 +1681,14 @@ def test_eight_frames_in_one_sendall_are_handed_over_from_fewer_reads(
 
     async def scenario(server):
         conn = await asyncio.to_thread(_RawConn, server, keys)
-        reads = server.stages.reads
+        reads = server.counts.reads
         conn.send(*(_verify_frame(i, keys, _indexed(1 + i, signers, b"e%d" % i))
                     for i in range(8)))
         replies = [await asyncio.to_thread(conn.read) for _ in range(8)]
         conn.close()
         assert replies == [(i, [1] * (1 + i)) for i in range(8)]
-        assert 1 <= server.stages.reads - reads < 8
-        assert server.stages.requests == 8
+        assert 1 <= server.counts.reads - reads < 8
+        assert server.counts.requests == 8
 
     asyncio.run(_with_server(tmp_path, keys, CountingBackend(), scenario))
 
@@ -1715,14 +1715,14 @@ def test_a_frame_fed_a_byte_at_a_time_decodes_the_same(tmp_path, signers):
             [blob[:first + 2], blob[first + 2: first + 40], blob[first + 40:]],
         ]
         for feed, counted in zip(feeds, (1, 3, 2)):
-            reads = server.stages.reads
+            reads = server.counts.reads
             conn, wire = _by_hand(server)
             for data in feed:
                 conn.data_received(data)
             await _until(lambda: len(wire.results()) == 3, "three replies")
             assert wire.results() == expected, len(feed)
             # Only a read that held the end of a request counts.
-            assert server.stages.reads - reads == counted
+            assert server.counts.reads - reads == counted
             assert conn.partial is None and not conn.slots
 
     asyncio.run(_with_server(tmp_path, keys, CountingBackend(), scenario))
@@ -1750,7 +1750,7 @@ def test_the_replies_of_one_launch_to_one_connection_leave_in_one_write(
         assert not server._pending  # the turn of the loop is not over
         await asyncio.sleep(0)
         assert len(server._pending) == 5  # both reads, handed over at once
-        writes, requests = server.stages.writes, server.stages.requests
+        writes, requests = server.counts.writes, server.counts.requests
         backend.gate.set()
         await _until(lambda: len(wire_two.results()) == 2, "answered")
         assert wire_one.results() == [(i, [1, 1]) for i in range(3)]
@@ -1760,8 +1760,8 @@ def test_the_replies_of_one_launch_to_one_connection_leave_in_one_write(
             assert (await asyncio.to_thread(conn.read))[1] == [1]
             conn.close()
         assert backend.sizes.count(8) == 1
-        assert server.stages.writes - writes == len(plugs) + 2
-        assert server.stages.requests - requests == len(plugs) + 5
+        assert server.counts.writes - writes == len(plugs) + 2
+        assert server.counts.requests - requests == len(plugs) + 5
 
     asyncio.run(_with_server(tmp_path, keys, backend, scenario))
 
@@ -1845,7 +1845,7 @@ def test_a_client_that_never_reads_stops_being_read_while_another_is_served(
         sender.start()
         await _until(lambda: served.write_paused, "the client's pipe is full")
         await _until(lambda: not served.transport.is_reading(), "and it is not read")
-        answered = server.stages.requests
+        answered = server.counts.requests
         assert answered < frames
         for i in range(3):  # the other connection is served meanwhile
             other.send(_verify_frame(
@@ -1853,7 +1853,7 @@ def test_a_client_that_never_reads_stops_being_read_while_another_is_served(
                           for k in range(3)]))
             assert await asyncio.to_thread(other.read) == (i, [1, 0, 1])
         assert len(served.slots) <= server.PIPELINE_DEPTH
-        assert server.stages.requests <= answered + 3 + server.PIPELINE_DEPTH
+        assert server.counts.requests <= answered + 3 + server.PIPELINE_DEPTH
         for i in range(frames):  # the client reads at last
             assert await asyncio.to_thread(silent.read) == (
                 i, [(i + k + 1) % 2 for k in range(n)]), i
@@ -1998,14 +1998,14 @@ def test_a_request_wider_than_a_launch_comes_back_whole(tmp_path, signers):
             _verify_frame(7, keys, items)
             + _verify_frame(8, keys, _indexed(2, signers, b"behind")))
         assert len(conn.slots) == 2 and conn.slots[0].waiting == 4
-        writes = server.stages.writes
+        writes = server.counts.writes
         backend.gate.set()
         await _until(lambda: len(wire.results()) == 2, "both answered")
         assert wire.results() == [(7, expected), (8, [1, 1])]
         assert sum(backend.sizes) == n + 2 and max(backend.sizes) <= cap
         assert backend.sizes.count(cap) == 3
-        assert 1 <= server.stages.writes - writes <= 2
-        assert server.stages.requests == 2 and server._in_service == 0
+        assert 1 <= server.counts.writes - writes <= 2
+        assert server.counts.requests == 2 and server._in_service == 0
 
     asyncio.run(_with_server(tmp_path, keys, backend, scenario))
 
@@ -2124,7 +2124,7 @@ def test_four_requests_in_flight_share_one_connection_and_one_read(
 
     async def scenario(server):
         client, served = await _shared_client(server, keys, signers, metrics)
-        stages = server.stages
+        stages = server.counts
         reads, requests = stages.reads, stages.requests
         batches = [_marked(2 + i, signers, b"four%d" % i, i) for i in range(4)]
         # Sent from the loop's own thread: the service cannot read before

@@ -108,7 +108,31 @@ def recorder_summary(
             "dropped": doc.get("dropped"),
             "last_events": (doc.get("events") or [])[-last:],
             "dumps": doc.get("dumps") or [],
+            "last_seconds": last_seconds(doc, last),
         }
+    return out
+
+
+# The stages of a validator's clock in which its host time can hide
+# (spans.NODE_STAGES; docs/fleet-tracing.md).
+HOST_STAGES = ("core_command", "loop_lag", "gc", "executor_wait",
+               "wal_write", "wal_sync", "checkpoint", "exec_fold", "scrape")
+
+
+def last_seconds(doc: dict, last: int = 10) -> List[dict]:
+    """The newest ``last`` seconds of the document's ``"stages"`` ring (a
+    live validator's stage clock): the threshold clock's rounds and the
+    host stage with the longest sample, a second."""
+    seconds = (doc.get("stages") or {}).get("seconds") or {}
+    out = []
+    for second in sorted(seconds, key=int)[-last:]:
+        entry = seconds[second]
+        worst = max(
+            ((entry[stage][3], stage) for stage in HOST_STAGES
+             if stage in entry), default=(0.0, None))
+        out.append({"second": int(second), "rounds": entry.get("rounds"),
+                    "worst_stage": worst[1],
+                    "worst_ms": round(1e3 * worst[0], 3)})
     return out
 
 

@@ -117,7 +117,9 @@ async def serve(args) -> dict:
         for _ in range(args.connections)
     ]
     await asyncio.sleep(1.0)  # every client is in its loop
-    stages = server.stages
+    # The service's own counts (``ServiceCounts``; on a tree before PR 39
+    # the stage clock kept them).
+    stages = getattr(server, "counts", None) or server.stages
 
     def reading():
         return (time.monotonic(), time.thread_time(), time.process_time(),
