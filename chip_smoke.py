@@ -25,8 +25,12 @@ on one TPU, and checks what comes out by the repo's own means:
   service must report platform ``tpu``, advertise it over HELLO_OK, have
   dispatched Pallas kernels only, and — being the second process to need
   them — have loaded every kernel it warms (the 256 bucket, all a
-  validator's 256-signature window can reach) from the compilation cache
-  the verifier leg filled.
+  validator's 256-signature window can reach): its lowered program from
+  the program store and its executable from the compilation cache, both as
+  the verifier leg left them.  A warm boot traces and compiles nothing:
+  about a second a kernel, where the verifier leg's first call of each
+  paid 7-40 s (the table printed below says which it was: ``program=``,
+  ``cache=``).
 * **four-chip leg**, only where the service saw four devices: the same
   fleet with the service sharding over the whole host (``shard_map``), in a
   fresh process; every device must hold a shard.  With one device it is
@@ -351,6 +355,10 @@ def check_fleet(fleet: dict, failures: list, tag: str, verifier: dict) -> None:
         if k["cache"] == "miss":
             fail(f"service kernel {k['kernel']}@{k['bucket']} was compiled, "
                  f"not loaded from the compilation cache ({k['seconds']}s)")
+        if not k["kernel"].startswith("mesh-") and k["program"] != "loaded":
+            fail(f"service kernel {k['kernel']}@{k['bucket']} was "
+                 f"{k['program']}, not loaded from the program store "
+                 f"({k['seconds']}s)")
     # Before HELLO_OK the service warms what a validator's 256-signature
     # window can reach; the verifier leg is where every bucket compiles.
     warmed = {(k["kernel"], k["bucket"]) for k in service.get("kernels", [])}
@@ -516,7 +524,8 @@ def run(args) -> int:
         for k in leg["kernels"]:
             log(f"    {k['kernel']:>12}@{k['bucket']:<5} {k['backend']} "
                 f"interpret={k['interpret']} tile={k['tile']} "
-                f"{k['seconds']:8.2f}s cache={k['cache']}")
+                f"{k['seconds']:8.2f}s cache={k['cache']} "
+                f"program={k['program']}")
         log(f"    batch: {leg['batch']}")
     log(f"  parity: pass={verifier['parity']['pass']} "
         f"rfc8032={verifier['parity']['rfc8032']} "
@@ -548,9 +557,12 @@ def run(args) -> int:
             f"{fleet.get('service_warm_seconds')}s")
         for k in service.get("kernels", []):
             log(f"    {k['kernel']:>12}@{k['bucket']:<5} {k['backend']} "
-                f"{k['seconds']:8.2f}s cache={k['cache']}"
+                f"{k['seconds']:8.2f}s cache={k['cache']} "
+                f"program={k['program']}"
                 + (f" shards on {k['shard_devices']}"
                    if "shard_devices" in k else ""))
+        log(f"    warm-up by part: {service.get('warm_parts')}; "
+            f"{service.get('compile_stats')}")
         log(f"  service dispatches: {service.get('dispatches')}")
         for row in fleet["per_node"]:
             log(f"  node {row}")

@@ -155,6 +155,7 @@ class TpuSignatureVerifier(SignatureVerifier):
     def __init__(self, mesh="auto", committee_keys=None) -> None:
         self._mesh = mesh
         self.kernel_report: list = []  # filled by warmup()
+        self.warm_parts: dict = {}  # so is this: warmup's seconds by part
         # Known signer set -> device-resident key table: the pk rides as an
         # index (26 words/sig on the wire instead of 33), uploaded once.
         self._table = None
@@ -183,22 +184,33 @@ class TpuSignatureVerifier(SignatureVerifier):
         return self._mesh
 
     def warmup(self, every_shape: bool = False) -> None:
-        """Compile (or load from the persistent cache) the kernels a batch
-        of block signatures reaches, so the first real batch does not stall
-        behind a compile.  By default the smallest bucket only: a collector
-        window (``BatchedSignatureVerifier.max_batch``) holds at most 256
-        signatures, and every kernel-bucket pair costs 7-40 s of tracing
-        and compiling, so a booting service warms what its clients can send
-        and the wider buckets compile on first use.  ``every_shape`` is the
-        compile proof ``chip_smoke.py`` takes: every bucket, and the
-        host-hashed ``packed`` kernel that only non-digest messages reach
-        (the service's wire format carries none).  Each kernel is timed
-        alone on an all-rejected batch and the outcome kept in
-        ``kernel_report``; a kernel the device refuses to compile raises
-        here."""
+        """Make runnable the kernels a batch of block signatures reaches, so
+        the first real batch does not stall behind a trace or a compile.
+        Each kernel's lowered program comes from the program store beside
+        the compilation cache (``ops.programs``) and its executable from
+        that cache, so a warm boot traces nothing and compiles nothing: on
+        a v5e about a second a kernel, where the first boot on an empty
+        cache directory pays 7-40 s a kernel-bucket pair to trace, lower,
+        compile and write both (numbers in ``PERF.md``).  By default the
+        smallest bucket only: a collector window
+        (``BatchedSignatureVerifier.max_batch``) holds at most 256
+        signatures, so a booting service warms what its clients can send
+        and the wider buckets load or compile on first use.
+        ``every_shape`` is the compile proof ``chip_smoke.py`` takes: every
+        bucket, and the host-hashed ``packed`` kernel that only non-digest
+        messages reach (the service's wire format carries none; it and the
+        mesh kernels are outside the store and trace in every process).
+        Each kernel is timed alone on an all-rejected batch and the outcome
+        kept in ``kernel_report`` — ``program`` says whether the store held
+        it (``loaded``), it was traced and written (``traced``), or a file
+        that could not be read back was replaced
+        (``reloaded-after-error``); ``cache`` says the same of XLA's
+        executable — and the boot's seconds by part in ``warm_parts``; a
+        kernel the device refuses to compile raises here."""
         import numpy as np
 
         from .ops import ed25519 as E
+        from .ops import programs
 
         E.install_compile_listeners()
         mesh = self._resolve_mesh()
@@ -210,41 +222,77 @@ class TpuSignatureVerifier(SignatureVerifier):
         else:
             interpret, tile = None, None
         report = []
-        for bucket in E.BUCKETS if every_shape else E.BUCKETS[:1]:
+        parts = {}
+        if backend == "pallas" and mesh is None and self._table is not None:
+            # The keyed kernel's per-key combs, built with Python ints:
+            # apart from the kernel that first asks for them.
+            started = time.monotonic()
+            self._table.neg_combs()
+            parts["neg_combs_s"] = round(time.monotonic() - started, 3)
+        probes = [
+            (bucket, name, lanes, probe)
+            for bucket in (E.BUCKETS if every_shape else E.BUCKETS[:1])
             for name, lanes, probe in self._kernel_probes(
                 mesh, bucket, backend, packed=every_shape
-            ):
-                before = dict(E.COMPILE_STATS)
-                started = time.monotonic()
-                out = probe()
+            )
+        ]
+
+        def timed(call):
+            """``call``'s result, its seconds, and COMPILE_STATS' growth."""
+            before = dict(E.COMPILE_STATS)
+            started = time.monotonic()
+            out = call()
+            if out is not None:
                 np.asarray(out)  # blocks until the kernel has run
-                seconds = time.monotonic() - started
-                hits = E.COMPILE_STATS["cache_hits"] - before["cache_hits"]
-                misses = (
-                    E.COMPILE_STATS["cache_misses"] - before["cache_misses"]
+            return out, time.monotonic() - started, {
+                k: v - before[k] for k, v in E.COMPILE_STATS.items()
+            }
+
+        # Every stored kernel's program first, then the compiles and the
+        # launches (``programs.preparing`` says why).
+        made = {}
+        for i, (_, name, _, probe) in enumerate(probes):
+            if name in self._STORED_KERNELS:
+                with programs.preparing():
+                    made[i] = timed(probe)[1:]
+        for i, (bucket, name, lanes, probe) in enumerate(probes):
+            out, seconds, grew = timed(probe)
+            if i in made:
+                seconds += made[i][0]
+                grew = {k: v + made[i][1][k] for k, v in grew.items()}
+            hits, misses = grew["cache_hits"], grew["cache_misses"]
+            entry = {
+                "kernel": name,
+                "bucket": bucket,
+                "lanes": lanes,
+                "backend": backend,
+                "interpret": interpret,
+                "tile": tile,
+                "seconds": round(seconds, 3),
+                "backend_compile_s": round(grew["backend_compile_s"], 3),
+                "cache": (
+                    "miss" if misses else "hit" if hits else "in-process"
+                ),
+                "program": (
+                    "reloaded-after-error" if grew["programs_rejected"]
+                    else "loaded" if grew["programs_loaded"]
+                    else "traced"
+                    if grew["programs_written"] or hits or misses
+                    else "in-process"
+                ),
+            }
+            if name.startswith("mesh-"):
+                entry["shard_devices"] = sorted(
+                    d.id for d in out.sharding.device_set
                 )
-                entry = {
-                    "kernel": name,
-                    "bucket": bucket,
-                    "lanes": lanes,
-                    "backend": backend,
-                    "interpret": interpret,
-                    "tile": tile,
-                    "seconds": round(seconds, 3),
-                    "backend_compile_s": round(
-                        E.COMPILE_STATS["backend_compile_s"]
-                        - before["backend_compile_s"], 3
-                    ),
-                    "cache": (
-                        "miss" if misses else "hit" if hits else "in-process"
-                    ),
-                }
-                if name.startswith("mesh-"):
-                    entry["shard_devices"] = sorted(
-                        d.id for d in out.sharding.device_set
-                    )
-                report.append(entry)
+            report.append(entry)
+            parts[f"{name}_{bucket}_s"] = entry["seconds"]
         self.kernel_report = report
+        self.warm_parts = parts
+
+    # The kernels whose programs the store keeps (``ops.programs``); the
+    # mesh's and the host-hashed one trace in every process.
+    _STORED_KERNELS = ("blob", "indexed", "keyed")
 
     def _kernel_probes(self, mesh, bucket: int, backend: str, packed: bool):
         """(name, lanes, thunk) per kernel this verifier's dispatches reach
@@ -299,18 +347,11 @@ class TpuSignatureVerifier(SignatureVerifier):
         """What this process's JAX runtime is and which kernels it warmed —
         written by the verifier service next to its socket so a launcher
         can show the device as seen INSIDE the one process that holds it."""
-        from importlib import metadata
-
         import jax
 
         from .ops import compilation_cache_dir
         from .ops import ed25519 as E
-
-        def _version(dist: str):
-            try:
-                return metadata.version(dist)
-            except metadata.PackageNotFoundError:
-                return None
+        from .ops.programs import installed_version
 
         devices = jax.devices()
         mesh = self._resolve_mesh()
@@ -324,8 +365,8 @@ class TpuSignatureVerifier(SignatureVerifier):
                 else "single device"
             ),
             "jax": jax.__version__,
-            "jaxlib": _version("jaxlib"),
-            "libtpu": _version("libtpu"),
+            "jaxlib": installed_version("jaxlib"),
+            "libtpu": installed_version("libtpu"),
             "compilation_cache_dir": compilation_cache_dir(),
             "kernels": list(self.kernel_report),
             "compile_stats": dict(E.COMPILE_STATS),

@@ -34,6 +34,8 @@ from .. import spans
 from . import field as F
 from . import scalar as SC
 from . import sha512 as H
+from . import programs
+from .programs import COMPILE_STATS, StoredProgram
 
 P = F.P
 L = (1 << 252) + 27742317777372353535851937790883648493  # group order
@@ -454,6 +456,7 @@ def verify_fused_blob_impl(blob: jnp.ndarray) -> jnp.ndarray:
 
 
 verify_fused_blob_kernel = jax.jit(verify_fused_blob_impl)
+_stored_blob_kernel = StoredProgram(verify_fused_blob_kernel)
 
 
 # ---------------------------------------------------------------------------
@@ -524,6 +527,7 @@ def verify_fused_indexed_impl(blob: jnp.ndarray, table: jnp.ndarray) -> jnp.ndar
 
 
 verify_fused_indexed_kernel = jax.jit(verify_fused_indexed_impl)
+_stored_indexed_kernel = StoredProgram(verify_fused_indexed_kernel)
 
 
 # ---------------------------------------------------------------------------
@@ -733,7 +737,7 @@ def _dispatch_indexed(blob, table) -> jnp.ndarray:
         from . import ed25519_pallas as PK
 
         return PK.verify_fused_indexed_blob_pallas(blob, table)
-    return verify_fused_indexed_kernel(blob, table)
+    return _stored_indexed_kernel(blob, table)
 
 
 def _dispatch_indexed_keyed(chunk: np.ndarray, table: "KeyTable", bucket: int):
@@ -958,13 +962,14 @@ def _backend() -> str:
 _attr_metrics = None
 _attr_listeners_installed = False
 
-# Process-wide compile accounting fed by the ``jax.monitoring`` listeners
-# below, and a count of dispatches per (kernel, bucket, backend).  Together
-# they let a caller (the verifier service's boot report, chip_smoke.py) show
-# which kernel form actually ran and whether it compiled or came from the
-# persistent cache — a silent switch to the XLA form or to the interpreter is
-# visible here, not just in the timing.
-COMPILE_STATS = {"cache_hits": 0, "cache_misses": 0, "backend_compile_s": 0.0}
+# Process-wide compile accounting (``COMPILE_STATS``, kept by ``programs``:
+# the ``jax.monitoring`` listeners below feed XLA's side of it, the program
+# store its own), and a count of dispatches per (kernel, bucket, backend).
+# Together they let a caller (the verifier service's boot report,
+# chip_smoke.py) show which kernel form actually ran, whether its program
+# was loaded or traced, and whether it compiled or came from the persistent
+# cache — a silent switch to the XLA form or to the interpreter is visible
+# here, not just in the timing.
 KERNEL_DISPATCHES: dict = {}
 _dispatch_count_lock = threading.Lock()  # the service dispatches from a pool
 
@@ -972,6 +977,8 @@ _dispatch_count_lock = threading.Lock()  # the service dispatches from a pool
 def _note_kernel(kernel: str, bucket: int, backend: str) -> None:
     """``bucket`` is the lanes dispatched: the bucket, or what a mesh pads
     it to (``parallel.mesh.mesh_lanes``)."""
+    if programs.is_preparing():
+        return  # a boot making the kernel's program: nothing is launched
     key = (kernel, int(bucket), backend)
     with _dispatch_count_lock:
         KERNEL_DISPATCHES[key] = KERNEL_DISPATCHES.get(key, 0) + 1
@@ -1074,7 +1081,7 @@ def _note(slot: int, amount: int) -> None:
     a call cost the service 3.6% of its throughput on the chip's host
     (PERF.md, PR 24)."""
     m = _attr_metrics
-    if m is None:
+    if m is None or programs.is_preparing():
         return
     try:
         pending = _transfer_local.pending
@@ -1131,7 +1138,7 @@ def _dispatch_blob(blob) -> jnp.ndarray:
         from . import ed25519_pallas as PK
 
         return PK.verify_fused_blob_pallas(blob)
-    return verify_fused_blob_kernel(blob)
+    return _stored_blob_kernel(blob)
 
 
 def iter_buckets(n: int):

@@ -33,6 +33,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import ed25519 as E
 from . import field as F
+from .programs import StoredProgram
 
 RADIX = F.RADIX
 NLIMBS = F.NLIMBS
@@ -704,7 +705,7 @@ def verify_keyed_blob(
     # The arrays are the jitted call's own arguments, numpy or device: that
     # one call moves a host blob to the chip itself.  A ``jnp.asarray``
     # before it is a second dispatch, through JAX's Python transfer path.
-    return _verify_keyed_blob_jit(
+    return _stored_keyed_blob(
         grouped,
         table_words,
         acomb,
@@ -739,6 +740,14 @@ def _verify_fused_indexed_pallas_jit(blob, table, *, tile, interpret):
     return _verify_pallas_jit(*args, tile=tile, interpret=interpret)
 
 
+# The three entry points a verifier's launches reach, through the program
+# store: a shape's first call loads its lowered program (or traces it, once,
+# and writes it); what then runs is a jitted function of the same name.
+_stored_keyed_blob = StoredProgram(_verify_keyed_blob_jit)
+_stored_fused_blob = StoredProgram(_verify_fused_blob_pallas_jit)
+_stored_fused_indexed = StoredProgram(_verify_fused_indexed_pallas_jit)
+
+
 def verify_fused_blob_pallas(
     blob, *, tile: Optional[int] = None, interpret: Optional[bool] = None
 ) -> jnp.ndarray:
@@ -752,7 +761,7 @@ def verify_fused_blob_pallas(
     if b % tile != 0:
         raise ValueError(f"batch {b} not a multiple of tile {tile}")
     # ``blob`` goes in as it is (see ``verify_keyed_blob``).
-    return _verify_fused_blob_pallas_jit(blob, tile=tile, interpret=interpret)
+    return _stored_fused_blob(blob, tile=tile, interpret=interpret)
 
 
 def verify_fused_indexed_blob_pallas(
@@ -768,9 +777,7 @@ def verify_fused_indexed_blob_pallas(
     if b % tile != 0:
         raise ValueError(f"batch {b} not a multiple of tile {tile}")
     # ``blob`` and ``table`` go in as they are (see ``verify_keyed_blob``).
-    return _verify_fused_indexed_pallas_jit(
-        blob, table, tile=tile, interpret=interpret
-    )
+    return _stored_fused_indexed(blob, table, tile=tile, interpret=interpret)
 
 
 def verify_fused_pallas(

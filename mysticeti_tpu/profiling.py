@@ -73,7 +73,7 @@ SUBSYSTEMS: Dict[str, str] = {
     "verifier_service": "verifier-pack", "ed25519": "verifier-pack",
     "ed25519_pallas": "verifier-pack", "field": "verifier-pack",
     "scalar": "verifier-pack", "sha512": "verifier-pack",
-    "mesh": "verifier-pack",
+    "mesh": "verifier-pack", "programs": "verifier-pack",
     # Durability plane.
     "wal": "wal", "storage": "wal", "block_store": "wal",
     # Client ingress (finality tracks submit→finality over ingress keys).

@@ -744,6 +744,10 @@ class VerifierServer:
         # it.
         self._fatal: Optional[BaseException] = None
         self._warm_seconds: Optional[float] = None
+        # The boot's seconds by part, in the order they were spent (the
+        # report's ``warm_parts``): ``run_service`` notes what it spent
+        # before there was a server, ``_ensure_backend`` the rest.
+        self.warm_parts: dict = {}
         self._failed = asyncio.Event()
         self._loop: Optional[asyncio.AbstractEventLoop] = None
 
@@ -775,17 +779,28 @@ class VerifierServer:
             if self._backend is None:
                 from .block_validator import TpuSignatureVerifier
 
+                started = time.monotonic()
                 self._backend = TpuSignatureVerifier(
                     mesh="auto" if self._devices is None else self._devices,
                     committee_keys=self._keys,
                 )
                 self._owns_backend = True
+                # The key table's upload (and the kernels' modules, where
+                # nothing imported them before).
+                self.warm_parts["key_table_s"] = round(
+                    time.monotonic() - started, 3
+                )
             if not self._warmed.is_set():
                 started = time.monotonic()
                 try:
                     self._backend.warmup()
+                    warmed = time.monotonic()
                     self._calibrate()
                     self._warm_seconds = time.monotonic() - started
+                    self.warm_parts.update(
+                        getattr(self._backend, "warm_parts", {}),
+                        calibrate_s=round(time.monotonic() - warmed, 3),
+                    )
                     self._write_report()
                 except BaseException as exc:
                     self._fail(exc)
@@ -825,6 +840,7 @@ class VerifierServer:
             return
         report = describe()
         report["warm_seconds"] = round(self._warm_seconds, 3)
+        report["warm_parts"] = dict(self.warm_parts)
         report["calibration"] = self._calibration
         report["stages"] = self.stages.export()
         path = report_path(self.socket_path)
@@ -2047,7 +2063,12 @@ def run_service(socket_path: str, committee_keys: Optional[Sequence[bytes]] = No
     (the runner stops the service this way, SIGKILL only after a timeout)."""
     import signal
 
+    started = time.monotonic()
     platform = require_accelerator()
+    runtime_s = time.monotonic() - started
+    from .ops import ed25519  # noqa: F401 - the backend's import, timed
+
+    modules_s = time.monotonic() - started - runtime_s
     log.info("verifier service starting on platform %r", platform)
 
     async def _main() -> None:
@@ -2061,6 +2082,11 @@ def run_service(socket_path: str, committee_keys: Optional[Sequence[bytes]] = No
             socket_path, committee_keys=committee_keys, metrics=metrics,
             devices=devices,
         )
+        # Importing JAX and starting its client on the device; importing
+        # the kernels' modules (their constant tables are built with
+        # Python ints and uploaded).
+        server.warm_parts["runtime_s"] = round(runtime_s, 3)
+        server.warm_parts["modules_s"] = round(modules_s, 3)
         # service_gc: a collection stops the dispatcher threads and the
         # loop at once, whichever thread trips it.  A hook of the process,
         # so it is set here and not by every VerifierServer a test builds.
