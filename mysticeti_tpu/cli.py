@@ -251,7 +251,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     ga.add_argument("--seed", type=int, default=0,
                     help="account i's key is derived from (seed, i)")
     ga.add_argument("--balance", type=int, default=1_000_000,
-                    help="every account's starting balance")
+                    help="every account's starting (checking) balance")
+    ga.add_argument("--savings", type=int, default=0,
+                    help="every account's starting savings balance; other "
+                    "than 0 writes the allocation with two balances")
     ga.add_argument("--out", required=True, help="the allocation file")
 
     def add_storage_flags(p):
@@ -453,10 +456,11 @@ def main(argv: Optional[List[str]] = None) -> int:
         from .execution import write_genesis_allocation
 
         write_genesis_allocation(
-            args.out, args.accounts, args.seed, args.balance
+            args.out, args.accounts, args.seed, args.balance, args.savings
         )
         print(f"{args.accounts} accounts funded with {args.balance} each "
-              f"in {args.out}")
+              + (f"and {args.savings} in savings " if args.savings else "")
+              + f"in {args.out}")
         return 0
     if args.command == "run":
         asyncio.run(

@@ -1,4 +1,4 @@
-"""Deterministic execution plane: account/transfer state machine on commits.
+"""Deterministic execution plane: an account ledger folded over the commits.
 
 The committed leader sequence is a total order every honest node derives
 identically (the same property :mod:`.reconfig` anchors epoch changes on),
@@ -8,11 +8,14 @@ folded over the linearized commits, whose per-commit **state root** becomes
 a cross-node safety invariant and the object clients actually wait for
 (execution-backed finality, the ACE-runtime shape from PAPERS.md).
 
-* ``ExecTx`` — a typed CREATE/MINT/TRANSFER transaction that rides the
-  committed sequence as an ordinary ``Share`` payload prefixed with
-  ``EXEC_MAGIC``.  Non-magic payloads (benchmark counters, stamped random
-  bytes, reconfig changes) are opaque no-ops — the runtime coexists with
-  every existing workload.
+* ``ExecTx`` — a typed transaction that rides the committed sequence as an
+  ordinary ``Share`` payload prefixed with ``EXEC_MAGIC``: CREATE / MINT /
+  TRANSFER over an account's one balance, and SmallBank's six procedures
+  (Balance, DepositChecking, TransactSavings, Amalgamate, WriteCheck,
+  SendPayment) over its two — the balance above is its **checking**, and
+  a **savings** balance stands beside it.  Non-magic payloads (benchmark
+  counters, stamped random bytes, reconfig changes) are opaque no-ops — the
+  runtime coexists with every existing workload.
 * ``ExecutionState`` — the per-node state machine owned by the consensus
   core: folds each committed sub-dag (linearized order, one commit at a
   time, the ``ReconfigState.observe_commit`` pattern) and emits a chained
@@ -29,7 +32,14 @@ Determinism rules (docs/execution.md):
   sub-dag linearized order).  No clocks, no RNG, no per-node identity.
 * Invalid transactions (bad nonce, overdraft, duplicate create, unknown
   account) are deterministic typed no-ops — every node rejects them with
-  the same verdict, so duplicates and garbage cannot fork the chain.
+  the same verdict, so duplicates and garbage cannot fork the chain.  They
+  consume no nonce.
+* A SmallBank procedure that its own rules abort (a SendPayment beyond the
+  checking balance) is an EXECUTED outcome, ``aborted``: no balance moves,
+  but the nonce is consumed and the account enters the commit's deltas and
+  the root.  A wallet that signs several operations ahead keeps its
+  sequence after one of them aborts; after a TRANSFER's
+  ``insufficient_balance``, which consumes none, it would not.
 * A payload carrying ``EXEC_MAGIC`` that fails to decode is an opaque
   no-op, exactly like :func:`.reconfig.parse_reconfig_tx` — a garbled
   transaction must not fork honest nodes on whether to error.
@@ -37,7 +47,8 @@ Determinism rules (docs/execution.md):
 Concurrency: mutation is single-owner (the consensus core task calls
 :meth:`ExecutionState.observe_commit`), but the ingress plane *probes*
 account state from submission threads for pre-consensus admission
-(bad-nonce / insufficient-balance shed before consensus pays for the tx),
+(a stale nonce or an unknown account is shed before consensus pays for the
+transaction),
 so the account table is guarded by ``_exec_lock`` (lint GUARDED_FIELDS).
 
 Signed transactions (``Parameters.signed_transactions``, docs/execution.md):
@@ -76,14 +87,35 @@ EXEC_MAGIC = b"\xffEXECTX\x01"
 SIGNED_MAGIC = b"\xffSIGNTX\x01"
 SIGNATURE_LEN = 64
 SIGNER_KEY_LEN = 32
-# First bytes of a genesis allocation file (``write_genesis_allocation``).
+# First bytes of a genesis allocation file (``write_genesis_allocation``):
+# one balance an account, or (the second magic) a checking and a savings
+# balance.  Allocations are told apart by their magic, nothing else.
 ALLOCATION_MAGIC = b"MYSTALLOC\x01"
+ALLOCATION_MAGIC_TWO = b"MYSTALLOC\x02"
 
 OP_CREATE = 0  # create account with an initial (faucet) balance; nonce must be 0
 OP_MINT = 1  # balance += amount on an existing account (nonce-gated)
 OP_TRANSFER = 2  # move amount to dest (auto-created at 0); nonce-gated
+# SmallBank (Alomari et al., ICDE 2008; docs/execution.md "SmallBank"):
+# account = N1 = the signer, amount = V, dest = N2.  Every one is
+# nonce-gated, creates no account, and consumes its nonce whenever it
+# executes — applied or aborted.
+OP_BALANCE = 3  # reads both balances, changes neither
+OP_DEPOSIT_CHECKING = 4  # checking += V
+OP_TRANSACT_SAVINGS = 5  # savings += V
+OP_AMALGAMATE = 6  # checking[N2] += savings[N1] + checking[N1]; N1's become 0
+OP_WRITE_CHECK = 7  # checking -= V, and 1 more where savings + checking < V
+OP_SEND_PAYMENT = 8  # aborts where checking[N1] < V; else V from N1's to N2's
 
-_OP_NAMES = {OP_CREATE: "create", OP_MINT: "mint", OP_TRANSFER: "transfer"}
+_OP_NAMES = {
+    OP_CREATE: "create", OP_MINT: "mint", OP_TRANSFER: "transfer",
+    OP_BALANCE: "balance", OP_DEPOSIT_CHECKING: "deposit_checking",
+    OP_TRANSACT_SAVINGS: "transact_savings", OP_AMALGAMATE: "amalgamate",
+    OP_WRITE_CHECK: "write_check", OP_SEND_PAYMENT: "send_payment",
+}
+# Operations that name a second account in ``dest``.
+_OPS_WITH_DEST = frozenset({OP_TRANSFER, OP_AMALGAMATE, OP_SEND_PAYMENT})
+_SMALLBANK_OPS = frozenset(range(OP_BALANCE, OP_SEND_PAYMENT + 1))
 
 # Typed apply verdicts.  The *names* are the metrics label set
 # (mysticeti_execution_txs_total{result}) and the ingress shed vocabulary —
@@ -93,6 +125,8 @@ REJECT_EXISTS = "account_exists"
 REJECT_UNKNOWN = "unknown_account"
 REJECT_BAD_NONCE = "bad_nonce"
 REJECT_OVERDRAFT = "insufficient_balance"
+# A SmallBank procedure that its own rules aborted: executed, nonce consumed.
+ABORTED = "aborted"
 # Where signatures are required: a bare EXECTX in the committed sequence
 # (the fold's verdict), and a transaction whose signature does not verify
 # (the gateway's verdict; such a transaction never reaches the fold).
@@ -109,13 +143,34 @@ ROOT_WINDOW = 1024
 GENESIS_ROOT = b"\x00" * 32
 
 # One account in the root's input and in the durable state: the canonical
-# serde fields ``bytes key ‖ u64 balance ‖ u64 nonce`` in one pack.
+# serde fields ``bytes key ‖ u64 balance ‖ u64 nonce`` in one pack.  An
+# account with savings, or whose checking balance a WriteCheck drove below
+# zero, takes the wide form: ``bytes key ‖ i64 checking ‖ u64 (nonce |
+# 2**63) ‖ u64 savings`` — the nonce's top bit, which no nonce counted up
+# from 0 reaches, says that the balance is signed and that savings follow.
 _ACCOUNT_TAIL = struct.Struct("<QQ")
+_ACCOUNT_TAIL_WIDE = struct.Struct("<qQQ")
+_WIDE = 1 << 63
 _U32 = struct.Struct("<I")
 
 
-def _account_entry(key: bytes, balance: int, nonce: int) -> bytes:
-    return _U32.pack(len(key)) + key + _ACCOUNT_TAIL.pack(balance, nonce)
+def _account_entry(key: bytes, balance: int, nonce: int,
+                   savings: int = 0) -> bytes:
+    if savings == 0 and balance >= 0:
+        return _U32.pack(len(key)) + key + _ACCOUNT_TAIL.pack(balance, nonce)
+    return _U32.pack(len(key)) + key + _ACCOUNT_TAIL_WIDE.pack(
+        balance, nonce | _WIDE, savings)
+
+
+def _read_account_entry(r: Reader) -> Tuple[bytes, Tuple[int, int, int]]:
+    """(key, (balance, nonce, savings)) of one entry off ``r``."""
+    key = bytes(r.bytes())
+    balance, nonce = r.u64(), r.u64()
+    if not nonce & _WIDE:
+        return key, (balance, nonce, 0)
+    if balance >= _WIDE:
+        balance -= 1 << 64
+    return key, (balance, nonce ^ _WIDE, r.u64())
 
 
 @dataclass(frozen=True)
@@ -135,10 +190,11 @@ class ExecTx:
             raise ValueError(
                 f"account key must be 1..{MAX_ACCOUNT_KEY_LEN} bytes"
             )
-        if self.op == OP_TRANSFER:
+        if self.op in _OPS_WITH_DEST:
             if not self.dest or len(self.dest) > MAX_ACCOUNT_KEY_LEN:
                 raise ValueError(
-                    f"transfer dest must be 1..{MAX_ACCOUNT_KEY_LEN} bytes"
+                    f"{_OP_NAMES[self.op]} dest must be "
+                    f"1..{MAX_ACCOUNT_KEY_LEN} bytes"
                 )
         elif self.dest:
             raise ValueError(f"{_OP_NAMES[self.op]} takes no dest")
@@ -268,16 +324,20 @@ def _account_keys(span: Tuple[int, int, int]) -> bytes:
     )
 
 
-def _allocation_header(balance: int, count: int) -> bytes:
-    return Writer().fixed(ALLOCATION_MAGIC).u64(balance).u32(count).finish()
+def _allocation_header(balance: int, count: int, savings: int = 0) -> bytes:
+    if savings == 0:
+        return Writer().fixed(ALLOCATION_MAGIC).u64(balance).u32(count).finish()
+    return (Writer().fixed(ALLOCATION_MAGIC_TWO).u64(balance).u64(savings)
+            .u32(count).finish())
 
 
 def write_genesis_allocation(path: str, count: int, seed: int,
-                             balance: int) -> None:
+                             balance: int, savings: int = 0) -> None:
     """``count`` accounts, each funded with ``balance``: magic ‖ u64 balance
-    ‖ u32 count ‖ count 32-byte keys, in index order.  A million key
-    derivations take a core a minute, so large counts are spread over the
-    host's cores."""
+    ‖ u32 count ‖ count 32-byte keys, in index order; where every account
+    also starts with ``savings``, the second magic ‖ u64 checking ‖ u64
+    savings ‖ u32 count ‖ the keys.  A million key derivations take a core
+    a minute, so large counts are spread over the host's cores."""
     ranges = [(seed, at, min(count, at + 8192))
               for at in range(0, count, 8192)]
     workers = min(len(ranges), os.cpu_count() or 1)
@@ -289,26 +349,30 @@ def write_genesis_allocation(path: str, count: int, seed: int,
     else:
         chunks = [_account_keys(span) for span in ranges]
     with open(path + ".tmp", "wb") as f:
-        f.write(_allocation_header(balance, count))
+        f.write(_allocation_header(balance, count, savings))
         for chunk in chunks:
             f.write(chunk)
     os.replace(path + ".tmp", path)
 
 
-def read_genesis_allocation(path: str) -> Tuple[int, bytes]:
-    """(balance, the keys' bytes back to back) of an allocation file."""
+def read_genesis_allocation(path: str) -> tuple:
+    """What ``ExecutionState.load_genesis`` takes, off an allocation file:
+    (balance, the keys' bytes back to back), and the savings balance
+    behind them where the file has two balances."""
     with open(path, "rb") as f:
         data = f.read()
     r = Reader(data)
-    if r.fixed(len(ALLOCATION_MAGIC)) != ALLOCATION_MAGIC:
+    magic = r.fixed(len(ALLOCATION_MAGIC))
+    if magic not in (ALLOCATION_MAGIC, ALLOCATION_MAGIC_TWO):
         raise SerdeError(f"{path} is no genesis allocation")
     balance = r.u64()
+    savings = r.u64() if magic == ALLOCATION_MAGIC_TWO else None
     count = r.u32()
     keys = data[r.pos:]
     if len(keys) != SIGNER_KEY_LEN * count:
         raise SerdeError(
             f"{path} names {count} accounts and holds {len(keys)} key bytes")
-    return balance, keys
+    return (balance, keys) if savings is None else (balance, keys, savings)
 
 
 @dataclass(frozen=True)
@@ -337,12 +401,14 @@ class ExecutionState:
         # takes transactions out of signed envelopes only.
         self.signed = signed
         self._exec_lock = threading.Lock()
-        # account key -> (balance, nonce).  Guarded by _exec_lock (lint
-        # GUARDED_FIELDS): the core task folds commits while ingress
-        # submission threads probe balances for pre-consensus admission.
-        self._exec_accounts: Dict[bytes, Tuple[int, int]] = {}
-        # The genesis allocation as loaded, (balance, keys), if any.
-        self._genesis: Optional[Tuple[int, bytes]] = None
+        # account key -> (balance, nonce, savings); the balance is the
+        # checking balance and may be negative (WriteCheck).  Guarded by
+        # _exec_lock (lint GUARDED_FIELDS): the core task folds commits
+        # while ingress submission threads probe balances for
+        # pre-consensus admission.
+        self._exec_accounts: Dict[bytes, Tuple[int, int, int]] = {}
+        # The genesis allocation as loaded, (balance, keys, savings), if any.
+        self._genesis: Optional[Tuple[int, bytes, int]] = None
         # Every account a commit has touched, with its encoded entry as of
         # its last commit, in the order they were first touched (the same
         # on every node, being the committed sequence's).  The durable
@@ -355,6 +421,9 @@ class ExecutionState:
         self.recent_roots: Deque[Tuple[int, bytes]] = deque(maxlen=ROOT_WINDOW)
         self.applied_total = 0
         self.rejected_total = 0
+        # Transactions folded as ``bad_nonce`` since this process started
+        # (spans.NODE_STAMPS ``exec_bad_nonce``): a sequence that broke.
+        self.bad_nonce_total = 0
         self.metrics = metrics
         # The validator's stage clock (spans.StageClock; None = not
         # clocked): ``observe_commit`` books ``exec_fold``.
@@ -366,6 +435,12 @@ class ExecutionState:
         """(balance, nonce) snapshot, or None for an unknown account.
         Advisory by design: in-flight committed transactions may move the
         account before a submission folded against this snapshot lands."""
+        entry = self.balances(account)
+        return None if entry is None else entry[:2]
+
+    def balances(self, account: bytes) -> Optional[Tuple[int, int, int]]:
+        """(checking, nonce, savings) snapshot — what a committed Balance
+        reads — or None for an unknown account."""
         with self._exec_lock:
             return self._exec_accounts.get(account)
 
@@ -373,24 +448,28 @@ class ExecutionState:
         with self._exec_lock:
             return len(self._exec_accounts)
 
-    def load_genesis(self, balance: int, keys: bytes) -> None:
+    def load_genesis(self, balance: int, keys: bytes,
+                     savings: int = 0) -> None:
         """Fund the genesis allocation's accounts (nonce 0) before height
         1.  The allocation enters the root chain: two validators that
         loaded different allocations disagree at the first root."""
         if self.last_height:
             raise ValueError("a genesis allocation is loaded before any "
                              "commit is folded")
-        self._genesis = (balance, keys)
+        self._genesis = (balance, keys, savings)
         # The root chain starts from the allocation file's bytes.
         h = hashlib.blake2b(GENESIS_ROOT, digest_size=32)
-        h.update(_allocation_header(balance, len(keys) // SIGNER_KEY_LEN))
+        h.update(_allocation_header(
+            balance, len(keys) // SIGNER_KEY_LEN, savings))
         h.update(keys)
         self.root = h.digest()
         self._fund_genesis()
 
     def _fund_genesis(self) -> None:
-        balance, keys = self._genesis
-        entry = (balance, 0)
+        balance, keys, savings = self._genesis
+        # One shared entry: a million distinct tuples in each of ten
+        # processes is host memory nothing needs.
+        entry = (balance, 0, savings)
         accounts = {keys[at:at + SIGNER_KEY_LEN]: entry
                     for at in range(0, len(keys), SIGNER_KEY_LEN)}
         with self._exec_lock:
@@ -418,32 +497,52 @@ class ExecutionState:
                 break
         return None
 
-    def admission_verdict(self, tx: ExecTx) -> Optional[str]:
-        """Pre-consensus admission check for the ingress plane: a typed
-        reject for transactions that are *already* doomed against current
-        state, None for plausibly-valid ones.
+    def admission(self, tx: ExecTx) -> Tuple[Optional[str], bool]:
+        """Pre-consensus admission check for the ingress plane: (a typed
+        reject for a transaction that is *already* doomed against current
+        state or None for a plausibly valid one, whether its nonce is
+        AHEAD of the account's — earlier operations of the account still
+        in flight).
 
-        Deliberately weaker than :meth:`_apply`: a nonce *ahead* of the
-        account (earlier transactions in flight) and a CREATE for a not-yet
-        -existing account are admitted — only verdicts that cannot be cured
-        by in-flight traffic (stale nonce, overdraft beyond current funds
-        plus any pending mint is still a heuristic — we only shed what is
-        wrong *now*) are shed before consensus pays for the transaction."""
-        snapshot = self.probe(tx.account)
+        Deliberately weaker than :meth:`_apply`, because the snapshot is
+        advisory: only what in-flight traffic cannot cure is shed before
+        consensus pays for the transaction.
+
+        * shed: a nonce BEHIND the account's (``bad_nonce``), an unknown
+          signer or — for a SmallBank operation, which creates no account —
+          an unknown ``dest`` (``unknown_account``), a CREATE of an account
+          that exists (``account_exists``), and a TRANSFER at the
+          account's current nonce for more than its current balance
+          (``insufficient_balance``: nothing of the account is in flight
+          ahead of it, so only another account's transfer could still fund
+          it, and the fold would refuse it without consuming a nonce);
+        * admitted: a nonce ahead, a CREATE of an account not yet there,
+          and every SmallBank operation whatever the funds — an abort is
+          an outcome the client is owed, and funds may arrive before the
+          operation folds."""
+        entry = self.balances(tx.account)
         if tx.op == OP_CREATE:
-            return REJECT_EXISTS if snapshot is not None else None
-        if snapshot is None:
-            return REJECT_UNKNOWN
-        balance, nonce = snapshot
+            return (REJECT_EXISTS if entry is not None else None), False
+        if entry is None:
+            return REJECT_UNKNOWN, False
+        balance, nonce, _ = entry
         if tx.nonce < nonce:
-            return REJECT_BAD_NONCE
-        if tx.op == OP_TRANSFER and tx.nonce == nonce and tx.amount > balance:
-            return REJECT_OVERDRAFT
-        return None
+            return REJECT_BAD_NONCE, False
+        if tx.op == OP_TRANSFER:
+            if tx.nonce == nonce and tx.amount > balance:
+                return REJECT_OVERDRAFT, False
+        elif tx.op in _OPS_WITH_DEST and self.balances(tx.dest) is None:
+            return REJECT_UNKNOWN, False
+        return None, tx.nonce > nonce
+
+    def admission_verdict(self, tx: ExecTx) -> Optional[str]:
+        """The reject of :meth:`admission`, or None."""
+        return self.admission(tx)[0]
 
     # -- the fold --------------------------------------------------------
 
-    def _apply(self, tx: ExecTx, deltas: Dict[bytes, Tuple[int, int]]) -> str:
+    def _apply(self, tx: ExecTx,
+               deltas: Dict[bytes, Tuple[int, int, int]]) -> str:
         """Apply one transaction against the account table (lock held by
         the caller), recording touched accounts into ``deltas``."""
         accounts = self._exec_accounts
@@ -452,33 +551,73 @@ class ExecutionState:
                 return REJECT_EXISTS
             if tx.nonce != 0:
                 return REJECT_BAD_NONCE
-            accounts[tx.account] = (tx.amount, 1)
+            accounts[tx.account] = (tx.amount, 1, 0)
             deltas[tx.account] = accounts[tx.account]
             return APPLIED
         entry = accounts.get(tx.account)
         if entry is None:
             return REJECT_UNKNOWN
-        balance, nonce = entry
+        balance, nonce, savings = entry
         if tx.nonce != nonce:
             return REJECT_BAD_NONCE
+        if tx.op in _SMALLBANK_OPS:
+            return self._apply_smallbank(tx, entry, deltas)
         if tx.op == OP_MINT:
-            accounts[tx.account] = (balance + tx.amount, nonce + 1)
+            accounts[tx.account] = (balance + tx.amount, nonce + 1, savings)
             deltas[tx.account] = accounts[tx.account]
             return APPLIED
         # OP_TRANSFER
         if tx.amount > balance:
             return REJECT_OVERDRAFT
-        dest_balance, dest_nonce = accounts.get(tx.dest, (0, 0))
         if tx.dest == tx.account:
             # Self-transfer: balance unchanged, nonce still consumed.
-            accounts[tx.account] = (balance, nonce + 1)
+            accounts[tx.account] = (balance, nonce + 1, savings)
             deltas[tx.account] = accounts[tx.account]
             return APPLIED
-        accounts[tx.account] = (balance - tx.amount, nonce + 1)
-        accounts[tx.dest] = (dest_balance + tx.amount, dest_nonce)
+        dest_balance, dest_nonce, dest_savings = accounts.get(
+            tx.dest, (0, 0, 0))
+        accounts[tx.account] = (balance - tx.amount, nonce + 1, savings)
+        accounts[tx.dest] = (dest_balance + tx.amount, dest_nonce,
+                             dest_savings)
         deltas[tx.account] = accounts[tx.account]
         deltas[tx.dest] = accounts[tx.dest]
         return APPLIED
+
+    def _apply_smallbank(self, tx: ExecTx, entry: Tuple[int, int, int],
+                         deltas: Dict[bytes, Tuple[int, int, int]]) -> str:
+        """One SmallBank procedure of a known signer at its nonce: the
+        nonce is consumed whatever the outcome, but for an unknown N2."""
+        accounts = self._exec_accounts
+        checking, nonce, savings = entry
+        op, amount = tx.op, tx.amount
+        if op in _OPS_WITH_DEST and tx.dest not in accounts:
+            return REJECT_UNKNOWN
+        verdict, credit = APPLIED, 0
+        if op == OP_DEPOSIT_CHECKING:
+            checking += amount
+        elif op == OP_TRANSACT_SAVINGS:
+            savings += amount
+        elif op == OP_AMALGAMATE:
+            credit, checking, savings = savings + checking, 0, 0
+        elif op == OP_WRITE_CHECK:
+            # The source's overdraft penalty: one more where the two
+            # balances together do not cover the check.
+            checking -= amount + 1 if savings + checking < amount else amount
+        elif op == OP_SEND_PAYMENT:
+            if checking < amount:
+                verdict = ABORTED
+            else:
+                checking -= amount
+                credit = amount
+        # OP_BALANCE reads, and writes nothing but the nonce.
+        deltas[tx.account] = accounts[tx.account] = (
+            checking, nonce + 1, savings)
+        if credit:
+            # After the signer's own entry, so that N2 = N1 reads it.
+            to_checking, to_nonce, to_savings = accounts[tx.dest]
+            deltas[tx.dest] = accounts[tx.dest] = (
+                to_checking + credit, to_nonce, to_savings)
+        return verdict
 
     def observe_commit(
         self, height: int, blocks: List[StatementBlock]
@@ -498,7 +637,9 @@ class ExecutionState:
         self, height: int, blocks: List[StatementBlock]
     ) -> ExecutionResult:
         verdicts: Dict[str, int] = {}
-        deltas: Dict[bytes, Tuple[int, int]] = {}
+        ops: Dict[int, int] = {}
+        conflicts = 0
+        deltas: Dict[bytes, Tuple[int, int, int]] = {}
         with self._exec_lock:
             for block in blocks:
                 for st in block.statements:
@@ -507,10 +648,15 @@ class ExecutionState:
                     tx = self.transaction_of(bytes(st.transaction))
                     if tx is None:
                         continue
-                    verdict = (
-                        tx if tx is REJECT_UNSIGNED
-                        else self._apply(tx, deltas)
-                    )
+                    if tx is REJECT_UNSIGNED:
+                        verdict = tx
+                    else:
+                        ops[tx.op] = ops.get(tx.op, 0) + 1
+                        # Its signer or its counterparty was written
+                        # earlier in this same commit.
+                        if tx.account in deltas or tx.dest in deltas:
+                            conflicts += 1
+                        verdict = self._apply(tx, deltas)
                     verdicts[verdict] = verdicts.get(verdict, 0) + 1
             entries = {
                 key: _account_entry(key, *deltas[key]) for key in deltas
@@ -526,15 +672,24 @@ class ExecutionState:
         self.root = h.digest()
         self.last_height = height
         self.recent_roots.append((height, self.root))
-        applied = verdicts.get(APPLIED, 0)
-        rejected = sum(v for k, v in verdicts.items() if k != APPLIED)
+        # ``aborted`` is an executed outcome, counted with ``applied``.
+        applied = verdicts.get(APPLIED, 0) + verdicts.get(ABORTED, 0)
+        rejected = sum(verdicts.values()) - applied
         self.applied_total += applied
         self.rejected_total += rejected
+        self.bad_nonce_total += verdicts.get(REJECT_BAD_NONCE, 0)
         if self.metrics is not None:
             for verdict, count in verdicts.items():
                 self.metrics.mysticeti_execution_txs_total.labels(
                     verdict
                 ).inc(count)
+            for op, count in ops.items():
+                self.metrics.mysticeti_execution_ops_total.labels(
+                    _OP_NAMES[op]
+                ).inc(count)
+            if conflicts:
+                self.metrics.mysticeti_execution_conflicts_total.inc(
+                    conflicts)
             self.metrics.mysticeti_execution_height.set(height)
             self.metrics.mysticeti_execution_accounts.set(
                 len(self._exec_accounts)
@@ -577,10 +732,10 @@ class ExecutionState:
         r = Reader(data)
         last_height = r.u64()
         root = r.fixed(32)
-        accounts: Dict[bytes, Tuple[int, int]] = {}
+        accounts: Dict[bytes, Tuple[int, int, int]] = {}
         for _ in range(r.u32()):
-            key = bytes(r.bytes())
-            accounts[key] = (r.u64(), r.u64())
+            key, entry = _read_account_entry(r)
+            accounts[key] = entry
         applied_total = r.u64()
         rejected_total = r.u64()
         r.expect_done()
@@ -591,8 +746,8 @@ class ExecutionState:
                 self._exec_accounts = {}
             self._exec_accounts.update(accounts)
             self._exec_touched = {
-                key: _account_entry(key, balance, nonce)
-                for key, (balance, nonce) in accounts.items()
+                key: _account_entry(key, *entry)
+                for key, entry in accounts.items()
             }
         self.last_height = last_height
         self.root = root

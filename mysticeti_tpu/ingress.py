@@ -108,6 +108,9 @@ _EXEC_SHED_REASONS = (
     SHED_ACCOUNT_EXISTS,
 )
 
+# Prefix of the fairness lane of an execution transaction's account.
+ACCOUNT_LANE = "acct:"
+
 # Floor on any retry-after hint: a zero tells a closed-loop client to spin.
 RETRY_AFTER_MIN_MS = 25
 
@@ -193,6 +196,8 @@ class Mempool:
         self._mempool_lock = threading.Lock()
         self._mempool_count = 0
         self._mempool_bytes = 0
+        # Deepest an account's lane has stood since ``take_lane_depth``.
+        self._lane_depth_high = 0
 
     # -- intake --
 
@@ -259,6 +264,9 @@ class Mempool:
                 accepted += 1
                 if fin is not None and fin.sampled(key):
                     sampled_keys.append(key)
+            if accepted and client.startswith(ACCOUNT_LANE):
+                self._lane_depth_high = max(self._lane_depth_high,
+                                            len(lane.queue))
         # Stamp outside _mempool_lock: the tracker has its own lock and the
         # lock-order lint wants no nesting between the two planes.
         if sampled_keys:
@@ -352,6 +360,14 @@ class Mempool:
             else 0.0
         )
         return max(by_count, by_bytes)
+
+    def take_lane_depth(self) -> int:
+        """The deepest an account's lane (``acct:<key>``) stood since the
+        last call: how many operations of one account waited for a
+        proposal together."""
+        with self._mempool_lock:
+            high, self._lane_depth_high = self._lane_depth_high, 0
+        return high
 
     def lane_stats(self) -> Dict[str, dict]:
         out: Dict[str, dict] = {}
@@ -511,6 +527,11 @@ class IngressPlane:
         # shed-schedule claim.
         self._accounting_lock = threading.Lock()
         self.admitted_total = 0
+        # Execution transactions that passed the pre-consensus check with
+        # a nonce ahead of their account's, and the deepest an account's
+        # lane has ever stood (the gauge holds the last tick's).
+        self.nonce_ahead_total = 0
+        self.lane_depth_max = 0
         self.shed_by_reason: Dict[str, int] = {}
         self.shed_log: List[dict] = []
         self._shed_log_dropped = 0
@@ -695,12 +716,19 @@ class IngressPlane:
         return self._submit(client, transactions, priority, refused)
 
     async def submit_checked(
-        self, client: str, transactions: List[bytes], priority: bool = False
+        self, client: str, transactions: List[bytes], priority: bool = False,
+        after: "Optional[asyncio.Future]" = None,
     ) -> SubmitResult:
         """:meth:`submit` for a caller on the event loop: the submission's
         signatures go to the verifier as one batch on an executor thread,
         so only this submission's reply waits for the verdicts.  Without
-        required signatures it is :meth:`submit`, with no await."""
+        required signatures it is :meth:`submit`, with no await.
+
+        ``after`` is the submission the same sender made before this one
+        (its task): signatures are verified side by side, but this one
+        enters the pool only once that one has — whichever verdicts come
+        back first, an account's operations are admitted in the order
+        their connection sent them."""
         refused: Dict[str, int] = {}
         found = self._unverified(transactions)
         if found:
@@ -715,6 +743,8 @@ class IngressPlane:
                     self._verify_transactions, found)
             transactions, refused = self._settle_signatures(
                 transactions, found, oks, started)
+        if after is not None and not after.done():
+            await asyncio.wait([after])
         return self._submit(client, transactions, priority, refused)
 
     def _submit(
@@ -801,6 +831,7 @@ class IngressPlane:
         from .execution import REJECT_UNSIGNED
 
         lanes: "OrderedDict[str, List[bytes]]" = OrderedDict()
+        nonce_ahead = 0
         for tx in transactions:
             parsed = self.execution.transaction_of(tx)
             if parsed is None:
@@ -809,11 +840,19 @@ class IngressPlane:
             if parsed is REJECT_UNSIGNED:
                 sheds[SHED_UNSIGNED] = sheds.get(SHED_UNSIGNED, 0) + 1
                 continue
-            verdict = self.execution.admission_verdict(parsed)
+            verdict, ahead = self.execution.admission(parsed)
             if verdict is not None:
                 sheds[verdict] = sheds.get(verdict, 0) + 1
                 continue
-            lanes.setdefault(f"acct:{parsed.account.hex()}", []).append(tx)
+            nonce_ahead += ahead
+            lanes.setdefault(
+                ACCOUNT_LANE + parsed.account.hex(), []).append(tx)
+        if nonce_ahead:
+            with self._accounting_lock:
+                self.nonce_ahead_total += nonce_ahead
+            if self.metrics is not None:
+                self.metrics.mysticeti_ingress_nonce_ahead_total.inc(
+                    nonce_ahead)
         return list(lanes.items())
 
     def drain(self, budget: int) -> List[bytes]:
@@ -945,9 +984,12 @@ class IngressPlane:
         return signals
 
     def _export_gauges(self, shed_mode: bool) -> None:
+        depth = self.mempool.take_lane_depth()
+        self.lane_depth_max = max(self.lane_depth_max, depth)
         m = self.metrics
         if m is None:
             return
+        m.mysticeti_ingress_lane_depth_max.set(depth)
         m.mysticeti_ingress_admitted_rate.set(round(self.controller.rate, 3))
         m.mysticeti_ingress_mempool_transactions.set(self.mempool.pending())
         m.mysticeti_ingress_mempool_bytes.set(self.mempool.pending_bytes())
@@ -1144,6 +1186,7 @@ class IngressGateway:
         default_lane = f"conn-{conn_id}"
         outbound: asyncio.Queue = asyncio.Queue(maxsize=256)
         sink = None
+        last_submit: Optional[asyncio.Future] = None
         self.connections += 1
         if self.plane.metrics is not None:
             self.plane.metrics.mysticeti_ingress_gateway_clients.set(
@@ -1166,9 +1209,10 @@ class IngressGateway:
                 _write_frame(writer, encode_message(msg))
                 await writer.drain()
 
-        async def checked_reply(lane, msg) -> GatewaySubmitReply:
+        async def checked_reply(lane, msg, after) -> GatewaySubmitReply:
             result = await self.plane.submit_checked(
-                lane, list(msg.transactions), priority=bool(msg.priority)
+                lane, list(msg.transactions), priority=bool(msg.priority),
+                after=after,
             )
             return GatewaySubmitReply(
                 result.status, result.accepted, result.shed,
@@ -1192,13 +1236,17 @@ class IngressGateway:
                     # reply is a task, written in the connection's reply
                     # order when it is done.  Where signatures are required
                     # it waits for the verifier's verdicts on the frame's
-                    # one batch; the loop, the other connections and this
-                    # connection's next frames do not (the outbound queue
-                    # bounds how many wait).
-                    await outbound.put(spawn_logged(
-                        checked_reply(lane, msg), log,
+                    # one batch; the loop, the other connections and the
+                    # verification of this connection's next frames do not
+                    # (the outbound queue bounds how many wait).  A frame
+                    # enters the pool after the connection's frame before
+                    # it: what one connection sent of one account is
+                    # admitted in the order sent (docs/ingress.md).
+                    last_submit = spawn_logged(
+                        checked_reply(lane, msg, last_submit), log,
                         name=f"gateway-submit-{conn_id}",
-                    ))
+                    )
+                    await outbound.put(last_submit)
                 elif isinstance(msg, GatewaySubscribeCommits):
                     # A later subscribe on the same connection REPLACES the
                     # filter (wire-format §5b): silently ignoring it would

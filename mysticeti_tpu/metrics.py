@@ -764,6 +764,19 @@ class Metrics:
             "transactions admitted into the mempool (offered = admitted + "
             "shed, per the typed SubmitResult contract)",
         )
+        self.mysticeti_ingress_nonce_ahead_total = counter(
+            "mysticeti_ingress_nonce_ahead_total",
+            "execution transactions that passed the pre-consensus check "
+            "with a nonce AHEAD of their account's executed nonce: earlier "
+            "operations of the same account were still in flight",
+        )
+        self.mysticeti_ingress_lane_depth_max = gauge(
+            "mysticeti_ingress_lane_depth_max",
+            "deepest an account's fairness lane (acct:<key>) stood in the "
+            "ingress tick interval that ended last (a high-water mark a "
+            "tick): one account's admitted operations waiting for a "
+            "proposal together",
+        )
         self.mysticeti_ingress_admitted_rate = gauge(
             "mysticeti_ingress_admitted_rate",
             "current AIMD-admitted transaction rate ceiling (tx/s) — cut "
@@ -860,8 +873,23 @@ class Metrics:
             "verdict: applied, or a typed deterministic reject "
             "(bad_nonce, insufficient_balance, unknown_account, "
             "account_exists) — rejects consume the commit slot but not "
-            "account state",
+            "account state — or aborted: a SmallBank procedure its own "
+            "rules aborted, executed with its nonce consumed",
             labels=("result",),
+        )
+        self.mysticeti_execution_ops_total = counter(
+            "mysticeti_execution_ops_total",
+            "execution transactions folded through the state machine by "
+            "operation (create, mint, transfer, balance, deposit_checking, "
+            "transact_savings, amalgamate, write_check, send_payment), "
+            "whatever the verdict",
+            labels=("op",),
+        )
+        self.mysticeti_execution_conflicts_total = counter(
+            "mysticeti_execution_conflicts_total",
+            "execution transactions whose signer or counterparty an "
+            "earlier transaction of the SAME commit had already written: "
+            "what a parallel fold would have to serialise",
         )
         self.mysticeti_execution_height = gauge(
             "mysticeti_execution_height",

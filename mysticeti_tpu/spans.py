@@ -538,11 +538,13 @@ NODE_STAGES = BLOCK_PATH_STAGES + (
 # What a validator's clock stamps once a second (``Validator._read_stamps``
 # reads them, cumulative, in this order): the threshold clock's round,
 # leaders committed, own proposals, blocks received, transactions admitted
-# and shed (all, and by ``lane_cap``), leader timeouts, and the requests it
-# sent to the verifier service.
+# and shed (all, and by ``lane_cap``), leader timeouts, the requests it
+# sent to the verifier service, and the execution transactions it folded as
+# ``bad_nonce`` (a cascade of those beside ``shed`` is an account's sequence
+# that an episode broke).
 NODE_STAMPS = ("rounds", "leaders", "proposals", "blocks_received",
                "tx_admitted", "shed", "shed_lane_cap", "leader_timeouts",
-               "verify_requests")
+               "verify_requests", "exec_bad_nonce")
 # Stages in which a request waits (for a launch, the device, the loop, the
 # GIL): wall time only, no CPU clock and no profiler annotation —
 # the runtime's own events mark them in a trace already.

@@ -254,6 +254,8 @@ class Validator:
             plane.shed_for(SHED_LANE_CAP) if plane is not None else 0,
             syncer.syncer.leader_timeouts,
             client.requests_sent if client is not None else 0,
+            core.execution.bad_nonce_total if core.execution is not None
+            else 0,
         )
 
     async def warmup_failure(self) -> None:

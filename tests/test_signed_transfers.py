@@ -727,3 +727,253 @@ def test_a_request_wider_than_the_warmed_width_compiles_nothing(tmp_path):
     grown = {k: n - counts.get(k, 0) for k, n in after.items()
              if n != counts.get(k, 0)}
     assert grown == {("blob", warmed): 3}  # 256 + 256 + 188, one request
+
+
+# -- (g) an account that signs ahead: order from the gateway to the fold --------------------
+
+from benchmark.reference import smallbank as bank  # noqa: E402
+
+BANK_ACCOUNTS, HOT_OPS = 520, 50
+
+
+@pytest.fixture(scope="module")
+def bank_allocation(tmp_path_factory):
+    path = str(tmp_path_factory.mktemp("bank") / "accounts.bin")
+    X.write_genesis_allocation(path, BANK_ACCOUNTS, SEED, 3000, 2000)
+    return path
+
+
+@pytest.fixture(scope="module")
+def bank_signers():
+    return [bank.account(SEED, i) for i in range(BANK_ACCOUNTS)]
+
+
+def _hot_frames(signers, frames=5, one_shot=500):
+    """Account 0's HOT_OPS operations in nonce order, cut into ``frames``
+    frames, each among its share of ``one_shot`` accounts that sign once."""
+    rng = random.Random(3)
+    codes = [X.OP_DEPOSIT_CHECKING, X.OP_BALANCE, X.OP_SEND_PAYMENT,
+             X.OP_WRITE_CHECK, X.OP_TRANSACT_SAVINGS, X.OP_AMALGAMATE]
+    hot = [
+        bank.make_operation(
+            # Beyond what a deposit brings back after an Amalgamate: the
+            # later payments abort.
+            signers[0], codes[n % 6], n,
+            700 if codes[n % 6] == X.OP_SEND_PAYMENT else 500,
+            signers[1 + n][1] if codes[n % 6] in bank.WITH_DEST else b"",
+            SIZE, FILLER)
+        for n in range(HOT_OPS)
+    ]
+    others = [
+        bank.make_operation(signers[10 + i], X.OP_DEPOSIT_CHECKING, 0, 130,
+                            b"", SIZE, FILLER)
+        for i in range(one_shot)
+    ]
+    out = []
+    for f in range(frames):
+        mine = hot[f * HOT_OPS // frames:(f + 1) * HOT_OPS // frames]
+        theirs = others[f * one_shot // frames:(f + 1) * one_shot // frames]
+        # The account's operations keep their order inside the frame.
+        slots = sorted(rng.randrange(len(theirs) + 1) for _ in mine)
+        frame, k = [], 0
+        for at in range(len(theirs) + 1):
+            while k < len(mine) and slots[k] == at:
+                frame.append(mine[k])
+                k += 1
+            frame.extend(theirs[at:at + 1])
+        out.append(frame)
+    return hot, out
+
+
+def test_one_accounts_operations_drain_in_nonce_order_over_many_cycles(
+        bank_allocation, bank_signers):
+    """Fifty operations of one account in five frames among 500 one-shot
+    senders, a proposal of at most 24: whatever the number of drains, the
+    account's operations leave the pool in the order they were sent, and
+    the plane counts the nonces ahead and the lane's depth."""
+    metrics = Metrics()
+    plane, _, state = _plane(bank_allocation, metrics=metrics)
+    plane.params.max_per_proposal = 24
+    hot, frames = _hot_frames(bank_signers)
+    drained = []
+    for frame in frames:
+        result = plane.submit("conn-1", frame)
+        assert (result.accepted, result.shed) == (len(frame), 0)
+        drained.extend(plane.drain(plane.max_per_proposal))
+    plane._export_gauges(False)  # what a tick does with the lanes' depth
+    while plane.pending():
+        drained.extend(plane.drain(plane.max_per_proposal))
+    assert len(drained) == HOT_OPS + 500
+    assert [tx for tx in drained if tx in set(hot)] == hot
+    # Nothing of the account has executed: every nonce but 0 is ahead.
+    assert plane.nonce_ahead_total == HOT_OPS - 1
+    assert metrics.mysticeti_ingress_nonce_ahead_total._value.get() == (
+        HOT_OPS - 1)
+    assert plane.lane_depth_max >= HOT_OPS // 5
+    # In that order the fold applies or aborts every one.
+    result = state.observe_commit(1, [type("B", (), {
+        "statements": [Share(tx) for tx in drained]})()])
+    assert dict(result.verdicts).get(X.REJECT_BAD_NONCE) is None
+    assert state.probe(bank_signers[0][1])[1] == HOT_OPS
+
+
+def test_a_connections_frames_are_admitted_in_the_order_it_sent_them(
+        bank_allocation, bank_signers):
+    """Two frames down one connection, the first one's verdicts back LAST:
+    signatures are verified side by side, the pool still takes the first
+    frame first."""
+    import threading
+
+    plane, collector, _ = _plane(bank_allocation)
+    inner = collector.verifier
+    second_done = threading.Event()
+    calls = []
+
+    class FirstIsSlow(CpuSignatureVerifier):
+        def verify_signatures(self, pks, digests, sigs):
+            calls.append(len(pks))
+            if len(calls) == 1:
+                second_done.wait(10)
+            out = inner.verify_signatures(pks, digests, sigs)
+            if len(calls) > 1:
+                second_done.set()
+            return out
+
+    plane._tx_verifier = FirstIsSlow()
+    hot, _ = _hot_frames(bank_signers, one_shot=0)
+    first, second = hot[:3], hot[3:5]
+
+    async def main():
+        gateway = await IngressGateway(plane, "127.0.0.1", 0).start()
+        port = gateway._server.sockets[0].getsockname()[1]
+        reader, writer = await asyncio.open_connection("127.0.0.1", port)
+        try:
+            for frame in (first, second):
+                _write_frame(writer, encode_message(
+                    GatewaySubmit(b"", 0, tuple(frame))))
+            await writer.drain()
+            return [decode_message(await _read_frame(reader))
+                    for _ in range(2)]
+        finally:
+            writer.close()
+            await gateway.stop()
+
+    replies = asyncio.run(main())
+    assert [r.accepted for r in replies] == [3, 2]
+    assert calls == [3, 2] and second_done.is_set()
+    assert plane.drain(100) == first + second
+
+
+@pytest.mark.chaos
+def test_four_validators_fold_an_account_that_signs_ahead_in_order(
+        bank_allocation, bank_signers, tmp_path):
+    """One account's fifty operations through one gateway's admission among
+    one-shot senders on all four, proposals cut small, in the simulator:
+    every one commits, none as ``bad_nonce``, with nonces admitted ahead
+    and a lane more than one deep; the roots are the reference's."""
+    from mysticeti_tpu.chaos import FaultPlan, run_chaos_sim
+    from mysticeti_tpu.config import StorageParameters
+
+    nodes = 4
+    committed = []
+    hot, frames = _hot_frames(bank_signers, frames=5, one_shot=400)
+    planes = []
+
+    def real_crypto(authority, committee, metrics):
+        return BatchedSignatureVerifier(
+            committee, CpuSignatureVerifier(), max_delay_s=0.002,
+            metrics=metrics)
+
+    async def driver(harness):
+        execution = harness.nodes[0].core.execution
+        fold = execution.observe_commit
+
+        def spy(height, blocks):
+            committed.append((height, [
+                bytes(st.transaction) for block in blocks
+                for st in block.statements if isinstance(st, Share)]))
+            return fold(height, blocks)
+
+        execution.observe_commit = spy
+        for a in range(nodes):
+            node = harness.nodes[a]
+            planes.append(IngressPlane(
+                IngressParameters(admission=False, max_per_proposal=12)
+            ).attach(core=node.core, block_verifier=node.block_verifier))
+        for step in range(16):
+            await asyncio.sleep(0.2)
+            if step < len(frames):
+                # The account talks to validator 0; the one-shot senders
+                # of the frame are spread over all four.
+                frame = frames[step]
+                mine = [tx for tx in frame if tx in hot]
+                theirs = [tx for tx in frame if tx not in hot]
+                for a, plane in enumerate(planes):
+                    share = theirs[a::nodes]
+                    if a == 0:
+                        share = share[:len(share) // 2] + mine + share[
+                            len(share) // 2:]
+                    result = await plane.submit_checked(f"conn-{a}", share)
+                    assert result.shed == 0
+            for a, plane in enumerate(planes):
+                plane.tick()
+                for tx in plane.drain(plane.max_per_proposal):
+                    harness.inject(a, tx)
+
+    params = Parameters(
+        leader_timeout_s=1.0, execution=True, signed_transactions=True,
+        genesis_allocation=bank_allocation,
+        storage=StorageParameters(checkpoint_interval=0),
+    )
+    report, harness = run_chaos_sim(
+        FaultPlan(seed=12), nodes, 8.0, str(tmp_path), parameters=params,
+        with_metrics=True, verifier_factory=real_crypto, extra_fault=driver,
+        committee=Committee.new_for_benchmarks(nodes),
+    )
+    fold = bank.Fold()
+    fold.load_genesis(*X.read_genesis_allocation(bank_allocation))
+    fold.log = []
+    for height, payloads in committed:
+        assert fold.commit(height, payloads) == harness.checker.state_root_at(
+            0, height)
+    by_hot = [verdict for payload, verdict in fold.log if payload in hot]
+    assert len(by_hot) == HOT_OPS
+    assert set(by_hot) <= set(bank.EXECUTED) and bank.ABORTED in by_hot
+    assert bank.BAD_NONCE not in fold.verdicts
+    assert [p for p, _ in fold.log if p in hot] == hot
+    assert fold.accounts[bank_signers[0][1]][1] == HOT_OPS
+    assert harness.nodes[0].core.execution.bad_nonce_total == 0
+    assert planes[0].nonce_ahead_total > 0
+    assert planes[0].lane_depth_max > 1
+    assert report.state_root_chain
+
+
+@pytest.mark.parametrize("n_accounts", [56, 255])
+def test_a_request_with_repeated_signers_equals_the_oracle(n_accounts):
+    """A quarter of the account signatures by four keys, so that the launch
+    holds the same signer many times, a quarter of all corrupted (some of
+    the repeats among them): every bit OpenSSL's, one kernel."""
+    from mysticeti_tpu.ops import ed25519 as E
+
+    signers = Committee.benchmark_signers(4)
+    table = E.KeyTable([s.public_key.bytes for s in signers])
+    rng = random.Random(4000 + n_accounts)
+    keys = [bank.account(SEED, i) for i in range(n_accounts)]
+    lanes = [rng.randrange(4) if rng.random() < 0.25
+             else rng.randrange(4, n_accounts) for _ in range(n_accounts)]
+    assert max(lanes.count(k) for k in range(4)) >= 2
+    request = oracle.signed_request(
+        rng, keys, lanes, rng.sample(range(n_accounts), n_accounts // 4))
+    author = signers[0]
+    digest = rng.randbytes(32)
+    pks = [author.public_key.bytes] + request["public_keys"]
+    digests = [digest] + request["digests"]
+    sigs = [author.sign(digest)] + request["signatures"]
+    before = {(d["kernel"], d["bucket"]): d["count"]
+              for d in E.dispatch_counts()}
+    got = E.dispatch_batch_table(table, pks, digests, sigs).result()
+    assert [bool(b) for b in got] == [True] + request["expected"]
+    assert 0 < sum(request["expected"]) < n_accounts
+    launched = {k: d["count"] - before.get(k, 0) for d in E.dispatch_counts()
+                for k in [(d["kernel"], d["bucket"])]}
+    assert {k: n for k, n in launched.items() if n} == {("blob", 256): 1}
