@@ -320,9 +320,12 @@ def prepare_fused(
     the encoding parse and the canonicity checks (s < L, A < p).  R canonicity
     needs no explicit check: the final compare is exact on raw limbs.
 
-    The scopes name this glue in a profile: it runs as XLA ops around the
-    ladder (a fifth of a launch's device time on a v5e), and without them
-    its ``while`` and ``dynamic-slice`` ops say nothing of what they are.
+    This is the ``xla`` backend's form (the CPU tier, and the four-chip
+    mesh's shards), batch-major XLA ops with two scans, and the reference
+    the tests hold the Pallas kernels to: a launch on a TPU runs the same
+    steps inside its Pallas call (``ops.ed25519_pallas._prepare``) and does
+    not come through here.  The scopes name these ops in a profile of the
+    ``xla`` form.
     """
     with jax.named_scope("ed25519_challenge_hash"):
         dig = H.sha512_96(msg_words)

@@ -3,6 +3,9 @@
 The fused path (pack_bytes -> device SHA-512 + mod-L + parse + ladder) must
 agree bit-for-bit with the CPU oracle (cryptography/OpenSSL) — the same
 accept/reject contract the consensus layer depends on (BASELINE config #2).
+
+Tier 1 (not marked ``kernel``): the blob entry point of the Pallas backend,
+whose preparation runs inside the call, on tests/kernel_cases.py.
 """
 import random
 
@@ -10,7 +13,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-pytestmark = pytest.mark.kernel
+kernel = pytest.mark.kernel  # tier 2: compile-heavy
 
 from mysticeti_tpu.crypto import (
     Ed25519PrivateKey,
@@ -20,6 +23,8 @@ from mysticeti_tpu.crypto import (
 
 from mysticeti_tpu.ops import ed25519 as E
 from mysticeti_tpu.ops import scalar as S
+
+import kernel_cases as KC
 
 
 def _keypair(rng):
@@ -46,6 +51,7 @@ def _fused(pks, msgs, sigs) -> np.ndarray:
     )
 
 
+@kernel
 def test_fused_accepts_valid_and_rejects_corrupted():
     rng = random.Random(10)
     pks, msgs, sigs, expect = [], [], [], []
@@ -68,6 +74,7 @@ def test_fused_accepts_valid_and_rejects_corrupted():
     assert any(expect) and not all(expect)
 
 
+@kernel
 def test_fused_rejects_noncanonical_s():
     """s' = s + L is congruent mod L but non-canonical: RFC 8032 / OpenSSL
     reject it, and so must the kernel (malleability defense)."""
@@ -82,6 +89,7 @@ def test_fused_rejects_noncanonical_s():
     assert list(got) == [True, False]
 
 
+@kernel
 def test_fused_rejects_noncanonical_a_and_r():
     """Point encodings with y >= p must be rejected (A via the explicit
     canonicity check, R via the exact raw-limb compare)."""
@@ -99,6 +107,7 @@ def test_fused_rejects_noncanonical_a_and_r():
     assert list(got) == expect == [True, False, False]
 
 
+@kernel
 def test_fused_matches_host_path():
     """Fused device packing must agree with the host pack_batch path on the
     same inputs (valid + invalid mix)."""
@@ -118,6 +127,7 @@ def test_fused_matches_host_path():
     assert list(fused) == list(host)
 
 
+@kernel
 def test_verify_batch_end_to_end_padding_and_malformed():
     """verify_batch: odd sizes (bucket padding), malformed lengths masked."""
     rng = random.Random(14)
@@ -145,6 +155,7 @@ def test_verify_batch_end_to_end_padding_and_malformed():
     assert E.verify_batch([], [], []).shape == (0,)
 
 
+@kernel
 def test_verify_batch_nonfused_fallback():
     """Non-32-byte messages take the host-hash path and still verify."""
     rng = random.Random(15)
@@ -159,6 +170,7 @@ def test_verify_batch_nonfused_fallback():
     assert list(got) == [True] * 5
 
 
+@kernel
 def test_fused_pallas_interpret_parity():
     """The Pallas fused wrapper agrees with the XLA fused kernel (interpret
     mode on CPU, tiny tile)."""
@@ -192,3 +204,20 @@ def test_fused_pallas_interpret_parity():
     )
     assert list(got) == list(want)
     assert any(want) and not all(want)
+
+
+@pytest.fixture(scope="module")
+def blob_verdicts():
+    """The blob entry point (strangers' signatures: the key rides in the
+    blob) over the cases, eight tiles a grid, beside the ``xla`` form."""
+    from mysticeti_tpu.ops import ed25519_pallas as PK
+
+    blob = KC.packed_blob()
+    got = PK.verify_fused_blob_pallas(blob, tile=KC.TILE, interpret=True)
+    return np.asarray(got), np.asarray(E.verify_fused_blob_kernel(blob))
+
+
+@pytest.mark.parametrize("name", KC.NAMES)
+def test_blob_entry_point_verdicts(name, blob_verdicts):
+    got, xla = blob_verdicts
+    KC.check_verdict(name, got[KC.LANE[name]], xla[KC.LANE[name]])

@@ -4,8 +4,17 @@ The keyed kernel must be bit-identical to the CPU oracle and the generic
 fused path — it is a pure strength reduction (zero doublings, no on-device A
 decompression), not a semantics change.  Runs under the Pallas interpreter
 on the CPU test mesh.
+
+Tier 1 (not marked ``kernel``): the keyed entry point, whose preparation
+runs inside the call, through the dispatch a launch takes, on
+tests/kernel_cases.py; and the kernels compiled for a v5e at the chip's
+tile by the chip's own compiler, which runs here without a chip (Mosaic
+refuses what the interpreter lets through: a layout, an unaligned slice,
+too much VMEM).
 """
 import random
+
+import jax
 
 import numpy as np
 import pytest
@@ -13,8 +22,10 @@ from mysticeti_tpu.crypto import Ed25519PrivateKey
 
 from mysticeti_tpu.ops import ed25519 as E
 
-pytestmark = [pytest.mark.kernel,
-              pytest.mark.filterwarnings("ignore::DeprecationWarning")]
+import kernel_cases as KC
+
+pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
+kernel = pytest.mark.kernel  # tier 2: compile-heavy
 
 
 @pytest.fixture(scope="module")
@@ -71,6 +82,7 @@ def test_group_blob_for_tiles_properties():
     assert E.group_blob_for_tiles(blob, num_keys, tile, tile) is None
 
 
+@kernel
 def test_keyed_kernel_matches_oracle(keyring):
     from mysticeti_tpu.ops import ed25519_pallas as PK
 
@@ -104,6 +116,7 @@ def test_keyed_kernel_matches_oracle(keyring):
         assert out[i] == oracle
 
 
+@kernel
 def test_keyed_flat_variant_matches_blob(keyring):
     """verify_keyed_flat (96 B/sig wire variant: key index reconstructed
     from tile_keys, ok as a packed bitmask, grouped-order output) agrees
@@ -140,6 +153,7 @@ def test_keyed_flat_variant_matches_blob(keyring):
     assert (out_flat[positions] == expect).all()
 
 
+@kernel
 def test_keyed_dispatch_end_to_end_forced_pallas(keyring, monkeypatch):
     """verify_batch_table with the backend forced to pallas(interpret) takes
     the keyed dispatch path and still matches expectations, including
@@ -152,6 +166,7 @@ def test_keyed_dispatch_end_to_end_forced_pallas(keyring, monkeypatch):
     assert (out == expect).all()
 
 
+@kernel
 def test_keyed_rejects_invalid_committee_key(keyring):
     """An off-curve key table entry force-rejects its lanes (the generic
     kernel rejects them via decompression failure — outputs must agree)."""
@@ -180,6 +195,7 @@ def test_keyed_rejects_invalid_committee_key(keyring):
     assert (out == (idx == 0) & expect).all()
 
 
+@kernel
 def test_neg_combs_first_window_is_negated_key(keyring):
     """Spot-check the comb contents: entry (w=0, v=1) must be the Niels form
     of -A itself."""
@@ -198,3 +214,71 @@ def test_neg_combs_first_window_is_negated_key(keyring):
         arr[0, 0, 2, :, 1]
         == F.int_to_limbs((E.P - E._D2 * x % E.P * y % E.P) % E.P)
     ).all()
+
+
+@pytest.fixture(scope="module")
+def keyed_verdicts():
+    """The keyed kernel over the cases as a launch reaches it
+    (``_dispatch_indexed_keyed``: lanes under a key that is no point are
+    refused by the host, tiles grouped by key, verdicts back in grouped
+    order), beside the ``xla`` form of the same indexed blob."""
+    blob, table = KC.indexed_blob()
+    handle, positions = E._dispatch_indexed_keyed(blob, table, 256)
+    grouped = np.asarray(handle)
+    assert grouped.sum() == grouped[positions].sum()  # no padding lane passes
+    xla = E.verify_fused_indexed_kernel(blob, table.words)
+    return grouped[positions], np.asarray(xla)
+
+
+@pytest.mark.parametrize("name", KC.NAMES)
+def test_keyed_entry_point_verdicts(name, keyed_verdicts):
+    got, xla = keyed_verdicts
+    KC.check_verdict(name, got[KC.LANE[name]], xla[KC.LANE[name]])
+
+
+# ---------------------------------------------------------------------------
+# Compiled for the chip, without one.  These are the repository's only tests
+# that load the TPU's compiler, and they stay in this one file: one process
+# at a time may hold it.
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv("TPU_LOG_DIR", "disabled")  # or it logs under /tmp
+        try:
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as e:  # noqa: BLE001 - no compiler here: nothing to hold
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("entry", ["blob", "keyed"])  # indexed: blob's kernel
+def test_entry_point_compiles_for_a_v5e(entry, one_v5e_chip):
+    """The ladder and the keyed kernel, preparation inside, at the service's
+    bucket and the chip's tile: Mosaic takes them, and what XLA keeps beside
+    the one custom call has no loop."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    fn, shapes = KC.entry_points(256, 256)[entry]
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+        for shape, dtype in shapes
+    ]
+    # A program compiled for a described chip cannot be read back from the
+    # persistent cache without one: keep it out.
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()  # or the process goes on as it decided
+    try:
+        text = fn.lower(*args, tile=256, interpret=False).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert text.count("tpu_custom_call") == 1
+    assert " while(" not in text and "while." not in text

@@ -294,14 +294,15 @@ import json, sys
 import numpy as np
 from mysticeti_tpu.block_validator import TpuSignatureVerifier
 from mysticeti_tpu.ops import ed25519 as E
+from mysticeti_tpu.ops import ed25519_pallas as PK
 
-# Every entry point's Python runs through prepare_fused: a call is a trace.
+# Every entry point's kernel body runs through _prepare: a call is a trace.
 traced = []
-prepare = E.prepare_fused
+prepare = PK._prepare
 def counting(*args):
     traced.append(1)
     return prepare(*args)
-E.prepare_fused = counting
+PK._prepare = counting
 
 batch = json.load(open(sys.argv[1]))
 keys, pks, msgs, sigs = (
