@@ -193,10 +193,12 @@ class BlockStore:
                 )
                 builder.note_retired_floor(dropped_max_round + 1)
         replayed_end: WalPosition = replay_start
+        entries = 0
         for pos, tag, payload in wal_reader.iter_from(
             replay_start, wal_writer.position()
         ):
             replayed_end = pos + HEADER_SIZE + len(payload)
+            entries += 1
             if tag == WAL_ENTRY_BLOCK:
                 block = StatementBlock.from_bytes(payload)
                 builder.block(pos, block)
@@ -228,7 +230,10 @@ class BlockStore:
                 block.reference, pos, proposed=tag == WAL_ENTRY_OWN_BLOCK
             )
             wal_writer.note_round(block.reference.round, pos)
-        builder.note_replayed(max(0, replayed_end - replay_start))
+        builder.note_replayed(
+            max(0, replayed_end - replay_start), entries,
+            max(0, wal_writer.position() - replayed_end),
+        )
         if replayed_end < wal_writer.position():
             # Torn tail (crash mid-write): replay stopped at the tear.  The
             # torn bytes must be truncated away before the first new append —
@@ -241,6 +246,11 @@ class BlockStore:
             wal_writer.truncate_to(replayed_end)
             wal_reader.cleanup()  # drop any mapping that covers the old size
         return builder.build(store)
+
+    def block_count(self) -> int:
+        """Blocks the index holds, loaded or not."""
+        with self._lock:
+            return sum(len(entries) for entries in self._index.values())
 
     # -- writes --
 

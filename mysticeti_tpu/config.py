@@ -185,15 +185,20 @@ class Parameters:
     number_of_leaders: int = 1
     enable_pipelining: bool = True
     # Leader liveness scoring (core.ready_new_block): stop gating proposals
-    # on a leader whose blocks have not been accepted locally for more than
-    # this many rounds (it is crashed, partitioned away, withholding, or
-    # signing invalidly — the leader timeout would fire anyway).  0 (the
-    # default) disables the filter: rounds are a LOAD-dependent clock, and
-    # on a contended host an honest-but-stalled leader can fall a fixed
-    # round count behind in well under the leader timeout — measured 18%
-    # fewer committed leaders on a loaded 4-validator testbed with an
-    # 8-round horizon, every lost slot an honest leader skipped.  The
-    # Byzantine scenario profile (scenarios.py) arms it at 4 where silent
+    # on a connected leader whose blocks have not been accepted locally for
+    # more than this many rounds (it is catching up after a restart,
+    # partitioned away, withholding, or signing invalidly - the leader
+    # timeout would fire anyway).  0 (the default) is the program's own
+    # horizon, ``Core.LEADER_HORIZON_ROUNDS`` (6): until PR 45 it turned
+    # the test off, and a validator back on its WAL, connected and
+    # hundreds of rounds behind, cost every other validator the leader
+    # timeout in each slot it led.  Rounds are a LOAD-dependent clock: on a
+    # contended host an honest-but-stalled leader can fall a fixed round
+    # count behind in well under the leader timeout (measured 18% fewer
+    # committed leaders on a loaded 4-validator testbed with an 8-round
+    # horizon, every lost slot an honest leader skipped) - a slot lost,
+    # where the wait would have held every validator for as long.  The
+    # Byzantine scenario profile (scenarios.py) sets 4 where silent
     # adversaries are declared and the round clock is the sim's own.
     leader_liveness_horizon_rounds: int = 0
     # Commit-anchored epoch reconfiguration (reconfig.py): committee-change

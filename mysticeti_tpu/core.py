@@ -183,7 +183,9 @@ class Core:
         self.epoch_manager = EpochManager()
         self.rounds_in_epoch = parameters.rounds_in_epoch
         self.store_retain_rounds = parameters.store_retain_rounds
-        self.leader_liveness_horizon = parameters.leader_liveness_horizon_rounds
+        self.leader_liveness_horizon = (
+            parameters.leader_liveness_horizon_rounds
+            or self.LEADER_HORIZON_ROUNDS)
         # Authorities the sync layer scored content-silent (live connection,
         # own blocks only ever recovered via relays/fetch — the withholder
         # shape).  Maintained by NetworkSyncer._score_missing; membership
@@ -481,14 +483,37 @@ class Core:
             self.epoch_manager.epoch_change_begun()
         return [s.block for s in sequence if s.kind == LeaderStatus.COMMIT]
 
+    # How far below its slot a connected leader's newest accepted block may
+    # lie and the leader still be waited for, where the configuration names
+    # no horizon of its own (``leader_liveness_horizon_rounds`` 0): a
+    # validator in step is a round or two behind its farthest peer's clock
+    # (a region away, three), one that is catching up is hundreds behind
+    # until it is not.
+    LEADER_HORIZON_ROUNDS = 6
+
     def ready_new_block(self, period: int, connected_authorities: AuthoritySet) -> bool:
         """Leader-aware proposal gating (core.rs:401-450): propose when the previous
-        round's connected leaders have been received, or there are none.
-        ``connected_authorities`` is the set as it is now: this validator
-        and every peer whose connection has not closed (net_sync.py takes a
-        peer out when its connection task ends), so a dead leader's slot is
-        not waited for and a connected, silent one is, until the leader
-        timeout forces the proposal."""
+        round's leaders that are worth the wait have been received, or
+        there are none.  ``connected_authorities`` is the set as it is now:
+        this validator and every peer whose connection has not closed
+        (net_sync.py takes a peer out when its connection task ends), so a
+        dead leader's slot is not waited for.  Of the connected, a leader
+        is waited for while its newest accepted block lies at most
+        ``leader_liveness_horizon`` rounds below its slot: one that is
+        further behind is catching up (a validator back on its WAL is
+        connected within a second and hundreds of rounds behind for many
+        more) or has gone silent, and a wait for it would cost every
+        validator the leader timeout in each slot it leads.  A slot its
+        leader has passed is not waited for either: a validator proposes at
+        its clock's round and never below it, so a leader whose clock took
+        two rounds in one batch (a pause of a tenth of a second does it)
+        leaves the round between empty for good - its own wait for that
+        block, and every peer's once a block of its from a round above has
+        been accepted, would last the whole timeout and end with nothing.
+        Such slots are decided like any empty one, by 2f + 1 blames, or by
+        the indirect rule where the block arrives late; a leader that is
+        in step and falls silent is waited for, until the leader timeout
+        forces the proposal."""
         quorum_round = self.threshold_clock.get_round()
         if quorum_round <= max(self.last_decided_leader.round, period - 1):
             return False
@@ -496,43 +521,35 @@ class Core:
         leaders = self.committer.get_leaders(leader_round)
         if not leaders:
             return True
-        connected_leaders = [
-            l for l in leaders if connected_authorities.contains(l)
-        ]
-        if self.leader_liveness_horizon > 0:
-            # Leader liveness scoring (docs/adversary.md): a leader whose
-            # blocks have not been ACCEPTED locally for more than the
-            # horizon is not worth gating the proposal on — a Byzantine
-            # authority that signs invalidly (or withholds from us) would
-            # otherwise tax every one of its slots with a full leader
-            # timeout.  The timeout task stays as the universal backstop,
-            # and the commit rule is untouched: the slot is still decided
-            # (skip) by 2f+1 non-links, exactly as on a timeout.  An
-            # authority that resumes producing acceptable blocks re-enters
-            # the wait set as soon as its last-seen round catches back up.
-            live = []
-            for leader in connected_leaders:
-                seen = self.block_store.last_seen_by_authority(leader)
-                lagging = leader_round - seen > self.leader_liveness_horizon
-                if lagging or leader in self.content_silent:
-                    # Once per (leader, round): readiness is polled on
-                    # every dispatcher event, so a bare inc() here would
-                    # count polls (thousands per skipped slot), not skips.
-                    if (
-                        self.metrics is not None
-                        and self._leader_skip_marked.get(leader) != leader_round
-                    ):
-                        self._leader_skip_marked[leader] = leader_round
-                        self.metrics.mysticeti_leader_wait_skipped_total.labels(
-                            str(leader)
-                        ).inc()
-                else:
-                    live.append(leader)
-            connected_leaders = live
-        if not connected_leaders:
+        waited = []
+        for leader in leaders:
+            if (leader == self.authority
+                    or not connected_authorities.contains(leader)):
+                continue
+            below = leader_round - self.block_store.last_seen_by_authority(
+                leader)
+            if below < 0:
+                continue  # it has passed the slot
+            if (below <= self.leader_liveness_horizon
+                    and leader not in self.content_silent):
+                waited.append(leader)
+                continue
+            # Let go: too far behind, or marked content-silent (net_sync.py:
+            # its blocks arrive by relays only, and a wait for the relay hop
+            # on each of its slots is the withholder's remaining tax,
+            # docs/adversary.md).  Counted once per (leader, round):
+            # readiness is polled on every dispatcher event, so a bare
+            # inc() here would count polls, not skips.
+            if (self.metrics is not None
+                    and self._leader_skip_marked.get(leader) != leader_round):
+                self._leader_skip_marked[leader] = leader_round
+                self.metrics.mysticeti_leader_wait_skipped_total.labels(
+                    str(leader)
+                ).inc()
+        if not waited:
             return True
         return self.block_store.all_blocks_exists_at_authority_round(
-            connected_leaders, leader_round
+            waited, leader_round
         )
 
     # -- commit persistence (core.rs:452-490) --

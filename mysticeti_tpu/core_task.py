@@ -41,6 +41,12 @@ class CoreTaskDispatcher:
     # genuine corruption poisons every mutation type.
     MAX_CONSECUTIVE_FAILURES = 16
     CPU_ONE_IN = 8
+    # Commands queued behind one another run back to back; once they have
+    # held the event loop this long with more waiting, the owner gives the
+    # loop one turn (live nodes only: the simulator's schedules stay as
+    # they are).  A validator in step never gets here: its commands take
+    # milliseconds and its queue is empty between them.
+    YIELD_AFTER_S = 0.05
 
     def __init__(self, syncer: Syncer, metrics=None,
                  fatal_handler=None, stages=None) -> None:
@@ -120,8 +126,23 @@ class CoreTaskDispatcher:
 
         measure_blocking = not is_simulated()
         turn = 0  # of the commands: which has its CPU read
+        held_from = None  # when this task last got the loop back
         while True:
+            if measure_blocking:
+                if self._queue.empty():
+                    held_from = None  # ``get`` suspends: the loop runs
+                elif held_from is not None and (
+                        monotonic() - held_from >= self.YIELD_AFTER_S):
+                    # ``Queue.get`` does not suspend while commands are
+                    # queued, so a backlog of them (a validator back on its
+                    # WAL taking in hundreds of rounds) would hold the loop
+                    # until it is empty: no handshake answered, no ping, no
+                    # scrape, no SIGTERM for seconds.  One turn, then on.
+                    await asyncio.sleep(0)
+                    held_from = None
             command, args, reply, internal = await self._queue.get()
+            if measure_blocking and held_from is None:
+                held_from = monotonic()
             if dequeued is not None:
                 dequeued.inc()
             try:

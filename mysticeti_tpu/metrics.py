@@ -908,6 +908,21 @@ class Metrics:
             "crash_recovery_total",
             "node boots that recovered state by replaying a non-empty WAL",
         )
+        self.committed_height = gauge(
+            "committed_height",
+            "height of the last commit this validator decided and logged "
+            "(absolute: a boot on a WAL starts at what it recovered, where "
+            "committed_leaders_total starts again at zero)",
+        )
+        self.wal_recovery = gauge(
+            "wal_recovery",
+            "what this boot recovered from its WAL, set once (absent on a "
+            "boot from genesis): blocks in the store's index, the highest "
+            "own round, the last committed height, bytes cut as a torn "
+            "tail, entries and bytes replayed after the checkpoint, the "
+            "checkpoint's height",
+            labels=("what",),
+        )
         self.chaos_faults_total = counter(
             "chaos_faults_total",
             "faults injected by the deterministic chaos engine",

@@ -1016,6 +1016,10 @@ class NetworkSyncer:
 
     # -- background tasks --
 
+    # A leader timeout that fires this much after it was due says that the
+    # process stood still, not that the leader did (``_leader_timeout_task``).
+    LATE_TIMER_S = 0.25
+
     async def _leader_timeout_task(self) -> None:
         """net_sync.rs:401-444: force a proposal if the round stalls.
 
@@ -1023,13 +1027,23 @@ class NetworkSyncer:
         liveness backstop, and an exception escaping this loop would
         silently remove the fleet's only stall-recovery mechanism."""
         timeout = self.parameters.leader_timeout_s
+        clock = asyncio.get_running_loop().time
         while True:
             waiter = self.signals.round_notify.subscribe()
             round_at_start = self.signals.current_round
+            armed = clock()
             try:
                 await asyncio.wait_for(waiter.wait(), timeout=timeout)
             except asyncio.TimeoutError:
                 if self.core.epoch_closed():
+                    continue
+                if clock() - armed > timeout + self.LATE_TIMER_S:
+                    # The timer fired late: this process was not running
+                    # (a stopped machine, a SIGSTOP, the loop held), so
+                    # what the leader sent meanwhile is still in its
+                    # sockets.  It is heard first: the wait starts again.
+                    self._record("leader-timeout-late", round=round_at_start,
+                                 late_s=round(clock() - armed - timeout, 3))
                     continue
                 log.debug(
                     "leader timeout at round %d: forcing proposal", round_at_start

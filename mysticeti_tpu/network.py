@@ -54,7 +54,7 @@ import os
 import random
 import sys
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Set, Tuple
 
 from .runtime import now as runtime_now
 from .serde import Reader, SerdeError, Writer
@@ -80,6 +80,10 @@ def mesh_legacy() -> bool:
     per connection setup, not cached — tests and the A/B harness flip it
     between runs in one process."""
     return os.environ.get("MYSTICETI_MESH_LEGACY", "") == "1"
+
+# A dial's connect, its hello and its ack each get this long.
+HANDSHAKE_TIMEOUT_S = 5.0
+
 
 def jittered_backoff(delay: float, rng: random.Random) -> float:
     """Uniform [0.5, 1.5)x jitter around an exponential-backoff delay.
@@ -1122,6 +1126,7 @@ class TcpNetwork:
         self._latency: Dict[int, float] = {}
         self._server: Optional[asyncio.AbstractServer] = None
         self._tasks: List[asyncio.Task] = []
+        self._inbound: Set[asyncio.Task] = set()  # accepted connections'
         self._stopped = False
 
     @classmethod
@@ -1142,8 +1147,14 @@ class TcpNetwork:
     # -- inbound --
 
     async def _handle_inbound(self, reader, writer) -> None:
+        # Known to ``stop``: the server starts this task, and nothing else
+        # would end it while the peer keeps its end open.
+        task = asyncio.current_task()
+        self._inbound.add(task)
+        task.add_done_callback(self._inbound.discard)
         try:
-            hello = await asyncio.wait_for(reader.readexactly(12), timeout=5.0)
+            hello = await asyncio.wait_for(
+                reader.readexactly(12), timeout=HANDSHAKE_TIMEOUT_S)
             magic = int.from_bytes(hello[:4], "little")
             peer = int.from_bytes(hello[4:], "little")
             if magic != HANDSHAKE_MAGIC or peer >= len(self.addresses):
@@ -1173,13 +1184,22 @@ class TcpNetwork:
         while not self._stopped:
             try:
                 host, port = self.addresses[peer]
-                reader, writer = await asyncio.open_connection(host, port)
+                # Bounded like the handshake below: a SYN sent while the
+                # peer's process is being torn down (SIGKILL, a restart) can
+                # go unanswered - neither accepted nor refused - and an
+                # unbounded connect then outlives the peer's absence: this
+                # worker would never dial the restarted peer.
+                reader, writer = await asyncio.wait_for(
+                    asyncio.open_connection(host, port),
+                    timeout=HANDSHAKE_TIMEOUT_S,
+                )
                 writer.write(
                     HANDSHAKE_MAGIC.to_bytes(4, "little")
                     + self.authority.to_bytes(8, "little")
                 )
                 await writer.drain()
-                ack = await asyncio.wait_for(_read_frame(reader), timeout=5.0)
+                ack = await asyncio.wait_for(
+                    _read_frame(reader), timeout=HANDSHAKE_TIMEOUT_S)
                 if (
                     int.from_bytes(ack[:4], "little") != HANDSHAKE_MAGIC
                     or int.from_bytes(ack[4:], "little") != peer
@@ -1366,8 +1386,15 @@ class TcpNetwork:
             writer.close()
 
     async def stop(self) -> None:
+        """The dial workers and the accepted connections' tasks are
+        cancelled, then the server closed.  ``Server.wait_closed`` waits for
+        every accepted connection to close (Python 3.12), and an accepted
+        connection's task ends by itself only when the PEER closes: a
+        validator stopped alone - a restart, an upgrade - while its peers
+        go on, or one whose reader is parked on a full queue, would wait
+        here for ever."""
         self._stopped = True
-        for t in self._tasks:
+        for t in self._tasks + list(self._inbound):
             t.cancel()
         if self._server is not None:
             self._server.close()

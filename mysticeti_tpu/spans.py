@@ -116,6 +116,11 @@ STAGES = (
     "checkpoint",
     "exec_fold",
     "scrape",
+    # A boot that recovered its state from the WAL (validator.py, once a
+    # boot; always on through the node's StageClock): the log opened, the
+    # newest checkpoint loaded, what follows it replayed and a torn tail
+    # cut (storage.open_store), wall and the thread's CPU.
+    "wal_replay",
     # The finality tracker's samples (finality.py), as they feed
     # ``mysticeti_e2e_finality_seconds{phase}``: submit -> admitted,
     # admitted -> proposed, proposed -> commit decision.
@@ -527,24 +532,29 @@ BLOCK_PATH_STAGES = ("receive", "verify", "dag_add")
 # the WAL's writer and syncer threads (wal.py, storage.py), the checkpoint
 # (storage.py), the execution fold (execution.py, a commit), the metrics
 # endpoint (metrics.py, a request); the finality tracker's phases
-# (finality.py, a sampled transaction).  What measures the host (the
-# middle nine) is off under the simulator; the rest is on the runtime clock.
+# (finality.py, a sampled transaction); the recovery of a boot that found a
+# WAL (validator.py, once).  What measures the host (the middle nine and
+# the last) is off under the simulator; the rest is on the runtime clock.
 NODE_STAGES = BLOCK_PATH_STAGES + (
     "leader_wait", "admit_verify", "mesh_hold",
     "core_command", "loop_lag", "gc", "executor_wait",
     "wal_write", "wal_sync", "checkpoint", "exec_fold", "scrape",
     "phase_admission", "phase_proposal", "phase_commit",
+    "wal_replay",
 )
 # What a validator's clock stamps once a second (``Validator._read_stamps``
 # reads them, cumulative, in this order): the threshold clock's round,
 # leaders committed, own proposals, blocks received, transactions admitted
 # and shed (all, and by ``lane_cap``), leader timeouts, the requests it
-# sent to the verifier service, and the execution transactions it folded as
+# sent to the verifier service, the execution transactions it folded as
 # ``bad_nonce`` (a cascade of those beside ``shed`` is an account's sequence
-# that an episode broke).
+# that an episode broke), and the highest round of any block it holds
+# (beside ``rounds`` and ``proposals``: a validator that is catching up
+# holds its peers' blocks rounds ahead of its own clock and proposals).
+# ``leaders`` is the committed height (``StorageLifecycle.commit_height``).
 NODE_STAMPS = ("rounds", "leaders", "proposals", "blocks_received",
                "tx_admitted", "shed", "shed_lane_cap", "leader_timeouts",
-               "verify_requests", "exec_bad_nonce")
+               "verify_requests", "exec_bad_nonce", "frontier_round")
 # Stages in which a request waits (for a launch, the device, the loop, the
 # GIL): wall time only, no CPU clock and no profiler annotation —
 # the runtime's own events mark them in a trace already.

@@ -85,6 +85,15 @@ class CoreRecoveredState:
     replay_start: WalPosition = 0
     replayed_bytes: int = 0
     checkpoint_height: int = 0
+    # What the boot reports of a recovery (``Validator._report_recovery``):
+    # entries replayed, blocks the store's index holds after it (the
+    # checkpoint's and the replayed), bytes cut as a torn tail, and what
+    # opening the store cost (``storage.open_store``: wall and CPU seconds).
+    replayed_entries: int = 0
+    recovered_blocks: int = 0
+    torn_bytes: int = 0
+    replay_wall_s: float = 0.0
+    replay_cpu_s: float = 0.0
     # Reconfiguration (reconfig.py): the serialized epoch chain from the
     # recovering checkpoint/snapshot, plus the commits replayed AFTER that
     # baseline — Core re-scans them so a crash between a boundary commit and
@@ -133,6 +142,8 @@ class RecoveredStateBuilder:
         self._checkpoint_height = 0
         self._replay_start: WalPosition = 0
         self._replayed_bytes = 0
+        self._replayed_entries = 0
+        self._torn_bytes = 0
         self._epoch_chain = b""
         self._exec_state = b""
 
@@ -172,8 +183,11 @@ class RecoveredStateBuilder:
         if manifest.exec_state:
             self._exec_state = manifest.exec_state
 
-    def note_replayed(self, replayed_bytes: int) -> None:
+    def note_replayed(self, replayed_bytes: int, entries: int,
+                      torn_bytes: int) -> None:
         self._replayed_bytes = replayed_bytes
+        self._replayed_entries = entries
+        self._torn_bytes = torn_bytes
 
     def note_retired_floor(self, floor: int) -> None:
         """Blocks below ``floor`` are known-gone (their segments were GC'd
@@ -234,6 +248,9 @@ class RecoveredStateBuilder:
             replay_start=self._replay_start,
             replayed_bytes=self._replayed_bytes,
             checkpoint_height=self._checkpoint_height,
+            replayed_entries=self._replayed_entries,
+            recovered_blocks=block_store.block_count(),
+            torn_bytes=self._torn_bytes,
             epoch_chain=self._epoch_chain,
             recovered_commits=list(self._committed_sub_dags),
             exec_state=self._exec_state,

@@ -49,6 +49,7 @@ from __future__ import annotations
 import json
 import os
 import threading
+import time
 import zlib
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Set, Tuple
@@ -1033,6 +1034,7 @@ class StorageLifecycle:
                     self._kept_checkpoints[-1][0]
                 )
             metrics.wal_segments.set(self._segment_count())
+            metrics.committed_height.set(self.commit_height)
 
     # -- helpers --
 
@@ -1060,6 +1062,8 @@ class StorageLifecycle:
             )
             if self._track_committed:
                 self._committed.update(commit.sub_dag)
+        if commit_data and self.metrics is not None:
+            self.metrics.committed_height.set(self.commit_height)
 
     # -- checkpoints --
 
@@ -1199,6 +1203,8 @@ class StorageLifecycle:
         """Adopt a remote commit baseline (the caller has already persisted
         the manifest as a WAL entry so a crash re-adopts it on replay)."""
         self.commit_height = manifest.commit_height
+        if self.metrics is not None:
+            self.metrics.committed_height.set(self.commit_height)
         self.last_committed_leader = manifest.last_committed_leader
         self.chain_digest = manifest.chain_digest
         floor = max(self.retired_round, manifest.gc_round)
@@ -1224,6 +1230,7 @@ def open_store(authority, wal_path, committee, parameters=None, metrics=None):
     diagnoses the same states offline)."""
     from .block_store import BlockStore
 
+    began, cpu_began = time.monotonic(), time.thread_time()
     params = parameters.storage if parameters is not None else StorageParameters()
     wal_writer, wal_reader = open_wal(wal_path, params)
     checkpoint = None
@@ -1241,6 +1248,10 @@ def open_store(authority, wal_path, committee, parameters=None, metrics=None):
         authority, wal_reader, wal_writer, committee, metrics,
         checkpoint=checkpoint,
     )
+    # What the recovery cost, for the boot's report: the WAL opened, the
+    # checkpoint loaded, the tail replayed and a torn end cut.
+    recovered.replay_wall_s = time.monotonic() - began
+    recovered.replay_cpu_s = time.thread_time() - cpu_began
     directory = wal_path if isinstance(wal_writer, SegmentedWalWriter) else None
     lifecycle = StorageLifecycle(
         directory, params, wal_writer, recovered, observer_recovered, metrics,

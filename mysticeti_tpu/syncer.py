@@ -112,7 +112,11 @@ class Syncer:
                     # counted per authority so fleet health can name the
                     # validator whose slots keep timing out.  The boot-time
                     # genesis kick reaches here too and indicts nobody.
-                    for leader in self.core.leaders(max(1, round_ - 1)):
+                    # ``round_`` is the clock's round + 1 (the timeout task
+                    # asks for a proposal above the round it was stuck in),
+                    # and the gate of the clock's round waits for the
+                    # leader of the round BELOW it.
+                    for leader in self.core.leaders(max(1, round_ - 2)):
                         channel = (
                             self.metrics.mysticeti_health_leader_timeout_total
                         )

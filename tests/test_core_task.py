@@ -161,3 +161,39 @@ def test_clean_stop_does_not_fire_fatal_handler():
         assert fired == []
 
     asyncio.run(scenario())
+
+
+@pytest.mark.parametrize("slow_ms, turns", [(30, 4), (1, 0)])
+def test_a_backlog_of_commands_gives_the_loop_a_turn(slow_ms, turns):
+    """``Queue.get`` does not suspend while commands are queued: twelve
+    commands of 30 ms queued at once would hold the loop for 360 ms, and a
+    validator taking in hundreds of rounds after a restart answers no
+    handshake and no SIGTERM meanwhile.  Once the owner has held the loop
+    for ``YIELD_AFTER_S`` with more queued it gives the loop one turn; a
+    validator in step (commands of a millisecond) never does."""
+    import time
+
+    async def scenario():
+        d = _dispatcher()
+        ticks, done = [], []
+
+        async def ticker():
+            while len(done) < 12:
+                ticks.append(len(done))
+                await asyncio.sleep(0)
+
+        for _ in range(12):
+            await d._queue.put(
+                (lambda: (time.sleep(slow_ms / 1e3), done.append(1)),
+                 (), None, False))
+        other = asyncio.ensure_future(ticker())
+        await other
+        d.stop()
+        return ticks
+
+    ticks = asyncio.run(scenario())
+    # The other task ran while the backlog was being worked off - at
+    # several distinct depths of it - or only before and after.
+    assert len(set(ticks) - {0, 12}) >= turns
+    if not turns:
+        assert set(ticks) <= {0, 12}
