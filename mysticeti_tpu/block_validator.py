@@ -400,6 +400,20 @@ class TpuSignatureVerifier(SignatureVerifier):
 
         return sum(mesh_lanes(mesh, bucket) for _, _, bucket in iter_buckets(n))
 
+    def indexed_keys(self, index):
+        """The key column of signatures whose signers are the rows ``index``
+        ((n,) unsigned, a VERIFY frame's own) of this verifier's committee
+        table, for ``verify_signatures`` / ``verify_signatures_async``: the
+        index goes into the launch as it is, and an index the table does
+        not hold is a rejected lane (``ops.ed25519.IndexedKeys``).  None
+        without a committee: the caller passes the keys themselves."""
+        return None if self._table is None else self._table.keys_at(index)
+
+    def road_counts(self):
+        """(launches whose keys came as ``indexed_keys``, chunks that went
+        into the keyed-tile grouping) so far: ``KeyTable.road_counts``."""
+        return (0, 0) if self._table is None else self._table.road_counts()
+
     def verify_signatures_async(self, public_keys, digests, signatures):
         """True async dispatch: pack on the calling (host) thread, submit
         every bucket chunk through JAX's async dispatch, return the device
@@ -409,6 +423,8 @@ class TpuSignatureVerifier(SignatureVerifier):
         from .ops import ed25519
 
         mesh = self._resolve_mesh()
+        if mesh is not None and isinstance(public_keys, ed25519.IndexedKeys):
+            public_keys = public_keys.rows()  # the mesh path searches them
         # The fused sharded kernel requires 32-byte messages (block digests);
         # other lengths fall back to the single-device host-hash path so the
         # result never depends on the device count.
@@ -433,10 +449,14 @@ class TpuSignatureVerifier(SignatureVerifier):
     def verify_signatures(self, public_keys, digests, signatures):
         """The three columns are sequences of bytes objects or (n, width)
         uint8 arrays (the verifier service's: ``ops.ed25519`` packs either
-        form into the same blob); the verdicts are Python bools."""
-        return self.verify_signatures_async(
+        form into the same blob).  The verdicts come back in the form the
+        signatures came in: a list of Python bools for a sequence, the
+        fetched (n,) bool array for rows of an array — the service sends
+        its bytes as they are, and nothing walks them."""
+        oks = self.verify_signatures_async(
             public_keys, digests, signatures
-        ).result().tolist()
+        ).result()
+        return oks if getattr(signatures, "ndim", 0) == 2 else oks.tolist()
 
 
 def _update_ema(current: float, sample: float, outlier_s: float) -> float:

@@ -23,7 +23,7 @@ import hashlib
 import os
 import threading
 import time
-from typing import Optional, Sequence, Tuple
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -494,12 +494,18 @@ def pack_blob_indexed(
     ``KeyTable.indices_for``) are masked host_ok=False here — never silently
     verified against some other table row.
     """
-    idx = np.asarray(indices, np.int64)
-    ok = idx >= 0
-    if num_keys is not None:
-        ok &= idx < num_keys
+    idx = np.asarray(indices)
+    if idx.dtype.kind == "u":
+        # The wire's own index (``IndexedKeys``): nothing lies below zero.
+        ok = None if num_keys is None else idx < num_keys
+    else:
+        idx = idx.astype(np.int64, copy=False)
+        ok = idx >= 0
+        if num_keys is not None:
+            ok &= idx < num_keys
+        idx = np.maximum(idx, 0)
     if host_ok is not None:
-        ok &= np.asarray(host_ok, bool)
+        ok = _host_ok(ok, np.asarray(host_ok, bool))
     sig_arr, sig_ok = _pack_fixed_rows(signatures, 64)
     msg_arr, msg_ok = _pack_fixed_rows(messages, 32)
     _note_pack(messages, signatures)
@@ -507,7 +513,7 @@ def pack_blob_indexed(
     blob[:, :8] = sig_arr[:, :32].view(">u4")
     blob[:, 8:16] = msg_arr.view(">u4")
     blob[:, 16:24] = sig_arr[:, 32:].view("<u4")
-    blob[:, 24] = np.maximum(idx, 0)
+    blob[:, 24] = idx
     blob[:, 25] = _host_ok(ok, sig_ok, msg_ok)
     return blob
 
@@ -673,6 +679,70 @@ def group_blob_for_tiles(
     return grouped, tile_keys, positions.astype(np.int32)
 
 
+class DispatchPlan(NamedTuple):
+    """Which form of the kernels a table's launches take: the backend
+    (``_backend``), whether a chunk that one key a tile can hold is given
+    to the keyed-tile kernel (the Pallas backend, unless MYSTICETI_KEYED=0),
+    and the Pallas tile and interpreter flag (None off that backend).
+    Resolved once a table, so that a launch reads neither ``os.environ``
+    nor ``jax.default_backend()``."""
+
+    backend: str
+    keyed: bool
+    tile: Optional[int]
+    interpret: Optional[bool]
+
+
+def resolve_plan() -> DispatchPlan:
+    backend = _backend()
+    if backend != "pallas":
+        return DispatchPlan(backend, False, None, None)
+    from . import ed25519_pallas as PK
+
+    return DispatchPlan(
+        backend,
+        os.environ.get("MYSTICETI_KEYED") != "0",
+        PK.default_tile(),
+        PK.interpret_mode(),
+    )
+
+
+class IndexedKeys:
+    """A batch's public keys as rows of a ``KeyTable``: the (n,) indices a
+    VERIFY frame carried, unsigned, as they came off the wire.  An index
+    the table does not hold stands for no key: that lane is rejected
+    (``pack_blob_indexed``), and read as bytes it is the all-zero key.
+
+    ``dispatch_batch_table`` on the same table takes the index as it is —
+    no key is gathered and none is searched for.  To everything else it is
+    a sequence of 32-byte keys: ``len``, slices (an ``IndexedKeys`` again),
+    items and iteration (bytes), ``rows()`` (one gather)."""
+
+    __slots__ = ("table", "index")
+
+    def __init__(self, table: "KeyTable", index: np.ndarray) -> None:
+        self.table = table
+        self.index = index
+
+    def __len__(self) -> int:
+        return len(self.index)
+
+    def __getitem__(self, at):
+        if isinstance(at, slice):
+            return IndexedKeys(self.table, self.index[at])
+        rows = self.table.key_rows
+        return rows[min(int(self.index[at]), len(rows) - 1)].tobytes()
+
+    def __iter__(self):
+        return iter([row.tobytes() for row in self.rows()])
+
+    def rows(self) -> np.ndarray:
+        """(n, 32) uint8: each lane's key, all-zero where its index is out
+        of range."""
+        table = self.table.key_rows
+        return table[np.minimum(self.index, len(table) - 1)]
+
+
 class KeyTable:
     """A committee's keys resident on device: upload once, verify by index.
 
@@ -680,7 +750,13 @@ class KeyTable:
     (callers mask them out or route them through the generic path).
 
     ``neg_combs`` lazily builds the per-key negated comb tables for the
-    keyed-tile Pallas kernel (see build_neg_key_combs)."""
+    keyed-tile Pallas kernel (see build_neg_key_combs).
+
+    ``plan`` is how this table's launches are dispatched, resolved here,
+    once; ``roads`` counts what its launches did on the way
+    (``road_counts``): ``direct`` — the keys came as the wire's indices
+    (``IndexedKeys``) and went into the blob as they were —, and
+    ``keyed_tried`` — a chunk went into the keyed-tile grouping."""
 
     def __init__(self, public_keys: Sequence[bytes]) -> None:
         if not public_keys:
@@ -700,9 +776,30 @@ class KeyTable:
             self._index.values(), np.int64, count=len(order)
         )[order]
         self._neg_combs: Optional[Tuple[jnp.ndarray, np.ndarray]] = None
+        # The keys by row, and below them the all-zero key that an index
+        # out of range reads as (``IndexedKeys.rows``).
+        self.key_rows = np.zeros((len(self._keys) + 1, 32), np.uint8)
+        self.key_rows[:-1] = np.frombuffer(
+            b"".join(self._keys), np.uint8).reshape(-1, 32)
+        self._size = len(self._keys)
+        self.plan = resolve_plan()
+        self.roads = {"direct": 0, "keyed_tried": 0}
 
     def __len__(self) -> int:
-        return self.words.shape[0]
+        return self._size
+
+    def keys_at(self, index: np.ndarray) -> IndexedKeys:
+        """The key column of a batch whose signers are rows ``index``."""
+        return IndexedKeys(self, index)
+
+    def road_counts(self) -> Tuple[int, int]:
+        """(``direct``, ``keyed_tried``) launches so far."""
+        with _dispatch_count_lock:
+            return self.roads["direct"], self.roads["keyed_tried"]
+
+    def _took(self, road: str) -> None:
+        with _dispatch_count_lock:  # the service launches from three threads
+            self.roads[road] += 1
 
     def indices_for(self, public_keys: Sequence[bytes]) -> np.ndarray:
         """The table row of each key, -1 where the table holds no such key:
@@ -733,14 +830,32 @@ class KeyTable:
         return self._neg_combs
 
 
-def _dispatch_indexed(blob, table) -> jnp.ndarray:
-    backend = _backend()
-    _note_kernel("indexed", blob.shape[0], backend)
-    if backend == "pallas":
+def _dispatch_indexed(blob, table, plan: Optional[DispatchPlan] = None) -> jnp.ndarray:
+    """One launch of the generic ladder over a bucket-shaped indexed blob;
+    ``table`` is the key table's words.  ``plan``: the table's, where the
+    caller has it (None resolves one here)."""
+    if plan is None:
+        plan = resolve_plan()
+    _note_kernel("indexed", blob.shape[0], plan.backend)
+    if plan.backend == "pallas":
         from . import ed25519_pallas as PK
 
-        return PK.verify_fused_indexed_blob_pallas(blob, table)
+        return PK.verify_fused_indexed_blob_pallas(
+            blob, table, tile=plan.tile, interpret=plan.interpret
+        )
     return _stored_indexed_kernel(blob, table)
+
+
+def _one_key_a_tile(index: np.ndarray, num_keys: int, tile: int, bucket: int) -> bool:
+    """Whether the keyed-tile grouping of a chunk can fit ``bucket``, from
+    its index column alone: ``sum(ceil(count_k / tile)) <= bucket / tile``.
+    Where the bucket is one tile that is one signer, and costs one look.
+    (``group_blob_for_tiles`` still has the last word: it parks rejected
+    lanes under key 0, which this does not see.)"""
+    if bucket <= tile:
+        return bool(index.min() == index.max())
+    counts = np.bincount(np.minimum(index, num_keys - 1), minlength=num_keys)
+    return int((-(-counts // tile)).sum()) <= bucket // tile
 
 
 def _dispatch_indexed_keyed(chunk: np.ndarray, table: "KeyTable", bucket: int):
@@ -749,7 +864,8 @@ def _dispatch_indexed_keyed(chunk: np.ndarray, table: "KeyTable", bucket: int):
     callers fall back to the generic ladder."""
     from . import ed25519_pallas as PK
 
-    tile = min(PK.default_tile(), bucket)
+    plan = table.plan  # without a tile off the Pallas backend (a test's call)
+    tile = min(plan.tile or PK.default_tile(), bucket)
     acomb, valid = table.neg_combs()
     spans.request_stage("service_pack")  # tile grouping
     if not valid.all():
@@ -771,7 +887,8 @@ def _dispatch_indexed_keyed(chunk: np.ndarray, table: "KeyTable", bucket: int):
     _note_transfer("to_device", grouped.nbytes + tile_keys.nbytes)
     _note_kernel("keyed", bucket, "pallas")
     handle = PK.verify_keyed_blob(
-        grouped, table.words, acomb, tile_keys, None, tile=tile
+        grouped, table.words, acomb, tile_keys, None, tile=tile,
+        interpret=plan.interpret,
     )
     return handle, positions
 
@@ -782,21 +899,29 @@ def dispatch_indexed_chunks(blob: np.ndarray, table: "KeyTable"):
     chunks, ``(count, handle, positions)`` for keyed-tile chunks whose
     results come back in GROUPED order (fetch_handles un-permutes on host).
 
-    On the Pallas backend each chunk takes the keyed-tile kernel when its
-    per-key grouping fits the bucket (the common case: committee authorship
-    is roughly uniform), falling back to the generic ladder otherwise.
-    MYSTICETI_KEYED=0 disables the keyed path."""
-    keyed = _backend() == "pallas" and os.environ.get("MYSTICETI_KEYED") != "0"
+    On the Pallas backend a chunk takes the keyed-tile kernel when its
+    per-key grouping fits the bucket, and the generic ladder otherwise.
+    Whether it can fit is read off the index column first
+    (``_one_key_a_tile``), so a chunk of several signers in a one-tile
+    bucket — every launch of a draining queue — never enters the grouping.
+    MYSTICETI_KEYED=0 disables the keyed path (``table.plan``: both
+    variables are read once a table)."""
+    plan = table.plan
     handles = []
     for start, count, b in iter_buckets(blob.shape[0]):
         chunk = blob[start : start + count]
-        hp = _dispatch_indexed_keyed(chunk, table, b) if keyed else None
+        hp = None
+        if plan.keyed and _one_key_a_tile(
+            chunk[:, 24], len(table), min(plan.tile, b), b
+        ):
+            table._took("keyed_tried")
+            hp = _dispatch_indexed_keyed(chunk, table, b)
         if hp is None:
             spans.request_stage("service_pack")
             padded = _pad_to(chunk, b)
             spans.request_stage("service_launch")
             _note_transfer("to_device", padded.nbytes)
-            h = _dispatch_indexed(padded, table.words)
+            h = _dispatch_indexed(padded, table.words, plan)
             handles.append((count, h))
         else:
             h, positions = hp
@@ -826,6 +951,23 @@ class VerifyDispatch:
         return fetch_handles(self._entries)
 
 
+def _fetch_early(handle) -> None:
+    """Ask for a launch's verdicts on the host as the launch is made, so
+    that the copy follows the program without waiting for the fetching
+    thread's turn (``np.asarray`` then finds it under way or done)."""
+    handle.copy_to_host_async()
+
+
+def _launched(handles) -> VerifyDispatch:
+    """The handle over a batch's launches.  Where that is one launch —
+    every launch of the verifier service — its result is asked for at
+    once; several are joined on the device first (``fetch_handles``), so
+    their own copies would be wasted."""
+    if len(handles) == 1:
+        _fetch_early(handles[0][1])
+    return VerifyDispatch(handles)
+
+
 def dispatch_batch_table(
     table: "KeyTable",
     public_keys: Sequence[bytes],
@@ -843,18 +985,28 @@ def dispatch_batch_table(
     n = len(signatures)
     if n == 0:
         return VerifyDispatch([])
-    if not _all_digests(messages):
+    digests = _all_digests(messages)
+    by_index = isinstance(public_keys, IndexedKeys)
+    if by_index and not (digests and public_keys.table is table):
+        by_index, public_keys = False, public_keys.rows()
+    if not digests:
         return dispatch_batch(public_keys, messages, signatures)
     # Inside the verifier service the request is in service_pack from here
     # and in service_launch around each jitted call (spans.request_stage:
     # a stage lasts until the next is named; outside a request it is a
     # no-op).
     spans.request_stage("service_pack")
-    idx = table.indices_for(public_keys)
-    if (idx < 0).any():
-        return dispatch_batch(public_keys, messages, signatures)
+    if by_index:
+        # The index stays an index: a lane whose index the table does not
+        # hold is rejected in its place, not the launch re-routed.
+        idx = public_keys.index
+        table._took("direct")
+    else:
+        idx = table.indices_for(public_keys)
+        if (idx < 0).any():
+            return dispatch_batch(public_keys, messages, signatures)
     blob = pack_blob_indexed(idx, messages, signatures, num_keys=len(table))
-    return VerifyDispatch(dispatch_indexed_chunks(blob, table))
+    return _launched(dispatch_indexed_chunks(blob, table))
 
 
 def verify_batch_table(
@@ -1244,7 +1396,7 @@ def dispatch_batch(
         # handle forces all results with a single combined fetch, so device
         # work and transfers overlap across chunks and only one round-trip
         # is paid at the end.
-        return VerifyDispatch(dispatch_blob_chunks(blob))
+        return _launched(dispatch_blob_chunks(blob))
     arrays = pack_batch(public_keys, messages, signatures)
     handles = []
     for start, count, b in iter_buckets(n):

@@ -107,6 +107,7 @@ _IDX_REC = 2 + 32 + 64  # u16 idx | digest | sig
 _RAW_REC = 32 + 32 + 64
 _HEADER = struct.Struct("<IB")  # u32 payload_len | u8 type
 _REQUEST = struct.Struct("<II")  # u32 req_id | u32 n
+_REQ_ID = struct.Struct("<I")
 
 ENV_SOCKET = "MYSTICETI_VERIFIER_SOCKET"
 
@@ -122,26 +123,33 @@ class ServiceCounts:
     carried) — plain sums of the one thread that reads requests and writes
     replies, which also stamps; and ``left``: the launches that left, by
     why (LEFT), counted where the dispatcher threads decide it, under the
-    service's condition."""
+    service's condition; and what the backend counts of its own launches
+    (``roads``: its ``road_counts``, once it is warm; a backend that counts
+    none reads zero): ``direct``, the launches of VERIFY frames alone whose
+    key indices went from the wire into the blob as they were, and
+    ``keyed_tried``, the launches that went into the keyed-tile kernel's
+    grouping."""
 
     # Why a launch left when it did (``VerifierServer._take``): ``left``
     # counts them in this order.
     LEFT = ("alone", "full", "drained", "expired")
     STAMPS = ("requests", "signatures", "launches",
-              *("left_" + why for why in LEFT), "reads", "writes")
+              *("left_" + why for why in LEFT), "direct", "keyed_tried",
+              "reads", "writes")
 
-    __slots__ = ("requests", "signatures", "launches", "left", "reads",
-                 "writes")
+    __slots__ = ("requests", "signatures", "launches", "left", "roads",
+                 "reads", "writes")
 
     def __init__(self) -> None:
         self.requests = self.signatures = self.launches = 0
         self.left = [0] * len(self.LEFT)
+        self.roads = lambda: (0, 0)
         self.reads = self.writes = 0
 
     def read(self) -> tuple:
         """STAMPS now."""
         return (self.requests, self.signatures, self.launches, *self.left,
-                self.reads, self.writes)
+                *self.roads(), self.reads, self.writes)
 
 
 # Why a launch left, as ``ServiceCounts.left`` is indexed.
@@ -233,16 +241,18 @@ class _Pending:
     that answers it into ``slot``.  ``handed``: when it was handed over,
     None for a request that is not clocked; ``alone``: it found a launch
     slot asleep while the service held at most one request more than it
-    has slots, so it is launched by itself."""
+    has slots, so it is launched by itself; ``wire_id``: the request id's
+    four bytes as the frame carried them, which its reply carries back."""
 
-    __slots__ = ("type_", "req_id", "n", "body", "conn_label", "handed",
-                 "slot", "piece", "alone")
+    __slots__ = ("type_", "req_id", "wire_id", "n", "body", "conn_label",
+                 "handed", "slot", "piece", "alone")
 
     def __init__(self, type_, req_id, n, body, conn_label, handed,
-                 slot, piece=0) -> None:
+                 slot, piece=0, wire_id=None) -> None:
         self.alone = False
         self.type_ = type_
         self.req_id = req_id
+        self.wire_id = _REQ_ID.pack(req_id) if wire_id is None else wire_id
         self.n = n
         self.body = body
         self.conn_label = conn_label
@@ -424,10 +434,12 @@ class _Connection(asyncio.Protocol):
                     # transactions — compiles nothing.
                     cap = server._launch_cap
                     body = payload[8:]
+                    wire_id = bytes(payload[:4])
                     if cap is None or n <= cap:
                         slot.waiting = 1
                         items.append(_Pending(
-                            type_, req_id, n, body, label, handed, slot))
+                            type_, req_id, n, body, label, handed, slot,
+                            0, wire_id))
                         continue
                     slot.waiting = -(-n // cap)
                     slot.parts = [None] * slot.waiting
@@ -436,7 +448,7 @@ class _Connection(asyncio.Protocol):
                             type_, req_id, min(cap, n - piece * cap),
                             body[piece * cap * rec: (piece + 1) * cap * rec],
                             label, handed if piece == 0 else None, slot,
-                            piece))
+                            piece, wire_id))
                 elif type_ == T_HELLO:
                     n_keys = (struct.unpack_from("<H", payload)[0]
                               if length >= 2 else -1)
@@ -731,6 +743,13 @@ class VerifierServer:
         self._launch_cap: Optional[int] = None
         # (the committee's key list, its rows as an array): ``_key_rows``.
         self._key_rows_cache: Optional[tuple] = None
+        # Signatures a launch held -> the lanes the backend padded them to
+        # (``padded_batch``, asked once a size), and the counter of the
+        # difference: ``_observe``.
+        self._lanes: dict = {}
+        self._wasted = (
+            None if metrics is None
+            else metrics.verify_padding_wasted_total.labels("service"))
         # HELLO and warm-up have a thread of their own: a warm-up takes
         # minutes, and HELLOs behind it wait for it anyway.
         self._hello_pool = ThreadPoolExecutor(
@@ -754,6 +773,10 @@ class VerifierServer:
     # -- backend lifecycle --
 
     def _ensure_backend(self, keys: List[bytes]):
+        # A launch of a warm service asks with the service's own key list
+        # and takes no lock on its way.
+        if self._warmed.is_set() and (not keys or keys is self._keys):
+            return self._backend
         # The whole init+warmup runs under the lock: concurrent HELLOs from a
         # booting fleet must not race two warmups through the JAX tracer —
         # the losers just block here until the first one finishes (which is
@@ -769,8 +792,8 @@ class VerifierServer:
                     # rebuild it around the real committee's key table.
                     self._keys = keys
                     if self._backend is not None and self._owns_backend:
-                        self._backend = None
                         self._warmed.clear()
+                        self._backend = None
                 elif self._keys != keys:
                     raise ValueError(
                         "committee mismatch: this verifier service was warmed "
@@ -806,6 +829,8 @@ class VerifierServer:
                     self._fail(exc)
                     raise
                 self._launch_cap = self._warmed_signatures()
+                self.counts.roads = getattr(
+                    self._backend, "road_counts", self.counts.roads)
                 self._warmed.set()
             return self._backend
 
@@ -849,25 +874,28 @@ class VerifierServer:
         os.replace(path + ".tmp", path)
 
     def _calibrate(self) -> None:
-        """Time the warmed backend once: a 1-signature dispatch (fixed cost)
-        and a 256-signature dispatch (marginal cost), on the deployed
-        committee-indexed path.  Shared with every client via HELLO_OK."""
+        """Time the warmed backend once: a 1-signature launch (fixed cost)
+        and a 256-signature launch (marginal cost), down the road a launch
+        of VERIFY frames takes (``_wire_rows``, then the backend's call).
+        Shared with every client via HELLO_OK; ``_hold_s`` reads it."""
         keys = self._keys or []
         if not keys:
             return
         import numpy as np
 
-        # As a launch hands them over (``_wire_rows``): rows of arrays.
         n = 256
-        pks = self._key_rows(keys)[np.arange(n) % len(keys)]
-        digests, sigs = np.zeros((n, 32), np.uint8), np.zeros((n, 64), np.uint8)
-        t0 = time.monotonic()
-        self._backend.verify_signatures(pks[:1], digests[:1], sigs[:1])
-        fixed = time.monotonic() - t0
-        t0 = time.monotonic()
-        self._backend.verify_signatures(pks, digests, sigs)
-        batch_t = time.monotonic() - t0
-        self._calibration = (fixed, max(0.0, (batch_t - fixed) / n))
+        records = np.zeros((n, _IDX_REC), np.uint8)
+        records[:, 0] = np.arange(n) % len(keys)  # under 256: the low byte
+        body = memoryview(records.tobytes())
+
+        def launch(k: int) -> float:
+            started = time.monotonic()
+            self._backend.verify_signatures(*self._wire_rows([_Pending(
+                T_VERIFY, 0, k, body[:k * _IDX_REC], "", None, None)]))
+            return time.monotonic() - started
+
+        fixed = launch(1)
+        self._calibration = (fixed, max(0.0, (launch(n) - fixed) / n))
         log.info(
             "verifier service calibrated: %.1f ms fixed + %.1f µs/sig",
             1e3 * self._calibration[0], 1e6 * self._calibration[1],
@@ -1134,7 +1162,25 @@ class VerifierServer:
                 self._resolve, batch, replies, error, built)
         except RuntimeError:
             pass  # the loop closed under a launch that stop() did not await
+        if error is None and self.metrics is not None:
+            self._observe(batch)
         return taken
+
+    def _observe(self, batch: List[_Pending]) -> None:
+        """The shape of the launch just made, for the scrape: after its
+        replies are on their way, so that it costs no request anything.
+        (The service owns the device, so it, not the jax-free clients, is
+        where launch shape and padding waste are measurable.)"""
+        metrics = self.metrics
+        total = sum(item.n for item in batch)
+        metrics.verify_dispatch_batch_size.observe(total)
+        metrics.verifier_service_coalesced_requests.observe(len(batch))
+        lanes = self._lanes.get(total)
+        if lanes is None:
+            padder = getattr(self._backend, "padded_batch", None)
+            lanes = self._lanes[total] = (
+                total if padder is None else padder(total))
+        self._wasted.inc(max(0, lanes - total))
 
     def _resolve(self, batch: List[_Pending], replies, error, built) -> None:
         """On the loop: a launch is done.  Its requests' slots are filled
@@ -1188,28 +1234,38 @@ class VerifierServer:
 
     def _wire_rows(self, batch: List[_Pending]):
         """The public keys, digests and signatures of every request of
-        ``batch``, in order, as three uint8 arrays of one row a signature:
-        column slices of the wire records, which nothing walks.  Requests
-        of one frame type that follow each other share one record array; a
-        VERIFY record's 2-byte index becomes its key's row with one
-        gather."""
+        ``batch``, in order, as three columns of one row a signature:
+        slices of the wire records, which nothing walks.  Requests of one
+        frame type that follow each other share one record array.
+
+        A launch of VERIFY frames alone keeps its keys as the 2-byte
+        indices the records carry, where the backend takes them so
+        (``indexed_keys``: the JAX backend writes them into its blob as
+        they are; an index out of range is a rejected lane).  In every
+        other launch — a RAW frame among them, a backend that wants keys —
+        a VERIFY record's index becomes its key's row with one gather."""
         import numpy as np
 
+        by_index = getattr(self._backend, "indexed_keys", None)
         columns: Tuple[list, list, list] = ([], [], [])
-        at = 0
-        while at < len(batch):
+        at, riders = 0, len(batch)
+        while at < riders:
             type_ = batch[at].type_
-            end = at + 1
-            while end < len(batch) and batch[end].type_ == type_:
+            start, end = at, at + 1
+            while end < riders and batch[end].type_ == type_:
                 end += 1
             body = (batch[at].body if end == at + 1
                     else b"".join([item.body for item in batch[at:end]]))
             at = end
             if type_ == T_VERIFY:
                 rows = np.frombuffer(body, np.uint8).reshape(-1, _IDX_REC)
-                table = self._key_rows(self._keys or [])
                 index = rows[:, :2].view("<u2")[:, 0]
-                pks = table[np.minimum(index, len(table) - 1)]
+                pks = None
+                if by_index is not None and start == 0 and end == riders:
+                    pks = by_index(index)
+                if pks is None:
+                    table = self._key_rows(self._keys or [])
+                    pks = table[np.minimum(index, len(table) - 1)]
                 digests, sigs = rows[:, 2:34], rows[:, 34:]
             else:
                 rows = np.frombuffer(body, np.uint8).reshape(-1, _RAW_REC)
@@ -1221,9 +1277,11 @@ class VerifierServer:
     def _verify_batch(self, batch: List[_Pending]) -> List[tuple]:
         """Verify every signature of every request of ``batch`` with one
         backend call and return each request's reply parts, ``(req_id
-        bytes, verdict bytes)`` — ``_resolve`` frames them.  The signatures travel as arrays from the wire
-        records to the backend (``_wire_rows``) and the verdicts back into
-        bytes with one conversion: nothing here runs once a signature."""
+        bytes, verdict bytes)`` — ``_resolve`` frames them.  The signatures
+        travel as arrays from the wire records to the backend
+        (``_wire_rows``) and the verdicts back as the bytes of the array
+        the JAX backend fetched (a host oracle's list is made one first):
+        nothing here runs once a signature."""
         import numpy as np
 
         backend = self._ensure_backend(self._keys or [])
@@ -1240,22 +1298,10 @@ class VerifierServer:
             raise RuntimeError(
                 f"backend returned {len(oks)} verdicts for {total} signatures"
             )
-        if self.metrics is not None:
-            # The service owns the device, so it (not the jax-free clients)
-            # is where launch shape and padding waste are measurable.
-            self.metrics.verify_dispatch_batch_size.observe(total)
-            self.metrics.verifier_service_coalesced_requests.observe(
-                len(batch))
-            padder = getattr(backend, "padded_batch", None)
-            if padder is not None:
-                self.metrics.verify_padding_wasted_total.labels(
-                    "service"
-                ).inc(max(0, padder(total) - total))
         verdicts = np.asarray(oks, bool).view(np.uint8).tobytes()
         replies, at = [], 0
         for item in batch:
-            replies.append((struct.pack("<I", item.req_id),
-                            verdicts[at: at + item.n]))
+            replies.append((item.wire_id, verdicts[at: at + item.n]))
             at += item.n
         return replies
 

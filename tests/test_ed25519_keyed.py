@@ -236,6 +236,35 @@ def test_keyed_entry_point_verdicts(name, keyed_verdicts):
     KC.check_verdict(name, got[KC.LANE[name]], xla[KC.LANE[name]])
 
 
+def test_one_signers_launch_by_its_indices_counts_a_keyed_dispatch(
+        keyed_verdicts):
+    """Under the Pallas backend's plan (interpreted here) a launch whose
+    keys ride as the wire's indices, all one signer's, is found to fit a
+    tile by one look at the index column, grouped, and launched on the
+    keyed kernel: the verdicts the fixture's launch gave those lanes."""
+    from mysticeti_tpu.ops import ed25519_pallas as PK
+
+    _, table = KC.indexed_blob()
+    table.plan = E.DispatchPlan("pallas", True, PK.default_tile(), True)
+    signer = KC.PKS[KC.LANE["honest-00"]]
+    lanes = [i for i, case in enumerate(KC.CASES)
+             if case[1] == signer and case[4]]
+    assert len(lanes) > 4
+    index = table.indices_for([signer] * len(lanes)).astype("<u2")
+    before = {(r["kernel"], r["bucket"], r["backend"]): r["count"]
+              for r in E.dispatch_counts()}
+    got = E.dispatch_batch_table(
+        table, table.keys_at(index), [KC.MSGS[i] for i in lanes],
+        [KC.SIGS[i] for i in lanes]).result()
+    now = {(r["kernel"], r["bucket"], r["backend"]): r["count"]
+           for r in E.dispatch_counts()}
+    assert {k: n - before.get(k, 0) for k, n in now.items()
+            if n != before.get(k, 0)} == {("keyed", 256, "pallas"): 1}
+    assert table.road_counts() == (1, 1)
+    assert got.tolist() == keyed_verdicts[0][lanes].tolist()
+    assert 0 < got.sum() < len(lanes)
+
+
 # ---------------------------------------------------------------------------
 # Compiled for the chip, without one.  These are the repository's only tests
 # that load the TPU's compiler, and they stay in this one file: one process

@@ -441,8 +441,8 @@ def _service_bookings(clock, counts):
 
 
 def test_the_services_export_is_what_it_was_for_the_same_bookings():
-    """Byte for byte, key order and all: the stages' rows, the twelve
-    stamps under their names (nine of the service's, three CPU clocks), the
+    """Byte for byte, key order and all: the stages' rows, the stamps
+    under their names (eleven of the service's, three CPU clocks), the
     collections by generation."""
     counts = ServiceCounts()
     clock = spans.StageClock(
@@ -451,8 +451,8 @@ def test_the_services_export_is_what_it_was_for_the_same_bookings():
         lag_stage="service_loop_lag", gc_stage="service_gc")
     assert clock.stamp_names == (
         "requests", "signatures", "launches", "left_alone", "left_full",
-        "left_drained", "left_expired", "reads", "writes", "process_cpu_s",
-        "threads_cpu_s", "loop_cpu_s")
+        "left_drained", "left_expired", "direct", "keyed_tried", "reads",
+        "writes", "process_cpu_s", "threads_cpu_s", "loop_cpu_s")
     _service_bookings(clock, counts)
     report = clock.export()
     seconds = {}
@@ -461,6 +461,9 @@ def test_the_services_export_is_what_it_was_for_the_same_bookings():
         assert list(entry)[-3:] == list(spans.StageClock.CPU_STAMPS)
         for name in spans.StageClock.CPU_STAMPS:
             assert isinstance(entry.pop(name), float)
+        # Since PR 46 two stamps of the backend's (no backend here: zero).
+        assert list(entry)[-4:-2] == ["direct", "keyed_tried"]
+        assert entry.pop("direct") == entry.pop("keyed_tried") == 0
         seconds[str(second)] = entry
     again = json.dumps({**{k: v for k, v in report.items()
                            if k != "seconds"}, "seconds": seconds})

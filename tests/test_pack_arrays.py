@@ -239,9 +239,12 @@ def warmed(tmp_path_factory):
     return server
 
 
-# What a launch holds, as (indexed?, signed) requests; the kernel the parent
-# commit routed it to.  (On the chip a launch of one signer takes the keyed
-# tile first; off it the XLA form serves every indexed launch.)
+# What a launch holds, as (indexed?, signed) requests; the kernel it is
+# routed to: PR 28's parent's, but for a VERIFY frame's index out of range,
+# which since PR 46 is a rejected lane of an indexed launch where the keys
+# ride as indices (VERIFY frames alone).  (On the chip a launch of one
+# signer takes the keyed tile first; off it the XLA form serves every
+# indexed launch.)
 LAUNCHES = {
     "one signer": (
         [(True, _signed(3, SIGNERS[:1], 1)), (True, _signed(1, SIGNERS[:1], 2))],
@@ -260,6 +263,10 @@ LAUNCHES = {
     "an index out of range": (
         [(True, _signed(4, SIGNERS[:3] + STRANGERS[:1], 10)),
          (True, _signed(2, salt=11))],
+        "indexed"),
+    "an index out of range beside a RAW frame": (
+        [(True, _signed(4, SIGNERS[:3] + STRANGERS[:1], 14)),
+         (False, _signed(2, salt=15))],
         "blob"),
     "strangers alone, wide": (
         [(False, _signed(128, STRANGERS, 12, corrupt={5, 127})),
@@ -324,21 +331,33 @@ WRAPPERS = {
 }
 
 
+# The launch a wrapper sees: with a RAW frame (three arrays of rows), and
+# VERIFY frames alone (since PR 46 the keys are an ``IndexedKeys``).
+WRAPPED = {
+    "VERIFY and RAW": [
+        (True, _signed(4, salt=20, corrupt={3})),
+        (False, _signed(5, SIGNERS[:2] + STRANGERS[:2], 21, corrupt={1, 4})),
+    ],
+    "VERIFY alone": [
+        (True, _signed(4, salt=22, corrupt={3})),
+        (True, _signed(5, SIGNERS[:3] + STRANGERS[:1], 23, corrupt={1, 4})),
+    ],
+}
+
+
+@pytest.mark.parametrize("launch", sorted(WRAPPED))
 @pytest.mark.parametrize("wrapper", sorted(WRAPPERS))
 def test_a_wrapper_written_like_the_controls_still_works(
-        warmed, wrapper, monkeypatch):
+        warmed, wrapper, launch, monkeypatch):
     """The controls under ``benchmark/tests`` patch the backend's
     ``verify_signatures``, slice its arguments, take ``len`` and
     ``bytes(pk)`` of them and return lists: all of that on what the service
-    now passes."""
+    now passes, whichever road the launch takes."""
     wrap, altered = WRAPPERS[wrapper]
     monkeypatch.setattr(
         TpuSignatureVerifier, "verify_signatures",
         wrap(TpuSignatureVerifier.verify_signatures))
-    requests = [
-        (True, _signed(4, salt=20, corrupt={3})),
-        (False, _signed(5, SIGNERS[:2] + STRANGERS[:2], 21, corrupt={1, 4})),
-    ]
+    requests = WRAPPED[launch]
     batch, expected = zip(*(
         _request(i + 1, signed, indexed)
         for i, (indexed, signed) in enumerate(requests)))
@@ -476,3 +495,233 @@ def test_the_new_path_compiles_nothing_after_warm_up(warmed):
             for i, (indexed, signed) in enumerate(requests)))
         assert _verdicts(warmed._verify_batch(list(batch))) == list(expected)
     assert E.COMPILE_STATS == before
+
+
+# -- (h) a launch of VERIFY frames keeps its key indices (PR 46) ---------------
+
+TWICE = KEYS[:-1] + [KEYS[0]]  # a committee that holds its first key twice
+
+
+def _indexed_request(req_id, indices, salt, keys=KEYS, corrupt=()):
+    """A VERIFY request whose i-th signature is by the signer of
+    ``keys[indices[i]]`` (an index ``keys`` does not hold: by a stranger),
+    and the oracle's verdicts for it."""
+    by_key = {s.public_key.bytes: s for s in SIGNERS}
+    digests, sigs, expected = [], [], []
+    for i, index in enumerate(indices):
+        known = index < len(keys)
+        signer = by_key[keys[index]] if known else STRANGERS[0]
+        digest = crypto.blake2b_256(b"direct-%d-%d" % (salt, i))
+        sig = signer.sign(digest)
+        if i in corrupt:
+            sig = bytes([sig[0] ^ 1]) + sig[1:]
+        digests.append(digest)
+        sigs.append(sig)
+        expected.append(known and i not in corrupt)
+    item = _Pending(T_VERIFY, req_id, len(indices),
+                    _verify_body(indices, digests, sigs), "c0", None, None)
+    return item, expected
+
+
+# case -> (the committee, the launch's requests as (indices, corrupt)).
+DIRECT = {
+    "ten signers mixed": (KEYS, [
+        ([i % 10 for i in range(15)], ()), ([3, 1, 4, 1], ()),
+        ([9], ()), ([(7 * i) % 10 for i in range(38)], ())]),
+    "one signer": (KEYS, [([6] * 4, ()), ([6], ()), ([6] * 15, ())]),
+    "an index out of range": (KEYS, [([2, 10, 3], ()), ([65535, 0], ())]),
+    "a corrupted signature": (KEYS, [([0, 1, 2, 3], {2}), ([4] * 4, {0})]),
+    "a committee that holds one key twice": (
+        TWICE, [([0, 9, 5, 9], ()), ([9, 0], {0})]),
+    "exactly the bucket": (KEYS, [
+        ([i % 10 for i in range(200)], {17}), ([5] * 56, ())]),
+    "one signature": (KEYS, [([8], ())]),
+}
+
+
+@pytest.fixture(scope="module")
+def twice(warmed, tmp_path_factory):
+    """A service whose committee holds one key twice, on a backend of its
+    own (the table is the backend's): the same shapes as ``warmed``'s, so
+    nothing compiles."""
+    server = VerifierServer(
+        str(tmp_path_factory.mktemp("twice") / "v.sock"),
+        committee_keys=TWICE,
+        backend=TpuSignatureVerifier(mesh=None, committee_keys=TWICE))
+    server._launch_cap = warmed._launch_cap
+    server._warmed.set()
+    return server
+
+
+@pytest.mark.parametrize("case", sorted(DIRECT))
+def test_the_direct_road_gives_the_old_roads_verdicts(warmed, twice, case):
+    """Verdict for verdict: a launch of VERIFY frames whose indices go into
+    the blob as they are, against ``dispatch_batch_table`` on the same
+    rows with the keys gathered — the road such a launch took before."""
+    keys, requests = DIRECT[case]
+    server = warmed if keys is KEYS else twice
+    table = server._backend._table
+    batch, expected = zip(*(
+        _indexed_request(i + 1, indices, 80 + i, keys, corrupt)
+        for i, (indices, corrupt) in enumerate(requests)))
+    before, roads = E.dispatch_counts(), table.road_counts()
+    got = [ok for verdicts in _verdicts(server._verify_batch(list(batch)))
+           for ok in verdicts]
+    one_signer = len({i for indices, _ in requests for i in indices}) == 1
+    kernel = "keyed" if one_signer and table.plan.keyed else "indexed"
+    assert _dispatched(before) == {(kernel, 256): 1}
+    assert table.road_counts() == (
+        roads[0] + 1, roads[1] + (kernel == "keyed"))
+    assert got == [ok for verdicts in expected for ok in verdicts]
+
+    rows = np.frombuffer(
+        b"".join(item.body for item in batch), np.uint8).reshape(-1, 98)
+    index = rows[:, :2].view("<u2")[:, 0]
+    gathered = server._key_rows(keys)[np.minimum(index, len(keys))]
+    old = E.dispatch_batch_table(
+        table, gathered, rows[:, 2:34], rows[:, 34:]).result()
+    assert table.road_counts()[0] == roads[0] + 1  # not direct: searched
+    assert got == old.tolist()
+
+
+def test_a_launch_that_holds_a_raw_frame_takes_the_old_road(warmed):
+    """One RAW request among VERIFY frames: the keys are gathered and
+    searched as before, with the verdicts of before."""
+    table = warmed._backend._table
+    requests = [(True, _signed(4, salt=90, corrupt={1})),
+                (False, _signed(3, salt=91)),
+                (True, _signed(2, salt=92))]
+    batch, expected = zip(*(
+        _request(i + 1, signed, indexed)
+        for i, (indexed, signed) in enumerate(requests)))
+    before, roads = E.dispatch_counts(), table.road_counts()
+    assert _verdicts(warmed._verify_batch(list(batch))) == list(expected)
+    assert _dispatched(before) == {("indexed", 256): 1}
+    assert table.road_counts() == roads
+
+
+def test_indexed_keys_read_as_the_keys_they_stand_for():
+    """To a wrapper written against sequences the index column is the keys:
+    ``len``, slices, items, iteration, and the all-zero key where the table
+    holds no such row."""
+    table = E.KeyTable(KEYS)
+    keys = table.keys_at(np.array([3, 0, 10, 9], "<u2"))
+    assert len(keys) == 4
+    assert list(keys) == [KEYS[3], KEYS[0], bytes(32), KEYS[9]]
+    assert keys[1] == KEYS[0] and keys[-1] == KEYS[9]
+    assert list(keys[1:3]) == [KEYS[0], bytes(32)]
+    assert isinstance(keys[:2], E.IndexedKeys) and keys[:2].table is table
+    assert keys.rows().shape == (4, 32)
+    # Another table's index is no index here: its keys are searched.
+    other = E.KeyTable(KEYS[::-1])
+    _, digests, sigs = _signed(2, [SIGNERS[3], SIGNERS[0]], salt=95)
+    blobs = []
+    for t, column in ((table, keys[:2]), (other, keys[:2])):
+        seen = []
+        real = E.dispatch_indexed_chunks
+        E.dispatch_indexed_chunks = lambda blob, tab: seen.append(blob) or []
+        try:
+            E.dispatch_batch_table(t, column, _rows(digests, 32), _rows(sigs, 64))
+        finally:
+            E.dispatch_indexed_chunks = real
+        blobs.append(seen[0])
+    assert blobs[0][:, 24].tolist() == [3, 0] and table.road_counts()[0] == 1
+    assert blobs[1][:, 24].tolist() == [6, 9] and other.road_counts()[0] == 0
+
+
+def _launched_on(before):
+    """(kernel, lanes, backend) -> launches since ``before``."""
+    was = {(r["kernel"], r["bucket"], r["backend"]): r["count"]
+           for r in before}
+    now = {(r["kernel"], r["bucket"], r["backend"]): r["count"]
+           for r in E.dispatch_counts()}
+    return {key: n - was.get(key, 0) for key, n in now.items()
+            if n != was.get(key, 0)}
+
+
+class _Spy:
+    def __init__(self, returns=None):
+        self.calls, self.returns = 0, returns
+
+    def __call__(self, *args, **kwargs):
+        self.calls += 1
+        return self.returns
+
+
+@pytest.fixture
+def chip_plan(monkeypatch, tmp_path):
+    """A service whose backend dispatches as on the chip — the Pallas
+    backend, the keyed kernel where one key a 256-lane tile fits — with
+    the two Pallas entry points a launch reaches replaced by spies (they
+    compile for minutes under the interpreter) that accept every lane."""
+    import jax.numpy as jnp
+
+    from mysticeti_tpu.ops import ed25519_pallas as PK
+
+    backend = TpuSignatureVerifier(mesh=None, committee_keys=KEYS)
+    backend._table.plan = E.DispatchPlan("pallas", True, 256, False)
+    accepted = jnp.ones(256, bool)
+    ladder, keyed = _Spy(accepted), _Spy(accepted)
+    monkeypatch.setattr(PK, "verify_fused_indexed_blob_pallas", ladder)
+    monkeypatch.setattr(PK, "verify_keyed_blob", keyed)
+    server = VerifierServer(
+        str(tmp_path / "v.sock"), committee_keys=KEYS, backend=backend)
+    server._launch_cap = 256
+    server.counts.roads = backend.road_counts
+    server._warmed.set()
+    return server, ladder, keyed
+
+
+def test_a_launch_of_several_signers_goes_from_the_wire_to_the_ladder_once(
+        chip_plan, monkeypatch):
+    """No key is searched for and nothing is grouped: the index column says
+    that ten signers do not fit one tile, and the stamps say which road the
+    launch took."""
+    server, ladder, keyed = chip_plan
+    searched, grouped = _Spy(), _Spy()
+    monkeypatch.setattr(E.KeyTable, "indices_for", searched)
+    monkeypatch.setattr(E, "group_blob_for_tiles", grouped)
+    batch = [_indexed_request(i + 1, indices, 100 + i)[0]
+             for i, indices in enumerate(([0, 1, 2, 3], [4] * 15, [9]))]
+    before, stamps = E.dispatch_counts(), server.counts.read()
+    replies = server._verify_batch(batch)
+    assert _verdicts(replies) == [[True] * item.n for item in batch]
+    assert (searched.calls, grouped.calls) == (0, 0)
+    assert (ladder.calls, keyed.calls) == (1, 0)
+    assert _launched_on(before) == {("indexed", 256, "pallas"): 1}
+    grew = dict(zip(server.counts.STAMPS, (
+        now - was for now, was in zip(server.counts.read(), stamps))))
+    assert (grew["direct"], grew["keyed_tried"]) == (1, 0)
+
+
+def test_a_launch_of_one_signer_still_reaches_the_keyed_kernel(chip_plan):
+    server, ladder, keyed = chip_plan
+    batch = [_indexed_request(1, [7] * 4, 110)[0],
+             _indexed_request(2, [7], 111)[0]]
+    before, stamps = E.dispatch_counts(), server.counts.read()
+    assert _verdicts(server._verify_batch(batch)) == [[True] * 4, [True]]
+    assert (ladder.calls, keyed.calls) == (0, 1)
+    assert _launched_on(before) == {("keyed", 256, "pallas"): 1}
+    assert tuple(now - was for now, was in zip(
+        server.counts.read(), stamps))[7:9] == (1, 1)
+
+
+def test_a_host_oracle_is_served_as_before_and_counts_no_road(tmp_path):
+    """A backend without ``indexed_keys`` gets the keys themselves, and the
+    service's two stamps of the backend's roads stay at zero."""
+    seen = []
+
+    class Oracle(CpuSignatureVerifier):
+        def verify_signatures(self, public_keys, digests, signatures):
+            seen.append(public_keys)
+            return super().verify_signatures(public_keys, digests, signatures)
+
+    server = VerifierServer(str(tmp_path / "v.sock"), committee_keys=KEYS,
+                            backend=Oracle())
+    server._warmed.set()
+    batch, expected = zip(_indexed_request(1, [2, 10, 3], 120),
+                          _indexed_request(2, [5], 121, corrupt={0}))
+    assert _verdicts(server._verify_batch(list(batch))) == list(expected)
+    assert isinstance(seen[0], np.ndarray) and seen[0].shape == (4, 32)
+    assert dict(zip(server.counts.STAMPS, server.counts.read()))[
+        "direct"] == 0

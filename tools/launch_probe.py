@@ -12,9 +12,15 @@ around (PERF.md section 5); this is the same code without the contention.
     chiprun -- python3 tools/launch_probe.py            # on the chip's host
     JAX_PLATFORMS=cpu python3 tools/launch_probe.py --stub   # host code only
 
-``--stub`` replaces the jitted calls by a constant, so the sandbox sizes the
-host's packing alone (a launch of the XLA form takes 0.8 s on a CPU).  Runs
-on any tree that has ``_verify_batch`` (``PYTHONPATH=<tree>``).
+``--stub`` replaces the jitted calls by a constant, the keyed kernel's too,
+so the sandbox sizes the host's packing alone (a launch of the XLA form
+takes 0.8 s on a CPU); with ``MYSTICETI_VERIFY_BACKEND=pallas`` that
+includes the decision whether a launch takes the keyed kernel.  Each shape
+prints how many of its launches kept their key indices (``direct``) and
+went into the keyed grouping (``keyed_tried``), on a tree that counts them.
+``--late-fetch`` takes the early request for the result's copy to the host
+(``ops.ed25519._fetch_early``) out again, to read it alone.  Runs on any
+tree that has ``_verify_batch`` (``PYTHONPATH=<tree>``).
 """
 from __future__ import annotations
 
@@ -32,6 +38,9 @@ SHAPES = (
     ("64 signatures, eight VERIFY requests", (4, 15, 4, 4, 15, 4, 14, 4), True, 9),
     ("169 signatures, two RAW requests by strangers", (128, 41), False, 40),
     ("3 signatures, one VERIFY request by one signer", (3,), True, 1),
+    # service10-catchup's launch since PR 37: what a draining queue gathers.
+    ("159 signatures, nineteen VERIFY requests, ten signers",
+     (4, 15, 4, 1, 4, 38, 4, 15, 4, 4, 15, 4, 1, 4, 15, 4, 4, 15, 4), True, 10),
 )
 
 
@@ -40,6 +49,8 @@ def main() -> None:
     parser.add_argument("--stub", action="store_true",
                         help="no device call: size the host's packing alone")
     parser.add_argument("--launches", type=int, default=1500)
+    parser.add_argument("--late-fetch", action="store_true",
+                        help="do not ask for the result's copy at the launch")
     args = parser.parse_args()
 
     import numpy as np
@@ -68,13 +79,23 @@ def main() -> None:
             records.append(head + digest + signer.sign(digest))
         return memoryview(b"".join(records))
 
+    if args.late_fetch or args.stub:
+        E._fetch_early = lambda handle: None
+    if args.stub:
+        accepted = np.ones(E.BUCKETS[0], bool)
+        E._dispatch_indexed = lambda *args, **kwargs: accepted
+        E._dispatch_blob = lambda *args, **kwargs: accepted
+        if E._backend() == "pallas":
+            from mysticeti_tpu.ops import ed25519_pallas as PK
+
+            PK.verify_keyed_blob = lambda *args, **kwargs: accepted
+            # The chip's tile, so that the sandbox sizes the chip's decision
+            # (the interpreter's own tile is 8 lanes).
+            PK.default_tile = lambda: 256
     server = VS.VerifierServer(
         "/tmp/launch_probe.sock", committee_keys=keys,
         backend=TpuSignatureVerifier(mesh=None, committee_keys=keys))
     if args.stub:
-        accepted = np.ones(E.BUCKETS[0], bool)
-        E._dispatch_indexed = lambda blob, table: accepted
-        E._dispatch_blob = lambda blob: accepted
         server._warmed.set()
     else:
         started = time.monotonic()
@@ -100,6 +121,8 @@ def main() -> None:
         for _ in range(20):
             server._verify_batch(batch)
         spent.clear()
+        roads = getattr(server._backend, "road_counts", lambda: None)
+        roads_before = roads()
         started = time.perf_counter()
         for _ in range(args.launches):
             stage("service_unpack")
@@ -109,6 +132,10 @@ def main() -> None:
         print("%s: %.3f ms a launch;" % (label, 1e3 * whole), " ".join(
             "%s %.3f" % (name, 1e3 * seconds / args.launches)
             for name, seconds in spent.items()), flush=True)
+        if roads_before is not None:
+            print("    direct %d keyed_tried %d of %d launches" % (
+                *(now - was for now, was in zip(roads(), roads_before)),
+                args.launches), flush=True)
     print("dispatches", E.dispatch_counts())
 
 
