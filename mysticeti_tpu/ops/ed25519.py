@@ -947,7 +947,10 @@ class VerifyDispatch:
         self._entries = list(entries)
 
     def result(self) -> np.ndarray:
-        spans.request_stage("service_fetch")
+        # Every launch is made (``_launched``) and nothing below needs the
+        # interpreter until the verdicts are on the host: the verifier
+        # service lets its next launch leave from here.
+        spans.request_fetch()
         return fetch_handles(self._entries)
 
 

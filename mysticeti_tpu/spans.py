@@ -601,10 +601,13 @@ class _Local(threading.local):
     """``state``: the thread's _ThreadState, made at its first use;
     ``frame``: the _Frame of the launch the thread works for now, if a
     clocked request rides it, None otherwise (a class default, so that
-    reading it costs a thread that never clocked one nothing)."""
+    reading it costs a thread that never clocked one nothing);
+    ``fetch_hook``: who hears that the launch the thread works for has
+    entered its fetch (``on_fetch``), None where nobody listens."""
 
     state = None
     frame = None
+    fetch_hook = None
 
 
 _tls = _Local()
@@ -700,6 +703,29 @@ def request_stage(name: str) -> None:
     slot = frame.clock._slot[name]
     if slot != frame.slot:
         frame.switch(slot)
+
+
+def on_fetch(hook) -> None:
+    """``hook()`` is called once, on this thread, when the launch it works
+    for from now on enters its fetch (``request_fetch``); None: nobody
+    listens any more.  The verifier service ends a part-full launch's hold
+    there (``VerifierServer._launch``)."""
+    _tls.fetch_hook = hook
+
+
+def request_fetch() -> None:
+    """The launch this thread works for is handed to the device and the
+    thread is about to block for its result with the GIL free: its host
+    path is over.  A backend says so between its last jitted call and the
+    blocking fetch; whoever listens on this thread (``on_fetch``) hears it
+    once, whether or not a clocked request rides the launch, and the launch
+    is in ``service_fetch`` from here.  Outside the service it does
+    nothing."""
+    hook = _tls.fetch_hook
+    if hook is not None:
+        _tls.fetch_hook = None
+        hook()
+    request_stage("service_fetch")
 
 
 class _Books:

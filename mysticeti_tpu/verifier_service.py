@@ -19,8 +19,10 @@ process, shared by every co-located validator.
     is pending when they are free, up to the bucket the backend warmed,
     and verify them with ONE backend call (group commit: a request that
     finds the service idle is launched at once, alone; while a queue
-    drains one part-full launch is out at a time and what arrives behind
-    it rides together when it lands, ``VerifierServer._take``).
+    drains one part-full launch is on its host path at a time and what
+    arrives behind it rides together when it lands — or, once it is in
+    its fetch, as soon as they are as many as it carries,
+    ``VerifierServer._take``).
   * :class:`RemoteSignatureVerifier` — the validator-side
     :class:`SignatureVerifier` that forwards batches to the service.  It
     never imports jax: a validator process using it boots import-light, and
@@ -132,7 +134,7 @@ class ServiceCounts:
 
     # Why a launch left when it did (``VerifierServer._take``): ``left``
     # counts them in this order.
-    LEFT = ("alone", "full", "drained", "expired")
+    LEFT = ("alone", "full", "drained", "expired", "overlapped")
     STAMPS = ("requests", "signatures", "launches",
               *("left_" + why for why in LEFT), "direct", "keyed_tried",
               "reads", "writes")
@@ -153,7 +155,23 @@ class ServiceCounts:
 
 
 # Why a launch left, as ``ServiceCounts.left`` is indexed.
-_ALONE, _FULL, _DRAINED, _EXPIRED = range(len(ServiceCounts.LEFT))
+_ALONE, _FULL, _DRAINED, _EXPIRED, _OVERLAPPED = range(
+    len(ServiceCounts.LEFT))
+
+
+class _Hold:
+    """A part-full launch that is out.  ``until``: when its hold on what
+    arrives behind it runs out, if nothing ends it before; ``riders``: the
+    requests it carries; ``fetching``: its backend said that it is in its
+    fetch (``spans.request_fetch``): its host path is over."""
+
+    __slots__ = ("until", "riders", "fetching")
+
+    def __init__(self, until: float, riders: int) -> None:
+        self.until = until
+        self.riders = riders
+        self.fetching = False
+
 
 # VerifierProtocolError (re-exported above from block_validator): the service
 # answered but REJECTED the request.  Excluded from the client's retry loop
@@ -673,15 +691,19 @@ class VerifierServer:
     # threads queue for the GIL with the loop: on the chip one slot
     # verified a third more signatures a second than two or three (PERF.md,
     # PR 25, has the pairs).  The rule collects that without giving up
-    # what the slots are for — at most ONE part-full launch is out at a
-    # time, and the other slots carry what must not wait for it: a request
-    # that goes alone (a client that keeps four requests in flight, the
-    # deepest a validator's verify pipeline goes, finds each launched at
-    # once and by itself at an otherwise idle service: three slots and one
-    # that waits its turn), a launch that is full (a wide request's pieces
-    # take every slot), and the launch after a hold has run out (a slow
-    # backend call holds the others up for two normal launches, not for
-    # its own length).
+    # what the slots are for — at most ONE part-full launch is on its host
+    # path at a time (unpacking, packing, the jitted call: the part that
+    # needs the interpreter), and the other slots carry what must not wait
+    # for it: a request that goes alone (a client that keeps four requests
+    # in flight, the deepest a validator's verify pipeline goes, finds
+    # each launched at once and by itself at an otherwise idle service:
+    # three slots and one that waits its turn), a launch that is full (a
+    # wide request's pieces take every slot), the launch after a hold has
+    # run out (a slow backend call holds the others up for two normal
+    # launches, not for its own length), and the next part-full launch
+    # where those that are out wait for their results with the GIL free
+    # and it carries as many requests as they do (``_may_overlap``) — so
+    # this is also the most part-full launches that are out at once.
     DISPATCHERS = 3
 
     def __init__(self, socket_path: str, committee_keys: Optional[Sequence[bytes]] = None,
@@ -730,10 +752,11 @@ class VerifierServer:
         self._idle = 0  # dispatchers asleep on the condition
         self._watching = 0  # of them, asleep until a hold runs out
         self._promised = 0  # pending requests that each woke one of them
-        # When the hold of each part-full launch that is out runs out: what
-        # is pending waits for the newest of them to land, or for that
-        # instant (``_take``).
-        self._part_full: List[float] = []
+        # The part-full launches that are out: what is pending waits for
+        # them to land, for the hold of the newest to run out, or — once
+        # each is in its fetch — to be as many requests as they carry
+        # (``_take``).
+        self._part_full: List[_Hold] = []
         self._in_service = 0  # handed over and not yet resolved (the loop's)
         self._stopping = False
         self._dispatchers: List[threading.Thread] = []
@@ -988,18 +1011,39 @@ class VerifierServer:
 
     def _may_leave(self) -> bool:
         """Whether ``_take`` could let pending requests that go with others
-        leave: no hold is in force, or they fill a launch (where one of
-        them goes alone the sum says so too soon, and a slot wakes in
-        vain)."""
+        leave: no hold is in force, they may overlap what is out, or they
+        fill a launch (where one of them goes alone the counts say so too
+        soon, and a slot wakes in vain)."""
         if self._held_for(time.monotonic()) <= 0.0:
             return True
+        if self._may_overlap(len(self._pending)):
+            return True
         return sum(item.n for item in self._pending) >= self._launch_cap
+
+    def _may_overlap(self, riders: int) -> bool:
+        """Whether a launch of ``riders`` requests may leave while
+        part-full launches are out: each of them is in its fetch — its
+        thread waits for the result with the GIL free, so no two host
+        paths overlap — and ``riders`` is at least the requests they
+        carry.  Fewer would only cut what is in flight into more and
+        smaller launches, each of which costs the one interpreter its
+        fixed share (on the chip ending every hold at its fetch read 727
+        launches a second of 9 requests where 395 of 19.5 verified 18%
+        more: PERF.md, PR 47); as many are the answers to a whole launch
+        come back, and have nothing to wait for."""
+        out = 0
+        for hold in self._part_full:
+            if not hold.fetching:
+                return False
+            out += hold.riders
+        return riders >= out
 
     def _held_for(self, now: float) -> float:
         """Seconds for which the newest part-full launch that is out still
         holds what is pending; none out, or zero and less: nothing is
         held."""
-        return max(self._part_full, default=now) - now
+        return max(
+            (hold.until for hold in self._part_full), default=now) - now
 
     def _hold_s(self) -> float:
         """How long a part-full launch holds what arrives behind it, if it
@@ -1017,7 +1061,7 @@ class VerifierServer:
         fixed, per_signature = self._calibration
         return 2.0 * (fixed + self._launch_cap * per_signature)
 
-    def _take(self, now: float) -> Optional[Tuple[List[_Pending], Optional[float]]]:
+    def _take(self, now: float) -> Optional[Tuple[List[_Pending], Optional[_Hold]]]:
         """The coalescer's rule (Nagle's, for launches).  What would ride
         the next launch is everything pending, in arrival order, while the
         signatures sum to at most what the backend warmed: whole requests
@@ -1031,16 +1075,24 @@ class VerifierServer:
           the sum is the cap), not because the list ran out.  A full
           launch shares its fixed cost as widely as a launch can, so it
           never waits;
-        * *drained*: no part-full launch — one that left under this clause
-          or the next — is out.  So one part-full launch is out at a time,
-          and what arrives while it is rides together when it lands, or
-          when it fills a launch;
+        * *drained*: no part-full launch — one that left under this
+          clause or the next two — is out;
         * *expired*: the newest part-full launch has been out for longer
-          than ``_hold_s``.
+          than ``_hold_s``;
+        * *overlapped*: every part-full launch that is out has said that it
+          is in its fetch (``_enters_fetch``) and the requests that would
+          ride are at least as many as those they carry
+          (``_may_overlap``).  So one part-full launch is on its host path
+          at a time — unpacking, packing, the jitted call: what needs the
+          interpreter — and a launch's wait for its result hides under the
+          next one's packing only where the next is a whole launch's worth
+          of answers come back, not a few early ones.  Behind a backend
+          that never says (a host oracle) one part-full launch is out at a
+          time, as before.
 
         Otherwise it stays pending, but for a request in it that goes
-        alone.  Returns the launch and when its hold runs out (None: it
-        holds nothing), or None.  Called with the condition held and
+        alone.  Returns the launch and its hold (None: it holds nothing),
+        or None.  Called with the condition held and
         something pending."""
         pending = self._pending
         first = pending[0]
@@ -1065,6 +1117,8 @@ class VerifierServer:
             why = _DRAINED
         elif self._held_for(now) <= 0.0:
             why = _EXPIRED
+        elif self._may_overlap(riders):
+            why = _OVERLAPPED
         else:
             for item in pending:
                 if item.alone:
@@ -1083,7 +1137,7 @@ class VerifierServer:
         if why == _ALONE:
             self._promised -= 1
         elif why != _FULL:
-            hold = now + self._hold_s()
+            hold = _Hold(now + self._hold_s(), len(batch))
             self._part_full.append(hold)
         if self._pending:
             self._wake_one()
@@ -1124,7 +1178,19 @@ class VerifierServer:
             self._idle -= 1
         return None
 
-    def _launch(self, batch: List[_Pending], hold: Optional[float]):
+    def _enters_fetch(self, hold: _Hold) -> None:
+        """The backend says that the part-full launch of ``hold`` is handed
+        to the device and that this thread, which made it, is about to
+        block for the result (``spans.request_fetch``): its host path is
+        over, and a sleeping slot is woken if what is pending may now leave
+        beside it (``_may_overlap``) — on this thread and not at the loop's
+        next turn, as where a launch lands (``_launch``)."""
+        with self._pending_cond:
+            hold.fetching = True
+            if self._pending and not self._stopping:
+                self._wake_one()
+
+    def _launch(self, batch: List[_Pending], hold: Optional[_Hold]):
         """One backend call for every request of ``batch``, then each
         request's reply to its slot, with one wake-up of the loop.  The
         thread works for the launch from here to ``built``: every
@@ -1144,11 +1210,14 @@ class VerifierServer:
             stages.begin_launch(clocked)
             spans.request_stage("service_unpack")
         replies = error = built = None
+        if hold is not None:
+            spans.on_fetch(functools.partial(self._enters_fetch, hold))
         try:
             replies = self._verify_batch(batch)
         except Exception as exc:  # noqa: BLE001 - ``_resolve`` logs it
             error = exc
         finally:
+            spans.on_fetch(None)
             if clocked:
                 built = stages.end_launch(len(batch))
         taken = None

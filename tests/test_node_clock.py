@@ -426,15 +426,15 @@ def _service_bookings(clock, counts):
         clock.book(names[i % len(names)], BASE + (i % 3) + 0.25,
                    0.001 * (i + 1), 0.0005 * i)
     counts.requests, counts.signatures, counts.launches = 5, 40, 2
-    counts.left[:] = [1, 0, 1, 0]
+    counts.left[:] = [1, 0, 1, 0, 0]
     counts.reads, counts.writes = 4, 3
     clock.stamp(BASE + 0.0)
     counts.requests, counts.signatures, counts.launches = 12, 100, 5
-    counts.left[:] = [1, 2, 2, 0]
+    counts.left[:] = [1, 2, 2, 0, 2]
     counts.reads, counts.writes = 9, 8
     clock.stamp(BASE + 1.5)
     counts.requests, counts.signatures, counts.launches = 30, 260, 9
-    counts.left[:] = [2, 3, 3, 1]
+    counts.left[:] = [2, 3, 3, 1, 5]
     counts.reads, counts.writes = 20, 15
     clock.stamp(BASE + 2.1)
     counts.requests = 31
@@ -442,7 +442,7 @@ def _service_bookings(clock, counts):
 
 def test_the_services_export_is_what_it_was_for_the_same_bookings():
     """Byte for byte, key order and all: the stages' rows, the stamps
-    under their names (eleven of the service's, three CPU clocks), the
+    under their names (twelve of the service's, three CPU clocks), the
     collections by generation."""
     counts = ServiceCounts()
     clock = spans.StageClock(
@@ -451,11 +451,12 @@ def test_the_services_export_is_what_it_was_for_the_same_bookings():
         lag_stage="service_loop_lag", gc_stage="service_gc")
     assert clock.stamp_names == (
         "requests", "signatures", "launches", "left_alone", "left_full",
-        "left_drained", "left_expired", "direct", "keyed_tried", "reads",
-        "writes", "process_cpu_s", "threads_cpu_s", "loop_cpu_s")
+        "left_drained", "left_expired", "left_overlapped", "direct",
+        "keyed_tried", "reads", "writes", "process_cpu_s", "threads_cpu_s",
+        "loop_cpu_s")
     _service_bookings(clock, counts)
     report = clock.export()
-    seconds = {}
+    seconds, overlapped = {}, []
     for second in (BASE, BASE + 1, BASE + 2):
         entry = dict(report["seconds"][str(second)])
         assert list(entry)[-3:] == list(spans.StageClock.CPU_STAMPS)
@@ -464,10 +465,14 @@ def test_the_services_export_is_what_it_was_for_the_same_bookings():
         # Since PR 46 two stamps of the backend's (no backend here: zero).
         assert list(entry)[-4:-2] == ["direct", "keyed_tried"]
         assert entry.pop("direct") == entry.pop("keyed_tried") == 0
+        # Since PR 47 a fifth reason a launch left, after the four.
+        assert list(entry)[-3] == "left_overlapped"
+        overlapped.append(entry.pop("left_overlapped"))
         seconds[str(second)] = entry
     again = json.dumps({**{k: v for k, v in report.items()
                            if k != "seconds"}, "seconds": seconds})
     assert again == EXPORTED_BEFORE
+    assert overlapped == [2, 3, 0]  # the growth from stamp to stamp
 
 
 # -- the two events -------------------------------------------------------------

@@ -290,6 +290,28 @@ def test_a_launch_verifies_and_routes_as_the_parent_did(warmed, case):
     assert _dispatched(before) == {(kernel, 256): 1}
 
 
+@pytest.mark.parametrize("case", [
+    "several signers, one corrupted", "strangers alone, wide"])
+def test_the_backend_says_once_a_launch_that_it_enters_its_fetch(warmed, case):
+    """Down either road, with no clocked request on the launch: the
+    listener of the launching thread hears it once, after the kernel's
+    launch was made and before the verdicts are back."""
+    from mysticeti_tpu import spans
+
+    requests, kernel = LAUNCHES[case]
+    batch, expected = zip(*(
+        _request(i + 1, signed, indexed)
+        for i, (indexed, signed) in enumerate(requests)))
+    before, heard = E.dispatch_counts(), []
+    spans.on_fetch(lambda: heard.append(_dispatched(before)))
+    try:
+        replies = warmed._verify_batch(list(batch))
+    finally:
+        spans.on_fetch(None)
+    assert heard == [{(kernel, 256): 1}]
+    assert _verdicts(replies) == list(expected)
+
+
 # -- (d) a backend wrapper written against sequences ---------------------------
 
 
@@ -702,8 +724,23 @@ def test_a_launch_of_one_signer_still_reaches_the_keyed_kernel(chip_plan):
     assert _verdicts(server._verify_batch(batch)) == [[True] * 4, [True]]
     assert (ladder.calls, keyed.calls) == (0, 1)
     assert _launched_on(before) == {("keyed", 256, "pallas"): 1}
-    assert tuple(now - was for now, was in zip(
-        server.counts.read(), stamps))[7:9] == (1, 1)
+    grew = dict(zip(server.counts.STAMPS, (
+        now - was for now, was in zip(server.counts.read(), stamps))))
+    assert (grew["direct"], grew["keyed_tried"]) == (1, 1)
+
+
+def test_a_keyed_launch_says_that_it_enters_its_fetch_after_the_kernel_call(
+        chip_plan):
+    from mysticeti_tpu import spans
+
+    server, ladder, keyed = chip_plan
+    heard = []
+    spans.on_fetch(lambda: heard.append((ladder.calls, keyed.calls)))
+    try:
+        replies = server._verify_batch([_indexed_request(1, [7] * 4, 120)[0]])
+    finally:
+        spans.on_fetch(None)
+    assert heard == [(0, 1)] and _verdicts(replies) == [[True] * 4]
 
 
 def test_a_host_oracle_is_served_as_before_and_counts_no_road(tmp_path):

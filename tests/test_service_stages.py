@@ -646,6 +646,36 @@ def test_request_stage_outside_a_launch_does_nothing():
     assert totals["service_fetch"]["wall_s"] == 0.0
 
 
+def test_a_fetch_is_said_once_to_who_listens_on_the_thread_clocked_or_not():
+    """``request_fetch`` tells the thread's listener once, with no clocked
+    request on the launch too, and never another thread's; with a frame it
+    also names ``service_fetch``; with nobody listening it is
+    ``request_stage("service_fetch")``."""
+    heard = []
+    spans.request_fetch()  # nobody listens, no frame: nothing
+    spans.on_fetch(lambda: heard.append("first"))
+    other = threading.Thread(target=spans.request_fetch)
+    other.start()
+    other.join(5)
+    assert heard == []  # another thread's launch is not this one's
+    spans.request_fetch()
+    spans.request_fetch()  # the launch said it already
+    assert heard == ["first"]
+    spans.on_fetch(lambda: heard.append("never"))
+    spans.on_fetch(None)  # the launch ended without saying
+    spans.request_fetch()
+    assert heard == ["first"]
+    clock = spans.StageClock(spans.SERVICE_STAGES)
+    clock.begin_launch([(("c0", 1), time.monotonic())])
+    spans.request_stage("service_launch")
+    spans.on_fetch(lambda: heard.append("second"))
+    spans.request_fetch()
+    time.sleep(0.002)
+    clock.end_launch(1)
+    assert heard == ["first", "second"]
+    assert clock.totals()["service_fetch"]["wall_s"] >= 0.002
+
+
 def _burn(seconds):
     """Use the calling thread's CPU for ``seconds`` of its own clock."""
     until = time.thread_time() + seconds

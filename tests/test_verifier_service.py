@@ -1624,15 +1624,19 @@ def test_launches_are_counted_by_why_they_left_in_the_ring_and_the_scrape(
         tmp_path, signers):
     """A scripted sequence — three plugs alone, five queued behind them
     once no part-full launch is out, two of three wide requests as a full
-    launch past it, the third when its hold has run out — read back from
-    the stage clock, the ring's stamp as the report carries it, and
-    ``verifier_service_launches_total{left}``."""
+    launch past it, the third when its hold has run out; then, the backend
+    saying when a launch enters its fetch, the same plugs and queue again
+    and as many requests more, which leave while that launch is out in its
+    fetch —
+    read back from the stage clock, the ring's stamp as the report carries
+    it, and ``verifier_service_launches_total{left}``."""
     from mysticeti_tpu.metrics import Metrics
 
     keys = [s.public_key.bytes for s in signers]
-    backend = GatedBackend()
+    backend = SignallingBackend(silent=True)
     metrics = Metrics()
-    expected = {"alone": 3, "full": 1, "drained": 1, "expired": 1}
+    expected = {"alone": 6, "full": 1, "drained": 2, "expired": 1,
+                "overlapped": 1}
 
     async def scenario(server):
         warm = await asyncio.to_thread(_RawConn, server, keys)
@@ -1647,6 +1651,16 @@ def test_launches_are_counted_by_why_they_left_in_the_ring_and_the_scrape(
             for i in range(3)))
         await _until(lambda: len(wire.results()) == 3, "answered")
         assert sorted(backend.sizes)[-3:] == [10, 100, 200]
+        assert _left(server) == {"alone": 3, "full": 1, "drained": 1,
+                                 "expired": 1, "overlapped": 0}
+        await _until(lambda: not server._in_service, "the slow one landed")
+        backend.silent = False
+        more, landing = await _a_launch_in_its_fetch(
+            server, backend, keys, signers)
+        conn.data_received(_frames(keys, signers, 3, (1,) * 5, b"over"))
+        await _until(lambda: len(wire.results()) == 8, "beside the one out")
+        landing.set()
+        others += more
         assert _left(server) == expected
         server.stages.stamp(time.monotonic() + 1.0)
         seconds = server.stages.export()["seconds"].values()
@@ -1660,6 +1674,414 @@ def test_launches_are_counted_by_why_they_left_in_the_ring_and_the_scrape(
             other.close()
 
     asyncio.run(_with_server(tmp_path, keys, backend, scenario, metrics))
+
+
+# ---------------------------------------------------------------------------
+# A backend that says when a launch enters its fetch: the hold ends there.
+
+
+class SignallingBackend(GatedBackend):
+    """GatedBackend whose calls say, past the gate, that they enter their
+    fetch (``spans.request_fetch``, as the JAX backend does between its
+    jitted call and the blocking fetch) unless ``silent``.  A call that
+    holds a digest of ``host`` waits for that event before it says so (it
+    is on its host path: ``on_host`` counts such calls), one that holds a
+    digest of ``fetch`` waits for that event after (``fetching`` counts
+    those); a ``poison`` digest raises where the fetch ends."""
+
+    def __init__(self, silent=False) -> None:
+        super().__init__()
+        self.silent = silent
+        self.host = {}
+        self.fetch = {}
+        self.on_host = self.fetching = 0
+
+    @staticmethod
+    def _wait(events, held) -> None:
+        for digest, event in list(events.items()):
+            if digest in held:
+                assert event.wait(30), "the test never let the call go on"
+
+    def verify_signatures(self, public_keys, digests, signatures):
+        from mysticeti_tpu import spans
+
+        if self.silent:
+            return super().verify_signatures(public_keys, digests, signatures)
+        self.waiting += 1
+        try:
+            assert self.gate.wait(30), "the test never opened the gate"
+        finally:
+            self.waiting -= 1
+        self.sizes.append(len(signatures))
+        held = {bytes(d) for d in digests}
+        self.on_host += 1
+        try:
+            self._wait(self.host, held)
+        finally:
+            self.on_host -= 1
+        spans.request_fetch()
+        self.fetching += 1
+        try:
+            self._wait(self.fetch, held)
+        finally:
+            self.fetching -= 1
+        if held & self.poison:
+            raise RuntimeError("device lost")
+        return CountingBackend.verify_signatures(
+            self, public_keys, digests, signatures)
+
+
+def _blocked(where, items):
+    """An event that the call holding ``items`` waits for in ``where``
+    (a SignallingBackend's ``host`` or ``fetch``)."""
+    event = where[items[0][1]] = threading.Event()
+    return event
+
+
+async def _a_launch_in_its_fetch(server, backend, keys, signers, riders=5,
+                                 on_host=False):
+    """Plug the slots, queue ``riders`` requests of two signatures behind
+    them (ids from 0) and open the gate: the plugs land and the queued
+    leave together, no part-full launch being out, on a call that has said
+    it is in its fetch and stays there until the returned event is set —
+    or, ``on_host``, that has not said so yet and will once the event is
+    set.  Returns once the other slots are asleep: the connections to
+    close (the queue's last) and the event."""
+    plugs = await _plug_the_slots(server, backend, keys, signers)
+    queue = await asyncio.to_thread(_RawConn, server, keys)
+    queued = [_indexed(2, signers, b"first%d" % i) for i in range(riders)]
+    event = _blocked(backend.host if on_host else backend.fetch, queued[0])
+    queue.send(*(_verify_frame(i, keys, items)
+                 for i, items in enumerate(queued)))
+    await _until(lambda: len(server._pending) == riders, "queued")
+    before = _left(server)
+    backend.gate.set()
+    await _until(lambda: (backend.on_host if on_host else backend.fetching)
+                 == 1, "the queued are out")
+    await _until(lambda: server._idle == len(plugs) - 1, "others asleep")
+    assert _grown(server, before) == {"drained": 1}
+    assert server._in_service == riders
+    assert [hold.fetching for hold in server._part_full] == [not on_host]
+    return plugs + [queue], event
+
+
+def _frames(keys, signers, first_id, sizes, tag):
+    """One read's worth of VERIFY frames, ids from ``first_id``."""
+    return b"".join(
+        _verify_frame(first_id + i, keys, _indexed(n, signers, tag + b"%d" % i))
+        for i, n in enumerate(sizes))
+
+
+def test_as_many_requests_as_are_out_in_their_fetch_leave_at_once_as_overlapped(
+        tmp_path, signers):
+    """A part-full launch of five requests has said it is in its fetch and
+    stays there; the service calibrated a full launch to 5 s.  Two requests
+    handed over behind it stay pending, a slot watching the hold: fewer
+    than are out would only make launches smaller.  With three more they
+    are as many as the launch carries and leave at once on a slot that
+    slept, together, counted as ``overlapped``, and are answered while the
+    first launch is still out; it lands when its fetch ends, with its own
+    replies."""
+    keys = [s.public_key.bytes for s in signers]
+    backend = SignallingBackend()
+
+    async def scenario(server):
+        warm = await asyncio.to_thread(_RawConn, server, keys)
+        warm.close()
+        server._calibration = (5.0, 0.0)
+        others, landing = await _a_launch_in_its_fetch(
+            server, backend, keys, signers)
+        before, launched = _left(server), len(backend.sizes)
+        conn, wire = _by_hand(server)
+        conn.data_received(_frames(keys, signers, 0, (3, 4), b"few"))
+        await asyncio.sleep(0.1)
+        assert len(server._pending) == 2 and server._watching == 1
+        assert len(backend.sizes) == launched
+        conn.data_received(_frames(keys, signers, 2, (1, 2, 3), b"more"))
+        await _until(lambda: len(wire.results()) == 5, "answered at once")
+        assert wire.results() == [
+            (i, [1] * n) for i, n in enumerate((3, 4, 1, 2, 3))]
+        assert backend.sizes[launched:] == [13] and len(wire.writes) == 1
+        assert _grown(server, before) == {"overlapped": 1}
+        # The first is still out, in its fetch.
+        assert backend.fetching == 1 and server._in_service == 5
+        assert [hold.fetching for hold in server._part_full] == [True]
+        landing.set()
+        for i in range(5):
+            assert await asyncio.to_thread(others[-1].read) == (i, [1, 1])
+        await _until(lambda: server._part_full == [], "landed")
+        assert _grown(server, before) == {"overlapped": 1}
+        for other in others:
+            other.close()
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
+
+
+def test_fewer_requests_than_are_out_in_their_fetch_wait_for_the_landing(
+        tmp_path, signers):
+    """Four requests behind a launch of five that is in its fetch stay
+    pending until it lands; the slot that lands it takes them itself, and
+    they count as ``drained``: nothing was out when they left."""
+    keys = [s.public_key.bytes for s in signers]
+    backend = SignallingBackend()
+
+    async def scenario(server):
+        warm = await asyncio.to_thread(_RawConn, server, keys)
+        warm.close()
+        server._calibration = (5.0, 0.0)
+        others, landing = await _a_launch_in_its_fetch(
+            server, backend, keys, signers)
+        before, launched = _left(server), len(backend.sizes)
+        conn, wire = _by_hand(server)
+        conn.data_received(_frames(keys, signers, 0, (1, 2, 3, 4), b"four"))
+        await asyncio.sleep(0.1)
+        assert len(server._pending) == 4 and not wire.writes
+        assert len(backend.sizes) == launched
+        landing.set()
+        await _until(lambda: len(wire.results()) == 4, "answered")
+        assert backend.sizes[launched:] == [10]
+        assert _grown(server, before) == {"drained": 1}
+        for other in others:
+            other.close()
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
+
+
+def test_a_launch_that_has_not_said_it_is_in_its_fetch_still_holds_what_arrives(
+        tmp_path, signers):
+    """The same launch still on its host path: as many requests as it
+    carries stay pending behind it, a slot watching the hold, until the
+    backend says the launch is in its fetch — then they leave at once, as
+    ``overlapped``, the first launch still out."""
+    keys = [s.public_key.bytes for s in signers]
+    backend = SignallingBackend()
+
+    async def scenario(server):
+        warm = await asyncio.to_thread(_RawConn, server, keys)
+        warm.close()
+        server._calibration = (5.0, 0.0)
+        landing = _blocked(backend.fetch, _indexed(2, signers, b"first0"))
+        others, packed = await _a_launch_in_its_fetch(
+            server, backend, keys, signers, on_host=True)
+        before, launched = _left(server), len(backend.sizes)
+        conn, wire = _by_hand(server)
+        conn.data_received(_frames(keys, signers, 0, (1, 2, 3, 4, 5), b"held"))
+        await asyncio.sleep(0.1)
+        assert len(server._pending) == 5 and server._watching == 1
+        assert len(backend.sizes) == launched
+        assert [hold.fetching for hold in server._part_full] == [False]
+        packed.set()  # its host path is over
+        await _until(lambda: len(wire.results()) == 5, "answered")
+        assert backend.sizes[launched:] == [15]
+        assert _grown(server, before) == {"overlapped": 1}
+        assert backend.fetching == 1 and len(server._part_full) == 1
+        landing.set()
+        for i in range(5):
+            assert await asyncio.to_thread(others[-1].read) == (i, [1, 1])
+        for other in others:
+            other.close()
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
+
+
+def test_host_paths_never_overlap_and_the_slots_bound_the_launches_out(
+        tmp_path, signers):
+    """With a launch of four in its fetch a second of four leaves and
+    stays on its host path: eight more requests — as many as both carry —
+    wait for the second to say it is in its fetch, not for the first to
+    land.  Then all three are out, one a slot, and what is handed over
+    next waits for a landing: the slot that lands takes it itself."""
+    keys = [s.public_key.bytes for s in signers]
+    backend = SignallingBackend()
+    assert VerifierServer.DISPATCHERS == 3
+
+    async def scenario(server):
+        warm = await asyncio.to_thread(_RawConn, server, keys)
+        warm.close()
+        server._calibration = (5.0, 0.0)
+        others, first_lands = await _a_launch_in_its_fetch(
+            server, backend, keys, signers, riders=4)
+        before, launched = _left(server), len(backend.sizes)
+        (one, wire_one), (two, wire_two), (three, wire_three) = (
+            _by_hand(server), _by_hand(server), _by_hand(server))
+        second = _indexed(1, signers, b"second0")
+        second_packed = _blocked(backend.host, second)
+        second_lands = _blocked(backend.fetch, second)
+        one.data_received(_frames(keys, signers, 0, (1, 1, 1, 1), b"second"))
+        await _until(lambda: backend.on_host == 1, "the second is out")
+        assert _grown(server, before) == {"overlapped": 1}
+        third_lands = _blocked(backend.fetch, _indexed(2, signers, b"third0"))
+        two.data_received(_frames(keys, signers, 0, (2,) * 8, b"third"))
+        await asyncio.sleep(0.1)
+        # Held by the second's host path, though a slot sleeps.
+        assert len(server._pending) == 8 and server._idle == 1
+        assert backend.sizes[launched:] == [4]
+        assert [hold.fetching for hold in server._part_full] == [True, False]
+        second_packed.set()
+        await _until(lambda: backend.fetching == 3, "three in their fetch")
+        assert backend.sizes[launched:] == [4, 16]
+        assert [hold.riders for hold in server._part_full] == [4, 4, 8]
+        assert server._idle == 0
+        # Every slot is out: what comes next waits for a landing.
+        three.data_received(_frames(keys, signers, 0, (5,), b"fourth"))
+        await asyncio.sleep(0.1)
+        assert [item.n for item in server._pending] == [5]
+        first_lands.set()
+        for i in range(4):
+            assert await asyncio.to_thread(others[-1].read) == (i, [1, 1])
+        assert not wire_three.writes  # one request: fewer than are out
+        second_lands.set()
+        third_lands.set()
+        await _until(lambda: len(wire_three.results()) == 1, "all answered")
+        assert wire_one.results() == [(i, [1]) for i in range(4)]
+        assert wire_two.results() == [(i, [1, 1]) for i in range(8)]
+        assert wire_three.results() == [(0, [1] * 5)]
+        grown = _grown(server, before)
+        assert grown.pop("overlapped") == 2
+        assert grown in ({"drained": 1}, {"alone": 1})
+        await _until(lambda: server._part_full == [], "all landed")
+        assert not server._pending
+        for other in others:
+            other.close()
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
+
+
+def test_a_launch_that_raises_in_its_fetch_fails_its_own_requests_alone(
+        tmp_path, signers):
+    """Two launches out in their fetch; the second raises there.  Its two
+    connections are closed and nothing else: the first launch's replies
+    come when it lands, nothing is left counted as out, and the next
+    request is served."""
+    keys = [s.public_key.bytes for s in signers]
+    backend = SignallingBackend()
+
+    async def scenario(server):
+        warm = await asyncio.to_thread(_RawConn, server, keys)
+        warm.close()
+        server._calibration = (5.0, 0.0)
+        others, first_lands = await _a_launch_in_its_fetch(
+            server, backend, keys, signers)
+        before = _left(server)
+        (one, wire_one), (two, wire_two) = _by_hand(server), _by_hand(server)
+        poisoned = _indexed(2, signers, b"poison0")
+        backend.poison.add(poisoned[1][1])
+        second_lands = _blocked(backend.fetch, poisoned)
+        # One turn of the loop: five requests, as many as are out.
+        one.data_received(_frames(keys, signers, 0, (3, 1, 1), b"beside"))
+        two.data_received(_frames(keys, signers, 0, (2, 1), b"poison"))
+        await _until(lambda: backend.fetching == 2, "both in their fetch")
+        assert _grown(server, before) == {"overlapped": 1}
+        assert 8 in backend.sizes and len(server._part_full) == 2
+        second_lands.set()
+        await _until(lambda: wire_one.closed and wire_two.closed,
+                     "its connections are closed")
+        assert not wire_one.writes and not wire_two.writes
+        assert [hold.riders for hold in server._part_full] == [5]
+        first_lands.set()
+        for i in range(5):
+            assert await asyncio.to_thread(others[-1].read) == (i, [1, 1])
+        await _until(lambda: server._part_full == [], "landed")
+        assert [t.is_alive() for t in server._dispatchers] == [True] * 3
+        before = _left(server)
+        others[-1].send(_verify_frame(9, keys, _indexed(2, signers, b"on")))
+        assert await asyncio.to_thread(others[-1].read) == (9, [1, 1])
+        assert _grown(server, before) in ({"drained": 1}, {"alone": 1})
+        for other in others:
+            other.close()
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
+
+
+def test_replies_keep_request_order_when_the_later_launch_lands_first(
+        tmp_path, signers):
+    """One connection's first four requests ride a launch that stays in
+    its fetch, its next four the launch after it, which lands at once:
+    nothing is written until the first launch lands, then all eight in
+    request order."""
+    import select
+
+    keys = [s.public_key.bytes for s in signers]
+    backend = SignallingBackend()
+
+    async def scenario(server):
+        warm = await asyncio.to_thread(_RawConn, server, keys)
+        warm.close()
+        server._calibration = (5.0, 0.0)
+        others, first_lands = await _a_launch_in_its_fetch(
+            server, backend, keys, signers, riders=4)
+        queue, before = others[-1], _left(server)
+        landed = server.counts.launches
+        queue.send(_frames(keys, signers, 4, (3, 3, 3, 3), b"later"))
+        await _until(lambda: server.counts.launches == landed + 1,
+                     "the later launch landed")
+        assert _grown(server, before) == {"overlapped": 1}
+        assert backend.fetching == 1  # the first is still out
+        readable, _, _ = await asyncio.to_thread(
+            select.select, [queue.sock], [], [], 0.1)
+        assert not readable
+        first_lands.set()
+        for i in range(4):
+            assert await asyncio.to_thread(queue.read) == (i, [1, 1])
+        for i in range(4, 8):
+            assert await asyncio.to_thread(queue.read) == (i, [1] * 3)
+        for other in others:
+            other.close()
+
+    asyncio.run(_with_server(tmp_path, keys, backend, scenario))
+
+
+def test_stop_with_launches_overlapped_closes_cleanly(tmp_path, signers):
+    """``stop()`` with one launch in its fetch, a second on its host path
+    and a request held behind that: the held one is let go unlaunched, the
+    second says it is in its fetch into a service that is stopping and
+    wakes nobody, both land, every dispatcher ends, the gauges come back."""
+    from mysticeti_tpu.metrics import Metrics
+
+    keys = [s.public_key.bytes for s in signers]
+    backend = SignallingBackend()
+    metrics = Metrics()
+
+    async def scenario():
+        server = VerifierServer(
+            str(tmp_path / "verifier.sock"), committee_keys=keys,
+            backend=backend, metrics=metrics,
+        )
+        await server.start()
+        warm = await asyncio.to_thread(_RawConn, server, keys)
+        warm.close()
+        server._calibration = (30.0, 0.0)
+        others, first_lands = await _a_launch_in_its_fetch(
+            server, backend, keys, signers)
+        second_packed = _blocked(
+            backend.host, _indexed(1, signers, b"second0"))
+        held = await asyncio.to_thread(_RawConn, server, keys)
+        held.send(_frames(keys, signers, 0, (1,) * 5, b"second"))
+        await _until(lambda: backend.on_host == 1, "the second is out")
+        launched = len(backend.sizes)
+        held.send(_verify_frame(5, keys, _indexed(2, signers, b"held")))
+        await _until(lambda: len(server._pending) == 1, "one held")
+        await _until(lambda: server._watching == 1, "a slot watches the hold")
+        depth = metrics.verifier_service_queue_depth._value.get
+        assert depth() == 5 + 6
+        started = time.monotonic()
+        stopping = asyncio.ensure_future(server.stop())
+        await _until(lambda: not server._pending, "stop() let it go")
+        second_packed.set()
+        first_lands.set()
+        await asyncio.wait_for(stopping, 20)
+        assert await asyncio.to_thread(held.read) is None
+        for thread in server._dispatchers:
+            await asyncio.to_thread(thread.join, 5)
+            assert not thread.is_alive()
+        assert time.monotonic() - started < 5  # nobody slept the hold out
+        assert len(backend.sizes) == launched  # the held: not launched
+        assert server._part_full == []
+        assert depth() == 0
+        for conn in others + [held]:
+            conn.close()
+
+    asyncio.run(scenario())
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,11 @@ so the sandbox sizes the host's packing alone (a launch of the XLA form
 takes 0.8 s on a CPU); with ``MYSTICETI_VERIFY_BACKEND=pallas`` that
 includes the decision whether a launch takes the keyed kernel.  Each shape
 prints how many of its launches kept their key indices (``direct``) and
-went into the keyed grouping (``keyed_tried``), on a tree that counts them.
+went into the keyed grouping (``keyed_tried``), on a tree that counts them,
+and how many said that they enter their fetch (``spans.request_fetch``: where
+the service ends a part-full launch's hold and counts the next launch as
+``left_overlapped``, PR 47) with the milliseconds of the launch before that —
+its host path, which is what the next launch still waits for.
 ``--late-fetch`` takes the early request for the result's copy to the host
 (``ops.ed25519._fetch_early``) out again, to read it alone.  Runs on any
 tree that has ``_verify_batch`` (``PYTHONPATH=<tree>``).
@@ -112,6 +116,13 @@ def main() -> None:
         now_in[0], now_in[1] = name, now
 
     spans.request_stage = stage
+    on_fetch = getattr(spans, "on_fetch", None)  # a tree before PR 47: None
+    said = [0, 0.0, 0.0]  # launches that said so, seconds before, the start
+
+    def enters_fetch():
+        said[0] += 1
+        said[1] += time.perf_counter() - said[2]
+
     for label, sizes, indexed, pool in SHAPES:
         batch = [
             VS._Pending(VS.T_VERIFY if indexed else VS.T_RAW, i + 1, n,
@@ -123,9 +134,13 @@ def main() -> None:
         spent.clear()
         roads = getattr(server._backend, "road_counts", lambda: None)
         roads_before = roads()
+        said[:2] = 0, 0.0
         started = time.perf_counter()
         for _ in range(args.launches):
             stage("service_unpack")
+            if on_fetch is not None:
+                said[2] = now_in[1]
+                on_fetch(enters_fetch)
             server._verify_batch(batch)
             stage(None)
         whole = (time.perf_counter() - started) / args.launches
@@ -136,6 +151,9 @@ def main() -> None:
             print("    direct %d keyed_tried %d of %d launches" % (
                 *(now - was for now, was in zip(roads(), roads_before)),
                 args.launches), flush=True)
+        if said[0]:
+            print("    entered its fetch %d of %d launches, %.3f ms in" % (
+                said[0], args.launches, 1e3 * said[1] / said[0]), flush=True)
     print("dispatches", E.dispatch_counts())
 
 

@@ -11,15 +11,20 @@ in flight each — all four down the client's one shared connection, as
 launch of the stub is ``--launch-cpu-ms`` of Python that holds the GIL (what
 packing and the jitted call cost the interpreter; 0.5 shows how many
 requests a launch gets when launches compete with the loop) and then
-``--launch-ms`` of sleep (the fetch).  What is left is the plumbing between
+``--launch-ms`` of sleep (the fetch); between the two it says that it enters
+its fetch, as the JAX backend does (``spans.request_fetch``: a part-full
+launch's hold ends there, PR 47), unless ``--silent`` — the same tree both
+ways, or a tree before PR 47.  What is left is the plumbing between
 the socket and the launch, both ways: the loop's own CPU and the process's
 CPU a request, the requests a second and a launch, and — where the tree
 counts them — the launches by why the coalescer let them leave
 (``VerifierServer._take``: the stub is calibrated like any backend, so a
-part-full launch holds what arrives behind it) and how many frames a read
-and replies a write carried.
+part-full launch holds what arrives behind it; ``overlapped``: it left while
+another was out in its fetch) and how many frames a read and replies a write
+carried.
 
     python3 tools/loop_probe.py --launch-cpu-ms 0.5        # this tree
+    python3 tools/loop_probe.py --launch-cpu-ms 0.5 --silent   # hold to landing
     PYTHONPATH=<other tree> python3 tools/loop_probe.py    # another one
     chiprun -- python3 tools/loop_probe.py                 # the chip's host
 
@@ -75,11 +80,14 @@ def client(path: str, depth: int, signatures: int, seconds: float) -> None:
 class Stub:
     """A backend whose launch is ``cpu_s`` of Python that holds the GIL
     (what packing and the jitted call cost the interpreter) and then a
-    sleep of ``launch_s`` with the GIL free (the fetch)."""
+    sleep of ``launch_s`` with the GIL free (the fetch); ``enters_fetch``
+    is called between the two (None: a silent stub)."""
 
-    def __init__(self, launch_s: float, cpu_s: float = 0.0) -> None:
+    def __init__(self, launch_s: float, cpu_s: float = 0.0,
+                 enters_fetch=None) -> None:
         self.launch_s = launch_s
         self.cpu_s = cpu_s
+        self.enters_fetch = enters_fetch
 
     def warmup(self) -> None:
         pass
@@ -94,18 +102,25 @@ class Stub:
         spin_until = time.perf_counter() + self.cpu_s
         while time.perf_counter() < spin_until:
             pass
+        if self.enters_fetch is not None:
+            self.enters_fetch()
         time.sleep(self.launch_s)
         return [True] * len(signatures)
 
 
 async def serve(args) -> dict:
+    from mysticeti_tpu import spans
     from mysticeti_tpu.metrics import Metrics
     from mysticeti_tpu.verifier_service import VerifierServer
 
+    # A tree before PR 47 has nothing to tell: every stub is silent there.
+    enters_fetch = (
+        None if args.silent else getattr(spans, "request_fetch", None))
     path = os.path.join(tempfile.mkdtemp(prefix="loop_probe"), "v.sock")
-    server = VerifierServer(path, committee_keys=KEYS, metrics=Metrics(),
-                            backend=Stub(args.launch_ms / 1e3,
-                                         args.launch_cpu_ms / 1e3))
+    server = VerifierServer(
+        path, committee_keys=KEYS, metrics=Metrics(),
+        backend=Stub(args.launch_ms / 1e3, args.launch_cpu_ms / 1e3,
+                     enters_fetch))
     await server.start()
     await asyncio.get_running_loop().run_in_executor(None, server.prewarm)
     clients = [
@@ -140,6 +155,7 @@ async def serve(args) -> dict:
         "process_cpu_us_per_request": round(
             1e6 * (process1 - process0) / requests, 2),
         "requests_per_launch": round(requests / (launches1 - launches0), 2),
+        "launches_s": round((launches1 - launches0) / (t1 - t0), 1),
     }
     if left0:  # why the coalescer let them leave (``VerifierServer._take``)
         out["launches_left"] = {
@@ -159,6 +175,8 @@ def main() -> None:
     parser.add_argument("--signatures", type=int, default=8)
     parser.add_argument("--launch-ms", type=float, default=1.2)
     parser.add_argument("--launch-cpu-ms", type=float, default=0.0)
+    parser.add_argument("--silent", action="store_true",
+                        help="the stub never says that it enters its fetch")
     parser.add_argument("--seconds", type=float, default=5.0)
     args = parser.parse_args()
     if args.client:
