@@ -5,7 +5,7 @@ Capability parity with ``mysticeti-core/src/block_handler.rs``:
 * ``BlockHandler`` interface {handle_blocks, handle_proposal, state, recover_state,
   cleanup} (block_handler.rs:26-40)
 * ``BenchmarkFastPathBlockHandler`` (:53-221) — pulls generated transactions from a
-  queue (bounded by SOFT_MAX_PROPOSED_PER_BLOCK), registers own shares, tallies
+  queue (bounded by MAX_PROPOSED_PER_BLOCK), registers own shares, tallies
   fast-path votes via TransactionAggregator, emits VoteRange replies, records
   certification latency metrics.
 * ``TestBlockHandler`` (:224-333) — votes immediately and emits one fresh
@@ -34,34 +34,10 @@ from .types import (
     TransactionLocator,
 )
 
+# A block's cap on transactions (block_handler.rs SOFT_MAX_PROPOSED_PER_BLOCK
+# and MAX_PROPOSED_PER_BLOCK in one): a proposal drains up to it; the ingress
+# plane's ``max_per_proposal`` is the lower cap a configuration sets.
 MAX_PROPOSED_PER_BLOCK = 10000
-
-
-def _soft_max_from_env() -> int:
-    raw = os.environ.get("MYSTICETI_MAX_BLOCK_TX")
-    if raw is None:
-        return MAX_PROPOSED_PER_BLOCK
-    try:
-        value = int(raw)
-    except ValueError:
-        raise ValueError(
-            f"MYSTICETI_MAX_BLOCK_TX must be an integer, got {raw!r}"
-        ) from None
-    if not 1 <= value <= MAX_PROPOSED_PER_BLOCK:
-        raise ValueError(
-            f"MYSTICETI_MAX_BLOCK_TX={value} out of range [1,"
-            f" {MAX_PROPOSED_PER_BLOCK}] (the block_handler.rs SOFT_MAX regime"
-            " caps proposals at the hard per-block maximum)"
-        )
-    return value
-
-
-# Proposal drain cap (block_handler.rs SOFT_MAX equivalent).  Env-tunable:
-# shrinking it raises the block rate at a given load, which reproduces the
-# per-node block-arrival (and therefore signature-verification) rate of a
-# large WAN committee on a small local fleet — the verification-bound regime
-# of BASELINE configs #4/#5.
-SOFT_MAX_PROPOSED_PER_BLOCK = _soft_max_from_env()
 
 
 class BlockHandler:
@@ -201,7 +177,7 @@ class BenchmarkFastPathBlockHandler(BlockHandler):
         return None
 
     def _proposal_budget(self) -> int:
-        cap = SOFT_MAX_PROPOSED_PER_BLOCK
+        cap = MAX_PROPOSED_PER_BLOCK
         if self.ingress is not None and self.ingress.max_per_proposal:
             cap = min(
                 max(1, self.ingress.max_per_proposal), MAX_PROPOSED_PER_BLOCK

@@ -108,11 +108,8 @@ async def run_node(
     from . import spans
     from .profiling import start_from_env, stop_from_env
 
-    # MYSTICETI_PROFILE=<path>.folded: lifetime flamegraph, now fed through
-    # the per-subsystem accountant (profiling.py); MYSTICETI_PERF_REPORT=
-    # <path>.json additionally writes the node's attribution report
-    # (per-subsystem CPU seconds, GIL convoy ratio) at shutdown — the input
-    # tools/perf_attr.py aggregates into the PERF_ATTR artifact.
+    # MYSTICETI_PROFILE=<path>.folded: lifetime flamegraph, fed through the
+    # per-subsystem accountant (profiling.py).
     start_from_env()
     # MYSTICETI_TRACE=<path>.json: per-block pipeline spans, exported as
     # Chrome trace-event JSON (Perfetto-loadable) at shutdown, with periodic

@@ -255,12 +255,7 @@ class Parameters:
 
     @classmethod
     def new_for_benchmarks(cls, ips: List[str]) -> "Parameters":
-        """Benchmark defaults mirroring Parameters::new_for_benchmarks (config.rs:57-72).
-
-        ``MYSTICETI_RETAIN_ROUNDS`` (genesis-time env) overrides the store
-        retain window: crash-recovery experiments need peers to retain the
-        whole downtime's worth of rounds or the rebooted node cannot fetch
-        its backlog (the default 500 rounds is seconds at saturation)."""
+        """Benchmark defaults mirroring Parameters::new_for_benchmarks (config.rs:57-72)."""
         identifiers = [
             Identifier(
                 hostname=ip,
@@ -269,16 +264,7 @@ class Parameters:
             )
             for i, ip in enumerate(ips)
         ]
-        overrides = {}
-        retain = int(os.environ.get("MYSTICETI_RETAIN_ROUNDS", "0") or 0)
-        if retain > 0:
-            overrides["store_retain_rounds"] = retain
-        # Local fleets don't need the 2 s WAN leader timeout; fault benches
-        # override it so a crashed leader's slots cost ms, not seconds.
-        timeout = float(os.environ.get("MYSTICETI_LEADER_TIMEOUT", "0") or 0)
-        if timeout > 0:
-            overrides["leader_timeout_s"] = timeout
-        return cls(identifiers=identifiers, **overrides)
+        return cls(identifiers=identifiers)
 
     def _check_link_delays(self) -> None:
         table = self.link_delay_ms

@@ -29,7 +29,7 @@ digests of the scheduler's behavior:
 
 ``tools/detsan.py`` drives all three against a seeded multi-node chaos
 sim (clean baseline must be byte-identical; a planted wall-clock leak
-must be bisected) and emits the ``DETSAN_*.json`` trend artifact.
+must be bisected) and emits its verdicts as one JSON document.
 """
 from __future__ import annotations
 

@@ -1,11 +1,10 @@
 """Overload-resilient ingress plane: gateway, admission-controlled mempool,
 graceful degradation under saturation.
 
-The MAXLOAD artifacts show why ingress policy matters: committed throughput
-*collapses* past saturation (r4: 40.3k committed at 57.6k offered) because
-transactions entered through ``BenchmarkFastPathBlockHandler.submit`` into an
-UNBOUNDED queue with nothing but the per-block SOFT_MAX drain cap — no dedup,
-no fairness, no shedding, and no backpressure signal from the core.  This
+Why ingress policy matters: committed throughput *collapses* past saturation
+when transactions enter through ``BenchmarkFastPathBlockHandler.submit`` into
+an UNBOUNDED queue with nothing but the per-block drain cap — no dedup, no
+fairness, no shedding, and no backpressure signal from the core.  This
 module is the real ingress plane (the ACE-runtime split between an admission
 edge and a finality core):
 

@@ -47,7 +47,6 @@ from .tracing import logger
 from .utils.tasks import spawn_logged
 
 log = logger(__name__)
-from .network import mesh_legacy
 from .synchronizer import (
     BlockDisseminator,
     BlockFetcher,
@@ -192,9 +191,8 @@ class NetworkSyncer:
         self._disseminators: Dict[int, BlockDisseminator] = {}
         # Encode-once fan-out (synchronizer.FrameCache): one shared cache
         # across every peer's disseminator, so N-1 subscribers at the same
-        # cursor ship one serialization.  MYSTICETI_MESH_LEGACY=1 restores
-        # the per-peer build path (the A/B baseline).
-        self.frame_cache = None if mesh_legacy() else FrameCache(metrics)
+        # cursor ship one serialization.
+        self.frame_cache = FrameCache(metrics)
         # Helper-stream bookkeeping (requester side; armed by the
         # disseminate_others_blocks knob): which connected peers relay which
         # unreachable authority's blocks for us, within the config caps.

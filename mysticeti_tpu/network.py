@@ -39,18 +39,12 @@ Broadcast-once data plane (endpoint-local; on-wire bytes unchanged):
   frames surface as memoryviews, ``decode_message`` makes block payloads
   sub-views, and ``StatementBlock.from_bytes`` materializes exactly one
   ``bytes`` per block for the canonical cache.
-
-``MYSTICETI_MESH_LEGACY=1`` forces the pre-r10 path (per-peer encode,
-per-frame write+drain, StreamReader receive) — the A/B baseline for
-``tools/mesh_ab.py`` and a safety valve; both endpoints interoperate either
-way because the frames are byte-identical.
 """
 from __future__ import annotations
 
 import asyncio
 import collections
 import dataclasses
-import os
 import random
 import sys
 import time
@@ -73,13 +67,6 @@ PING_INTERVAL_S = 30.0
 # buffering the whole queue.
 MAX_COALESCE_BYTES = 1 << 20
 
-
-def mesh_legacy() -> bool:
-    """True when ``MYSTICETI_MESH_LEGACY=1``: run the pre-broadcast-once
-    data plane (per-peer encode, per-frame write, stream receive).  Read
-    per connection setup, not cached — tests and the A/B harness flip it
-    between runs in one process."""
-    return os.environ.get("MYSTICETI_MESH_LEGACY", "") == "1"
 
 # A dial's connect, its hello and its ack each get this long.
 HANDSHAKE_TIMEOUT_S = 5.0
@@ -837,10 +824,8 @@ class _FrameReceiver(asyncio.BufferedProtocol):
         """Switch a handshaken stream connection to zero-copy reads.
 
         Returns None when the transport cannot be switched (mock streams in
-        tests, ``MYSTICETI_MESH_LEGACY=1``) — the caller falls back to the
-        ``_read_frame(reader)`` stream path, frame-for-frame compatible."""
-        if mesh_legacy():
-            return None
+        tests) — the caller falls back to the ``_read_frame(reader)`` stream
+        path, frame-for-frame compatible."""
         transport = getattr(writer, "transport", None)
         buffered = getattr(reader, "_buffer", None)
         if (
@@ -1233,11 +1218,10 @@ class TcpNetwork:
             delay_line=self._delay_line(peer),
         )
         await self.connections.put(conn)
-        legacy = mesh_legacy()
-        receiver = None if legacy else _FrameReceiver.attach(reader, writer)
+        receiver = _FrameReceiver.attach(reader, writer)
         metrics = self.metrics
         recv_bytes = sent_bytes = coalesced = None
-        if metrics is not None and not legacy:
+        if metrics is not None:
             recv_bytes = metrics.mesh_wire_bytes_total.labels("received")
             sent_bytes = metrics.mesh_wire_bytes_total.labels("sent")
             coalesced = metrics.mesh_frames_coalesced_total
@@ -1317,16 +1301,6 @@ class TcpNetwork:
             if conn.delay_line is not None:
                 await _held_write_loop(conn, writer, encode_timer,
                                        sent_bytes, coalesced)
-            if legacy:
-                # Pre-r10 path: one encode + one concat + one drain PER
-                # frame.  The encode timer runs here too so the A/B
-                # artifact can compare mesh encode CPU across modes.
-                while True:
-                    msg = await conn.sender.get()
-                    with encode_timer("net:mesh_encode"):
-                        payload = frame_payload(msg)
-                    _write_frame(writer, payload)
-                    await writer.drain()
             while True:
                 # Scatter-gather coalescing: drain the queue non-blocking
                 # and ship the batch as one writelines + ONE drain — the

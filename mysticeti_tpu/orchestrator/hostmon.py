@@ -60,8 +60,7 @@ class HostSampler:
         load_1m, load_5m, load_15m = os.getloadavg()
         # CPU steal: time another guest on the hypervisor took from us —
         # on a shared cloud box it explains loop-lag spikes no in-process
-        # attribution can (the GIL/host conditions a PERF_ATTR artifact
-        # was measured under).
+        # attribution can.
         steal = getattr(psutil.cpu_times_percent(None), "steal", None)
         return {
             "timestamp_s": time.time(),

@@ -22,8 +22,6 @@ Wire-up: ``MYSTICETI_PROFILE=/path/out.folded`` makes the node CLI sample
 for its whole lifetime and write the folded file at shutdown;
 ``python tools/mkflamegraph.py out.folded > flame.svg`` renders it and
 ``--diff base.folded new.folded`` renders an A/B flame diff.
-``MYSTICETI_PERF_REPORT=/path/report.json`` writes the deterministic
-attribution report at shutdown (tools/perf_attr.py consumes it).
 """
 from __future__ import annotations
 
@@ -685,32 +683,6 @@ def active_accountant() -> Optional[SubsystemAccountant]:
     return _active.accountant if _active is not None else None
 
 
-def write_report_from_env() -> Optional[str]:
-    """Write the attribution report when ``MYSTICETI_PERF_REPORT`` is set
-    (atomic, %p-expanded); returns the path written."""
-    path = os.environ.get("MYSTICETI_PERF_REPORT")
-    if not path or _active is None or _active.accountant is None:
-        return None
-    path = path.replace("%p", str(os.getpid()))
-    # The written file carries the native data-plane inventory alongside
-    # the attribution numbers (A/B harnesses record which path the node
-    # ran); report_bytes() itself stays environment-independent — the
-    # seeded census pin in tests/test_hostattr.py covers it, not this.
-    doc = json.loads(_active.accountant.report_bytes())
-    try:
-        from .native import active_functions
-
-        doc["native_active"] = list(active_functions())
-    except Exception:  # noqa: BLE001 - inventory is best-effort evidence
-        pass
-    tmp = f"{path}.tmp"
-    with open(tmp, "w") as f:
-        json.dump(doc, f, sort_keys=True, separators=(",", ":"))
-        f.write("\n")
-    os.replace(tmp, path)
-    return path
-
-
 def stop_from_env() -> None:
     global _active
     path = os.environ.get("MYSTICETI_PROFILE")
@@ -720,5 +692,4 @@ def stop_from_env() -> None:
     _active.stop()
     _active.write_folded(path)
     render_file(path)
-    write_report_from_env()
     _active = None

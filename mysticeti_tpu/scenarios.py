@@ -26,8 +26,7 @@ seeded runs:
   same-seed re-run is byte-identical.
 
 ``mysticeti-tpu scenarios`` runs one scenario or the whole matrix;
-``tools/scenario_matrix.py`` pins the matrix verdicts into the
-``SCENARIO_rNN.json`` artifact family consumed by ``tools/bench_trend.py``.
+``tools/scenario_matrix.py`` pins the matrix verdicts into one JSON document.
 """
 from __future__ import annotations
 
@@ -1101,7 +1100,7 @@ def run_reconfig_matrix(
     real_crypto: bool = False,
 ) -> dict:
     """Run the continuous-churn family and aggregate the RECONFIG artifact
-    document (tools/reconfig_matrix.py pins it into RECONFIG_rNN.json)."""
+    document (tools/reconfig_matrix.py writes it out)."""
     doc = run_matrix(
         scenarios if scenarios is not None else reconfig_matrix(),
         wal_root=wal_root,

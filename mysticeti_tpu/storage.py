@@ -153,7 +153,7 @@ class SegmentedWalWriter:
     """
 
     def __init__(self, directory: str, params: StorageParameters,
-                 async_writes: Optional[bool] = None) -> None:
+                 async_writes: bool = True) -> None:
         self._dir = directory
         self._params = params
         self._async = async_writes

@@ -137,8 +137,8 @@ class BlockDisseminator:
         self.parameters = parameters or SynchronizerParameters()
         self.metrics = metrics
         # Encode-once fan-out: shared across the node's disseminators by
-        # NetworkSyncer; None (direct construction, MYSTICETI_MESH_LEGACY)
-        # keeps the per-peer build path.
+        # NetworkSyncer; None only where a test builds the disseminator
+        # bare, and each call then builds its own frame.
         self.frame_cache = frame_cache
         self._stream_task: Optional[asyncio.Task] = None
         # Helper streams (synchronizer.rs:169-205, dormant in the reference;

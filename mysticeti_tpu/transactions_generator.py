@@ -9,7 +9,7 @@ Capability parity with ``mysticeti-core/src/transactions_generator.rs``:
   nonce; ``extract_timestamp`` recovers it for end-to-end latency metrics
   (:103-108)
 
-Ingress-plane additions (the OVERLOAD artifact's load clients):
+Ingress-plane additions (the overload scenario's load clients, ``ingress.py``):
 
 * **overload schedule** — ``overload_schedule=[(t_offset_s, multiplier),...]``
   scales the offered rate over the run (1x -> 5x ramps), so one generator can
@@ -46,8 +46,8 @@ RETRY_QUEUE_TICKS = 10
 
 
 def parse_overload_schedule(text: str) -> List[Tuple[float, float]]:
-    """Parse ``"0:1,30:3,60:5"`` (``t_offset_s:multiplier`` pairs) — the
-    ``MYSTICETI_OVERLOAD_SCHEDULE`` env format the node CLI accepts."""
+    """Parse ``"0:1,30:3,60:5"`` (``t_offset_s:multiplier`` pairs), the
+    format of the CLI's ``--schedule``."""
     schedule: List[Tuple[float, float]] = []
     for part in text.split(","):
         part = part.strip()
@@ -83,7 +83,7 @@ class TransactionGenerator:
         self.closed_loop = closed_loop
         self.metrics = metrics
         self._task: Optional[asyncio.Task] = None
-        # Offered-load accounting (the OVERLOAD artifact's client ledger).
+        # Offered-load accounting (the overload scenario's client ledger).
         self.submitted = 0
         self.accepted = 0
         self.shed_observed = 0

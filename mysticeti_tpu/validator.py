@@ -85,8 +85,8 @@ def _make_verifier(kind: str, committee: Committee, metrics=None):
     aggregate = kind.endswith("-agg")
     if aggregate:
         kind = kind[: -len("-agg")]
-    # Collection window (ms).  The same small default applies in aggregate
-    # mode: a wide window would pace round advance (verification sits on the
+    # The collector keeps its small default window in aggregate mode too: a
+    # wide window would pace round advance (verification sits on the
     # round-advance critical path), costing more cadence than the skips
     # recover at steady state.  Aggregation instead engages through
     # BACKPRESSURE — when the verifier lags the arrival rate (catch-up
@@ -94,16 +94,7 @@ def _make_verifier(kind: str, committee: Committee, metrics=None):
     # flushes span many rounds from every peer, and quorum-endorsed interiors
     # skip their dispatch: a self-relieving valve exactly where verification
     # binds, at zero steady-state cost.
-    window_ms = float(os.environ.get("MYSTICETI_VERIFY_WINDOW_MS", "5"))
-    # Staged dispatch pipeline depth (verify_pipeline.py): default adapts to
-    # the collector's measured dispatch latency; pin it for experiments.
-    depth_env = os.environ.get("MYSTICETI_VERIFY_PIPELINE_DEPTH")
-    collector_opts = dict(
-        metrics=metrics,
-        aggregate=aggregate,
-        max_delay_s=window_ms / 1e3,
-        pipeline_depth=int(depth_env) if depth_env else None,
-    )
+    collector_opts = dict(metrics=metrics, aggregate=aggregate)
     if kind in ("tpu", "tpu-only"):
         committee_keys = committee.public_key_bytes()
         if os.environ.get("MYSTICETI_VERIFIER_SOCKET"):
@@ -486,12 +477,6 @@ class Validator:
         core.committer.ledger.recorder = recorder
         block_verifier = _make_verifier(verifier, committee, v.metrics)
         block_verifier.stages = v._host_clock()
-        # Overload modes (tools/overload_bench.py drives these through the
-        # environment): an offered-load multiplier schedule and a closed
-        # loop that consumes the ingress plane's SHED/retry-after verdicts.
-        from .transactions_generator import parse_overload_schedule
-
-        schedule_env = os.environ.get("MYSTICETI_OVERLOAD_SCHEDULE")
         v.generator = TransactionGenerator(
             submit=handler.submit,
             seed=authority,
@@ -499,24 +484,12 @@ class Validator:
             transaction_size=transaction_size,
             initial_delay_s=float(os.environ.get("INITIAL_DELAY", "2")),
             ready=block_verifier.ready.is_set,
-            overload_schedule=(
-                parse_overload_schedule(schedule_env) if schedule_env else None
-            ),
-            closed_loop=(
-                os.environ.get("MYSTICETI_CLOSED_LOOP", "") == "1"
-                and plane is not None
-            ),
             # Client-observed finality: armed whenever the server-side
-            # tracker runs (or forced via MYSTICETI_CLIENT_FINALITY=1), with
-            # the same content-based sampling stride so both sides measure
-            # the same transactions.
+            # tracker runs, with the same content-based sampling stride so
+            # both sides measure the same transactions.
             finality_sample_every=(
                 parameters.ingress.finality_sample_every
-                if plane is not None
-                and (
-                    plane.finality is not None
-                    or os.environ.get("MYSTICETI_CLIENT_FINALITY", "") == "1"
-                )
+                if plane is not None and plane.finality is not None
                 else 0
             ),
             metrics=v.metrics,

@@ -156,7 +156,7 @@ class VerifyPipeline:
     def note_stage(self, stage: str, seconds: float) -> None:
         """One dispatch's seconds in one stage.  The shares of dispatch time
         (device-busy / host-pack / fetch-wait) are this histogram's sums over
-        their total: ``tools/perf_attr.py`` derives them from a scrape."""
+        their total."""
         if self.metrics is not None:
             self.metrics.verify_pipeline_stage_seconds.labels(stage).observe(
                 seconds

@@ -57,7 +57,7 @@ class WalError(IOError):
 
 
 def walf(
-    path: str, async_writes: Optional[bool] = None
+    path: str, async_writes: bool = True
 ) -> Tuple["WalWriter", "WalReader"]:
     """Open (creating if needed) the log at ``path`` (wal.rs:38-50).
 
@@ -100,11 +100,9 @@ class WalWriter:
     the 1 s syncer thread bounds the fsync loss window, and a crash
     truncates to a torn tail exactly as before (the queue preserves append
     order; the drain thread writes sequentially).
-    ``MYSTICETI_SYNC_WAL_WRITES=1`` restores fully synchronous appends.
-    A/B at 24k offered tx/s on a single-core host: identical throughput,
-    27% lower average commit latency with the writer thread (221 ms vs
-    304 ms) — write stalls leave the consensus critical path even when the
-    core itself stays busy.
+    ``async_writes=False`` appends synchronously (the simulator's and the
+    tests' seeded runs); with the writer thread, write stalls leave the
+    consensus critical path even when the core itself stays busy.
     """
 
     __slots__ = ("_fd", "_pos", "_path", "_closed", "_async", "_queue",
@@ -112,14 +110,12 @@ class WalWriter:
                  "stages")
 
     def __init__(self, fd: int, pos: int, path: str,
-                 async_writes: Optional[bool] = None) -> None:
+                 async_writes: bool = True) -> None:
         self._fd = fd
         self._pos = pos
         self._path = path
         self._closed = False
         os.lseek(fd, 0, os.SEEK_END)  # append after any recovered content
-        if async_writes is None:
-            async_writes = os.environ.get("MYSTICETI_SYNC_WAL_WRITES") != "1"
         self._async = async_writes
         self._error: Optional[BaseException] = None
         # The validator's stage clock (spans.StageClock; None = not

@@ -1,6 +1,4 @@
 """Block handler drain semantics (block_handler.rs SOFT_MAX regime)."""
-import pytest
-
 from mysticeti_tpu import block_handler as bh
 from mysticeti_tpu.committee import Committee
 from mysticeti_tpu.types import Share
@@ -16,7 +14,7 @@ def test_soft_max_slices_oversize_submissions(monkeypatch):
     admitted whole: the cap is a per-block transaction cap (block_handler.rs
     SOFT_MAX), and the generator's 100 ms chunks (tps/10 transactions) would
     otherwise blow past it on every proposal."""
-    monkeypatch.setattr(bh, "SOFT_MAX_PROPOSED_PER_BLOCK", 16)
+    monkeypatch.setattr(bh, "MAX_PROPOSED_PER_BLOCK", 16)
     h = _handler()
     h.submit([bytes([i % 256]) * 32 for i in range(100)])
     stmts = h.handle_blocks([], require_response=True)
@@ -29,28 +27,12 @@ def test_soft_max_slices_oversize_submissions(monkeypatch):
 
 
 def test_soft_max_exact_budget_not_sliced(monkeypatch):
-    monkeypatch.setattr(bh, "SOFT_MAX_PROPOSED_PER_BLOCK", 16)
+    monkeypatch.setattr(bh, "MAX_PROPOSED_PER_BLOCK", 16)
     h = _handler()
     h.submit([b"x" * 32 for _ in range(10)])
     h.submit([b"y" * 32 for _ in range(6)])
     stmts = h.handle_blocks([], require_response=True)
     assert len([s for s in stmts if isinstance(s, Share)]) == 16
-
-
-def test_env_cap_validation(monkeypatch):
-    monkeypatch.setenv("MYSTICETI_MAX_BLOCK_TX", "64")
-    assert bh._soft_max_from_env() == 64
-    monkeypatch.setenv("MYSTICETI_MAX_BLOCK_TX", "0")
-    with pytest.raises(ValueError, match="out of range"):
-        bh._soft_max_from_env()
-    monkeypatch.setenv("MYSTICETI_MAX_BLOCK_TX", "99999")
-    with pytest.raises(ValueError, match="out of range"):
-        bh._soft_max_from_env()
-    monkeypatch.setenv("MYSTICETI_MAX_BLOCK_TX", "lots")
-    with pytest.raises(ValueError, match="integer"):
-        bh._soft_max_from_env()
-    monkeypatch.delenv("MYSTICETI_MAX_BLOCK_TX")
-    assert bh._soft_max_from_env() == bh.MAX_PROPOSED_PER_BLOCK
 
 
 def test_log_range_matches_per_locator_format(tmp_path):

@@ -115,7 +115,7 @@ def test_everything_but_the_service_backend_stays_off_jax():
     it)."""
     proc = _python(
         "import sys\n"
-        "import chip_smoke, bench\n"
+        "import chip_smoke\n"
         "import mysticeti_tpu.cli, mysticeti_tpu.validator\n"
         "import mysticeti_tpu.orchestrator.runner\n"
         "import mysticeti_tpu.orchestrator.orchestrator\n"
@@ -140,13 +140,6 @@ def test_chip_smoke_on_the_cpu_fails_and_says_no_tpu(tmp_path):
     assert proc.returncode != 0
     assert "no TPU found" in proc.stderr
     assert '"ok"' not in proc.stdout  # no result line
-
-
-def test_bench_on_the_cpu_fails_instead_of_measuring_the_host():
-    proc = _python(["bench.py"], {"JAX_PLATFORMS": "cpu"})
-    assert proc.returncode != 0
-    assert "no accelerator" in proc.stderr
-    assert "ed25519_verifies_per_sec" not in proc.stdout
 
 
 def test_service_without_a_tpu_refuses_unless_the_cpu_was_named(tmp_path):

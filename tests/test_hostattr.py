@@ -77,7 +77,7 @@ def test_attribute_precedence():
         ("net_sync", "handle", True),
     ]) == "mesh-parse"
     assert attribute([("wal", "fsync", True)]) == "wal"
-    # Nothing recognizable -> other (and the perf_attr gate caps its share).
+    # Nothing recognizable -> other.
     assert attribute([("mystery", "f", False)]) == "other"
     assert attribute([]) == "other"
 
@@ -332,30 +332,6 @@ def test_loop_lag_probe_tells_a_listener_every_sample():
     row = clock.totals()["service_loop_lag"]
     assert row["count"] == probe.sample_count() >= 3
     assert row["wall_s"] >= 0.05 and row["cpu_s"] == 0.0
-
-
-def test_perf_attr_derives_the_occupancy_shares_from_the_stage_sums(
-        monkeypatch):
-    """No gauge carries the shares any more: they are each stage's part of
-    the ``verify_pipeline_stage_seconds`` sums of a scrape."""
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    import perf_attr
-
-    text = "\n".join([
-        'verify_pipeline_stage_seconds_sum{stage="pack"} 1.0',
-        'verify_pipeline_stage_seconds_sum{stage="device"} 1.0',
-        'verify_pipeline_stage_seconds_sum{stage="fetch"} 6.0',
-        'verify_pipeline_stage_seconds_count{stage="fetch"} 9',
-    ])
-    monkeypatch.setattr(
-        perf_attr, "_http_get",
-        lambda host, port, path, timeout=3.0:
-        text if path == "/metrics" else None)
-    scrape = perf_attr.scrape_node("127.0.0.1", 1)
-    assert scrape["occupancy"] == {"pack": 0.125, "device": 0.125,
-                                   "fetch": 0.75}
-    doc = perf_attr.aggregate({"n0": scrape}, {})
-    assert doc["device"]["occupancy_fractions"]["fetch"] == 0.75
 
 
 def test_host_monitor_state_shape():

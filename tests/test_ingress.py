@@ -317,7 +317,7 @@ def test_legacy_soft_cap_truncation_counts(monkeypatch):
     from mysticeti_tpu import block_handler as bh
     from mysticeti_tpu.committee import Committee
 
-    monkeypatch.setattr(bh, "SOFT_MAX_PROPOSED_PER_BLOCK", 10)
+    monkeypatch.setattr(bh, "MAX_PROPOSED_PER_BLOCK", 10)
     metrics = Metrics()
     handler = bh.BenchmarkFastPathBlockHandler(
         Committee.new_test([1] * 4), 0, metrics=metrics

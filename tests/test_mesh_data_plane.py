@@ -31,10 +31,10 @@ from mysticeti_tpu.network import (
     TcpNetwork,
     TimestampedBlocks,
     _FrameReceiver,
+    _read_frame,
     decode_message,
     encode_message,
     frame_payload,
-    mesh_legacy,
 )
 from mysticeti_tpu.types import BlockReference, Share, StatementBlock
 
@@ -474,21 +474,46 @@ def test_receive_buffer_shrinks_after_jumbo_frame():
     asyncio.run(main())
 
 
-def test_legacy_env_disables_new_plane(monkeypatch):
-    monkeypatch.setenv("MYSTICETI_MESH_LEGACY", "1")
-    assert mesh_legacy()
+class _UnswitchableWriter:
+    """A stream writer whose transport cannot change protocol (a mock
+    stream, a wrapper): all ``_FrameReceiver.attach`` looks at."""
+
+    transport = None
+
+
+def test_unswitchable_transport_falls_back_to_stream_frames():
+    """Where the transport cannot be switched ``attach`` returns None and
+    leaves the StreamReader whole: ``_read_frame`` then yields, frame for
+    frame, what a switched connection's receiver yields."""
 
     async def main():
-        # attach() refuses, so the stream fallback runs.
+        sent = [encode_message(msg) for msg, _hex in GOLDEN_CORPUS]
+        wire = [part for enc in sent
+                for part in (len(enc).to_bytes(4, "little"), enc)]
         server, c_reader, c_writer, s_reader, s_writer = await _socket_pair()
-        assert _FrameReceiver.attach(s_reader, s_writer) is None
+        assert _FrameReceiver.attach(s_reader, _UnswitchableWriter()) is None
+        c_writer.writelines(wire)
+        await c_writer.drain()
+        streamed = [
+            await asyncio.wait_for(_read_frame(s_reader), 5) for _ in sent
+        ]
         s_writer.close()
         c_writer.close()
         server.close()
 
+        server, c_reader, c_writer, s_reader, s_writer = await _socket_pair()
+        recv = _FrameReceiver.attach(s_reader, s_writer)
+        assert recv is not None
+        c_writer.writelines(wire)
+        await c_writer.drain()
+        switched = [
+            bytes(await asyncio.wait_for(recv.read_frame(), 5)) for _ in sent
+        ]
+        c_writer.close()
+        server.close()
+        assert streamed == switched == sent
+
     asyncio.run(main())
-    monkeypatch.delenv("MYSTICETI_MESH_LEGACY")
-    assert not mesh_legacy()
 
 
 # --- ingest batching audit -------------------------------------------------

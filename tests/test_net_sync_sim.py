@@ -282,11 +282,11 @@ def _hundred_nodes_scenario(tmp_path):
 @pytest.mark.skipif(
     not os.environ.get("MYSTICETI_BIG_SIMS"),
     reason="100-authority whole-stack sim: several minutes wall; run with "
-    "MYSTICETI_BIG_SIMS=1 (the driver artifact HUNDRED_r04.json pins it)",
+    "MYSTICETI_BIG_SIMS=1",
 )
 def test_hundred_nodes_commit(tmp_path):
-    """Driver-artifact entry point (HUNDRED_r0N.json pins MYSTICETI_BIG_SIMS
-    so the scenario also runs standalone in the fast tier on demand)."""
+    """MYSTICETI_BIG_SIMS=1 runs the scenario standalone in the fast tier
+    on demand."""
     _hundred_nodes_scenario(tmp_path)
 
 

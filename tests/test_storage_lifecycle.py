@@ -404,7 +404,7 @@ def test_snapshot_catchup_rejoins_and_commits_fleet_sequence(tmp_path):
     so block-by-block pull from round zero is impossible) rejoins via the
     snapshot stream, adopts the fleet's commit baseline, and commits the
     SAME leader sequence at every shared height.  (The >= 1000-round regime
-    rides in tools/storage_probe.py -> STORAGE_r08.json.)"""
+    rides in tools/storage_probe.py.)"""
     params = _params(snapshot_catchup=True, catchup_threshold_commits=50)
     plan = FaultPlan(
         seed=13, crashes=[CrashFault(node=3, at_s=3.0, downtime_s=30.0)]

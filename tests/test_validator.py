@@ -94,6 +94,11 @@ def test_make_verifier_kinds(monkeypatch, kind, backend, aggregate):
     # Warmup threads would trace/compile the kernel; wiring is what's tested.
     monkeypatch.setattr(bv.TpuSignatureVerifier, "warmup", lambda self: None)
     monkeypatch.delenv("MYSTICETI_VERIFIER_SOCKET", raising=False)
+    # The collector's window and the pipeline's depth are no longer read
+    # from the environment: a process started with the old names set runs
+    # as if they were not.
+    monkeypatch.setenv("MYSTICETI_VERIFY_WINDOW_MS", "50")
+    monkeypatch.setenv("MYSTICETI_VERIFY_PIPELINE_DEPTH", "1")
     committee = Committee.new_for_benchmarks(4)
 
     v = _make_verifier(kind, committee)
@@ -103,6 +108,8 @@ def test_make_verifier_kinds(monkeypatch, kind, backend, aggregate):
         return
     assert isinstance(v, bv.BatchedSignatureVerifier)
     assert v.aggregate is aggregate
+    assert v.max_delay_s == 0.005
+    assert v.pipeline.depth() >= v.pipeline.MIN_DEPTH
     assert type(v.verifier) is getattr(bv, backend)
     wraps_breaker = kind.startswith("tpu") and kind != "tpu-only"
     assert hasattr(v.verifier, "breaker_open") is wraps_breaker

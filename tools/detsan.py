@@ -3,7 +3,7 @@
 
 The dynamic half of the determinism plane (the static half is
 ``tools/lint.py``'s ``sim-taint`` rule).  One invocation produces the
-``DETSAN_rNN.json`` trend artifact by exercising every layer:
+``--out`` document by exercising every layer:
 
 1. **clean** — the seeded N-node chaos sim runs twice under a
    :class:`~mysticeti_tpu.detsan.DetsanRecorder`; the per-event digest
@@ -23,8 +23,7 @@ The dynamic half of the determinism plane (the static half is
 
 Usage:
     python tools/detsan.py                         # run, print verdicts
-    python tools/detsan.py --out DETSAN_r16.json   # also write the artifact
-    python tools/detsan.py --append-trend          # fold into BENCH_TREND.json
+    python tools/detsan.py --out detsan.json       # also write the document
     python tools/detsan.py --nodes 10 --duration 3 --seed 42
 
 Exit code 0 when every section passes, 1 otherwise.
@@ -195,8 +194,6 @@ def main(argv=None) -> int:
                         help="max stored trace events per run")
     parser.add_argument("--out", default=None,
                         help="write the DETSAN artifact JSON here")
-    parser.add_argument("--append-trend", action="store_true",
-                        help="fold the artifact into BENCH_TREND.json")
     args = parser.parse_args(argv)
 
     print(f"detsan: clean run-twice ({args.nodes} nodes, "
@@ -251,12 +248,6 @@ def main(argv=None) -> int:
             json.dump(artifact, f, indent=2)
             f.write("\n")
         print(f"detsan: artifact -> {os.path.relpath(path, _REPO_ROOT)}")
-
-    if args.append_trend:
-        sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-        import bench_trend
-
-        bench_trend.main(["--repo", _REPO_ROOT])
 
     print(f"detsan: {'PASS' if passed else 'FAIL'}")
     return 0 if passed else 1

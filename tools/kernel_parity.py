@@ -15,7 +15,7 @@ Covered paths:
 Case classes: valid, corrupted R, corrupted s, corrupted message, wrong key,
 non-canonical s (s+L), corrupted pk (table path: unknown-key fallback).
 
-Usage: python tools/kernel_parity.py --n 12288 --out KERNEL_PARITY_r04.json
+Usage: python tools/kernel_parity.py --n 12288 --out kernel_parity.json
 """
 from __future__ import annotations
 

@@ -1,8 +1,7 @@
 #!/usr/bin/env python3
-"""Epoch-reconfiguration probe -> RECONFIG_r18.json.
+"""Epoch-reconfiguration probe -> reconfig_matrix.json.
 
-Three legs, pinned into the ``RECONFIG_rNN.json`` artifact family consumed
-by ``tools/bench_trend.py``:
+Three legs, pinned into one JSON document:
 
 * **continuous-churn matrix** — the reconfig scenario family
   (mysticeti_tpu/scenarios.py::reconfig_matrix): seeded 10-node sims with
@@ -21,7 +20,7 @@ by ``tools/bench_trend.py``:
 
 Usage::
 
-    python tools/reconfig_matrix.py [--out RECONFIG_r18.json] [--quick]
+    python tools/reconfig_matrix.py [--out reconfig_matrix.json] [--quick]
 """
 from __future__ import annotations
 
@@ -171,7 +170,7 @@ def live_leg(tps: int) -> dict:
 
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    parser.add_argument("--out", default="RECONFIG_r18.json")
+    parser.add_argument("--out", default="reconfig_matrix.json")
     parser.add_argument("--quick", action="store_true",
                         help="shortened scenarios (smoke, not acceptance: "
                         "short runs may not reach every min_epoch gate)")

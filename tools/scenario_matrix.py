@@ -1,10 +1,9 @@
 #!/usr/bin/env python3
-"""Resilience scenario-matrix probe -> SCENARIO_r12.json.
+"""Resilience scenario-matrix probe -> scenario_matrix.json.
 
 Runs the declarative Byzantine scenario matrix (mysticeti_tpu/scenarios.py)
 — every entry an attacked seeded sim plus a same-seed clean twin — and pins
-the per-scenario verdicts into the ``SCENARIO_rNN.json`` artifact family
-consumed by ``tools/bench_trend.py``:
+the per-scenario verdicts into one JSON document:
 
 * **safety** — zero honest-node SafetyChecker violations per scenario;
 * **liveness** — honest-authored committed throughput >= the scenario's
@@ -21,7 +20,7 @@ the claim.
 
 Usage::
 
-    python tools/scenario_matrix.py [--out SCENARIO_r12.json] [--quick]
+    python tools/scenario_matrix.py [--out scenario_matrix.json] [--quick]
 """
 from __future__ import annotations
 
@@ -63,7 +62,7 @@ def determinism_leg(name: str, quick: bool) -> dict:
 
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    parser.add_argument("--out", default="SCENARIO_r12.json")
+    parser.add_argument("--out", default="scenario_matrix.json")
     parser.add_argument("--quick", action="store_true",
                         help="shortened scenarios (smoke, not acceptance)")
     parser.add_argument("--scenario", default=None,

@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Storage lifecycle acceptance probe -> STORAGE_r08.json.
+"""Storage lifecycle acceptance probe -> storage_probe.json.
 
 Two deterministic sims on the virtual-time loop (no accelerator, no real
 network), exercising the storage plane end-to-end at the scale the
@@ -21,7 +21,7 @@ acceptance criteria name:
 
 Usage::
 
-    python tools/storage_probe.py [--out STORAGE_r08.json] [--quick]
+    python tools/storage_probe.py [--out storage_probe.json] [--quick]
 """
 from __future__ import annotations
 
@@ -193,7 +193,7 @@ def snapshot_catchup_scenario(quick: bool) -> dict:
 
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    parser.add_argument("--out", default="STORAGE_r08.json")
+    parser.add_argument("--out", default="storage_probe.json")
     parser.add_argument("--quick", action="store_true",
                         help="shortened scenarios (smoke, not acceptance)")
     args = parser.parse_args(argv)
